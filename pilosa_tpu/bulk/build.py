@@ -15,11 +15,12 @@ Three stages, shared by both lanes:
 3. **scatter** — OR each position's bit into its group's word plane.
 
 :func:`build_planes_numpy` is the host twin (vectorized lexsort +
-``bitwise_or.reduceat``); :func:`build_planes_jax` runs the
-sort/segment/scatter on device under ``jax.jit`` with padded shapes
-(deduped positions make scatter-add equal scatter-or, which XLA lacks
-natively).  Both return identical planes for identical input — the
-differential suite in tests/test_bulk.py holds them to it.
+``bitwise_or.reduceat``); :func:`build_planes_jax` sorts on host too
+(the group table comes out of that sort) and runs the segment/scatter on
+device under ``jax.jit`` with padded shapes (deduped positions make
+scatter-add equal scatter-or, which XLA lacks natively).  Both return
+identical planes for identical input — the differential suite in
+tests/test_bulk.py holds them to it.
 """
 
 from __future__ import annotations
@@ -149,41 +150,48 @@ from pilosa_tpu.analysis import lockcheck as _lockcheck
 _JIT_CACHE = _lockcheck.named_global("bulk.build.jit_kernel", max_entries=4)
 
 
+# Planes one kernel call builds: 16384 x 128 KiB = 2 GiB of words, every
+# word index (and the scratch slot past them) inside int32 — jax runs
+# without x64, so a 64-bit composite position key (group * 2^20 + column)
+# would be truncated in silence past 2048 groups.
+_GROUPS_PER_CALL = 16384
+
+
 def _jax_kernel(jnp, jax):
-    """The jitted sort/segment/scatter body (one compile per padded
-    (P, GW) bucket pair, memoized by jax.jit itself)."""
+    """The jitted segment/scatter body (one compile per padded (P, GW)
+    bucket pair, memoized by jax.jit itself).  Its inputs arrive sorted
+    by (group, in-slice column): the host sort that yields the group
+    table (:func:`group_pairs`) already ordered them, and a second sort
+    on device would cost the TPU compiler tens of seconds per bucket."""
 
-    def pack(pos, n_out):
-        # sort: deduplicable global keys (gid * SLICE_WIDTH + local);
-        # pad entries carry the sentinel n_out * 32 * SLICE_WIDTH-safe
-        # key that lands on the scratch slot past the planes.
-        pos = jnp.sort(pos)
-        # segment: first occurrence of each key survives, duplicates
-        # zero out — after which scatter-ADD is exactly scatter-OR.
-        first = jnp.concatenate(
-            [jnp.ones((1,), dtype=bool), pos[1:] != pos[:-1]]
+    def pack(gid, local, n_out):
+        # segment: the first occurrence of each position keeps its bit,
+        # duplicates contribute zero — after which scatter-ADD is exactly
+        # scatter-OR.
+        first = jnp.concatenate([
+            jnp.ones((1,), dtype=bool),
+            (gid[1:] != gid[:-1]) | (local[1:] != local[:-1]),
+        ])
+        val = jnp.where(
+            first, jnp.uint32(1) << (local & 31).astype(jnp.uint32), jnp.uint32(0)
         )
-        gid = pos // SLICE_WIDTH
-        local = pos % SLICE_WIDTH
-        flat = gid * WORDS_PER_PLANE + (local >> 5)
-        flat = jnp.where(first, flat, n_out)  # dup -> scratch slot
-        flat = jnp.minimum(flat, n_out)  # sentinel pads -> scratch slot
-        val = (jnp.uint32(1) << (local & 31).astype(jnp.uint32)).astype(
-            jnp.uint32
-        )
-        # scatter: one segment-sum over the padded word arena.
+        # scatter: one segment-sum over the padded word arena; pad
+        # entries carry a group id past every real one and land on the
+        # scratch slot past the planes.  The indices stay nondecreasing.
+        flat = jnp.minimum(gid * WORDS_PER_PLANE + (local >> 5), n_out)
         out = jnp.zeros(n_out + 1, dtype=jnp.uint32)
-        return out.at[flat].add(val)[:n_out]
+        return out.at[flat].add(val, indices_are_sorted=True)[:n_out]
 
-    return jax.jit(pack, static_argnums=(1,))
+    return jax.jit(pack, static_argnums=(2,))
 
 
 def build_planes_jax(rows, cols, jnp=None):
     """Device build lane: same contract as :func:`build_planes_numpy`,
-    with the sort/segment/scatter running under ``jax.jit`` on padded
-    power-of-two shapes (stable compile buckets).  The group table is
-    computed on host (the fragment commit needs host ids regardless);
-    the bit data itself sorts, dedups, and scatters on device.
+    with the segment/scatter running under ``jax.jit`` on padded
+    power-of-two shapes (stable compile buckets).  The sort and the group
+    table are computed on host (the fragment commit needs host ids
+    regardless); the bit data itself dedups and scatters into planes on
+    device, at most ``_GROUPS_PER_CALL`` planes per kernel call.
     """
     import jax
 
@@ -199,14 +207,20 @@ def build_planes_jax(rows, cols, jnp=None):
     if kern is None:
         kern = _jax_kernel(jnp, jax)  # tracing outside any lock
         _JIT_CACHE.put("pack", kern)
-    pos = gid * SLICE_WIDTH + local  # int64, monotone-safe (< 2^63)
-    p = _pad_pow2(len(pos))
-    gp = _pad_pow2(g, floor=1)
-    n_out = gp * WORDS_PER_PLANE
-    padded = np.full(p, n_out * 32, dtype=np.int64)  # past every real key
-    padded[: len(pos)] = pos
-    words = kern(jnp.asarray(padded), n_out)
-    planes = np.asarray(words).reshape(gp, WORDS_PER_PLANE)[:g]
+    blocks = []
+    for g0 in range(0, g, _GROUPS_PER_CALL):
+        g1 = min(g, g0 + _GROUPS_PER_CALL)
+        lo, hi = np.searchsorted(gid, [g0, g1])  # gid is nondecreasing
+        p = _pad_pow2(hi - lo)
+        gp = _pad_pow2(g1 - g0, floor=1)
+        n_out = gp * WORDS_PER_PLANE
+        gid_p = np.full(p, gp, dtype=np.int32)  # past every real group
+        gid_p[: hi - lo] = gid[lo:hi] - g0
+        local_p = np.zeros(p, dtype=np.int32)
+        local_p[: hi - lo] = local[lo:hi]
+        words = kern(jnp.asarray(gid_p), jnp.asarray(local_p), n_out)
+        blocks.append(np.asarray(words).reshape(gp, WORDS_PER_PLANE)[: g1 - g0])
+    planes = blocks[0] if len(blocks) == 1 else np.concatenate(blocks)
     return slice_ids, row_ids, np.ascontiguousarray(planes)
 
 

@@ -9,6 +9,7 @@ from __future__ import annotations
 import argparse
 import io
 import json
+import subprocess
 import sys
 import tarfile
 import time
@@ -42,9 +43,9 @@ def _spawn_reuseport_workers(cfg, server, args) -> list:
     sibling is a full server over the same data-dir: read-path scaling
     only — route writes through the replica router (DEVELOPMENT.md
     "Multi-core serving") when multi-process write consistency matters.
+    Host-only servers (engine "numpy") only: see _check_workers.
     """
     import os
-    import subprocess
 
     n = int(getattr(cfg, "server_workers", 0) or 0)
     if n <= 1 or os.environ.get("PILOSA_TPU_SERVER_WORKER_CHILD") == "1":
@@ -66,10 +67,31 @@ def _spawn_reuseport_workers(cfg, server, args) -> list:
     return procs
 
 
+def _check_workers(cfg) -> None:
+    """[server] workers > 1 forks sibling server processes on one port.
+    A chip belongs to one process: the siblings of a jax or mesh server
+    could never reach it, so that combination refuses to start."""
+    from pilosa_tpu.engine import engine_name
+
+    n = int(cfg.server_workers or 0)
+    name = engine_name(cfg.engine)
+    if n > 1 and name != "numpy":
+        raise ValueError(
+            f"[server] workers = {n} with engine {name!r}: a chip belongs to "
+            "one process, so sibling server processes cannot share it; "
+            'use workers = 1, or name engine = "numpy" for a host-only server'
+        )
+
+
 def cmd_server(args) -> int:
+    from pilosa_tpu.engine import configure_compile_cache, engine_name
+    from pilosa_tpu.server.handler import device_status
     from pilosa_tpu.server.server import Server
 
     cfg = _load_config(args)
+    _check_workers(cfg)
+    if engine_name(cfg.engine) != "numpy":
+        configure_compile_cache()
     profiler = None
     if getattr(args, "profile_cpu", None):
         # cmd/server.go:100 parity: profile the whole serving lifetime,
@@ -104,7 +126,11 @@ def cmd_server(args) -> int:
     server = Server(cfg)
     server.open()
     workers = _spawn_reuseport_workers(cfg, server, args)
-    print(f"pilosa-tpu serving on http://{server.host} (data: {server.data_dir})")
+    print(
+        f"pilosa-tpu serving on http://{server.host} (data: {server.data_dir}) "
+        f"device: {json.dumps(device_status(server.executor.engine))}",
+        flush=True,
+    )
     if args.test_exit:  # for CLI tests: start, report, stop
         _finish()
         return 0
@@ -143,10 +169,12 @@ def cmd_lockstep(args) -> int:
     coordinator flags (topology comes from the runtime).
     """
     from pilosa_tpu.core.holder import Holder
+    from pilosa_tpu.engine import configure_compile_cache
     from pilosa_tpu.parallel.multihost import init_multihost
     from pilosa_tpu.parallel.service import LockstepService
 
     cfg = _load_config(args)
+    configure_compile_cache()
     init_multihost(
         coordinator=args.coordinator,
         num_processes=args.num_processes,

@@ -62,7 +62,7 @@ class Config:
     # disables repair outright (the bench A/B lever).
     repair_rows_max: int = 64
     # Row ceiling for the cached all-pairs Gram strategy (4096 rows = a
-    # 64 MiB Gram; raise on host-attached hardware).
+    # 64 MiB Gram; chosen on an earlier rig, not measured on this chip).
     gram_rows_max: int = 4096
     # -- executor strategy knobs (top-level, like gram-rows-max) ----------
     # These route the executor's remaining raw-env tuning knobs through

@@ -10,8 +10,8 @@ where that matrix lives:
   path: one device dispatch per query stage for *all* local slices, the
   TPU-native replacement for the reference's goroutine-per-slice fan-out
   (executor.go:1209-1244).
-- `NumpyEngine` — pure numpy; used for tests, TPU-less hosts, and tiny
-  working sets where a device round-trip costs more than the op.
+- `NumpyEngine` — pure numpy; chosen by name only (``engine = "numpy"``:
+  the CPU tests and host-only tools say so explicitly).
 
 Both satisfy the same small protocol; results surface as numpy.
 """
@@ -321,6 +321,12 @@ class NumpyEngine:
 
     def to_numpy(self, x) -> np.ndarray:
         return np.asarray(x)
+
+    def device_info(self) -> dict:
+        """What this engine computes on (the ``device`` object of the
+        server's startup line and ``GET /status``): the host."""
+        return {"engine": self.name, "platform": "host", "device_kind": None,
+                "count": 0, "devices": []}
 
 
 class JaxEngine:
@@ -697,6 +703,28 @@ class JaxEngine:
     def to_numpy(self, x) -> np.ndarray:
         return np.asarray(x)
 
+    def _devices(self) -> list:
+        import jax
+
+        return jax.devices()
+
+    def device_info(self) -> dict:
+        """The devices this engine computes on, as jax reports them,
+        with each one's allocator counters (``memory_stats()``; the CPU
+        backend reports none)."""
+        devs = self._devices()
+        per_dev = []
+        for d in devs:
+            ms = d.memory_stats() or {}
+            per_dev.append({
+                "id": d.id,
+                "bytes_in_use": ms.get("bytes_in_use"),
+                "peak_bytes_in_use": ms.get("peak_bytes_in_use"),
+            })
+        return {"engine": self.name, "platform": devs[0].platform,
+                "device_kind": devs[0].device_kind, "count": len(devs),
+                "devices": per_dev}
+
 
 class MeshEngine(JaxEngine):
     """JaxEngine whose slice stacks are sharded over a local device mesh.
@@ -775,6 +803,45 @@ class MeshEngine(JaxEngine):
         self._gather_jit = jax.jit(_bw.gather_count, static_argnums=0)
         self._gather_multi_jit = jax.jit(_bw.gather_count_multi, static_argnums=0)
         self._tree_jit = None  # built on first tree batch
+        self._count_jit = jax.jit(_bw.count)
+
+        jnp = self._jnp
+
+        def and_count(rows, src, tiled):
+            axes = (-2, -1) if tiled else (-1,)
+            inter = jax.lax.population_count(jnp.bitwise_and(rows, src))
+            return jnp.sum(inter.astype(jnp.int32), axis=axes)
+
+        self._and_count_jit = jax.jit(and_count, static_argnums=2)
+
+    # A pallas_call cannot be partitioned by GSPMD ("Mosaic kernels cannot
+    # be automatically partitioned"), and every array this engine hands
+    # out lives on the whole mesh — sharded, or replicated like a slice
+    # indexed out of a sharded matrix.  The parent's single-chip kernel
+    # dispatch for these two reductions therefore fails on real devices
+    # (CPU meshes never saw it: there dispatch picks the jnp form anyway).
+    # Both are one elementwise pass + reduce, which XLA partitions along
+    # the slice axis itself with no communication.
+
+    def count(self, batch) -> np.ndarray:
+        if batch.size == 0:
+            return np.zeros(batch.shape[:-1], dtype=np.int64)
+        return self._fetch(self._count_jit(batch)).astype(np.int64)
+
+    def batch_intersection_count(self, rows, src, tiled: bool = False) -> np.ndarray:
+        return self._fetch(self._and_count_jit(rows, src, tiled)).astype(np.int64)
+
+    def tile_src(self, src_dense: np.ndarray):
+        """A dense [W] operand in tiled form, REPLICATED over the mesh: it
+        pairs with rows indexed out of a sharded matrix (``matrix[si]``
+        comes back replicated), and one jitted call takes all its
+        operands on the same devices."""
+        src = np.asarray(src_dense)
+        self.stat_upload_bytes += src.nbytes
+        return self.mesh.replicate(self._tile_host(src))
+
+    def _devices(self) -> list:
+        return list(self.mesh.mesh.devices.flat)
 
     def _shard_stack(self, x):
         # Shard only cleanly-divisible leading axes (device_put requires
@@ -800,6 +867,20 @@ class MeshEngine(JaxEngine):
         """One sharded transfer: the slice axis lands partitioned; stored
         in the same tiled 4D form as JaxEngine (relayout-free kernels)."""
         return self._shard_stack(self._tile_host(host_matrix))
+
+    def _match_block(self, matrix, block):
+        """A pool miss block spans every slice ([S, k, W]): upload it
+        sharded like the matrix it is scattered into, so each device
+        receives only its own slices — the parent's default-device upload
+        would put every 2 GiB block whole on device 0.  Blocks over a
+        subset of slices (plane refreshes) stay small and take the
+        parent's path."""
+        block = np.asarray(block)
+        if block.shape[0] != matrix.shape[0]:
+            return super()._match_block(matrix, block)
+        if matrix.ndim == block.ndim + 1:
+            block = self._tile_host(block)
+        return self._shard_stack(block)  # counts the upload bytes itself
 
     def _repin(self, out, like):
         # Scatter/concat along or around the sharded slice axis may leave
@@ -978,28 +1059,53 @@ class MeshEngine(JaxEngine):
         return self.gather_count_tree(row_matrix, leaves, opc)
 
 
-def new_engine(name: str = "auto"):
-    """Engine factory. "auto" honors PILOSA_TPU_ENGINE, defaulting to jax
-    with a numpy fallback when no jax backend can initialize."""
-    fallback_ok = False
+def engine_name(name: str = "auto") -> str:
+    """The engine a configured name selects: "auto" honors
+    PILOSA_TPU_ENGINE and otherwise means jax."""
     if name == "auto":
-        env = os.environ.get("PILOSA_TPU_ENGINE")
-        # Only a true default (no env override) may silently fall back; an
-        # explicit PILOSA_TPU_ENGINE=jax must surface jax failures.
-        fallback_ok = env is None
-        name = env or "jax"
+        return os.environ.get("PILOSA_TPU_ENGINE") or "jax"
+    return name
+
+
+def new_engine(name: str = "auto"):
+    """Engine factory.  A jax backend that cannot initialize raises the
+    backend's own error here, at construction.  The numpy engine serves
+    only when it is named — a server that lost its chip must not answer
+    from the host unannounced."""
+    name = engine_name(name)
     if name == "numpy":
         return NumpyEngine()
     if name == "mesh":
         return MeshEngine()
     if name == "jax":
-        if fallback_ok:
-            try:
-                eng = JaxEngine()
-                eng.count(eng.asarray(np.zeros(8, dtype=np.uint32)))  # backend probe
-                return eng
-            # analysis-ok: exception-hygiene: backend probe; the numpy engine is the documented fallback
-            except Exception:
-                return NumpyEngine()
+        import jax
+
+        jax.devices()  # backend probe: fail at startup, not at the first query
         return JaxEngine()
     raise ValueError(f"unknown engine: {name!r}")
+
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def compile_cache_dir() -> str:
+    """Where jax's persistent compilation cache lives: wherever
+    JAX_COMPILATION_CACHE_DIR says, else ``<checkout>/.jax_cache`` — a
+    fixed path, because the path is part of every cache key."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        _CHECKOUT, ".jax_cache"
+    )
+
+
+def configure_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; call before the first
+    jit.  With JAX_COMPILATION_CACHE_DIR set, jax already has its
+    directory and none is set here.  Most kernels of this program
+    compile in under a second — below jax's default threshold for
+    storing an entry — so the threshold drops to zero."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return compile_cache_dir()

@@ -2,7 +2,9 @@
 
 Auto-builds the shared library with the in-tree Makefile on first use when
 a toolchain is present; every entry point has a pure-Python/numpy fallback
-so the framework runs identically (slower) without it.  The analog of the
+so the framework runs identically (slower) without it (no toolchain, or
+``PILOSA_TPU_NO_NATIVE=1``).  A library that is present but fails to load
+is an error, not a fallback.  The analog of the
 reference's asm-vs-Go split (roaring/assembly_asm.go vs assembly.go) for
 the host side of this build.
 """
@@ -81,8 +83,17 @@ def load() -> Optional[ctypes.CDLL]:
                 return None
         try:
             lib = ctypes.CDLL(lib_path)
-        except OSError:
-            return None
+        except OSError as e:
+            # A library that exists but cannot load (built for another
+            # CPU, a stale ABI) must not hand the request path to the
+            # Python lanes in silence: only PILOSA_TPU_NO_NATIVE (above)
+            # asks for those.  Every later call raises again.
+            _tried = False
+            raise RuntimeError(
+                f"native library {lib_path} failed to load ({e}); rebuild it "
+                "(make -C native clean && make -C native) or set "
+                "PILOSA_TPU_NO_NATIVE=1 to serve from the Python lanes"
+            ) from e
         _lib_path_loaded = os.path.abspath(lib_path)
         u8p = ctypes.POINTER(ctypes.c_uint8)
         u32p = ctypes.POINTER(ctypes.c_uint32)

@@ -12,7 +12,8 @@ with a pure-numpy ground truth.
 Two consumers run the same cases:
 - the pytest suite (tests/test_differential_kernels.py), CPU backend,
   Pallas kernels in interpret mode;
-- ``tpu_selftest.py`` on a real chip, the actual Mosaic lowering.
+- ``chip_smoke.py`` (kernels phase) on a real chip, the actual Mosaic
+  lowering.
 """
 
 from __future__ import annotations

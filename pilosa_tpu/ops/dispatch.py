@@ -48,11 +48,7 @@ def _rm3(row_matrix):
 
 @functools.lru_cache(maxsize=None)
 def _backend_is_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    # analysis-ok: exception-hygiene: backend feature probe; False routes to the portable lane
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def use_pallas() -> bool:

@@ -51,25 +51,14 @@ def init_multihost(
     import jax
 
     if local_device_count is not None:
-        # Force a CPU backend with N virtual devices even when a TPU
-        # plugin latched the platform at import time (same workaround as
-        # tests/conftest.py — backends are created lazily).
+        # A CPU dev rig: N virtual devices per process, gloo collectives
+        # between processes.  jax may already be imported (the config
+        # keys are read at backend init, which by this function's
+        # contract hasn't happened), so set the config, not just the env.
         os.environ["JAX_PLATFORMS"] = "cpu"
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", local_device_count)
-        except AttributeError:
-            # Older jax: the option predates jax_num_cpu_devices — the
-            # XLA flag does the same thing and is read at backend init
-            # (which hasn't happened yet by this function's contract).
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "")
-                + f" --xla_force_host_platform_device_count={local_device_count}"
-            ).strip()
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except AttributeError:
-            pass  # older jax: gloo is the only distributed CPU choice anyway
+        jax.config.update("jax_num_cpu_devices", local_device_count)
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     if coordinator is None:
         jax.distributed.initialize()
     else:

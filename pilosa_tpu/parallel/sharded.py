@@ -27,26 +27,6 @@ from typing import Sequence
 import numpy as np
 
 
-def _shard_map(jax):
-    """Compat shim: ``jax.shard_map`` (with ``check_vma``) is the
-    current API; older releases only have
-    ``jax.experimental.shard_map.shard_map`` (with ``check_rep``).
-    Returns a callable with the CURRENT keyword surface either way, so
-    every kernel below writes modern code once."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as legacy
-
-    def adapted(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return legacy(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
-    return adapted
-
-
 class SliceMesh:
     """A 1-D device mesh over the ``slice`` axis.
 
@@ -103,7 +83,7 @@ def sharded_count_and(mesh: SliceMesh, a, b):
     from jax.sharding import PartitionSpec as P
 
     @functools.partial(
-        _shard_map(jax),
+        jax.shard_map,
         mesh=mesh.mesh,
         in_specs=(P(mesh.AXIS, None), P(mesh.AXIS, None)),
         out_specs=P(),
@@ -158,7 +138,7 @@ def sharded_count_call(mesh: SliceMesh, op: str, a, b):
         raise ValueError(op)
 
     @functools.partial(
-        _shard_map(jax),
+        jax.shard_map,
         mesh=mesh.mesh,
         in_specs=(P(mesh.AXIS, None), P(mesh.AXIS, None)),
         out_specs=P(),
@@ -189,7 +169,7 @@ def _sharded_pair_kernel(
     )
 
     @functools.partial(
-        _shard_map(jax),
+        jax.shard_map,
         mesh=mesh_obj,
         in_specs=(P(axis, *([None] * (rm_ndim - 1))), P(None, None)),
         out_specs=P(),
@@ -214,7 +194,7 @@ def _sharded_multi_kernel(mesh_obj, axis: str, op: str, interpret: bool, rm_ndim
     from pilosa_tpu.ops.pallas_kernels import fused_gather_count_multi
 
     @functools.partial(
-        _shard_map(jax),
+        jax.shard_map,
         mesh=mesh_obj,
         in_specs=(P(axis, *([None] * (rm_ndim - 1))), P(None, None)),
         out_specs=P(),
@@ -308,7 +288,7 @@ def _sharded_tree_kernel(mesh_obj, axis: str, interpret: bool, rm_ndim: int = 3)
     from pilosa_tpu.ops.pallas_kernels import fused_gather_count_tree
 
     @functools.partial(
-        _shard_map(jax),
+        jax.shard_map,
         mesh=mesh_obj,
         in_specs=(P(axis, *([None] * (rm_ndim - 1))), P(None, None), P(None, None)),
         out_specs=P(),
@@ -359,7 +339,7 @@ def _sharded_scorer_kernel(mesh_obj, axis: str, rm_ndim: int, src_ndim: int):
     from jax.sharding import PartitionSpec as P
 
     @functools.partial(
-        _shard_map(jax),
+        jax.shard_map,
         mesh=mesh_obj,
         in_specs=(
             P(axis, *([None] * (rm_ndim - 1))),
@@ -417,7 +397,7 @@ def sharded_topn_counts(mesh: SliceMesh, rows, src):
     from jax.sharding import PartitionSpec as P
 
     @functools.partial(
-        _shard_map(jax),
+        jax.shard_map,
         mesh=mesh.mesh,
         in_specs=(P(mesh.AXIS, None, None), P(mesh.AXIS, None)),
         out_specs=P(),
@@ -567,7 +547,7 @@ def _replica_pair_kernel(mesh_obj, slice_axis: str, replica_axis: str, op: str,
     )
 
     @functools.partial(
-        _shard_map(jax),
+        jax.shard_map,
         mesh=mesh_obj,
         # Matrix: sharded over slice, REPLICATED over replica (each
         # group holds a full copy).  Pairs: split over replica.

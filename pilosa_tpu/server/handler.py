@@ -92,6 +92,18 @@ def result_to_json(result):
     return result
 
 
+def device_status(engine) -> dict:
+    """The ``device`` object of the server's startup line and of
+    ``GET /status``: which engine answers, on which devices (as jax
+    reports them, with allocator counters), and whether the native host
+    lanes are loaded — so an operator never has to guess whether the
+    chip is serving."""
+    from pilosa_tpu import native
+
+    path = native.loaded_path()
+    return {**engine.device_info(), "native": path is not None, "native_path": path}
+
+
 class Handler:
     """Routes requests to the holder/executor; transport-agnostic core."""
 
@@ -502,6 +514,7 @@ class Handler:
             "state": "UP",
             "cluster": self.cluster.status_json() if self.cluster else {"nodes": []},
             "indexes": self.holder.schema(),
+            "device": device_status(self.executor.engine),
         }
         return self._json({"status": status})
 

@@ -160,6 +160,26 @@ def test_build_jax_matches_numpy_ragged_last_slice():
     assert np.array_equal(nplanes, jplanes)
 
 
+def test_build_jax_matches_numpy_sparse_many_groups(monkeypatch):
+    """A sparse chunk touches thousands of (slice, row) groups: past 2048
+    of them a composite position key no longer fits int32 (jax runs
+    without x64), so the device lane sorts two int32 keys and builds at
+    most _GROUPS_PER_CALL planes per kernel call (cap lowered here so the
+    block loop runs)."""
+    pytest.importorskip("jax")
+    monkeypatch.setattr(bulk_build, "_GROUPS_PER_CALL", 1024)
+    rng = np.random.default_rng(6)
+    g = 2500
+    rows = np.repeat(np.arange(g), 2).astype(np.uint64)
+    cols = rng.integers(0, 2 * SLICE_WIDTH, size=2 * g).astype(np.uint64)
+    rows = np.concatenate([rows, rows[:50]])  # duplicates
+    cols = np.concatenate([cols, cols[:50]])
+    ns, nr, nplanes = build_planes_numpy(rows, cols)
+    js, jr, jplanes = bulk_build.build_planes_jax(rows, cols)
+    assert ns.tolist() == js.tolist() and nr.tolist() == jr.tolist()
+    assert np.array_equal(nplanes, jplanes)
+
+
 def test_plane_positions_matches_roaring_bit_order():
     from pilosa_tpu.roaring import Bitmap
 

@@ -1,8 +1,9 @@
 """Generated differential fuzz over every kernel/strategy lane.
 
 CPU form of the asm-vs-Go idiom (roaring/assembly_test.go): the Pallas
-kernels run in interpret mode here; ``python tpu_selftest.py`` runs the
-SAME generated cases against the real Mosaic lowering on a chip.
+kernels run in interpret mode here; ``python chip_smoke.py`` (kernels
+phase) runs the SAME generated cases against the real Mosaic lowering on
+a chip.
 """
 
 import pytest
