@@ -23,6 +23,7 @@ import os
 import numpy as np
 
 from pilosa_tpu.roaring import _POPCNT8
+from pilosa_tpu.stats import NOP_STATS
 
 # Pair-op table for the numpy engine.  Deliberately NOT shared with
 # ops.bitwise.apply_pair_op: importing ops.bitwise pulls in jax at module
@@ -347,6 +348,13 @@ class JaxEngine:
         # GIL; the executor's dispatch meter reads deltas around engine
         # calls to attribute transfer bytes per dispatch.
         self.stat_upload_bytes = 0
+        # The executor hands its stats client over (``engine.upload_bytes``
+        # at /debug/vars); directly-constructed engines count to nowhere.
+        self.stats = NOP_STATS
+
+    def _note_upload(self, n: int) -> None:
+        self.stat_upload_bytes += n
+        self.stats.count("engine.upload_bytes", n)
 
     def stack(self, rows: list[np.ndarray]):
         return self._jnp.asarray(np.stack(rows)) if rows else self._jnp.zeros((0, 0), dtype=self._jnp.uint32)
@@ -380,7 +388,7 @@ class JaxEngine:
     def matrix(self, host_matrix: np.ndarray):
         """One host→device transfer for an assembled row matrix, stored in
         canonical tiled form uint32[S, R, W/128, 128]."""
-        self.stat_upload_bytes += host_matrix.nbytes
+        self._note_upload(host_matrix.nbytes)
         return self._jnp.asarray(self._tile_host(host_matrix))
 
     def gather_count_and(self, row_matrix, pairs) -> np.ndarray:
@@ -425,7 +433,7 @@ class JaxEngine:
         """Upload a ROW-MAJOR [R, S, W] host block in tiled form — the
         layout whose per-row bytes are one contiguous DMA descriptor
         (dispatch.gather_count_rowmajor)."""
-        self.stat_upload_bytes += host_matrix.nbytes
+        self._note_upload(host_matrix.nbytes)
         return self._jnp.asarray(self._tile_host(host_matrix))
 
     def rowmajor_ok(self, n_slices: int, words: int, k: int = 2) -> bool:
@@ -500,7 +508,7 @@ class JaxEngine:
     def prepare_topn_src(self, src_stack: np.ndarray):
         """Upload a host [S, W] src stack once per TopN query (tiled)."""
         src = np.ascontiguousarray(src_stack)
-        self.stat_upload_bytes += src.nbytes
+        self._note_upload(src.nbytes)
         return self._jnp.asarray(self._tile_host(src))
 
     def topn_scorer_counts(self, matrix, pos, src_dev) -> np.ndarray:
@@ -558,14 +566,14 @@ class JaxEngine:
         """Upload a dense [W] operand in the matrix-compatible tiled form
         (so kernels can pair it with rows sliced from a 4D matrix)."""
         src = np.asarray(src_dense)
-        self.stat_upload_bytes += src.nbytes
+        self._note_upload(src.nbytes)
         return self._jnp.asarray(self._tile_host(src))
 
     def _match_block(self, matrix, block):
         """Reshape a host [.., .., W] block to the matrix's storage form
         (tiled 4D matrices take [.., .., W/128, 128] blocks)."""
         block = np.asarray(block)
-        self.stat_upload_bytes += block.nbytes
+        self._note_upload(block.nbytes)
         if matrix.ndim == block.ndim + 1:
             block = self._tile_host(block)
         return self._jnp.asarray(block)
@@ -837,7 +845,7 @@ class MeshEngine(JaxEngine):
         comes back replicated), and one jitted call takes all its
         operands on the same devices."""
         src = np.asarray(src_dense)
-        self.stat_upload_bytes += src.nbytes
+        self._note_upload(src.nbytes)
         return self.mesh.replicate(self._tile_host(src))
 
     def _devices(self) -> list:
@@ -849,7 +857,7 @@ class MeshEngine(JaxEngine):
         # first, placement when the shapes allow it.  Only stack_slices
         # routes here, so the leading axis is always the slice axis.
         if isinstance(x, np.ndarray):
-            self.stat_upload_bytes += x.nbytes
+            self._note_upload(x.nbytes)
         if x.ndim < 2 or x.shape[0] < 2 or x.shape[0] % self.mesh.n_devices:
             return self._jnp.asarray(x)
         from jax.sharding import NamedSharding, PartitionSpec as P

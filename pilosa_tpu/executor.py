@@ -391,6 +391,8 @@ class Executor:
             from pilosa_tpu import costs as costs_mod
 
             self.meter = costs_mod.DispatchMeter(stats, engine=self.engine)
+            if hasattr(self.engine, "stats"):
+                self.engine.stats = stats  # engine.upload_bytes
         if write_queue:
             from pilosa_tpu.ingest import WriteQueue
 
@@ -508,7 +510,10 @@ class Executor:
                     # Gram / gather kernels behind one entry point).
                     span.tags["lane"] = "flat"
                 if qtoken is not None:
+                    csp = span.child("qcache.commit") if span is not None else None
                     self.qcache.commit(self.holder, qtoken, fast)
+                    if csp is not None:
+                        csp.finish()
                 return fast
             psp = span.child("parse") if span is not None else None
             query = pql.parse_cached(query)
@@ -592,7 +597,10 @@ class Executor:
             if csp is not None:
                 csp.finish()
         if qtoken is not None:
+            csp = span.child("qcache.commit") if span is not None else None
             self.qcache.commit(self.holder, qtoken, results)
+            if csp is not None:
+                csp.finish()
         return results
 
     # -- query-batch fusion ------------------------------------------------
@@ -826,9 +834,12 @@ class Executor:
             raw = src.encode("utf-8")
         except UnicodeEncodeError:
             return None
+        span = opt.span if opt is not None else None
+        # write.apply: container insert, op-log append, a snapshot when
+        # one is due - here in one native crossing (its "device" child).
+        wsp = span.child("write.apply") if span is not None else None
         if self.meter is not None:
-            span = opt.span if opt is not None else None
-            with self.meter.measure("native", span) as d:
+            with self.meter.measure("native", wsp) as d:
                 res = frag.write_batch(
                     raw, st["frame_b"], st["rowkey_b"], st["colkey_b"]
                 )
@@ -838,8 +849,12 @@ class Executor:
                 raw, st["frame_b"], st["rowkey_b"], st["colkey_b"]
             )
         if res is None:
+            if wsp is not None:
+                span.children.remove(wsp.finish())  # declined: nothing applied
             return None
         changed, types, rows, cols = res
+        if wsp is not None and changed is not None:
+            wsp.finish().tags["changed"] = int(np.count_nonzero(changed))
         if changed is not None:
             if len(changed) == 1:  # singleton hot path: no numpy work
                 ch = bool(changed[0])
@@ -856,6 +871,8 @@ class Executor:
         # in-batch ordering matters).
         if (types == 0).all():
             ch = frame.set_bits(VIEW_STANDARD, rows, cols)
+            if wsp is not None:
+                wsp.finish().tags["changed"] = int(np.count_nonzero(ch))
             if ch.any():
                 self._note_dirty_rows(index, fname, rows[ch].tolist())
             return ch.tolist()
@@ -869,6 +886,8 @@ class Executor:
             if ok:
                 touched.append(r)
             out.append(ok)
+        if wsp is not None:
+            wsp.finish().tags["changed"] = len(touched)
         if touched:
             self._note_dirty_rows(index, fname, touched)
         return out
@@ -925,10 +944,13 @@ class Executor:
         ):
             return None
         row_id, col_id = int(v1), int(v2)
+        sp = opt.span.child("write.apply") if opt is not None and opt.span is not None else None
         if name == "SetBit":
             ch = frame.set_bit(VIEW_STANDARD, row_id, col_id)
         else:
             ch = frame.clear_bit(VIEW_STANDARD, row_id, col_id)
+        if sp is not None:
+            sp.finish().tags["changed"] = int(ch)
         if ch:
             self._note_dirty_rows(index, fname, (row_id,))
         return [ch]
@@ -988,12 +1010,26 @@ class Executor:
             sn = _FRAME_SNIFF_RX.search(src, 0, 512)
             fname = sn.group(1) or sn.group(2) or sn.group(3) if sn else DEFAULT_FRAME
             st = self._serve_states.get((index, fname))
-            if st is not None and not self._serve_state_valid(st):
+            span = opt.span
+            valid = True
+            if st is not None:
+                sp = span.child("serve.validate") if span is not None else None
+                valid = self._serve_state_valid(st)
+                if sp is not None:
+                    sp.finish().tags["valid"] = valid
+            if not valid:
                 # Patch lane: a small write repairs the warm state in
                 # place (matrix rows + rank-k Gram + glut) and re-arms;
                 # only structural or over-budget deltas pop the entry
                 # and pay the full rebuild through the general lane.
-                st = self._serve_state_repair((index, fname), st)
+                sp = span.child("serve.repair") if span is not None else None
+                st = self._serve_state_repair((index, fname), st, sp)
+                if sp is not None:
+                    # False where another request had repaired the pool
+                    # while this one waited for its lock.
+                    sp.finish().tags["repaired"] = any(
+                        c.name in ("pool.repair", "pool.refresh") for c in sp.children
+                    )
                 if st is None:
                     with self._matrix_mu:
                         self._serve_states.pop((index, fname), None)
@@ -1110,6 +1146,7 @@ class Executor:
                     (op_ids, frame_ids, r1, r2),
                     (tuple(frames_b), tuple(keys_b)),
                     tuple(std_slices),
+                    opt.span,
                 )
             )
         if self._is_distributed(opt):
@@ -1129,12 +1166,12 @@ class Executor:
                 index, idxs, std_slices, opt,
                 lambda: pql.parse_cached(src),
                 lambda node_slices: self._fused_local_counts(
-                    index, matched, idxs, node_slices, plan=opt.plan
+                    index, matched, idxs, node_slices, plan=opt.plan, span=opt.span
                 ),
             )
         return self._fused_local_counts_arrays(
             index, frame_names, op_ids, frame_ids, r1, r2, std_slices,
-            plan=opt.plan,
+            plan=opt.plan, span=opt.span,
         )
 
     def _serve_state_valid(self, st: dict) -> bool:
@@ -1397,7 +1434,7 @@ class Executor:
                     return None
         return dirty if dirty else None
 
-    def _serve_state_repair(self, key: tuple, st: dict) -> Optional[dict]:
+    def _serve_state_repair(self, key: tuple, st: dict, span=None) -> Optional[dict]:
         """The serve-state PATCH lane (the Roaring repair principle one
         level up): a state invalidated by a small write is repaired —
         the pool matrix's dirty rows rewritten in place, the Gram
@@ -1440,7 +1477,7 @@ class Executor:
         # actually written are re-gathered.  The box (with its glut)
         # survives.
         pool = self._pool_for(index, fname, VIEW_STANDARD, slices)
-        _, _, box = pool.acquire([], tuple(new_gens), dirty_rows=dirty)
+        _, _, box = pool.acquire([], tuple(new_gens), dirty_rows=dirty, span=span)
         glut = box.get("gram_lut")
         if glut is None:
             return None  # box didn't survive (evicted/reset elsewhere)
@@ -1562,11 +1599,13 @@ class Executor:
         op/frame/row arrays and run through ONE
         ``_fused_local_counts_arrays`` pass — with a warm Gram that is a
         single native call answering every queued request — then split
-        back per request.
+        back per request.  The shared pass has one owner in the traces:
+        the group's first sampled request gets its pool and device spans
+        (tag ``coalesced`` = requests answered by the pass).
         """
         results: list = [None] * len(items)
         groups: dict[tuple, list[int]] = {}
-        for i, (index, _arrays, tables, slices) in enumerate(items):
+        for i, (index, _arrays, tables, slices, _span) in enumerate(items):
             groups.setdefault((index, tables, slices), []).append(i)
         for (index, tables, slices), idxs in groups.items():
             frame_names = [b.decode("utf-8") for b in tables[0]]
@@ -1578,8 +1617,11 @@ class Executor:
                 fids = np.concatenate([items[i][1][1] for i in idxs])
                 rr1 = np.concatenate([items[i][1][2] for i in idxs])
                 rr2 = np.concatenate([items[i][1][3] for i in idxs])
+            span = next((items[i][4] for i in idxs if items[i][4] is not None), None)
+            if span is not None:
+                span.tags["coalesced"] = len(idxs)
             counts = self._fused_local_counts_arrays(
-                index, frame_names, ops, fids, rr1, rr2, list(slices)
+                index, frame_names, ops, fids, rr1, rr2, list(slices), span=span
             )
             off = 0
             for i in idxs:
@@ -1590,7 +1632,7 @@ class Executor:
 
     def _fused_local_counts_arrays(
         self, index: str, frame_names, op_ids, frame_ids, r1, r2, slices,
-        plan=None, _prearm=False,
+        plan=None, _prearm=False, span=None,
     ) -> list[int]:
         """Vectorized local evaluator for the compiled-query lane: group by
         (frame, op) with numpy masks, map row ids to matrix positions via
@@ -1676,7 +1718,7 @@ class Executor:
                     rm_pool = False  # diverged lane caps: stay chunkable
                 id_pos, matrix, box = self._frame_matrix(
                     index, fname, slices, set(rows.tolist()),
-                    lane="rmgather" if rm_pool else "",
+                    lane="rmgather" if rm_pool else "", span=span,
                 )
                 gram = None if rm_pool else self._frame_gram(matrix, box)
                 if gram is not None:  # implies a live box (_frame_gram contract)
@@ -1924,7 +1966,7 @@ class Executor:
             index, idxs, slices, opt,
             lambda: pql.Query(calls=[calls[i] for i in idxs]),
             lambda node_slices: self._fused_local_counts(
-                index, matched, idxs, node_slices, plan=opt.plan
+                index, matched, idxs, node_slices, plan=opt.plan, span=opt.span
             ),
         )
         return dict(zip(idxs, totals))
@@ -2234,7 +2276,8 @@ class Executor:
         )
 
     def _fused_local_counts(
-        self, index: str, matched: dict, idxs: list[int], slices, plan=None
+        self, index: str, matched: dict, idxs: list[int], slices, plan=None,
+        span=None,
     ) -> list[int]:
         """Fused counts for the given slice batch, aligned with idxs.
 
@@ -2349,7 +2392,7 @@ class Executor:
                         rm_pool = False
                     id_pos, matrix, box = self._frame_matrix(
                         index, frame, slices, set(want), view,
-                        lane="rmgather" if rm_pool else "",
+                        lane="rmgather" if rm_pool else "", span=span,
                     )
                     # The Gram only answers 2-operand counts — don't
                     # trigger its (expensive, cached) build for requests
@@ -2363,7 +2406,7 @@ class Executor:
                         counts = self.engine.to_numpy(
                             self._group_counts(
                                 gk, op_idxs, matched, id_pos, matrix, static,
-                                gram, row_major=rm_pool,
+                                gram, row_major=rm_pool, span=span,
                             )
                         )
                         for k2, i in enumerate(op_idxs):
@@ -2404,13 +2447,13 @@ class Executor:
                     for c0 in range(0, len(slices), s_chunk):
                         matrix = self._transient_matrix(
                             index, frame, view, slices[c0 : c0 + s_chunk], want,
-                            row_major=row_major,
+                            row_major=row_major, span=span,
                         )
                         for gk, op_idxs in sorted(groups.items(), key=_group_sort_key):
                             acc.setdefault(gk, []).append(
                                 self._group_counts(
                                     gk, op_idxs, matched, id_pos, matrix, static,
-                                    None, row_major=row_major,
+                                    None, row_major=row_major, span=span,
                                 )
                             )
                     for gk, op_idxs in sorted(groups.items(), key=_group_sort_key):
@@ -2422,14 +2465,15 @@ class Executor:
         return [out[i] for i in idxs]
 
     def _group_counts(
-        self, gk, op_idxs, matched, id_pos, matrix, static, gram, row_major=False
+        self, gk, op_idxs, matched, id_pos, matrix, static, gram, row_major=False,
+        span=None,
     ):
         """One fused dispatch for an (op, arity-bucket) call group; returns
         the engine-native count array (fetch deferred to the caller).
         Metered as the "gather" lane (cost attribution): dispatch wall
         time + any host->device operand bytes the engine ledger sees."""
         if self.meter is not None:
-            with self.meter.measure("gather"):
+            with self.meter.measure("gather", span):
                 return self._group_counts_inner(
                     gk, op_idxs, matched, id_pos, matrix, static, gram,
                     row_major=row_major,
@@ -2531,7 +2575,8 @@ class Executor:
         return block
 
     def _transient_matrix(
-        self, index, frame, view, chunk_slices, rows_sorted, row_major=False
+        self, index, frame, view, chunk_slices, rows_sorted, row_major=False,
+        span=None,
     ):
         """One slice chunk's transient matrix, built host-side and moved
         in a single transfer; NOT cached — streaming shapes would evict
@@ -2542,7 +2587,7 @@ class Executor:
         if self.meter is not None:
             # Streaming lane: the chunk upload is the cost (the chunk's
             # dispatches meter separately as "gather").
-            with self.meter.measure("stream"):
+            with self.meter.measure("stream", span):
                 if row_major:
                     return self.engine.matrix_rows(block)
                 return self.engine.matrix(block)
@@ -2702,7 +2747,8 @@ class Executor:
                     )
 
                 pool = DeviceRowPool(
-                    self.engine, len(slices), _WORDS, fetch, row_major=row_major
+                    self.engine, len(slices), _WORDS, fetch, row_major=row_major,
+                    stats=self.meter.stats if self.meter is not None else None,
                 )
                 self._matrix_cache[key] = pool
             self._matrix_cache.move_to_end(key)
@@ -2712,7 +2758,7 @@ class Executor:
 
     def _frame_matrix(
         self, index: str, frame: str, slices, want: set[int],
-        view: str = VIEW_STANDARD, lane: str = "",
+        view: str = VIEW_STANDARD, lane: str = "", span=None,
     ) -> tuple[dict[int, int], object, Optional[dict]]:
         """Device row matrix holding (at least) ``want`` for a frame view.
 
@@ -2737,7 +2783,7 @@ class Executor:
         pool_gens = pool.gens
         if pool_gens is not None and pool_gens != gens:
             dirty = self._journal_dirty_rows(frags, pool_gens, gens)
-        out = pool.acquire(sorted(want), gens, dirty_rows=dirty)
+        out = pool.acquire(sorted(want), gens, dirty_rows=dirty, span=span)
         if self.meter is not None:
             self._note_resident()
         return out

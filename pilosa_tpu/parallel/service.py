@@ -740,8 +740,8 @@ class LockstepService:
                 tr.root.tags["index"] = index
                 # The queue phase already happened (arrival -> ship):
                 # record it with its measured duration.
-                qsp = tr.root.child("lockstep.queue")
-                qsp.ms = (t_ship - t_enq) * 1e3
+                tr.root.record("lockstep.queue", tr.root.t0,
+                               tr.root.t0 + (t_ship - t_enq))
             traces.append(tr)
         ship_spans = [
             tr.root.child("lockstep.ship") if tr is not None else None

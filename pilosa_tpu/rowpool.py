@@ -31,6 +31,7 @@ import os
 import threading
 
 from pilosa_tpu.analysis import lockcheck
+from pilosa_tpu.stats import NOP_STATS
 from collections import OrderedDict
 from typing import Callable, Optional, Sequence
 
@@ -78,8 +79,10 @@ class DeviceRowPool:
         fetch: Callable[[Sequence[int], Sequence[int]], np.ndarray],
         cap_max: int = 0,
         row_major: bool = False,
+        stats=None,
     ):
         self.engine = engine
+        self.stats = stats if stats is not None else NOP_STATS
         self.n_slices = n_slices
         self.words = words
         self.fetch = fetch
@@ -103,6 +106,8 @@ class DeviceRowPool:
         self.lru: OrderedDict[int, None] = OrderedDict()
         self.box: dict = self._new_box()
         # Telemetry for benches/tests: paging behavior must be observable.
+        # Each also goes out through ``stats`` where it is incremented
+        # (rowpool.misses, .evictions, .resets, .repairs, .patch_planes).
         self.stat_misses = 0
         self.stat_evictions = 0
         self.stat_resets = 0
@@ -169,6 +174,7 @@ class DeviceRowPool:
         # Matrix contents are stale garbage but unreferenced: no slot maps
         # to them, and gathers only index mapped slots.
         self.stat_resets += 1
+        self.stats.count("rowpool.resets")
 
     def _refresh_stale(self, stale: list[int]) -> None:
         """Re-pull resident rows' planes for written slices, or reset.
@@ -192,7 +198,7 @@ class DeviceRowPool:
         else:  # block: [len(stale), len(rows), W]
             self.matrix = self.engine.set_plane_rows(self.matrix, stale, slots, block)
 
-    def _repair_dirty(self, stale: list[int], dirty_rows) -> bool:
+    def _repair_dirty(self, stale: list[int], dirty_rows, span=None) -> bool:
         """Patch ONLY the written (row, slice) planes and rank-k-repair
         the box Gram, instead of the blind whole-plane refresh + box
         reset: the box (and with it the Gram, its glut, and the id_pos
@@ -207,7 +213,11 @@ class DeviceRowPool:
         (fragment dirty-row journals); rows not resident in the pool
         need no patch at all.  Returns False (nothing mutated) when the
         dirty slots fall outside the Gram's slot range — an invariant
-        breach that the conservative full refresh handles."""
+        breach that the conservative full refresh handles.  ``span``
+        (the request's ``pool.repair``) gets a child per stage:
+        ``pool.fetch`` (host densify), ``pool.scatter`` (upload and
+        plane scatter) and ``pool.gram``, where the host blocks on the
+        device for the rank-k counts."""
         if isinstance(dirty_rows, dict):
             per_slice = {
                 si: sorted(r for r in set(dirty_rows.get(si, ())) if r in self.slot_of)
@@ -233,8 +243,13 @@ class DeviceRowPool:
         for rows_t, group in by_rows.items():
             rows = list(rows_t)
             slots = [self.slot_of[r] for r in rows]
+            sp = span.child("pool.fetch") if span is not None else None
             block = self.fetch(rows, group)  # layout per self.row_major
+            if sp is not None:
+                sp.finish()
+                sp = span.child("pool.scatter")
             self.stat_patch_planes += len(rows) * len(group)
+            self.stats.count("rowpool.patch_planes", len(rows) * len(group))
             if self.row_major:
                 self.matrix = self.engine.set_plane_rows_rm(
                     self.matrix, group, slots, block
@@ -243,7 +258,10 @@ class DeviceRowPool:
                 self.matrix = self.engine.set_plane_rows(
                     self.matrix, group, slots, block
                 )
+            if sp is not None:
+                sp.finish()
         if gram is not None:
+            sp = span.child("pool.gram") if span is not None else None
             d = gram.shape[0]
             m = self.matrix if d == self.cap else self.matrix[:, :d]
             m_old = old_matrix if d == self.cap else old_matrix[:, :d]
@@ -256,11 +274,27 @@ class DeviceRowPool:
                 # rs/ps are membership-keyed and membership didn't change;
                 # only the count table is new.
                 self.box["gram_lut"] = (glut[0], np.ascontiguousarray(gram), glut[2])
+            if sp is not None:
+                sp.finish()
         return True
+
+    def _repair_spanned(self, stale: list[int], dirty_rows, span) -> bool:
+        """``_repair_dirty`` under the request's ``pool.repair`` span."""
+        if span is None:
+            return self._repair_dirty(stale, dirty_rows)
+        sp = span.child("pool.repair")
+        planes0, up0 = self.stat_patch_planes, self.engine.stat_upload_bytes
+        ok = self._repair_dirty(stale, dirty_rows, sp)
+        sp.finish().annotate(
+            planes=self.stat_patch_planes - planes0,
+            upload_bytes=self.engine.stat_upload_bytes - up0,
+            slices=len(stale),
+        )
+        return ok
 
     # -- API --------------------------------------------------------------
 
-    def acquire(self, want: Sequence[int], gens: tuple, dirty_rows=None):
+    def acquire(self, want: Sequence[int], gens: tuple, dirty_rows=None, span=None):
         """Ensure ``want`` rows are resident; returns (id_pos, matrix, box).
 
         ``id_pos`` maps every RESIDENT row id to its slot (a stable
@@ -276,13 +310,22 @@ class DeviceRowPool:
         slice) — or None when unknown.  When provided, a generation
         mismatch takes the PATCH lane (_repair_dirty) and the cache box
         — including a warm Gram — survives the write.
+
+        ``span``: the traced request's span (None = unsampled: one branch
+        per site).  Children: ``pool.lock_wait`` (entry to ``self.mu``
+        held), then whichever of ``pool.repair`` (the patch lane),
+        ``pool.refresh`` (the blind refresh) and ``pool.miss`` (paging)
+        this call ran under the lock.
         """
         want = list(dict.fromkeys(want))  # de-dup, keep order
         if len(want) > self.cap_max:
             raise ValueError(
                 f"want {len(want)} rows > pool capacity {self.cap_max}; chunk the batch"
             )
+        sp = span.child("pool.lock_wait") if span is not None else None
         with self.mu:
+            if sp is not None:
+                sp.finish()
             changed = False
             if self.gens != gens:
                 if self.gens is not None:
@@ -290,17 +333,28 @@ class DeviceRowPool:
                         si for si in range(self.n_slices) if self.gens[si] != gens[si]
                     ]
                     if stale:
-                        if dirty_rows is not None and self._repair_dirty(
-                            stale, dirty_rows
+                        if dirty_rows is not None and self._repair_spanned(
+                            stale, dirty_rows, span
                         ):
                             self.stat_repairs += 1
+                            self.stats.count("rowpool.repairs")
                         else:
+                            sp = span.child("pool.refresh") if span is not None else None
+                            up0 = self.engine.stat_upload_bytes
                             self._refresh_stale(stale)
                             changed = True
+                            if sp is not None:
+                                sp.finish().annotate(
+                                    rows=len(self.slot_of),
+                                    upload_bytes=self.engine.stat_upload_bytes - up0,
+                                )
                 self.gens = gens
             missing = [r for r in want if r not in self.slot_of]
             if missing:
+                sp = span.child("pool.miss") if span is not None else None
+                up0, ev0 = self.engine.stat_upload_bytes, self.stat_evictions
                 self.stat_misses += len(missing)
+                self.stats.count("rowpool.misses", len(missing))
                 changed = True
                 need = len(self.slot_of) + len(missing)
                 if need > self.cap:
@@ -329,6 +383,14 @@ class DeviceRowPool:
                 for r, s in zip(missing, slots):
                     self.slot_of[r] = s
                     self.row_at[s] = r
+                evicted = self.stat_evictions - ev0
+                if evicted:
+                    self.stats.count("rowpool.evictions", evicted)
+                if sp is not None:
+                    sp.finish().annotate(
+                        rows=len(missing), evicted=evicted,
+                        upload_bytes=self.engine.stat_upload_bytes - up0,
+                    )
             for r in want:
                 self.lru[r] = None
                 self.lru.move_to_end(r)

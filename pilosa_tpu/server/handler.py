@@ -243,8 +243,16 @@ class Handler:
             ("GET", re.compile(r"^/version$"), self.get_version),
         ]
 
-    def dispatch(self, method: str, path: str, params: dict, body: bytes, headers: dict):
+    def dispatch(self, method: str, path: str, params: dict, body: bytes, headers: dict,
+                 taken=None):
         """Returns (status, content_type, payload bytes[, extra headers]).
+
+        ``taken``: the door's take-up stamps, ``(perf_counter(),
+        thread_time())`` read when the request line arrived.  A sampled
+        request's root span starts there, its ``door.read`` child covers
+        take-up to here (header parse, body read), and its ``cpu_ms`` tag
+        is the thread's CPU time over the root's interval, so ``ms -
+        cpu_ms`` is the time the request held no core.
 
         The TRACE door wraps the QoS door: the head-sampling decision is
         made once here (``X-Pilosa-Trace`` forces it — the client
@@ -262,17 +270,24 @@ class Handler:
             out = self._dispatch_qos(method, path, params, body, headers, None)
             self._note_applied(headers, out)
             return self._with_group(out)
-        trace = tracer.begin(headers, name=f"{method} {path}")
-        if trace is not None and headers.get("x-pilosa-replay"):
-            # Catch-up replays are router-originated re-deliveries, not
-            # client traffic: tag the root so /debug/traces (and the
-            # slow-query log) can split replay load from live load.
-            trace.root.tags["replay"] = True
-        t0 = time.perf_counter()
+        t_here = time.perf_counter()
+        t0 = t_here if taken is None else taken[0]
+        trace = tracer.begin(headers, name=f"{method} {path}", t0=t0)
+        if trace is not None:
+            cpu0 = time.thread_time() if taken is None else taken[1]
+            if taken is not None:
+                trace.root.record("door.read", t0, t_here).tags["bytes"] = len(body)
+            if headers.get("x-pilosa-replay"):
+                # Catch-up replays are router-originated re-deliveries, not
+                # client traffic: tag the root so /debug/traces (and the
+                # slow-query log) can split replay load from live load.
+                trace.root.tags["replay"] = True
         out = self._dispatch_qos(
             method, path, params, body, headers, trace.root if trace else None
         )
         dt_ms = (time.perf_counter() - t0) * 1e3
+        if trace is not None:
+            trace.root.tags["cpu_ms"] = round((time.thread_time() - cpu0) * 1e3, 3)
         self._note_applied(headers, out)
         # An UNSAMPLED request crossing slow-ms synthesizes a root-only
         # trace inside finish_request; hand it the QoS class + tenant
@@ -794,10 +809,20 @@ class Handler:
         )
         if self._profiling:
             raise HTTPError(409, "profile already running")
+        # The device and the program's spans (trace.Span annotations),
+        # not Python: the Python tracer slowed the traced program by a
+        # quarter and more and made stop_trace take 15 s.
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
         try:
-            jax.profiler.start_trace(trace_dir)
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
         except Exception as e:
             raise HTTPError(500, f"profiler: {e}")
+        # The steady span lies between these two markers (starting and
+        # stopping take time under load); readers of the trace find them
+        # by name.
+        with jax.profiler.TraceAnnotation("profile_door start_trace"):
+            pass
         self._profiling = trace_dir
         return self._json({"tracing": trace_dir})
 
@@ -807,6 +832,8 @@ class Handler:
         if not self._profiling:
             raise HTTPError(409, "no profile running")
         try:
+            with jax.profiler.TraceAnnotation("profile_door stop_trace"):
+                pass
             jax.profiler.stop_trace()
         finally:
             trace_dir, self._profiling = self._profiling, None
@@ -960,16 +987,21 @@ class Handler:
                         if attrs:
                             column_attr_sets.append((col, attrs))
 
+        esp = span.child("encode") if span is not None else None
         if self._wants_protobuf(headers):
-            return 200, PROTOBUF, wire.encode_query_response(
+            resp = 200, PROTOBUF, wire.encode_query_response(
                 results=results, column_attr_sets=column_attr_sets
             )
-        out = {"results": [result_to_json(r) for r in results]}
-        if column_attr_sets:
-            out["columnAttrSets"] = [
-                {"id": id, "attrs": attrs} for id, attrs in column_attr_sets
-            ]
-        return self._json(out)
+        else:
+            out = {"results": [result_to_json(r) for r in results]}
+            if column_attr_sets:
+                out["columnAttrSets"] = [
+                    {"id": id, "attrs": attrs} for id, attrs in column_attr_sets
+                ]
+            resp = self._json(out)
+        if esp is not None:
+            esp.finish().tags["bytes"] = len(resp[2])
+        return resp
 
     # -- streaming columnar ingest (the bulk-write front door) --------------
 
@@ -1307,13 +1339,22 @@ class _HTTPRequestHandler(BaseHTTPRequestHandler):
     handler: Handler = None  # set by serve()
     protocol_version = "HTTP/1.1"
 
+    _taken = None  # (perf_counter, thread_time) when the request line arrived
+
+    def parse_request(self):
+        # Take-up: handle_one_request has just read the request line.  A
+        # sampled request's root span and its cpu_ms start here.
+        self._taken = (time.perf_counter(), time.thread_time())
+        return super().parse_request()
+
     def _run(self, method: str):
         parsed = urlparse(self.path)
         params = parse_qs(parsed.query)
         length = int(self.headers.get("Content-Length") or 0)
         body = self.rfile.read(length) if length else b""
         headers = {k.lower(): v for k, v in self.headers.items()}
-        out = self.handler.dispatch(method, parsed.path, params, body, headers)
+        out = self.handler.dispatch(method, parsed.path, params, body, headers,
+                                    taken=self._taken)
         status, ctype, payload = out[:3]
         extra = out[3] if len(out) > 3 else {}
         self.send_response(status)

@@ -50,6 +50,24 @@ Design:
   error isolation.  Only rank 0 records spans (ship/execute phases);
   tracing never changes execution, so workers only count the flags.
 
+- **One clock with the device trace** — while a sampled span is open it
+  is also a ``jax.profiler.TraceAnnotation`` of the same name (entered
+  in ``Span.__init__``, left in ``finish``), so a device profile taken
+  through ``/debug/profile/start|stop`` holds the program's spans in
+  its host plane, on its clock, and an idle gap of the device is named
+  by the layer that held the host (``pool.repair``, ``encode``), not by
+  a Python frame.  Outside a profile an annotation costs one flag check;
+  an unsampled request builds neither span nor annotation.  jax is
+  imported at the first sampled span, so this module imports without it.
+- **Span names of the served path** — root (``POST /index/<i>/query``,
+  from the request line's arrival; tag ``cpu_ms`` = the serving thread's
+  CPU time over the same interval), ``door.read``, ``qos.admit``,
+  ``qcache.lookup`` / ``qcache.commit``, ``serve.validate``, ``serve.repair`` >
+  ``pool.lock_wait``, ``pool.repair`` > ``pool.fetch`` / ``pool.scatter``
+  / ``pool.gram``, ``pool.refresh``, ``pool.miss``, ``device`` (tag
+  ``lane``), ``write.apply``, ``parse``, ``fused``, ``call.<Name>``,
+  ``slices`` / ``slice_chunk``, ``remote``, ``encode``.
+
 Finished traces land in a bounded in-memory ring served at
 ``/debug/traces`` (JSON, newest-first, ``?min-ms=`` filter).  Config:
 ``[trace] sample-rate / slow-ms / ring`` TOML, ``PILOSA_TPU_TRACE_*``
@@ -58,14 +76,15 @@ env, wired through Config into the server, lockstep CLI, and handler.
 
 from __future__ import annotations
 
+import itertools
 import json
 import logging
+import os
 import random
 import threading
 
 from pilosa_tpu.analysis import lockcheck
 import time
-import uuid
 from collections import deque
 from typing import Any, Optional
 
@@ -86,31 +105,85 @@ DEFAULT_RING = 256
 
 _slow_logger = logging.getLogger("pilosa_tpu.slowquery")
 
+# Trace ids: a per-process prefix and a process-wide counter (no uuid4
+# per sampled request).  A forked worker draws a prefix of its own.
+_id_prefix = os.urandom(4).hex()
+_id_seq = itertools.count(1)
+
+
+def _new_id_prefix() -> None:
+    global _id_prefix
+    _id_prefix = os.urandom(4).hex()
+
+
+os.register_at_fork(after_in_child=_new_id_prefix)
+
+
+def new_trace_id() -> str:
+    return f"{_id_prefix}{next(_id_seq) & 0xFFFFFFFF:08x}"
+
+
+# jax.profiler.TraceAnnotation, resolved at the first sampled span (False
+# where jax is not importable).
+_annotation_cls: Any = None
+
+
+def _open_annotation(name: str):
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:  # no jax: spans alone
+            cls = False
+        _annotation_cls = cls
+    if cls is False:
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
+
 
 class Span:
     """One timed stage of a request.  Finish is idempotent; an
     unfinished span serializes with its duration measured at
     serialization time (a crash/timeout mid-stage still shows where
-    the time went)."""
+    the time went).  ``t0`` backdates the start (the root starts when
+    the request line arrived); ``annotate=False`` is for a span made
+    after the fact, which has nothing left to cover in a profile."""
 
-    __slots__ = ("name", "trace_id", "t0", "ms", "tags", "children")
+    __slots__ = ("name", "trace_id", "t0", "ms", "tags", "children", "_ann")
 
-    def __init__(self, name: str, trace_id: str = ""):
+    def __init__(self, name: str, trace_id: str = "",
+                 t0: Optional[float] = None, annotate: bool = True):
         self.name = name
         self.trace_id = trace_id
-        self.t0 = time.perf_counter()
+        self.t0 = time.perf_counter() if t0 is None else t0
         self.ms: Optional[float] = None
         self.tags: dict = {}
         self.children: list = []
+        self._ann = _open_annotation(name) if annotate else None
 
     def child(self, name: str) -> "Span":
         sp = Span(name, self.trace_id)
         self.children.append(sp)  # list.append: atomic under the GIL
         return sp
 
+    def record(self, name: str, t0: float, t1: float) -> "Span":
+        """A finished child over a stretch that was timed before the
+        span tree existed (perf_counter seconds)."""
+        sp = Span(name, self.trace_id, t0=t0, annotate=False)
+        sp.ms = (t1 - t0) * 1e3
+        self.children.append(sp)
+        return sp
+
     def finish(self) -> "Span":
         if self.ms is None:
             self.ms = (time.perf_counter() - self.t0) * 1e3
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
         return self
 
     def annotate(self, **tags) -> "Span":
@@ -166,9 +239,10 @@ class Trace:
     __slots__ = ("id", "root", "forced", "propagate", "wall_ts")
 
     def __init__(self, name: str, trace_id: str = "", forced: bool = False,
-                 propagate: bool = False):
-        self.id = trace_id or uuid.uuid4().hex[:16]
-        self.root = Span(name, self.id)
+                 propagate: bool = False, t0: Optional[float] = None,
+                 annotate: bool = True):
+        self.id = trace_id or new_trace_id()
+        self.root = Span(name, self.id, t0=t0, annotate=annotate)
         self.forced = forced
         # An inbound X-Pilosa-Trace header means the caller wants the
         # span tree back in the response header (a hop, or a client
@@ -253,21 +327,24 @@ class Tracer:
             return True
         return self.sample_rate > 0.0 and self._rng.random() < self.sample_rate
 
-    def begin(self, headers=None, name: str = "request") -> Optional[Trace]:
+    def begin(self, headers=None, name: str = "request",
+              t0: Optional[float] = None) -> Optional[Trace]:
         """The per-request entry: an inbound ``X-Pilosa-Trace`` header
         forces the trace (and carries the upstream trace id unless it is
         a bare "1"-style override); otherwise the sampler decides.
         Returns None for the (common) unsampled request — callers pass
         ``trace.root`` downstream only when a trace exists, so every
-        downstream site stays a single ``span is None`` branch."""
+        downstream site stays a single ``span is None`` branch.  ``t0``
+        is the door's take-up stamp (perf_counter): the root starts
+        there, not at the sampling decision, which needs the headers."""
         raw = (headers or {}).get(_TRACE_HEADER_L)
         if raw is None:
             if not (self.sample_rate > 0.0 and self._rng.random() < self.sample_rate):
                 return None
-            trace = Trace(name)
+            trace = Trace(name, t0=t0)
         else:
             tid = "" if raw.strip().lower() in ("1", "true", "yes") else raw.strip()
-            trace = Trace(name, trace_id=tid, forced=True, propagate=True)
+            trace = Trace(name, trace_id=tid, forced=True, propagate=True, t0=t0)
         with self._mu:
             self.stat_sampled += 1
         self.stats.count("trace.sampled")
@@ -298,7 +375,7 @@ class Tracer:
             # Unsampled but slow: synthesize a root-only trace so the
             # ring and the log still carry the event (head sampling
             # cannot reconstruct stages after the fact).
-            trace = Trace(name)
+            trace = Trace(name, annotate=False)
             trace.root.ms = dt_ms
             trace.root.tags["unsampled"] = True
         root = trace.root

@@ -489,6 +489,90 @@ def test_profile_endpoints(client):
     assert status4 == 409
 
 
+def test_profile_door_traces_the_device_and_the_spans_not_python(client, monkeypatch):
+    """The door hands jax a ProfileOptions with the Python tracer off (the
+    host tracer stays at its default, which keeps annotations)."""
+    import jax
+
+    seen = {}
+
+    def start_trace(log_dir, *a, profiler_options=None, **kw):
+        seen["dir"], seen["options"] = log_dir, profiler_options
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: seen.setdefault("stopped", True))
+    status, _ = client._request("POST", "/debug/profile/start")
+    assert status == 200
+    assert isinstance(seen["options"], jax.profiler.ProfileOptions)
+    assert seen["options"].python_tracer_level == 0
+    assert seen["options"].host_tracer_level == jax.profiler.ProfileOptions().host_tracer_level
+    status, _ = client._request("POST", "/debug/profile/stop")
+    assert status == 200 and seen["stopped"]
+
+
+def test_profile_holds_the_programs_spans_on_the_traces_clock(tmp_path):
+    """A traced request between start and stop leaves host events named
+    after its spans in the .xplane.pb, between the door's two markers,
+    and no Python frame."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    s = Server(Config(data_dir=str(tmp_path / "prof"), host="127.0.0.1:0", engine="jax"))
+    s.open()
+    try:
+        c = Client(s.host)
+        c.create_index("i")
+        c.create_frame("i", "f")
+        for r in range(3):
+            c.execute_query("i", " ".join(
+                f'SetBit(rowID={r}, frame="f", columnID={k * 3 + r})' for k in range(20)))
+        q = " ".join(
+            f'Count(Intersect(Bitmap(rowID={a}, frame="f"), Bitmap(rowID={b}, frame="f")))'
+            for a, b in ((0, 1), (0, 2), (1, 2)))
+
+        def post(body, trace):
+            req = urllib.request.Request(
+                f"http://{s.host}/index/i/query", data=body.encode(), method="POST")
+            req.add_header("X-Pilosa-No-Cache", "1")
+            if trace:
+                req.add_header("X-Pilosa-Trace", "1")
+            return urllib.request.urlopen(req, timeout=120).read()
+
+        for _ in range(4):  # rows resident, Gram built, serve state armed
+            post(q, trace=False)
+        trace_dir = str(tmp_path / "xplane")
+        status, _ = c._request("POST", f"/debug/profile/start?dir={trace_dir}")
+        if status == 500:
+            pytest.skip("jax profiler unavailable in this environment")
+        post('SetBit(rowID=1, frame="f", columnID=900)', trace=True)
+        post(q, trace=True)     # finds the state stale: repairs under the pool's lock
+        post(q, trace=False)    # an unsampled request leaves nothing
+        status, _ = c._request("POST", "/debug/profile/stop")
+        assert status == 200
+        (path,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+        events = []
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name == "/host:CPU":
+                for line in plane.lines:
+                    events += [(ev.name, ev.start_ns, ev.duration_ns) for ev in line.events]
+        names = [n for n, _, _ in events]
+        for want in ("profile_door start_trace", "profile_door stop_trace", "POST /index/i/query",
+                     "serve.repair", "pool.lock_wait", "pool.repair", "pool.gram", "write.apply",
+                     "device", "encode"):
+            assert want in names, (want, sorted(set(names))[:40])
+        assert names.count("profile_door start_trace") == names.count("profile_door stop_trace") == 1
+        assert names.count("encode") == 2 and "door.read" not in names
+        assert not any(".py:" in n or n.startswith("$") for n in names)  # no Python frame
+        at = {n: (t0, t0 + d) for n, t0, d in events}
+        lo, hi = at["profile_door start_trace"][1], at["profile_door stop_trace"][0]
+        assert lo <= at["pool.repair"][0] <= at["pool.repair"][1] <= hi
+        # The stages sit inside the repair on the trace's clock too.
+        assert at["pool.repair"][0] <= at["pool.gram"][0] <= at["pool.gram"][1] <= at["pool.repair"][1]
+    finally:
+        s.close()
+
+
 def test_set_quick_property(tmp_path):
     """Full-stack property test (server_test.go:42-121 TestMain_Set_Quick):
     random SetBits over HTTP, Bitmap() must match a model dict, and state

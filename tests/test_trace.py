@@ -258,12 +258,14 @@ def test_executor_spans_fused_and_lanes(holder):
         assert fsp.tags["calls"] == 2 and fsp.tags["slices"] >= 1
     finally:
         del os.environ["PILOSA_TPU_NO_FASTLANE"]
-    # Fast lanes tag without span children (single-branch sites).
+    # Fast lanes tag the root; the write lane's one child is its apply.
     ex2 = Executor(holder, engine="numpy")
     root2 = Span("root")
     ex2.execute("i", 'SetBit(rowID=9, frame="f", columnID=3)',
                 opt=ExecOptions(span=root2))
     assert root2.tags.get("lane") == "write_fast"
+    assert [c.name for c in root2.children] == ["write.apply"]
+    assert root2.children[0].tags == {"changed": 1} and root2.children[0].ms is not None
 
 
 def test_executor_qcache_span_outcomes(holder):
@@ -275,10 +277,12 @@ def test_executor_qcache_span_outcomes(holder):
     r1 = Span("r1")
     ex.execute("i", q, opt=ExecOptions(span=r1))
     assert r1.tags["qcache"] == "miss"
+    names1 = [c.name for c in r1.children]
+    assert names1.index("qcache.lookup") < names1.index("qcache.commit")  # the miss's admission
     r2 = Span("r2")
     ex.execute("i", q, opt=ExecOptions(span=r2))
     assert r2.tags["qcache"] == "hit"
-    assert any(c.name == "qcache.lookup" for c in r2.children)
+    assert [c.name for c in r2.children] == ["qcache.lookup"]  # a hit commits nothing
     r3 = Span("r3")
     ex.execute("i", q, opt=ExecOptions(span=r3, no_cache=True))
     assert r3.tags["qcache"] == "bypass"
@@ -399,3 +403,360 @@ def test_client_deadline_expiry_during_backoff_aborts_retry():
         assert time.monotonic() - t0 < 1.0
     finally:
         stub.close()
+
+
+# -- the served path's layers: door, serve state, pool, write lane, encode ----
+#
+# One real server on the jax engine (CPU backend): the host Gram lanes
+# serve the reads, a SetBit makes the serve state stale, and the next
+# read repairs the pool's planes and Gram under the pool's lock.
+
+_PAIRS = " ".join(
+    f'Count(Intersect(Bitmap(rowID={a}, frame="f"), Bitmap(rowID={b}, frame="f")))'
+    for a in range(4) for b in range(a + 1, 4)
+)
+
+
+def _post(host, body, trace=True):
+    import urllib.request
+
+    req = urllib.request.Request(
+        f"http://{host}/index/i/query", data=body.encode(), method="POST"
+    )
+    req.add_header("X-Pilosa-No-Cache", "1")
+    if trace:
+        req.add_header(TRACE_HEADER, "1")
+    with urllib.request.urlopen(req, timeout=120) as r:
+        out = json.loads(r.read())["results"]
+        tree = r.headers.get(TRACE_SPANS_HEADER)
+    return out, (json.loads(tree)[0] if tree else None)
+
+
+def _find(node, name):
+    """Every span of that name in a serialized tree, depth first."""
+    out = [node] if node["name"] == name else []
+    for c in node.get("children", []):
+        out.extend(_find(c, name))
+    return out
+
+
+def _fits(node, outer_start, outer_end, slack=0.05):
+    end = node["start_ms"] + node["ms"]
+    ok = node["start_ms"] >= outer_start - slack and end <= outer_end + slack
+    return ok and all(_fits(c, node["start_ms"], end, slack) for c in node.get("children", []))
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    from pilosa_tpu.server.client import Client
+    from pilosa_tpu.server.server import Server
+
+    cfg = Config(data_dir=str(tmp_path_factory.mktemp("served")), host="127.0.0.1:0",
+                 engine="jax")
+    s = Server(cfg)
+    s.open()
+    c = Client(s.host)
+    c.create_index("i")
+    c.create_frame("i", "f")
+    for r in range(4):
+        c.execute_query("i", " ".join(
+            f'SetBit(rowID={r}, frame="f", columnID={k * 3 + r})' for k in range(40)))
+    for _ in range(4):  # page the rows in, build the Gram, arm the serve state
+        _post(s.host, _PAIRS, trace=False)
+    yield s
+    s.close()
+
+
+def _pool(served):
+    (pool,) = served.executor._matrix_cache.values()
+    return pool
+
+
+def test_served_flat_read_has_a_span_for_each_layer(served):
+    res, root = _post(served.host, _PAIRS)
+    assert len(res) == 6 and root["tags"]["lane"] == "flat"
+    names = [c["name"] for c in root["children"]]
+    for want in ("door.read", "qos.admit", "serve.validate", "device", "encode"):
+        assert want in names, names
+    assert root["children"][0]["name"] == "door.read"
+    assert root["children"][0]["start_ms"] == 0.0  # the root starts at take-up
+    assert root["children"][0]["tags"]["bytes"] == len(_PAIRS)
+    assert _find(root, "serve.validate")[0]["tags"] == {"valid": True}
+    assert _find(root, "encode")[0]["tags"]["bytes"] > 0
+    assert _fits(root, 0.0, root["ms"])
+    assert 0 < root["tags"]["cpu_ms"] <= root["ms"]
+    # One after another: the layers' spans never overlap.
+    flat = sorted(root["children"], key=lambda c: c["start_ms"])
+    for a, b in zip(flat, flat[1:]):
+        assert a["start_ms"] + a["ms"] <= b["start_ms"] + 0.05
+
+
+def test_read_after_a_setbit_shows_the_repair_under_the_pools_lock(served):
+    host = served.host
+    res0, _ = _post(host, _PAIRS, trace=False)
+    wres, _ = _post(host, 'SetBit(rowID=1, frame="f", columnID=1000)', trace=False)
+    assert wres == [True]
+    res, root = _post(host, _PAIRS)
+    assert _find(root, "serve.validate")[0]["tags"] == {"valid": False}
+    (rep,) = _find(root, "serve.repair")
+    assert rep["tags"] == {"repaired": True}
+    assert [c["name"] for c in rep["children"]] == ["pool.lock_wait", "pool.repair"]
+    (prep,) = _find(rep, "pool.repair")
+    assert [c["name"] for c in prep["children"]] == ["pool.fetch", "pool.scatter", "pool.gram"]
+    assert prep["tags"]["planes"] == 1 and prep["tags"]["slices"] == 1
+    assert prep["tags"]["upload_bytes"] >= 131072  # one 128 KiB plane
+    assert sum(c["ms"] for c in prep["children"]) <= prep["ms"] + 0.05
+    assert _fits(root, 0.0, root["ms"])
+    assert "device" in [c["name"] for c in root["children"]]  # then served natively
+    assert res == res0  # column 1000 is in no other row
+
+
+def test_a_reader_behind_a_held_pool_lock_shows_the_wait(served):
+    import threading
+
+    host, pool = served.host, _pool(served)
+    _post(host, 'SetBit(rowID=2, frame="f", columnID=2000)', trace=False)
+    trees = []
+
+    def reader():
+        trees.append(_post(host, _PAIRS)[1])
+
+    with pool.mu:
+        threads = [threading.Thread(target=reader) for _ in range(2)]
+        for t in threads:
+            t.start()
+        time.sleep(0.4)  # both have found the state stale and queue for the lock
+        t_hold = time.perf_counter()
+        time.sleep(0.15)
+        held_ms = (time.perf_counter() - t_hold) * 1e3
+    for t in threads:
+        t.join(60)
+    assert len(trees) == 2
+    repairs = [_find(t, "serve.repair")[0] for t in trees]
+    for rep in repairs:
+        (wait,) = _find(rep, "pool.lock_wait")
+        assert wait["ms"] >= held_ms
+        assert rep["ms"] >= wait["ms"]
+    # One of the two repaired; the other found it done when the lock came.
+    assert sorted(r["tags"]["repaired"] for r in repairs) == [False, True]
+    idle = next(r for r in repairs if not r["tags"]["repaired"])
+    assert [c["name"] for c in idle["children"]] == ["pool.lock_wait"]
+    for t in trees:  # waiting holds no core
+        assert t["ms"] - t["tags"]["cpu_ms"] >= held_ms
+
+
+@pytest.mark.parametrize("body,lane,changed", [
+    ('SetBit(rowID=3, frame="f", columnID=3000)', "write_fast", 1),
+    ('SetBit(rowID=3, frame="f", columnID=3000)', "write_fast", 0),
+    ('SetBit(rowID=3, frame="f", columnID=3001) SetBit(rowID=3, frame="f", columnID=3002)',
+     "write_native", 2),
+    ('ClearBit(rowID=3, frame="f", columnID=3001)', "write_fast", 1),
+])
+def test_a_write_has_write_apply(served, body, lane, changed):
+    _res, root = _post(served.host, body)
+    assert root["tags"]["lane"] == lane
+    (wsp,) = _find(root, "write.apply")
+    assert wsp["tags"]["changed"] == changed
+    assert _fits(root, 0.0, root["ms"])
+    assert not _find(root, "serve.validate")  # a read's span
+
+
+def test_an_unsampled_request_builds_no_span_and_no_annotation(served, monkeypatch):
+    from pilosa_tpu import trace as trace_mod
+
+    made = {"span": 0, "annotation": 0}
+    real_init = Span.__init__
+
+    def counting_init(self, *a, **kw):
+        made["span"] += 1
+        real_init(self, *a, **kw)
+
+    def counting_annotation(name):
+        made["annotation"] += 1
+        return None
+
+    monkeypatch.setattr(Span, "__init__", counting_init)
+    monkeypatch.setattr(trace_mod, "_open_annotation", counting_annotation)
+    host = served.host
+    _post(host, 'SetBit(rowID=0, frame="f", columnID=4000)', trace=False)
+    res, tree = _post(host, _PAIRS, trace=False)  # repairs, then serves
+    _post(host, _PAIRS, trace=False)
+    assert len(res) == 6 and tree is None
+    assert made == {"span": 0, "annotation": 0}
+    # The same three sampled: every span opens one annotation, but for
+    # door.read, which is made after the fact.
+    _post(host, 'SetBit(rowID=0, frame="f", columnID=4001)')
+    _res, tree = _post(host, _PAIRS)
+    assert made["span"] > 10
+    assert made["span"] - made["annotation"] == 2  # two requests' door.read
+
+
+def test_spans_are_annotations_while_open(monkeypatch):
+    from pilosa_tpu import trace as trace_mod
+
+    log = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    monkeypatch.setattr(trace_mod, "_annotation_cls", Ann)
+    root = Span("root")
+    a = root.child("a")
+    a.finish()
+    a.finish()  # idempotent: leaves once
+    root.record("before", root.t0 - 0.002, root.t0 - 0.001)
+    root.finish()
+    assert log == [("enter", "root"), ("enter", "a"), ("exit", "a"), ("exit", "root")]
+    monkeypatch.setattr(trace_mod, "_annotation_cls", False)  # no jax: spans alone
+    assert Span("x").finish().ms is not None
+
+
+def test_root_starts_at_the_doors_stamp_and_record_backdates():
+    tr = Tracer(sample_rate=1.0)
+    t0 = time.perf_counter() - 0.010
+    trace = tr.begin({}, name="POST /x", t0=t0)
+    sp = trace.root.record("door.read", t0, t0 + 0.004)
+    trace.root.finish()
+    assert trace.root.ms >= 10.0
+    js = trace.root.to_json()
+    assert js["children"][0] == {"name": "door.read", "start_ms": 0.0, "ms": 4.0}
+    assert sp.ms == pytest.approx(4.0)
+    # An unsampled-but-slow request synthesizes a root that opens nothing.
+    assert Trace("slow", annotate=False).root._ann is None
+
+
+def test_forced_trace_ids_are_distinct_without_uuid():
+    import sys
+
+    tr = Tracer()
+    ids = {tr.begin({TRACE_HEADER.lower(): "1"}, name="r").id for _ in range(10000)}
+    assert len(ids) == 10000
+    assert all(len(i) == 16 and int(i, 16) >= 0 for i in ids)
+    assert len({i[:8] for i in ids}) == 1  # one process, one prefix
+    assert "uuid" not in vars(sys.modules["pilosa_tpu.trace"])
+
+
+def test_cost_ledger_and_slow_stages_read_a_tree_with_the_new_children(caplog):
+    from pilosa_tpu.costs import CostLedger
+
+    ledger = CostLedger()
+    tr = Tracer(sample_rate=1.0, slow_ms=0.0001, costs=ledger)
+    trace = tr.begin({}, name="POST /index/i/query")
+    root = trace.root
+    root.tags.update(index="i", frame="f", lane="flat")
+    root.record("door.read", root.t0, root.t0 + 0.001)
+    root.child("serve.validate").finish()
+    rep = root.child("serve.repair")
+    rep.child("pool.lock_wait").finish()
+    prep = rep.child("pool.repair")
+    for name in ("pool.fetch", "pool.scatter", "pool.gram"):
+        prep.child(name).finish()
+    prep.finish()
+    rep.finish()
+    dev = root.child("device")
+    dev.ms = 0.7
+    dev.tags.update(lane="native", bytes=437)
+    wsp = root.child("write.apply")
+    nested = wsp.child("device")  # the write lane's crossing nests one deeper
+    nested.ms = 0.3
+    nested.tags.update(lane="native", bytes=40)
+    wsp.finish()
+    root.child("encode").finish()
+    with caplog.at_level(logging.WARNING, logger="pilosa_tpu.slowquery"):
+        tr.finish_request(trace, name=root.name, dt_ms=5.0, body=b"Count(x)")
+    (entry,) = ledger.entries()
+    assert entry["lane"] == "flat" and entry["frame"] == "f"
+    assert entry["ewma_device_ms"] == pytest.approx(1.0)  # both crossings, each once
+    rec = json.loads(caplog.records[-1].getMessage().split("slow-query ", 1)[1])
+    assert set(rec["stages"]) == {"door.read", "serve.validate", "serve.repair", "device",
+                                  "write.apply", "encode"}
+    assert rec["stages"]["device"] == 0.7 and rec["stages"]["door.read"] == 1.0
+
+
+def test_pool_and_engine_counters_reach_debug_vars(served):
+    import urllib.request
+
+    host = served.host
+    snap0 = json.loads(urllib.request.urlopen(f"http://{host}/debug/vars", timeout=30).read())
+    _post(host, 'SetBit(rowID=1, frame="f", columnID=5000)', trace=False)
+    _post(host, _PAIRS, trace=False)
+    snap = json.loads(urllib.request.urlopen(f"http://{host}/debug/vars", timeout=30).read())
+    pool = _pool(served)
+    assert snap["rowpool.misses"] == pool.stat_misses == 4
+    assert snap["rowpool.repairs"] == pool.stat_repairs == snap0["rowpool.repairs"] + 1
+    assert snap["rowpool.patch_planes"] == pool.stat_patch_planes
+    assert snap["engine.upload_bytes"] == served.executor.engine.stat_upload_bytes
+    assert snap["engine.upload_bytes"] - snap0["engine.upload_bytes"] >= 131072
+    metrics = urllib.request.urlopen(f"http://{host}/metrics", timeout=30).read().decode()
+    assert "rowpool_repairs" in metrics and "engine_upload_bytes" in metrics
+
+
+def test_a_cold_read_through_the_coalescing_queue_owns_its_pool_spans(tmp_path):
+    """The unarmed flat lane hands its arrays to the serve queue; the
+    shared pass's pool spans go to the group's first sampled request."""
+    from pilosa_tpu.server.client import Client
+    from pilosa_tpu.server.server import Server
+
+    s = Server(Config(data_dir=str(tmp_path / "cold"), host="127.0.0.1:0", engine="jax"))
+    s.open()
+    try:
+        c = Client(s.host)
+        c.create_index("i")
+        c.create_frame("i", "f")
+        c.execute_query("i", " ".join(
+            f'SetBit(rowID={r}, frame="f", columnID={r + 7})' for r in range(4)))
+        _res, root = _post(s.host, _PAIRS)
+        assert root["tags"]["coalesced"] == 1
+        (miss,) = _find(root, "pool.miss")
+        assert miss["tags"]["rows"] == 4 and miss["tags"]["evicted"] == 0
+        assert miss["tags"]["upload_bytes"] >= 4 * 131072  # the rows, and the new pool
+        assert _find(root, "pool.lock_wait")
+    finally:
+        s.close()
+
+
+def test_pool_spans_and_counters_for_paging_refresh_and_eviction():
+    """The pool alone, on the numpy engine: a miss pages rows in
+    (``pool.miss``), a write with no journal takes the blind refresh
+    (``pool.refresh``), a full pool evicts; each count also goes out
+    through the stats client where it is incremented."""
+    import numpy as np
+
+    from pilosa_tpu.engine import NumpyEngine
+    from pilosa_tpu.rowpool import DeviceRowPool
+
+    class Counts:
+        def __init__(self):
+            self.n = {}
+
+        def count(self, name, value=1):
+            self.n[name] = self.n.get(name, 0) + value
+
+    def fetch(row_ids, slice_idxs):
+        return np.zeros((len(slice_idxs), len(row_ids), 8), dtype=np.uint32)
+
+    stats = Counts()
+    pool = DeviceRowPool(NumpyEngine(), 2, 8, fetch, cap_max=2, stats=stats)
+    root = Span("root")
+    pool.acquire([1, 2], (0, 0), span=root)
+    pool.acquire([1, 2], (0, 1), span=root)            # slice 1 written, delta unknown
+    pool.acquire([3], (0, 1), span=root)               # full: row 1 goes
+    pool.acquire([3], (0, 1))                          # unsampled: no span
+    names = [c.name for c in root.children]
+    assert names == ["pool.lock_wait", "pool.miss", "pool.lock_wait", "pool.refresh",
+                     "pool.lock_wait", "pool.miss"]
+    assert all(c.ms is not None for c in root.children)
+    assert root.children[1].tags == {"rows": 2, "evicted": 0, "upload_bytes": 0}
+    assert root.children[3].tags == {"rows": 2, "upload_bytes": 0}
+    assert root.children[5].tags == {"rows": 1, "evicted": 1, "upload_bytes": 0}
+    assert stats.n == {"rowpool.misses": 3, "rowpool.evictions": 1}
+    assert (pool.stat_misses, pool.stat_evictions, pool.stat_repairs) == (3, 1, 0)
+    pool._reset()
+    assert stats.n["rowpool.resets"] == pool.stat_resets == 1
