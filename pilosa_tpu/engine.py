@@ -61,6 +61,35 @@ def nbytes(*arrays) -> int:
     return total
 
 
+def _pow2(n: int) -> int:
+    """The power-of-two bucket of a count (jitted shapes stay few)."""
+    return 1 << (n - 1).bit_length() if n > 1 else 1
+
+
+def _repair_planes_composed(engine, matrix, gram, groups):
+    """The copying form of ``repair_planes``, from the engine's own
+    ``set_plane_rows`` (one functional scatter per group: a new array each
+    time, the caller's stays whole) and ``gram_update_rows``, which gets
+    the pre-patch array for its restricted-slice delta.  What the numpy
+    and mesh engines run, and the jax engine for the repairs its compiled
+    step does not take."""
+    old = matrix
+    for slice_idxs, slots, block in groups:
+        matrix = engine.set_plane_rows(matrix, slice_idxs, slots, block)
+    if gram is None:
+        return matrix, None, False
+
+    def finish():
+        d = gram.shape[0]
+        new, was = (matrix, old) if d == matrix.shape[1] else (matrix[:, :d], old[:, :d])
+        return engine.gram_update_rows(
+            new, gram, [s for _, slots, _ in groups for s in slots], old_matrix=was,
+            slice_idxs=[si for slice_idxs, _, _ in groups for si in slice_idxs],
+        )
+
+    return matrix, finish, False
+
+
 class NumpyEngine:
     name = "numpy"
     # No jit: callers may use exact (ragged) dispatch shapes freely.
@@ -280,16 +309,18 @@ class NumpyEngine:
         return None
 
     def gram_update_rows(self, matrix, gram, slots, old_matrix=None, slice_idxs=None):
-        """Rank-k repair of a host AND-count Gram after in-place row
-        rewrites: recompute ONLY the dirty rows/columns with one batched
-        pair-count pass against the (already patched) resident matrix —
-        O(K*R*W) instead of the O(R^2*W) full rebuild.  Returns a NEW
-        array (copy-on-write: readers holding the old Gram keep a
-        consistent pre-write snapshot; AND is symmetric, so one K x R
-        count block fills both the rows and the columns).
+        """Rank-k repair of a host AND-count Gram after row rewrites
+        (the Gram half of the copying ``repair_planes``): recompute ONLY
+        the dirty rows/columns with one batched pair-count pass against
+        the (already patched) resident matrix — O(K*R*W) instead of the
+        O(R^2*W) full rebuild.  Returns a NEW array (copy-on-write:
+        readers holding the old Gram keep a consistent pre-write
+        snapshot; AND is symmetric, so one K x R count block fills both
+        the rows and the columns).
 
-        Per-(row, slice) delta mode: with ``old_matrix`` (the pre-patch
-        snapshot) and ``slice_idxs`` (the slice planes actually written),
+        Per-(row, slice) delta mode: with ``old_matrix`` (the array as it
+        was before the patch, which the copying repair still holds) and
+        ``slice_idxs`` (the slice planes actually written),
         the dirty rows' counts are ADJUSTED by (new - old) restricted to
         those slices instead of recomputed over the whole span —
         unchanged slices cancel out of the difference, so the dispatch
@@ -319,6 +350,19 @@ class NumpyEngine:
         out[slots, :] = block
         out[:, slots] = block.T
         return out
+
+    def repair_planes(self, matrix, gram, groups, donate=False):
+        """Patch the written planes of a pool matrix and repair its Gram:
+        ``groups`` = [(slice_idxs, slots, block[len(slice_idxs),
+        len(slots), W])], the written (slice, slot) cells with their new
+        contents; ``gram`` the host Gram over the first ``gram.shape[0]``
+        slots, or None (the planes alone).  Returns ``(matrix, finish,
+        in_place)``: the patched matrix at once, ``finish()`` the repaired
+        Gram (a new array; it blocks on the device where there is one;
+        None without a Gram), and whether the caller's array was updated
+        in place and is gone.  ``donate`` says the caller holds the only
+        reference to ``matrix``; this engine copies regardless."""
+        return _repair_planes_composed(self, matrix, gram, groups)
 
     def to_numpy(self, x) -> np.ndarray:
         return np.asarray(x)
@@ -638,8 +682,10 @@ class JaxEngine:
         return self.to_numpy(self._gram_jit(self._jnp.asarray(matrix))).astype(np.int64)
 
     def gram_update_rows(self, matrix, gram, slots, old_matrix=None, slice_idxs=None):
-        """Rank-k Gram repair (see NumpyEngine.gram_update_rows): one
-        batched gather-count dispatch recomputes the dirty rows/columns.
+        """Rank-k Gram repair (see NumpyEngine.gram_update_rows), for the
+        repairs the compiled step of ``repair_planes`` does not take and
+        for the mesh engine: one batched gather-count dispatch
+        recomputes the dirty rows/columns.
         The dirty-slot axis pads to a power-of-two bucket (recomputing a
         row twice is idempotent) so the jitted dispatch shape stays
         stable across repairs of 1..K rows.
@@ -656,7 +702,7 @@ class JaxEngine:
         wouldn't pay (>= half the slices dirty after padding)."""
         slots = sorted({int(s) for s in slots})
         k = len(slots)
-        kb = 1 << (k - 1).bit_length() if k > 1 else 1
+        kb = _pow2(k)
         padded = np.asarray(slots + [slots[0]] * (kb - k), dtype=np.int32)
         n = gram.shape[0]
         pairs = np.empty((kb * n, 2), dtype=np.int32)
@@ -666,7 +712,7 @@ class JaxEngine:
         n_slices = matrix.shape[0]
         si = sorted({int(s) for s in slice_idxs}) if slice_idxs is not None else None
         if old_matrix is not None and si:
-            sb = 1 << (len(si) - 1).bit_length() if len(si) > 1 else 1
+            sb = _pow2(len(si))
             clean = next((s for s in range(n_slices) if s not in set(si)), None)
             if clean is not None and 2 * sb < n_slices:
                 sel = self._jnp.asarray(
@@ -707,6 +753,60 @@ class JaxEngine:
         out[idx, :] = block
         out[:, idx] = block.T
         return out
+
+    def repair_planes(self, matrix, gram, groups, donate=False):
+        """One compiled step (``ops.bitwise.repair_planes``, jitted with
+        the matrix donated) for the repairs that ``gram_update_rows``
+        would answer by its restricted-slice delta: one upload of the
+        written planes, one dispatch that writes them into the pool's
+        own buffer and counts what each write does to the Gram, and in
+        ``finish()`` one blocking read of int32[cells, n], folded into a
+        copy of the host Gram.  The cell axis pads to a power-of-two
+        bucket so the compiled shapes stay few.  Without ``donate`` a
+        reader still holds ``matrix``: the step then runs on a copy made
+        first.  Repairs without a Gram, wide ones and those over half
+        the slices keep the composed form."""
+        cells = [
+            (si, slot) for slice_idxs, slots, _ in groups
+            for si in slice_idxs for slot in slots
+        ]
+        k = len({slot for _, slot in cells})
+        sb = _pow2(len({si for si, _ in cells}))
+        if gram is None or 2 * k >= gram.shape[0] or 2 * sb >= matrix.shape[0]:
+            return _repair_planes_composed(self, matrix, gram, groups)
+        if not hasattr(self, "_repair_jit"):
+            import jax
+
+            from pilosa_tpu.ops.bitwise import repair_planes
+
+            self._repair_jit = jax.jit(
+                repair_planes, static_argnums=3, donate_argnums=0
+            )
+        cb = _pow2(len(cells))
+        idx = np.full((cb, 2), -1, dtype=np.int32)
+        idx[: len(cells)] = cells
+        planes = np.zeros((cb,) + groups[0][2].shape[2:], dtype=np.uint32)
+        at = 0
+        for slice_idxs, slots, block in groups:
+            g = len(slice_idxs) * len(slots)
+            planes[at : at + g] = np.asarray(block).reshape(g, -1)
+            at += g
+        self._note_upload(planes.nbytes)
+        if matrix.ndim == 4:
+            planes = self._tile_host(planes)
+        if not donate:
+            matrix = self._jnp.copy(matrix)
+        matrix, delta = self._repair_jit(matrix, idx, planes, gram.shape[0])
+
+        def finish():
+            out = np.array(gram, copy=True)
+            for (_, slot), d in zip(cells, np.asarray(delta)):
+                out[slot, :] += d
+                out[:, slot] += d
+                out[slot, slot] -= d[slot]
+            return out
+
+        return matrix, finish, donate
 
     def to_numpy(self, x) -> np.ndarray:
         return np.asarray(x)
@@ -923,6 +1023,12 @@ class MeshEngine(JaxEngine):
         # multi-process jobs).  The full rank-k recompute stays
         # SPMD-safe on every rank.
         return super().gram_update_rows(matrix, gram, slots)
+
+    def repair_planes(self, matrix, gram, groups, donate=False):
+        # The compiled step indexes single slices of the pool; on the
+        # sharded slice axis the composed form stays, with the overrides
+        # above.
+        return _repair_planes_composed(self, matrix, gram, groups)
 
     def _pallas_mode(self, n_slices: int, w: int) -> str:
         """How to run kernels under the mesh: "pallas" (shard_map'd

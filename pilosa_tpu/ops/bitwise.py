@@ -370,6 +370,50 @@ def pair_gram(row_matrix):
     return lax.scan(step, jnp.zeros((r, r), jnp.int32), jnp.arange(s * nc))[0]
 
 
+def repair_planes(row_matrix, cells, planes, n: int):
+    """Write new planes into a pool matrix and return what each write
+    does to the AND-count Gram over the first ``n`` slots.
+
+    ``row_matrix``: uint32[S, cap, ...words] (tiled or logical), meant to
+    be donated, so that the writes land in the caller's buffer;
+    ``cells``: int32[C, 2] of (slice, slot), a bucket's unused tail
+    (-1, -1); ``planes``: uint32[C, ...words], the cells' new contents.
+    Returns ``(row_matrix, delta int32[C, n])``.
+
+    One cell after another: ``delta[c, j]`` = |new & row_j| - |old &
+    row_j| over the cell's slice as the cells before it left it (one pass
+    over that slice's rows: 32 MiB at cap 256), and at the cell's own
+    slot |new| - |old|; then the plane is written.  The deltas telescope:
+    adding ``delta[c]`` into row and column ``slot_c`` of the Gram, and
+    taking ``delta[c, slot_c]`` off the diagonal once, gives the Gram of
+    the patched matrix exactly, also where a burst wrote several rows of
+    one slice.  Only the first ``count(slice >= 0)`` cells run, so a
+    bucket's tail costs its upload alone.
+    """
+    wd = row_matrix.shape[2:]
+    z = (0,) * len(wd)
+    axes = tuple(range(1, 1 + len(wd)))
+
+    def ones(x, over):
+        return jnp.sum(lax.population_count(x).astype(jnp.int32), axis=over)
+
+    def one_cell(c, carry):
+        m, delta = carry
+        si, slot = cells[c, 0], cells[c, 1]
+        new = planes[c]
+        old = lax.dynamic_slice(m, (si, slot) + z, (1, 1) + wd)[0, 0]
+        rows = lax.dynamic_slice(m, (si, 0) + z, (1, n) + wd)[0]
+        d = ones(rows & new, axes) - ones(rows & old, axes)
+        d = d.at[slot].set(ones(new, None) - ones(old, None))
+        m = lax.dynamic_update_slice(m, new[None, None], (si, slot) + z)
+        return m, lax.dynamic_update_index_in_dim(delta, d, c, 0)
+
+    return lax.fori_loop(
+        0, jnp.sum(cells[:, 0] >= 0), one_cell,
+        (row_matrix, jnp.zeros((cells.shape[0], n), jnp.int32)),
+    )
+
+
 def gram_pair_counts(op: str, gram, pairs):
     """Per-pair counts for any pair op from the AND-Gram matrix.
 

@@ -16,13 +16,16 @@ power-of-two doubling up to an HBM budget.  Query kernels index rows by
 SLOT id — the same gather kernels as before, they never cared whether
 slot assignment was dense or paged.
 
-Consistency model: every content change produces a NEW engine array
-(functional ``.at[].set``), so a reader that acquired ``(positions,
-matrix)`` holds an immutable snapshot — a concurrent eviction can only
-affect later acquires, never a result in flight.  Write invalidation is
-generation-based exactly like the old cache: stale slices get their
-planes re-fetched (bounded), or the pool resets when a refresh would
-cost more than repopulating on demand.
+Consistency model: a reader that acquired ``(positions, matrix)`` for
+rows it wanted holds an immutable snapshot — a concurrent eviction or
+write repair can only affect later acquires, never a result in flight.
+Paging, growth and the blind refresh produce a NEW engine array
+(functional ``.at[].set``); the write repair (``_repair_dirty``) may
+update the pool's array in place, and does so only while that array has
+never been handed to a reader.  Write invalidation is generation-based
+exactly like the old cache: stale slices get their planes re-fetched
+(bounded), or the pool resets when a refresh would cost more than
+repopulating on demand.
 """
 
 from __future__ import annotations
@@ -99,7 +102,7 @@ class DeviceRowPool:
         self._cap_override = cap_max
         self.mu = lockcheck.named_rlock("rowpool.mu")
         self.gens: Optional[tuple] = None
-        self.matrix = None  # engine array [n_slices, cap, W]
+        self.matrix = None  # engine array [n_slices, cap, W]; clears _handed_out
         self.cap = 0
         self.slot_of: dict[int, int] = {}
         self.row_at: list[Optional[int]] = []
@@ -107,11 +110,15 @@ class DeviceRowPool:
         self.box: dict = self._new_box()
         # Telemetry for benches/tests: paging behavior must be observable.
         # Each also goes out through ``stats`` where it is incremented
-        # (rowpool.misses, .evictions, .resets, .repairs, .patch_planes).
+        # (rowpool.misses, .evictions, .resets, .repairs, .repairs_in_place,
+        # .patch_planes).
         self.stat_misses = 0
         self.stat_evictions = 0
         self.stat_resets = 0
         self.stat_repairs = 0
+        # Of those, the ones that updated the pool's array where it lay
+        # (no copy of the pool: the engine's compiled step, donated).
+        self.stat_repairs_in_place = 0
         # (row, slice) planes actually fetched by the patch lane — the
         # per-(row, slice) granularity benches/tests assert on this.
         self.stat_patch_planes = 0
@@ -122,6 +129,18 @@ class DeviceRowPool:
         shared with callers that must predict a pool's capacity WITHOUT
         instantiating it (executor lane probes)."""
         return max(1, pool_capacity(n_slices, words))
+
+    @property
+    def matrix(self):
+        return self._matrix
+
+    @matrix.setter
+    def matrix(self, m) -> None:
+        # A new array object has not left the pool yet: ``acquire`` marks
+        # it when it hands it to a caller that asked for rows, and a write
+        # repair may donate it (update it in place) until then.
+        self._matrix = m
+        self._handed_out = False
 
     @property
     def cap_max(self) -> int:
@@ -176,6 +195,15 @@ class DeviceRowPool:
         self.stat_resets += 1
         self.stats.count("rowpool.resets")
 
+    def _drop(self) -> None:
+        """To the empty state: a repair that failed after the array was
+        donated leaves none, so the next acquire rebuilds from storage."""
+        self.matrix = None
+        self.cap = 0
+        self.gens = None
+        self._reset()
+        self.box = self._new_box()
+
     def _refresh_stale(self, stale: list[int]) -> None:
         """Re-pull resident rows' planes for written slices, or reset.
 
@@ -203,8 +231,8 @@ class DeviceRowPool:
         the box Gram, instead of the blind whole-plane refresh + box
         reset: the box (and with it the Gram, its glut, and the id_pos
         snapshot) SURVIVES the write, so a small write costs O(dirty
-        planes) row fetches plus one dirty x resident pair-count
-        dispatch — not an O(R^2) Gram rebuild.  ``dirty_rows`` is either
+        planes) row fetches plus one pass over the written slices' rows
+        — not an O(R^2) Gram rebuild.  ``dirty_rows`` is either
         a ``{slice_index: rows}`` mapping (per-(row, slice) granularity:
         each stale slice re-fetches only the rows written IN that slice)
         or a flat row iterable (legacy: every dirty row re-fetched
@@ -213,11 +241,22 @@ class DeviceRowPool:
         (fragment dirty-row journals); rows not resident in the pool
         need no patch at all.  Returns False (nothing mutated) when the
         dirty slots fall outside the Gram's slot range — an invariant
-        breach that the conservative full refresh handles.  ``span``
-        (the request's ``pool.repair``) gets a child per stage:
-        ``pool.fetch`` (host densify), ``pool.scatter`` (upload and
-        plane scatter) and ``pool.gram``, where the host blocks on the
-        device for the rank-k counts."""
+        breach that the conservative full refresh handles.
+
+        Planes and Gram go through ONE engine call a repair
+        (``engine.repair_planes``).  While the pool's array has never
+        been handed to a reader, the jax engine updates it IN PLACE (the
+        array is donated to one compiled step: no copy of the pool, and
+        the old array object is gone); otherwise, and on the other
+        engines, the update is functional and a reader's snapshot stays
+        whole.  A step that fails after taking the array leaves the pool
+        empty (``_drop``) and raises.  Row-major pools carry no Gram and
+        keep their functional scatter.
+
+        ``span`` (the request's ``pool.repair``) gets a child per stage:
+        ``pool.fetch`` (host densify), ``pool.scatter`` (index building,
+        upload and the dispatch) and ``pool.gram``, where the host blocks
+        on the device for the counts and folds them into its Gram."""
         if isinstance(dirty_rows, dict):
             per_slice = {
                 si: sorted(r for r in set(dirty_rows.get(si, ())) if r in self.slot_of)
@@ -229,53 +268,64 @@ class DeviceRowPool:
         patched = [si for si in stale if per_slice[si]]
         if not patched:
             return True  # writes only touched rows the pool doesn't hold
-        all_slots = sorted({self.slot_of[r] for si in patched for r in per_slice[si]})
         gram = self.box.get("gram")
-        if gram is not None and any(s >= gram.shape[0] for s in all_slots):
+        if gram is not None and any(
+            self.slot_of[r] >= gram.shape[0] for si in patched for r in per_slice[si]
+        ):
             return False  # defensive: slot outside the Gram bucket
-        old_matrix = self.matrix  # pre-patch snapshot (functional updates)
-        # One fetch + one scatter per distinct row set: slices written
-        # with the same rows batch into a single transfer, and a slice
-        # whose dirty rows aren't resident costs nothing at all.
+        # One fetch per distinct row set: slices written with the same
+        # rows batch into a single densify, and a slice whose dirty rows
+        # aren't resident costs nothing at all.
         by_rows: dict[tuple, list[int]] = {}
         for si in patched:
             by_rows.setdefault(tuple(per_slice[si]), []).append(si)
-        for rows_t, group in by_rows.items():
-            rows = list(rows_t)
-            slots = [self.slot_of[r] for r in rows]
-            sp = span.child("pool.fetch") if span is not None else None
-            block = self.fetch(rows, group)  # layout per self.row_major
-            if sp is not None:
-                sp.finish()
-                sp = span.child("pool.scatter")
-            self.stat_patch_planes += len(rows) * len(group)
-            self.stats.count("rowpool.patch_planes", len(rows) * len(group))
-            if self.row_major:
+        sp = span.child("pool.fetch") if span is not None else None
+        groups = [
+            (group, [self.slot_of[r] for r in rows_t], self.fetch(list(rows_t), group))
+            for rows_t, group in by_rows.items()  # blocks laid out per self.row_major
+        ]
+        if sp is not None:
+            sp.finish()
+            sp = span.child("pool.scatter")
+        planes = sum(len(group) * len(slots) for group, slots, _ in groups)
+        self.stat_patch_planes += planes
+        self.stats.count("rowpool.patch_planes", planes)
+        if self.row_major:
+            for group, slots, block in groups:
                 self.matrix = self.engine.set_plane_rows_rm(
                     self.matrix, group, slots, block
                 )
-            else:
-                self.matrix = self.engine.set_plane_rows(
-                    self.matrix, group, slots, block
-                )
             if sp is not None:
                 sp.finish()
-        if gram is not None:
-            sp = span.child("pool.gram") if span is not None else None
-            d = gram.shape[0]
-            m = self.matrix if d == self.cap else self.matrix[:, :d]
-            m_old = old_matrix if d == self.cap else old_matrix[:, :d]
-            gram = self.engine.gram_update_rows(
-                m, gram, all_slots, old_matrix=m_old, slice_idxs=patched
+            return True
+        taken = self.matrix
+        try:
+            self.matrix, finish, in_place = self.engine.repair_planes(
+                taken, gram, groups, donate=not self._handed_out
             )
+            if sp is not None:
+                sp.finish()
+            if finish is not None:
+                sp = span.child("pool.gram") if span is not None else None
+                gram = finish()
+                if sp is not None:
+                    sp.finish()
+        except BaseException:
+            # The array was taken and not given back, or the planes are
+            # written and the Gram is not: nothing here can be trusted.
+            if self.matrix is not taken or getattr(taken, "is_deleted", lambda: False)():
+                self._drop()
+            raise
+        if in_place:
+            self.stat_repairs_in_place += 1
+            self.stats.count("rowpool.repairs_in_place")
+        if finish is not None:
             self.box["gram"] = gram
             glut = self.box.get("gram_lut")
             if glut is not None:
                 # rs/ps are membership-keyed and membership didn't change;
                 # only the count table is new.
                 self.box["gram_lut"] = (glut[0], np.ascontiguousarray(gram), glut[2])
-            if sp is not None:
-                sp.finish()
         return True
 
     def _repair_spanned(self, stale: list[int], dirty_rows, span) -> bool:
@@ -284,11 +334,13 @@ class DeviceRowPool:
             return self._repair_dirty(stale, dirty_rows)
         sp = span.child("pool.repair")
         planes0, up0 = self.stat_patch_planes, self.engine.stat_upload_bytes
+        in_place0 = self.stat_repairs_in_place
         ok = self._repair_dirty(stale, dirty_rows, sp)
         sp.finish().annotate(
             planes=self.stat_patch_planes - planes0,
             upload_bytes=self.engine.stat_upload_bytes - up0,
             slices=len(stale),
+            in_place=self.stat_repairs_in_place > in_place0,
         )
         return ok
 
@@ -299,7 +351,11 @@ class DeviceRowPool:
 
         ``id_pos`` maps every RESIDENT row id to its slot (a stable
         snapshot — safe to index concurrently); ``matrix`` is the engine
-        array snapshot those slots refer to.  Raises ValueError when
+        array snapshot those slots refer to: the caller's to keep and to
+        dispatch on outside the lock when it asked for rows (the array
+        is then marked as handed out, and no later repair touches it); a
+        caller with no ``want`` takes the box alone, for the array may be
+        updated in place by the next write repair.  Raises ValueError when
         ``want`` alone exceeds the pool capacity — callers chunk their
         query batch by unique-row count first (``chunk_queries``).
 
@@ -404,6 +460,8 @@ class DeviceRowPool:
             # write tokens onto pre-write data (permanent stale serves).
             self.box["gens"] = gens
             self.box["hits"] += 1
+            if want:
+                self._handed_out = True
             return self.box["id_pos"], self.matrix, self.box
 
 

@@ -17,7 +17,7 @@ from pilosa_tpu.rowpool import DeviceRowPool, chunk_queries, pool_capacity
 W = 16  # small word count: pool logic is W-agnostic
 
 
-def make_pool(n_slices=2, cap_max=8, rows=None, fetch_log=None):
+def make_pool(n_slices=2, cap_max=8, rows=None, fetch_log=None, engine=None):
     rows = rows if rows is not None else {}
 
     def fetch(row_ids, slice_idxs):
@@ -29,7 +29,7 @@ def make_pool(n_slices=2, cap_max=8, rows=None, fetch_log=None):
                 block[bi, k] = rows.get((si, r), np.zeros(W, np.uint32))
         return block
 
-    return DeviceRowPool(NumpyEngine(), n_slices, W, fetch, cap_max=cap_max), rows
+    return DeviceRowPool(engine or NumpyEngine(), n_slices, W, fetch, cap_max=cap_max), rows
 
 
 def fill_rows(rng, n_slices, row_ids):
@@ -391,3 +391,157 @@ def test_acquire_without_dirty_rows_still_resets_box():
     id_pos, matrix, box2 = pool.acquire([0, 1], (2, 1))
     assert box2 is not box1 and pool.stat_repairs == 0
     np.testing.assert_array_equal(matrix[0, id_pos[0]], live[(0, 0)])
+
+
+# -- the repair as one engine call (engine.repair_planes) -----------------
+
+ENGINES = ["numpy", "jax"]
+
+
+def _engine(name):
+    if name == "numpy":
+        return NumpyEngine()
+    from pilosa_tpu.engine import JaxEngine
+
+    return JaxEngine()
+
+
+def _warm_pool(engine, rng, n_slices=16, n_rows=6):
+    """A pool with rows 0..n_rows-1 resident (cap 8), handed to a reader
+    once, and a warm Gram + glut in its box, as the executor leaves it."""
+    rows = fill_rows(rng, n_slices, range(n_rows))
+    pool, live = make_pool(n_slices=n_slices, rows=rows, cap_max=8, engine=engine)
+    gens = [1] * n_slices
+    id_pos, matrix, box = pool.acquire(list(range(n_rows)), tuple(gens))
+    gram = _np_gram(pool, live, range(n_rows), 8)
+    box["gram"] = gram
+    rs = np.array(sorted(id_pos), dtype=np.int64)
+    ps = np.fromiter((id_pos[int(v)] for v in rs), dtype=np.int32, count=len(rs))
+    box["gram_lut"] = (rs, np.ascontiguousarray(gram), ps)
+    return pool, live, gens, matrix, box
+
+
+def _write(rng, pool, live, gens, burst, want=()):
+    """New contents for the burst's (slice, row) cells, then the acquire
+    that repairs them."""
+    for si, rs in burst.items():
+        gens[si] += 1
+        for r in rs:
+            live[(si, r)] = rng.integers(0, 1 << 32, size=W, dtype=np.uint32)
+    return pool.acquire(list(want), tuple(gens), dirty_rows=burst)
+
+
+def _assert_pool_is(pool, live, n_rows=6):
+    m = np.asarray(pool.matrix).reshape(pool.n_slices, pool.cap, W)
+    for (si, r), words in live.items():
+        np.testing.assert_array_equal(m[si, pool.slot_of[r]], words)
+    want = _np_gram(pool, live, range(n_rows), 8)
+    np.testing.assert_array_equal(pool.box["gram"], want)
+    np.testing.assert_array_equal(pool.box["gram_lut"][1], want)
+    full = pool.engine.pair_gram(pool.matrix)
+    if full is not None:  # the jax engine's own full recompute
+        np.testing.assert_array_equal(pool.box["gram"], full)
+
+
+_BURSTS = {
+    "one_cell": {2: {1}},
+    "two_rows_in_one_slice": {3: {0, 4}},   # dirty-by-dirty entries
+    "one_row_in_two_slices": {1: {2}, 5: {2}},
+    "three_cells_pad_to_four": {0: {1, 3}, 2: {3}},
+    "five_cells_pad_to_eight": {0: {1, 3}, 2: {3}, 7: {5}, 9: {1}},
+    "half_the_slices": {si: {2} for si in range(8)},   # the composed form on every engine
+    "wide": {4: {0, 1, 2, 3}},                         # 2k >= n: likewise
+}
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+@pytest.mark.parametrize("burst", sorted(_BURSTS))
+def test_repair_planes_gram_equals_the_full_recompute(engine, burst):
+    """Whatever burst the journals produce, patched planes and repaired
+    Gram equal storage and the full recompute: once on an array a reader
+    holds (a copy is patched), once on the pool's own (in place, on the
+    jax engine, for the bursts its compiled step takes)."""
+    rng = np.random.default_rng(21)
+    pool, live, gens, _, box = _warm_pool(_engine(engine), rng)
+    for nth in (1, 2):
+        _, _, box2 = _write(rng, pool, live, gens, _BURSTS[burst])
+        assert box2 is box and pool.stat_repairs == nth
+        _assert_pool_is(pool, live)
+    stepped = engine == "jax" and burst not in ("half_the_slices", "wide")
+    assert pool.stat_repairs_in_place == (1 if stepped else 0)
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_snapshot_isolation_across_repair(engine):
+    """test_snapshot_isolation_across_eviction's contract on the repair
+    path: the array a reader took for rows it wanted stays readable and
+    unchanged; the repair that finds it handed out patches a copy, the
+    next one (nobody took the new array) updates in place."""
+    rng = np.random.default_rng(22)
+    pool, live, gens, matrix, _ = _warm_pool(_engine(engine), rng)
+    snap = np.array(matrix)
+    _write(rng, pool, live, gens, {2: {1}})
+    assert pool.stat_repairs == 1 and pool.stat_repairs_in_place == 0
+    unread = pool.matrix
+    assert unread is not matrix
+    _write(rng, pool, live, gens, {2: {1}, 6: {4}})
+    assert pool.stat_repairs == 2
+    assert pool.stat_repairs_in_place == (1 if engine == "jax" else 0)
+    if engine == "jax":
+        assert unread.is_deleted() and not matrix.is_deleted()
+    np.testing.assert_array_equal(np.asarray(matrix), snap)
+    _assert_pool_is(pool, live)
+    # A reader that wants rows takes the array again: hands off until the
+    # next functional update.
+    _, taken, _ = _write(rng, pool, live, gens, {3: {0}}, want=[0])
+    snap = np.array(taken)
+    _write(rng, pool, live, gens, {3: {0}})
+    assert pool.stat_repairs_in_place == (2 if engine == "jax" else 0)
+    np.testing.assert_array_equal(np.asarray(taken), snap)
+    _assert_pool_is(pool, live)
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_failed_repair_leaves_a_pool_the_next_acquire_rebuilds(engine, monkeypatch):
+    """A step that raises after it took the array (jax), or between
+    planes and Gram (numpy), leaves nothing to trust: the pool drops to
+    its empty state, the error surfaces, the next acquire pages in."""
+    rng = np.random.default_rng(23)
+    eng = _engine(engine)
+    pool, live, gens, _, box = _warm_pool(eng, rng)
+    _write(rng, pool, live, gens, {2: {1}})  # the pool's array is its own now
+
+    def boom(matrix, *args, **kw):
+        if engine == "jax":
+            matrix.delete()  # what a donated argument is after the call
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(eng, "_repair_jit" if engine == "jax" else "gram_update_rows",
+                        boom, raising=False)
+    with pytest.raises(RuntimeError, match="device lost"):
+        _write(rng, pool, live, gens, {2: {1}})
+    assert pool.matrix is None and pool.cap == 0 and not pool.slot_of
+    assert pool.gens is None and pool.box is not box and "gram" not in pool.box
+    assert pool.stat_repairs == 1
+    monkeypatch.undo()
+    check(pool, live, [0, 1, 2, 5], tuple(gens))
+    assert pool.cap == 4 and pool.box.get("gens") == tuple(gens)
+
+
+def test_repair_planes_on_a_tiled_matrix():
+    """The jax engine stores W % 128 == 0 matrices tiled ([S, R, W/128,
+    128]): the step takes its planes in that form, over a Gram narrower
+    than the pool's capacity."""
+    from pilosa_tpu.engine import JaxEngine
+
+    rng = np.random.default_rng(24)
+    eng, (S, R, n, w) = JaxEngine(), (8, 8, 4, 256)
+    host = rng.integers(0, 1 << 32, size=(S, R, w), dtype=np.uint32)
+    gram = eng.pair_gram(eng.matrix(host[:, :n]))
+    block = rng.integers(0, 1 << 32, size=(2, 1, w), dtype=np.uint32)
+    given = eng.matrix(host.copy())  # the CPU backend may alias a numpy buffer
+    matrix, finish, in_place = eng.repair_planes(given, gram, [([1, 6], [3], block)], donate=True)
+    assert in_place and given.is_deleted() and matrix.shape == (S, R, w // 128, 128)
+    host[[1, 6], 3] = block[:, 0]
+    np.testing.assert_array_equal(np.asarray(matrix).reshape(S, R, w), host)
+    np.testing.assert_array_equal(finish(), eng.pair_gram(eng.matrix(host[:, :n])))

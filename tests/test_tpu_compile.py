@@ -182,6 +182,23 @@ def test_pair_gram_compiles_for_v5e(n_rows, one_chip):
     _compile(jax.jit(pair_gram), [_rm(n_slices, n_rows)], one_chip, kernel=False)
 
 
+@pytest.mark.parametrize("cells", [1, 8])
+def test_repair_step_updates_the_deployments_pool_in_place(cells, one_chip):
+    """The write repair's compiled step (plain XLA) on the deployment's
+    2 GiB pool, donated: output aliased to the pool, and no temporary
+    near a slice's rows (32 MiB), let alone a copy of the pool."""
+    import jax
+
+    from pilosa_tpu.ops.bitwise import repair_planes
+
+    step = jax.jit(repair_planes, static_argnums=3, donate_argnums=0)
+    shapes = [_shape(s, d, one_chip) for s, d in
+              (_rm(64, 256), _ids(cells, 2), ((cells, T, 128), "uint32"))]
+    mem = step.lower(*shapes, 256).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == 64 * 256 * W * 4
+    assert mem.temp_size_in_bytes < 4 * 1024**2
+
+
 def test_bulk_build_kernel_compiles_for_v5e(one_chip):
     """The bulk door's sort/segment/scatter pack kernel at the default
     chunk (65536 pairs) into 1024 planes, and at its per-call cap."""

@@ -509,6 +509,49 @@ def test_read_after_a_setbit_shows_the_repair_under_the_pools_lock(served):
     assert _fits(root, 0.0, root["ms"])
     assert "device" in [c["name"] for c in root["children"]]  # then served natively
     assert res == res0  # column 1000 is in no other row
+    # One slice, so no clean one: the composed repair, which copies.
+    assert prep["tags"]["in_place"] is False and _pool(served).stat_repairs_in_place == 0
+
+
+def test_a_repair_of_an_array_no_reader_holds_is_in_place(tmp_path):
+    """Four slices: a write to one is a repair the jax engine's compiled
+    step takes.  The first patches a copy (the warm-up's readers hold the
+    pool's array); the new array never left the pool, so the second
+    updates it in place; tag, counter and /debug/vars say so, and the
+    stages keep their names and order."""
+    import urllib.request
+
+    from pilosa_tpu.server.client import Client
+    from pilosa_tpu.server.server import Server
+
+    s = Server(Config(data_dir=str(tmp_path / "s4"), host="127.0.0.1:0", engine="jax"))
+    s.open()
+    try:
+        c = Client(s.host)
+        c.create_index("i")
+        c.create_frame("i", "f")
+        for r in range(4):
+            c.execute_query("i", " ".join(
+                f'SetBit(rowID={r}, frame="f", columnID={(sl << 20) + k * 3 + r})'
+                for sl in range(4) for k in range(10)))
+        for _ in range(4):
+            res0, _ = _post(s.host, _PAIRS, trace=False)
+        pool = _pool(s)
+        for nth, in_place in ((1, False), (2, True), (3, True)):
+            _post(s.host, f'SetBit(rowID=1, frame="f", columnID={(2 << 20) + 1000 + nth})',
+                  trace=False)
+            res, root = _post(s.host, _PAIRS)
+            (prep,) = _find(root, "pool.repair")
+            assert [c["name"] for c in prep["children"]] == [
+                "pool.fetch", "pool.scatter", "pool.gram"]
+            assert prep["tags"]["in_place"] is in_place and prep["tags"]["planes"] == 1
+            assert prep["tags"]["upload_bytes"] == 131072
+            assert res == res0  # those columns are in no other row
+        assert (pool.stat_repairs, pool.stat_repairs_in_place) == (3, 2)
+        snap = json.loads(urllib.request.urlopen(f"http://{s.host}/debug/vars", timeout=30).read())
+        assert snap["rowpool.repairs"] == 3 and snap["rowpool.repairs_in_place"] == 2
+    finally:
+        s.close()
 
 
 def test_a_reader_behind_a_held_pool_lock_shows_the_wait(served):
