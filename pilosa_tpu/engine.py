@@ -66,28 +66,55 @@ def _pow2(n: int) -> int:
     return 1 << (n - 1).bit_length() if n > 1 else 1
 
 
+def _padded_cells(groups):
+    """A repair's written cells as one scatter's arguments: ``cells`` (the
+    (slice, slot) pairs in group order), ``idx`` int32[C, 2] and ``planes``
+    uint32[C, W], C the power-of-two bucket of ``len(cells)`` (compiled
+    shapes stay few); a bucket's unused tail is (-1, -1) over zero planes,
+    which every consumer drops."""
+    cells = [
+        (si, slot) for slice_idxs, slots, _ in groups
+        for si in slice_idxs for slot in slots
+    ]
+    cb = _pow2(len(cells))
+    idx = np.full((cb, 2), -1, dtype=np.int32)
+    idx[: len(cells)] = cells
+    planes = np.zeros((cb, np.asarray(groups[0][2]).shape[-1]), dtype=np.uint32)
+    at = 0
+    for slice_idxs, slots, block in groups:
+        g = len(slice_idxs) * len(slots)
+        planes[at : at + g] = np.asarray(block).reshape(g, -1)
+        at += g
+    return cells, idx, planes
+
+
 def _repair_planes_composed(engine, matrix, gram, groups):
     """The copying form of ``repair_planes``, from the engine's own
-    ``set_plane_rows`` (one functional scatter per group: a new array each
-    time, the caller's stays whole) and ``gram_update_rows``, which gets
-    the pre-patch array for its restricted-slice delta.  What the numpy
-    and mesh engines run, and the jax engine for the repairs its compiled
-    step does not take."""
+    ``set_plane_cells`` (ONE functional scatter of every written cell: a
+    new array, the caller's stays whole.  A scatter per group put as many
+    copies of the pool in flight as a burst had groups - dispatch is
+    asynchronous and every output is allocated at once: 14.1 GiB on a 16
+    GiB chip for eight groups on a 2 GiB shard) and ``gram_update_rows``,
+    which gets the pre-patch array for its restricted-slice delta.  What
+    the numpy and mesh engines run, and the jax engine for the repairs its
+    compiled step does not take.  ``finish(span)`` hands the request's
+    span (or None) on to ``gram_update_rows``."""
     old = matrix
-    for slice_idxs, slots, block in groups:
-        matrix = engine.set_plane_rows(matrix, slice_idxs, slots, block)
+    _, idx, planes = _padded_cells(groups)
+    matrix = engine.set_plane_cells(matrix, idx, planes)
     if gram is None:
-        return matrix, None, False
+        return matrix, None, False, "composed"
 
-    def finish():
+    def finish(span=None):
         d = gram.shape[0]
         new, was = (matrix, old) if d == matrix.shape[1] else (matrix[:, :d], old[:, :d])
         return engine.gram_update_rows(
             new, gram, [s for _, slots, _ in groups for s in slots], old_matrix=was,
             slice_idxs=[si for slice_idxs, _, _ in groups for si in slice_idxs],
+            span=span,
         )
 
-    return matrix, finish, False
+    return matrix, finish, False, "composed"
 
 
 class NumpyEngine:
@@ -283,6 +310,14 @@ class NumpyEngine:
         out[np.ix_(list(slice_idxs), list(slots))] = block
         return out
 
+    def set_plane_cells(self, matrix, cells, planes):
+        """Functionally write ``planes[c]`` into the (slice, slot) cell
+        ``cells[c]``; a cell of (-1, -1) (a bucket's tail) is skipped."""
+        out = matrix.copy()
+        keep = cells[:, 0] >= 0
+        out[cells[keep, 0], cells[keep, 1]] = planes[keep]
+        return out
+
     def build_planes(self, rows, cols):
         """Bulk sort/segment/scatter build: (row, col) uint64 columns ->
         ``(slice_ids, row_ids, planes uint32[G, W])`` — the device-layout
@@ -308,7 +343,8 @@ class NumpyEngine:
         all-pairs popcount would dwarf the direct path)."""
         return None
 
-    def gram_update_rows(self, matrix, gram, slots, old_matrix=None, slice_idxs=None):
+    def gram_update_rows(self, matrix, gram, slots, old_matrix=None, slice_idxs=None,
+                         span=None):
         """Rank-k repair of a host AND-count Gram after row rewrites
         (the Gram half of the copying ``repair_planes``): recompute ONLY
         the dirty rows/columns with one batched pair-count pass against
@@ -326,7 +362,9 @@ class NumpyEngine:
         unchanged slices cancel out of the difference, so the dispatch
         covers K x R x |dirty slices| instead of K x R x S.  Falls back
         to the full recompute when the restriction wouldn't pay
-        (>= half the slices dirty)."""
+        (>= half the slices dirty).  ``span``: the request's span where
+        one is sampled; only the mesh engine has a stage of its own to
+        show under it."""
         slots = np.asarray(sorted({int(s) for s in slots}), dtype=np.int64)
         n = gram.shape[0]
         pairs = np.empty((len(slots) * n, 2), dtype=np.int32)
@@ -357,12 +395,19 @@ class NumpyEngine:
         len(slots), W])], the written (slice, slot) cells with their new
         contents; ``gram`` the host Gram over the first ``gram.shape[0]``
         slots, or None (the planes alone).  Returns ``(matrix, finish,
-        in_place)``: the patched matrix at once, ``finish()`` the repaired
-        Gram (a new array; it blocks on the device where there is one;
-        None without a Gram), and whether the caller's array was updated
-        in place and is gone.  ``donate`` says the caller holds the only
-        reference to ``matrix``; this engine copies regardless."""
+        in_place, form)``: the patched matrix at once, ``finish(span)``
+        the repaired Gram (a new array; it blocks on the device where
+        there is one; None without a Gram), whether the caller's array was
+        updated in place and is gone, and which form ran (``"step"``: the
+        jax engine's compiled step; ``"composed"``: the copying form).
+        ``donate`` says the caller holds the only reference to ``matrix``;
+        this engine copies regardless."""
         return _repair_planes_composed(self, matrix, gram, groups)
+
+    def slice_axis_devices(self, n_slices: int) -> int:
+        """Devices that share the slice axis of an ``[n_slices, ...]``
+        array of this engine (what the row pool's budget follows)."""
+        return 1
 
     def to_numpy(self, x) -> np.ndarray:
         return np.asarray(x)
@@ -655,12 +700,33 @@ class JaxEngine:
 
     def set_plane_rows(self, matrix, slice_idxs, slots, block):
         """Scatter (stale slice, resident slot) cells: only the touched
-        rows cross host->device."""
-        si = self._jnp.asarray(np.asarray(slice_idxs, dtype=np.int32))
-        sl = self._jnp.asarray(np.asarray(slots, dtype=np.int32))
-        return matrix.at[si[:, None], sl[None, :]].set(
-            self._match_block(matrix, block)
-        )
+        rows cross host->device (``set_plane_cells`` over the product)."""
+        cells = np.stack(
+            np.meshgrid(slice_idxs, slots, indexing="ij"), axis=-1
+        ).reshape(-1, 2).astype(np.int32)
+        block = np.asarray(block)
+        return self.set_plane_cells(matrix, cells, block.reshape(len(cells), block.shape[-1]))
+
+    def set_plane_cells(self, matrix, cells, planes):
+        """A new matrix with ``planes[c]`` (host uint32[C, W]) written
+        into the (slice, slot) cell ``cells[c]`` (int32[C, 2]; (-1, -1) is
+        dropped): one program a cell count
+        (``ops.bitwise.set_plane_cells``), whose ops carry the name
+        ``pool.set_plane_rows`` in a device trace."""
+        if not hasattr(self, "_set_plane_cells_jit"):
+            import jax
+
+            from pilosa_tpu.ops.bitwise import set_plane_cells
+
+            self._set_plane_cells_jit = jax.jit(set_plane_cells)
+        return self._set_plane_cells_jit(matrix, cells, self._cell_planes(matrix, planes))
+
+    def _cell_planes(self, matrix, planes) -> np.ndarray:
+        """Host planes [C, W] in the matrix's storage form, their upload
+        counted (the jitted scatter takes them from the host)."""
+        planes = np.asarray(planes)
+        self._note_upload(planes.nbytes)
+        return self._tile_host(planes) if matrix.ndim == 4 else planes
 
     def build_planes(self, rows, cols):
         """Bulk sort/segment/scatter build on device: the jitted pack
@@ -681,7 +747,22 @@ class JaxEngine:
             self._gram_jit = jax.jit(pair_gram)
         return self.to_numpy(self._gram_jit(self._jnp.asarray(matrix))).astype(np.int64)
 
-    def gram_update_rows(self, matrix, gram, slots, old_matrix=None, slice_idxs=None):
+    def _gram_counts(self, matrix, pairs, span=None) -> np.ndarray:
+        """The Gram repair's device work: |row_a & row_b| for each slot
+        pair, as one program whose ops carry the name ``pool.gram_update``
+        in a device trace (the same dispatch as ``gather_count``)."""
+        if not hasattr(self, "_gram_counts_jit"):
+            import jax
+
+            def gram_update(rm, prs):
+                with jax.named_scope("pool.gram_update"):
+                    return self._dispatch.gather_count("and", rm, prs, allow_gram=False)
+
+            self._gram_counts_jit = jax.jit(gram_update)
+        return self.to_numpy(self._gram_counts_jit(matrix, pairs)).astype(np.int64)
+
+    def gram_update_rows(self, matrix, gram, slots, old_matrix=None, slice_idxs=None,
+                         span=None):
         """Rank-k Gram repair (see NumpyEngine.gram_update_rows), for the
         repairs the compiled step of ``repair_planes`` does not take and
         for the mesh engine: one batched gather-count dispatch
@@ -734,21 +815,15 @@ class JaxEngine:
                         return (
                             np.asarray(gram) + (pg_new - pg_old)
                         ).astype(gram.dtype)
-                new_c = np.asarray(self.gather_count("and", matrix[sel], pairs))
-                old_c = np.asarray(self.gather_count("and", old_matrix[sel], pairs))
-                delta = (new_c.astype(np.int64) - old_c.astype(np.int64)).reshape(
-                    kb, n
-                )[:k]
+                new_c = self._gram_counts(matrix[sel], pairs, span)
+                old_c = self._gram_counts(old_matrix[sel], pairs, span)
+                delta = (new_c - old_c).reshape(kb, n)[:k]
                 block = (np.asarray(gram)[idx, :] + delta).astype(gram.dtype)
                 out = np.array(gram, copy=True)
                 out[idx, :] = block
                 out[:, idx] = block.T
                 return out
-        block = (
-            np.asarray(self.gather_count("and", matrix, pairs))
-            .reshape(kb, n)[:k]
-            .astype(gram.dtype)
-        )
+        block = self._gram_counts(matrix, pairs, span).reshape(kb, n)[:k].astype(gram.dtype)
         out = np.array(gram, copy=True)
         out[idx, :] = block
         out[:, idx] = block.T
@@ -766,10 +841,7 @@ class JaxEngine:
         reader still holds ``matrix``: the step then runs on a copy made
         first.  Repairs without a Gram, wide ones and those over half
         the slices keep the composed form."""
-        cells = [
-            (si, slot) for slice_idxs, slots, _ in groups
-            for si in slice_idxs for slot in slots
-        ]
+        cells, idx, planes = _padded_cells(groups)
         k = len({slot for _, slot in cells})
         sb = _pow2(len({si for si, _ in cells}))
         if gram is None or 2 * k >= gram.shape[0] or 2 * sb >= matrix.shape[0]:
@@ -782,15 +854,6 @@ class JaxEngine:
             self._repair_jit = jax.jit(
                 repair_planes, static_argnums=3, donate_argnums=0
             )
-        cb = _pow2(len(cells))
-        idx = np.full((cb, 2), -1, dtype=np.int32)
-        idx[: len(cells)] = cells
-        planes = np.zeros((cb,) + groups[0][2].shape[2:], dtype=np.uint32)
-        at = 0
-        for slice_idxs, slots, block in groups:
-            g = len(slice_idxs) * len(slots)
-            planes[at : at + g] = np.asarray(block).reshape(g, -1)
-            at += g
         self._note_upload(planes.nbytes)
         if matrix.ndim == 4:
             planes = self._tile_host(planes)
@@ -798,7 +861,7 @@ class JaxEngine:
             matrix = self._jnp.copy(matrix)
         matrix, delta = self._repair_jit(matrix, idx, planes, gram.shape[0])
 
-        def finish():
+        def finish(span=None):
             out = np.array(gram, copy=True)
             for (_, slot), d in zip(cells, np.asarray(delta)):
                 out[slot, :] += d
@@ -806,7 +869,12 @@ class JaxEngine:
                 out[slot, slot] -= d[slot]
             return out
 
-        return matrix, finish, donate
+        return matrix, finish, donate, "step"
+
+    def slice_axis_devices(self, n_slices: int) -> int:
+        """Devices that share the slice axis of an ``[n_slices, ...]``
+        array of this engine (what the row pool's budget follows): one."""
+        return 1
 
     def to_numpy(self, x) -> np.ndarray:
         return np.asarray(x)
@@ -951,14 +1019,22 @@ class MeshEngine(JaxEngine):
     def _devices(self) -> list:
         return list(self.mesh.mesh.devices.flat)
 
+    def slice_axis_devices(self, n_slices: int) -> int:
+        """The mesh's devices where ``_shard_stack`` partitions a slice
+        axis of this length over them (device_put requires even shards);
+        a ragged or single-slice axis stays on one device."""
+        if n_slices < 2 or n_slices % self.mesh.n_devices:
+            return 1
+        return self.mesh.n_devices
+
     def _shard_stack(self, x):
-        # Shard only cleanly-divisible leading axes (device_put requires
-        # even shards); ragged slice counts stay unsharded — correctness
-        # first, placement when the shapes allow it.  Only stack_slices
-        # routes here, so the leading axis is always the slice axis.
+        # Shard only cleanly-divisible leading axes; ragged slice counts
+        # stay unsharded — correctness first, placement when the shapes
+        # allow it.  Only stack_slices routes here, so the leading axis is
+        # always the slice axis.
         if isinstance(x, np.ndarray):
             self._note_upload(x.nbytes)
-        if x.ndim < 2 or x.shape[0] < 2 or x.shape[0] % self.mesh.n_devices:
+        if x.ndim < 2 or self.slice_axis_devices(x.shape[0]) == 1:
             return self._jnp.asarray(x)
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -1011,18 +1087,70 @@ class MeshEngine(JaxEngine):
         return self._repin(super().set_rows_at(matrix, slots, block), matrix)
 
     def grow_rows(self, matrix, n):
-        return self._repin(super().grow_rows(matrix, n), matrix)
+        # The zero rows are born with the matrix's sharding: made on the
+        # default device (the parent's way) they are one device's to hold
+        # whole - 4 GiB for 128 rows at 256 slices, and a 15.1 GB peak on
+        # device 0 of a 16 GB chip when a four-chip pool grew to 256 slots.
+        z = self._jnp.zeros(
+            (matrix.shape[0], n) + matrix.shape[2:], dtype=matrix.dtype,
+            device=matrix.sharding,
+        )
+        return self._repin(self._jnp.concatenate([matrix, z], axis=1), matrix)
 
-    def set_plane_rows(self, matrix, slice_idxs, slots, block):
-        return self._repin(super().set_plane_rows(matrix, slice_idxs, slots, block), matrix)
+    def set_plane_cells(self, matrix, cells, planes):
+        if self.slice_axis_devices(matrix.shape[0]) == 1:
+            return super().set_plane_cells(matrix, cells, planes)
+        from pilosa_tpu.parallel.sharded import sharded_set_plane_cells
 
-    def gram_update_rows(self, matrix, gram, slots, old_matrix=None, slice_idxs=None):
+        # Every device patches its own slices of its own shard.
+        return sharded_set_plane_cells(
+            self.mesh, self._shard_stack(matrix), cells, self._cell_planes(matrix, planes)
+        )
+
+    def pair_gram(self, matrix):
+        """Every device's Gram of its own slices, psummed (a matrix the
+        mesh cannot shard takes the parent's single program)."""
+        if self.slice_axis_devices(matrix.shape[0]) == 1:
+            return super().pair_gram(matrix)
+        from pilosa_tpu.parallel.sharded import sharded_pair_gram
+
+        out = sharded_pair_gram(self.mesh, self._shard_stack(matrix))
+        return self._fetch(out).astype(np.int64)
+
+    def gram_update_rows(self, matrix, gram, slots, old_matrix=None, slice_idxs=None,
+                         span=None):
         # No restricted-slice delta on meshes: indexing a subset of the
         # sharded slice axis breaks the shard_map divisibility the
         # kernels need (and touches non-addressable shards on
         # multi-process jobs).  The full rank-k recompute stays
         # SPMD-safe on every rank.
-        return super().gram_update_rows(matrix, gram, slots)
+        return super().gram_update_rows(matrix, gram, slots, span=span)
+
+    def _gram_counts(self, matrix, pairs, span=None) -> np.ndarray:
+        """``JaxEngine._gram_counts`` over the mesh: every device counts
+        its own slices (the shard_map'd kernels, or the jnp form where the
+        mesh cannot shard the axis) and a psum merges them; ``mesh.fetch``
+        under ``span`` is the host's wait for the reduced counts."""
+        out = self._gram_counts_program()(self._shard_stack(matrix), pairs)
+        return self._fetch(out, span).astype(np.int64)
+
+    def _gram_counts_program(self):
+        if not hasattr(self, "_gram_counts_jit"):
+            from pilosa_tpu.ops import bitwise as _bw
+            from pilosa_tpu.ops.pallas_kernels import rm_words
+            from pilosa_tpu.parallel.sharded import sharded_gather_count
+
+            def gram_update(rm, prs):
+                with self._jax.named_scope("pool.gram_update"):
+                    mode = self._pallas_mode(rm.shape[0], rm_words(rm))
+                    if not mode:
+                        return _bw.gather_count("and", rm, prs)
+                    return sharded_gather_count(
+                        self.mesh, "and", rm, prs, interpret=(mode == "interpret")
+                    )
+
+            self._gram_counts_jit = self._jax.jit(gram_update)
+        return self._gram_counts_jit
 
     def repair_planes(self, matrix, gram, groups, donate=False):
         # The compiled step indexes single slices of the pool; on the
@@ -1070,17 +1198,24 @@ class MeshEngine(JaxEngine):
         out = self._gather_jit(op, rm, self._jnp.asarray(pairs))
         return self._fetch(out).astype(np.int64)
 
-    def _fetch(self, arr) -> np.ndarray:
+    def _fetch(self, arr, span=None) -> np.ndarray:
         """Fetch an engine array to host, allgathering when its shards
         span other processes (multi-host mesh) — the DCN analog of the
-        reference streaming result segments back to the coordinator."""
+        reference streaming result segments back to the coordinator.
+        Under a sampled request's ``span`` the wait is its ``mesh.fetch``
+        child: the host blocks here on the mesh's reduced result."""
+        sp = span.child("mesh.fetch") if span is not None else None
         if getattr(arr, "is_fully_addressable", True) or getattr(
             arr, "is_fully_replicated", False
         ):
-            return np.asarray(arr)
-        from jax.experimental import multihost_utils
+            out = np.asarray(arr)
+        else:
+            from jax.experimental import multihost_utils
 
-        return np.asarray(multihost_utils.process_allgather(arr, tiled=True))
+            out = np.asarray(multihost_utils.process_allgather(arr, tiled=True))
+        if sp is not None:
+            sp.finish()
+        return out
 
     def to_numpy(self, x) -> np.ndarray:
         # Every inherited JaxEngine host conversion routes through here,

@@ -1120,7 +1120,7 @@ class Executor:
         if not std_slices:
             return None
         if (
-            pool_capacity(len(std_slices), _WORDS) < 64
+            pool_capacity(len(std_slices), _WORDS, self.engine) < 64
             or len(std_slices) > _INT32_SAFE_SLICES
         ):
             # Slice-streaming regime (working set >> HBM pool budget) or a
@@ -2711,7 +2711,7 @@ class Executor:
             pool = self._matrix_cache.get(key)
             if pool is not None:
                 return pool.cap_max
-        return DeviceRowPool.default_cap(len(slices), _WORDS)
+        return DeviceRowPool.default_cap(len(slices), _WORDS, self.engine)
 
     def _pool_for(
         self, index: str, frame: str, view: str, slices, lane: str = ""
@@ -2719,9 +2719,12 @@ class Executor:
         """The paged device row pool for one (frame, view, slice batch).
 
         Pools live in the same small LRU the old fixed matrices did; each
-        is bounded by the PILOSA_TPU_POOL_BYTES HBM budget and pages rows
-        in/out on demand (rowpool.DeviceRowPool) — the row-count ceiling
-        of the old design is gone.  ``lane`` separates workloads with
+        is bounded by the HBM budget (2 GiB per device that shares the
+        pool's slice axis: one device on the jax engine, the mesh's on
+        the mesh engine; or PILOSA_TPU_POOL_BYTES for the whole pool,
+        where set) and pages rows in/out on demand
+        (rowpool.DeviceRowPool) — the row-count ceiling of the old
+        design is gone.  ``lane`` separates workloads with
         different paging patterns (TopN candidate streams vs fused count
         working sets vs the row-major gather lane) so one can't evict
         another's residency.  Lanes holding the same frame's rows each

@@ -370,6 +370,21 @@ def pair_gram(row_matrix):
     return lax.scan(step, jnp.zeros((r, r), jnp.int32), jnp.arange(s * nc))[0]
 
 
+def set_plane_cells(row_matrix, cells, planes, first_slice=0):
+    """A pool matrix with ``planes[c]`` written into the (slice, slot)
+    cell ``cells[c]`` (int32[C, 2]): the scatter of the copying repair and
+    of the blind refresh (a new array; jitted by the engines, one program
+    a cell count).  A cell whose slice, less ``first_slice``, is not one
+    of ``row_matrix``'s is dropped: a bucket's (-1, -1) tail, and on a
+    mesh the cells of the other devices' shards.  Its ops carry
+    ``pool.set_plane_rows`` in a device trace."""
+    with jax.named_scope("pool.set_plane_rows"):
+        n = row_matrix.shape[0]
+        si = cells[:, 0] - first_slice
+        si = jnp.where((cells[:, 0] >= 0) & (si >= 0) & (si < n), si, n)  # past the end: dropped
+        return row_matrix.at[si, cells[:, 1]].set(planes, mode="drop")
+
+
 def repair_planes(row_matrix, cells, planes, n: int):
     """Write new planes into a pool matrix and return what each write
     does to the AND-count Gram over the first ``n`` slots.

@@ -329,6 +329,77 @@ def sharded_gather_count_tree(
 
 
 @functools.lru_cache(maxsize=None)
+def _sharded_set_plane_cells_kernel(mesh_obj, axis: str, rm_ndim: int):
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from pilosa_tpu.ops.bitwise import set_plane_cells
+
+    rest = [None] * (rm_ndim - 1)
+
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh_obj,
+        in_specs=(P(axis, *rest), P(None, None), P(None, *rest[1:])),
+        out_specs=P(axis, *rest),
+        check_vma=False,
+    )
+    def set_plane_cells_shards(rm_shard, cells, planes):
+        return set_plane_cells(
+            rm_shard, cells, planes, first_slice=lax.axis_index(axis) * rm_shard.shape[0]
+        )
+
+    return jax.jit(set_plane_cells_shards)
+
+
+def sharded_set_plane_cells(mesh: SliceMesh, row_matrix, cells, planes):
+    """``ops.bitwise.set_plane_cells`` on a slice-sharded pool matrix: every
+    device writes the cells whose slice it holds into a copy of its own
+    shard and drops the rest - no communication, and the result is born
+    with the matrix's sharding.  (Left to GSPMD, the scatter of one cell
+    gathers the WHOLE pool onto every device: 8 GiB a device at 256 slices
+    x 256 slots, found by compiling for a described v5e:2x2.)  ``cells``:
+    int32[C, 2] of (slice, slot); ``planes``: [C, ...words], replicated."""
+    _require_divisible(row_matrix.shape[0], mesh.n_devices)
+    kernel = _sharded_set_plane_cells_kernel(mesh.mesh, mesh.AXIS, row_matrix.ndim)
+    return kernel(row_matrix, cells, planes)
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_pair_gram_kernel(mesh_obj, axis: str, rm_ndim: int):
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from pilosa_tpu.ops.bitwise import pair_gram
+
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh_obj,
+        in_specs=(P(axis, *([None] * (rm_ndim - 1))),),
+        out_specs=P(),
+        check_vma=False,
+    )
+    def pair_gram_shards(rm_shard):
+        with jax.named_scope("pool.pair_gram"):
+            return lax.psum(pair_gram(rm_shard), axis)
+
+    return jax.jit(pair_gram_shards)
+
+
+def sharded_pair_gram(mesh: SliceMesh, row_matrix):
+    """The all-pairs AND-count Gram of a slice-sharded matrix: slices are
+    disjoint bit ranges, so every device builds the Gram of its own slices
+    (``ops.bitwise.pair_gram``, one streamed pass of MXU work) and a psum
+    adds them.  (Left to GSPMD, ``pair_gram``'s scan indexes the sharded
+    axis and the partitioner gathers the whole matrix onto every device
+    first.)  Returns int32[R, R], replicated."""
+    _require_divisible(row_matrix.shape[0], mesh.n_devices)
+    return _sharded_pair_gram_kernel(mesh.mesh, mesh.AXIS, row_matrix.ndim)(row_matrix)
+
+
+@functools.lru_cache(maxsize=None)
 def _sharded_scorer_kernel(mesh_obj, axis: str, rm_ndim: int, src_ndim: int):
     """Jitted shard_map'd scorer kernel, cached per (mesh, layouts) — a
     fresh closure per call would retrace + recompile every candidate

@@ -40,13 +40,23 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
-def _pool_bytes() -> int:
-    """HBM budget for ONE pool's matrix (read per call: benches and tests
-    tune it).  Total pool memory is bounded by this times the executor's
-    matrix-cache entry count; transient peaks reach 2x one pool during a
-    functional scatter (old + new array alive)."""
+POOL_BYTES_PER_DEVICE = 2 * 1024 * 1024 * 1024
+
+
+def pool_bytes(engine=None, n_slices: int = 0) -> tuple[int, int]:
+    """(HBM budget for ONE pool's matrix, devices that hold it): 2 GiB PER
+    DEVICE that shares the pool's slice axis - one device on the numpy and
+    jax engines, the mesh's devices on the mesh engine where it shards
+    ``n_slices`` (``engine.slice_axis_devices``), so each device holds
+    the 2 GiB share it would hold alone.  ``PILOSA_TPU_POOL_BYTES``, where
+    set, is one pool's whole budget whatever the engine (read per call:
+    benches and tests tune it).  Total pool memory is bounded by this times
+    the executor's matrix-cache entry count; transient peaks reach 2x one
+    pool during a functional scatter (old + new array alive)."""
+    devices = engine.slice_axis_devices(n_slices) if engine is not None else 1
     # analysis-ok: lockstep-determinism: deployment config, launcher sets identical env on every rank
-    return int(os.environ.get("PILOSA_TPU_POOL_BYTES", str(2 * 1024 * 1024 * 1024)))
+    env = os.environ.get("PILOSA_TPU_POOL_BYTES")
+    return (int(env) if env else POOL_BYTES_PER_DEVICE * devices), devices
 
 
 def _refresh_bytes_max() -> int:
@@ -57,9 +67,12 @@ def _refresh_bytes_max() -> int:
     return int(os.environ.get("PILOSA_TPU_POOL_REFRESH_BYTES", str(512 * 1024 * 1024)))
 
 
-def pool_capacity(n_slices: int, words: int, budget_bytes: int = 0) -> int:
-    """Slot capacity the budget allows for an ``[n_slices, cap, W]`` pool."""
-    budget = budget_bytes or _pool_bytes()
+def pool_capacity(n_slices: int, words: int, engine=None, budget_bytes: int = 0) -> int:
+    """Slot capacity the budget allows for an ``[n_slices, cap, W]`` pool
+    on ``engine``'s devices: the per-device budget times the devices that
+    share the slice axis (``pool_bytes``), so a mesh of four holds four
+    times the slots of one device at the same bytes per device."""
+    budget = budget_bytes or pool_bytes(engine, n_slices)[0]
     return max(0, budget // max(1, n_slices * words * 4))
 
 
@@ -111,7 +124,7 @@ class DeviceRowPool:
         # Telemetry for benches/tests: paging behavior must be observable.
         # Each also goes out through ``stats`` where it is incremented
         # (rowpool.misses, .evictions, .resets, .repairs, .repairs_in_place,
-        # .patch_planes).
+        # .repairs_composed, .patch_planes).
         self.stat_misses = 0
         self.stat_evictions = 0
         self.stat_resets = 0
@@ -119,16 +132,20 @@ class DeviceRowPool:
         # Of those, the ones that updated the pool's array where it lay
         # (no copy of the pool: the engine's compiled step, donated).
         self.stat_repairs_in_place = 0
+        # ... and the ones that took the copying form (set_plane_cells and
+        # gram_update_rows: every repair of the numpy and mesh engines).
+        self.stat_repairs_composed = 0
         # (row, slice) planes actually fetched by the patch lane — the
         # per-(row, slice) granularity benches/tests assert on this.
         self.stat_patch_planes = 0
 
     @staticmethod
-    def default_cap(n_slices: int, words: int) -> int:
-        """The budget-driven cap an un-overridden pool would report —
-        shared with callers that must predict a pool's capacity WITHOUT
-        instantiating it (executor lane probes)."""
-        return max(1, pool_capacity(n_slices, words))
+    def default_cap(n_slices: int, words: int, engine=None) -> int:
+        """The budget-driven cap an un-overridden pool on ``engine`` would
+        report (2 GiB per device that shares the slice axis) — shared with
+        callers that must predict a pool's capacity WITHOUT instantiating
+        it (executor lane probes)."""
+        return max(1, pool_capacity(n_slices, words, engine))
 
     @property
     def matrix(self):
@@ -146,7 +163,7 @@ class DeviceRowPool:
     def cap_max(self) -> int:
         if self._cap_override:
             return self._cap_override
-        return self.default_cap(self.n_slices, self.words)
+        return self.default_cap(self.n_slices, self.words, self.engine)
 
     @cap_max.setter
     def cap_max(self, v: int) -> None:
@@ -185,6 +202,10 @@ class DeviceRowPool:
             self.matrix = self.engine.grow_rows(self.matrix, new_cap - self.cap)
         self.row_at.extend([None] * (new_cap - self.cap))
         self.cap = new_cap
+        # What the pool that last took device memory was budgeted.
+        budget, devices = pool_bytes(self.engine, self.n_slices)
+        self.stats.gauge("rowpool.budget_bytes_per_device", budget // devices)
+        self.stats.gauge("rowpool.capacity_slots", self.cap_max)
 
     def _reset(self) -> None:
         self.slot_of.clear()
@@ -248,15 +269,20 @@ class DeviceRowPool:
         been handed to a reader, the jax engine updates it IN PLACE (the
         array is donated to one compiled step: no copy of the pool, and
         the old array object is gone); otherwise, and on the other
-        engines, the update is functional and a reader's snapshot stays
+        engines (the mesh's composed form: a functional scatter over the
+        sharded pool, then the written rows counted on every device and
+        reduced), the update is functional and a reader's snapshot stays
         whole.  A step that fails after taking the array leaves the pool
         empty (``_drop``) and raises.  Row-major pools carry no Gram and
         keep their functional scatter.
 
-        ``span`` (the request's ``pool.repair``) gets a child per stage:
+        ``span`` (the request's ``pool.repair``) gets the tag ``form``
+        (``step`` or ``composed``: which form the engine ran) and a child
+        per stage:
         ``pool.fetch`` (host densify), ``pool.scatter`` (index building,
         upload and the dispatch) and ``pool.gram``, where the host blocks
-        on the device for the counts and folds them into its Gram."""
+        on the device for the counts and folds them into its Gram (on the
+        mesh engine with a ``mesh.fetch`` child for the wait itself)."""
         if isinstance(dirty_rows, dict):
             per_slice = {
                 si: sorted(r for r in set(dirty_rows.get(si, ())) if r in self.slot_of)
@@ -300,14 +326,15 @@ class DeviceRowPool:
             return True
         taken = self.matrix
         try:
-            self.matrix, finish, in_place = self.engine.repair_planes(
+            self.matrix, finish, in_place, form = self.engine.repair_planes(
                 taken, gram, groups, donate=not self._handed_out
             )
             if sp is not None:
                 sp.finish()
+                span.annotate(form=form)
             if finish is not None:
                 sp = span.child("pool.gram") if span is not None else None
-                gram = finish()
+                gram = finish(sp)
                 if sp is not None:
                     sp.finish()
         except BaseException:
@@ -319,6 +346,9 @@ class DeviceRowPool:
         if in_place:
             self.stat_repairs_in_place += 1
             self.stats.count("rowpool.repairs_in_place")
+        if form == "composed":
+            self.stat_repairs_composed += 1
+            self.stats.count("rowpool.repairs_composed")
         if finish is not None:
             self.box["gram"] = gram
             glut = self.box.get("gram_lut")
@@ -341,6 +371,7 @@ class DeviceRowPool:
             upload_bytes=self.engine.stat_upload_bytes - up0,
             slices=len(stale),
             in_place=self.stat_repairs_in_place > in_place0,
+            devices=self.engine.slice_axis_devices(self.n_slices),
         )
         return ok
 

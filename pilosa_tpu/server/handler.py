@@ -95,13 +95,20 @@ def result_to_json(result):
 def device_status(engine) -> dict:
     """The ``device`` object of the server's startup line and of
     ``GET /status``: which engine answers, on which devices (as jax
-    reports them, with allocator counters), and whether the native host
-    lanes are loaded — so an operator never has to guess whether the
-    chip is serving."""
+    reports them, with allocator counters and, beside each device's
+    ``bytes_in_use``, the row pool's budget on that device), and whether
+    the native host lanes are loaded — so an operator never has to guess
+    whether the chip is serving."""
     from pilosa_tpu import native
+    from pilosa_tpu.rowpool import pool_bytes
 
+    info = engine.device_info()
+    # A slice axis every device of the engine shares: a pool as large as it gets.
+    budget, devices = pool_bytes(engine, 2 * max(1, info["count"]))
+    for dev in info["devices"]:
+        dev["pool_budget_bytes"] = budget // devices
     path = native.loaded_path()
-    return {**engine.device_info(), "native": path is not None, "native_path": path}
+    return {**info, "native": path is not None, "native_path": path}
 
 
 class Handler:

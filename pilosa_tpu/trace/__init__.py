@@ -64,7 +64,8 @@ Design:
   CPU time over the same interval), ``door.read``, ``qos.admit``,
   ``qcache.lookup`` / ``qcache.commit``, ``serve.validate``, ``serve.repair`` >
   ``pool.lock_wait``, ``pool.repair`` > ``pool.fetch`` / ``pool.scatter``
-  / ``pool.gram``, ``pool.refresh``, ``pool.miss``, ``device`` (tag
+  / ``pool.gram`` (> ``mesh.fetch``: the mesh engine's wait for a reduced
+  result), ``pool.refresh``, ``pool.miss``, ``device`` (tag
   ``lane``), ``write.apply``, ``parse``, ``fused``, ``call.<Name>``,
   ``slices`` / ``slice_chunk``, ``remote``, ``encode``.
 

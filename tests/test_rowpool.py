@@ -540,8 +540,8 @@ def test_repair_planes_on_a_tiled_matrix():
     gram = eng.pair_gram(eng.matrix(host[:, :n]))
     block = rng.integers(0, 1 << 32, size=(2, 1, w), dtype=np.uint32)
     given = eng.matrix(host.copy())  # the CPU backend may alias a numpy buffer
-    matrix, finish, in_place = eng.repair_planes(given, gram, [([1, 6], [3], block)], donate=True)
-    assert in_place and given.is_deleted() and matrix.shape == (S, R, w // 128, 128)
+    matrix, finish, in_place, form = eng.repair_planes(given, gram, [([1, 6], [3], block)], donate=True)
+    assert in_place and form == "step" and given.is_deleted() and matrix.shape == (S, R, w // 128, 128)
     host[[1, 6], 3] = block[:, 0]
     np.testing.assert_array_equal(np.asarray(matrix).reshape(S, R, w), host)
     np.testing.assert_array_equal(finish(), eng.pair_gram(eng.matrix(host[:, :n])))

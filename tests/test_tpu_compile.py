@@ -309,3 +309,52 @@ def test_mesh_engine_path_compiles_for_four_chips(path, topo, slice_mesh):
     mem = lowered.compile().memory_analysis()
     per_device = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     assert per_device < HBM_BYTES // 2
+
+
+@pytest.mark.parametrize("program", [
+    "pair_gram", "set_plane_cells_1", "set_plane_cells_8",
+    "gram_update_b256", "gram_update_b1024"])
+def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, monkeypatch):
+    """What the four-chip dashboard deployment (256 slices x 256 slots: a
+    2 GiB shard a device) runs on its pool besides paging: the Gram build
+    at load, and a write repair's scatter and rank-k recount.  Left to
+    GSPMD, the scatter of one cell and the Gram's scan each gathered the
+    whole 8 GiB pool onto every device; under shard_map nothing but the
+    counts' all-reduce crosses the mesh.  A repair is ONE scatter of all
+    its cells (1, 2, 4 or 8: a scatter per group of cells had as many
+    copies of the 2 GiB shard in flight as a burst had groups)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pilosa_tpu.engine import MeshEngine
+    from pilosa_tpu.ops import dispatch
+    from pilosa_tpu.parallel import sharded
+
+    def on(spec, shape, dtype="uint32"):
+        return _shape(shape, dtype, NamedSharding(slice_mesh, P(*spec)))
+
+    rm = on(("slice", None, None, None), (256, 256, T, 128))
+    if program == "pair_gram":
+        lowered = sharded._sharded_pair_gram_kernel(slice_mesh, "slice", 4).lower(rm)
+    elif program.startswith("set_plane_cells"):
+        c = int(program.rsplit("_", 1)[1])
+        lowered = sharded._sharded_set_plane_cells_kernel(slice_mesh, "slice", 4).lower(
+            rm, on((None, None), (c, 2), "int32"), on((None, None, None), (c, T, 128)))
+    else:
+        monkeypatch.setattr(dispatch, "use_pallas", lambda: True)  # as on the chip
+        b = int(program.rsplit("b", 1)[1])
+        lowered = MeshEngine(devices=topo.devices)._gram_counts_program().lower(
+            rm, on((None, None), (b, 2), "int32"))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "all-gather" not in text and "all-to-all" not in text
+    mem = compiled.memory_analysis()
+    shard = 256 * 256 * W * 4 // 4
+    assert mem.argument_size_in_bytes < shard + 64 * 2**20
+    assert mem.temp_size_in_bytes < 2**30
+    if program.startswith("set_plane_cells"):
+        assert mem.output_size_in_bytes == shard and "all-reduce" not in text
+        assert "pool.set_plane_rows" in text
+    else:
+        assert "all-reduce" in text
+        assert ("tpu_custom_call" in text) == program.startswith("gram_update")
+        assert ("pool.gram_update" if program.startswith("gram_update") else "pool.pair_gram") in text

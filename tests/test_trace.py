@@ -782,6 +782,9 @@ def test_pool_spans_and_counters_for_paging_refresh_and_eviction():
         def count(self, name, value=1):
             self.n[name] = self.n.get(name, 0) + value
 
+        def gauge(self, name, value):
+            self.g = dict(getattr(self, "g", {}), **{name: value})
+
     def fetch(row_ids, slice_idxs):
         return np.zeros((len(slice_idxs), len(row_ids), 8), dtype=np.uint32)
 
@@ -800,6 +803,8 @@ def test_pool_spans_and_counters_for_paging_refresh_and_eviction():
     assert root.children[3].tags == {"rows": 2, "upload_bytes": 0}
     assert root.children[5].tags == {"rows": 1, "evicted": 1, "upload_bytes": 0}
     assert stats.n == {"rowpool.misses": 3, "rowpool.evictions": 1}
+    # what the pool was budgeted when it took device memory: the default per device, its own cap
+    assert stats.g == {"rowpool.budget_bytes_per_device": 2 << 30, "rowpool.capacity_slots": 2}
     assert (pool.stat_misses, pool.stat_evictions, pool.stat_repairs) == (3, 1, 0)
     pool._reset()
     assert stats.n["rowpool.resets"] == pool.stat_resets == 1
