@@ -96,9 +96,9 @@ def _repair_planes_composed(engine, matrix, gram, groups):
     asynchronous and every output is allocated at once: 14.1 GiB on a 16
     GiB chip for eight groups on a 2 GiB shard) and ``gram_update_rows``,
     which gets the pre-patch array for its restricted-slice delta.  What
-    the numpy and mesh engines run, and the jax engine for the repairs its
-    compiled step does not take.  ``finish(span)`` hands the request's
-    span (or None) on to ``gram_update_rows``."""
+    the numpy engine runs, and the jax and mesh engines for the repairs
+    their compiled step does not take.  ``finish(span)`` hands the
+    request's span (or None) on to ``gram_update_rows``."""
     old = matrix
     _, idx, planes = _padded_cells(groups)
     matrix = engine.set_plane_cells(matrix, idx, planes)
@@ -399,7 +399,8 @@ class NumpyEngine:
         the repaired Gram (a new array; it blocks on the device where
         there is one; None without a Gram), whether the caller's array was
         updated in place and is gone, and which form ran (``"step"``: the
-        jax engine's compiled step; ``"composed"``: the copying form).
+        jax and mesh engines' compiled step; ``"composed"``: the copying
+        form).
         ``donate`` says the caller holds the only reference to ``matrix``;
         this engine copies regardless."""
         return _repair_planes_composed(self, matrix, gram, groups)
@@ -764,9 +765,8 @@ class JaxEngine:
     def gram_update_rows(self, matrix, gram, slots, old_matrix=None, slice_idxs=None,
                          span=None):
         """Rank-k Gram repair (see NumpyEngine.gram_update_rows), for the
-        repairs the compiled step of ``repair_planes`` does not take and
-        for the mesh engine: one batched gather-count dispatch
-        recomputes the dirty rows/columns.
+        repairs the compiled step of ``repair_planes`` does not take: one
+        batched gather-count dispatch recomputes the dirty rows/columns.
         The dirty-slot axis pads to a power-of-two bucket (recomputing a
         row twice is idempotent) so the jitted dispatch shape stays
         stable across repairs of 1..K rows.
@@ -831,21 +831,43 @@ class JaxEngine:
 
     def repair_planes(self, matrix, gram, groups, donate=False):
         """One compiled step (``ops.bitwise.repair_planes``, jitted with
-        the matrix donated) for the repairs that ``gram_update_rows``
-        would answer by its restricted-slice delta: one upload of the
-        written planes, one dispatch that writes them into the pool's
-        own buffer and counts what each write does to the Gram, and in
-        ``finish()`` one blocking read of int32[cells, n], folded into a
-        copy of the host Gram.  The cell axis pads to a power-of-two
-        bucket so the compiled shapes stay few.  Without ``donate`` a
-        reader still holds ``matrix``: the step then runs on a copy made
-        first.  Repairs without a Gram, wide ones and those over half
-        the slices keep the composed form."""
+        the matrix donated; on the mesh engine the same step on every
+        device's own shard, ``_repair_step``) for the repairs that
+        ``gram_update_rows`` would answer by its restricted-slice delta:
+        one upload of the written planes, one dispatch that writes them
+        into the pool's own buffer and counts what each write does to the
+        Gram, and in ``finish()`` one blocking read of int32[cells, n],
+        folded into a copy of the host Gram.  The cell axis pads to a
+        power-of-two bucket so the compiled shapes stay few.  Without
+        ``donate`` a reader still holds ``matrix``: the step then runs on
+        a copy made first (which keeps the matrix's sharding).  Repairs
+        without a Gram, wide ones and those over half the slices keep the
+        composed form."""
         cells, idx, planes = _padded_cells(groups)
         k = len({slot for _, slot in cells})
         sb = _pow2(len({si for si, _ in cells}))
         if gram is None or 2 * k >= gram.shape[0] or 2 * sb >= matrix.shape[0]:
             return _repair_planes_composed(self, matrix, gram, groups)
+        self._note_upload(planes.nbytes)
+        if matrix.ndim == 4:
+            planes = self._tile_host(planes)
+        if not donate:
+            matrix = self._jnp.copy(matrix)
+        matrix, delta = self._repair_step(matrix, idx, planes, gram.shape[0])
+
+        def finish(span=None):
+            out = np.array(gram, copy=True)
+            for (_, slot), d in zip(cells, self.to_numpy(delta, span)):
+                out[slot, :] += d
+                out[:, slot] += d
+                out[slot, slot] -= d[slot]
+            return out
+
+        return matrix, finish, donate, "step"
+
+    def _repair_step(self, matrix, idx, planes, n: int):
+        """``ops.bitwise.repair_planes`` as one program, ``matrix``
+        donated: ``(matrix, delta int32[C, n])``."""
         if not hasattr(self, "_repair_jit"):
             import jax
 
@@ -854,29 +876,16 @@ class JaxEngine:
             self._repair_jit = jax.jit(
                 repair_planes, static_argnums=3, donate_argnums=0
             )
-        self._note_upload(planes.nbytes)
-        if matrix.ndim == 4:
-            planes = self._tile_host(planes)
-        if not donate:
-            matrix = self._jnp.copy(matrix)
-        matrix, delta = self._repair_jit(matrix, idx, planes, gram.shape[0])
-
-        def finish(span=None):
-            out = np.array(gram, copy=True)
-            for (_, slot), d in zip(cells, np.asarray(delta)):
-                out[slot, :] += d
-                out[:, slot] += d
-                out[slot, slot] -= d[slot]
-            return out
-
-        return matrix, finish, donate, "step"
+        return self._repair_jit(matrix, idx, planes, n)
 
     def slice_axis_devices(self, n_slices: int) -> int:
         """Devices that share the slice axis of an ``[n_slices, ...]``
         array of this engine (what the row pool's budget follows): one."""
         return 1
 
-    def to_numpy(self, x) -> np.ndarray:
+    def to_numpy(self, x, span=None) -> np.ndarray:
+        """``x`` on the host; ``span`` is for the mesh engine, whose wait
+        is a ``mesh.fetch`` child of it."""
         return np.asarray(x)
 
     def _devices(self) -> list:
@@ -1152,11 +1161,17 @@ class MeshEngine(JaxEngine):
             self._gram_counts_jit = self._jax.jit(gram_update)
         return self._gram_counts_jit
 
-    def repair_planes(self, matrix, gram, groups, donate=False):
-        # The compiled step indexes single slices of the pool; on the
-        # sharded slice axis the composed form stays, with the overrides
-        # above.
-        return _repair_planes_composed(self, matrix, gram, groups)
+    def _repair_step(self, matrix, idx, planes, n: int):
+        """The compiled step on every device's own shard, under
+        ``shard_map`` with the pool donated: each device writes the cells
+        whose slice it holds and counts them against that one slice's
+        rows, and a psum hands the whole delta to every device (a matrix
+        the mesh cannot shard takes the parent's single program)."""
+        if self.slice_axis_devices(matrix.shape[0]) == 1:
+            return super()._repair_step(matrix, idx, planes, n)
+        from pilosa_tpu.parallel.sharded import sharded_repair_planes
+
+        return sharded_repair_planes(self.mesh, matrix, idx, planes, n)
 
     def _pallas_mode(self, n_slices: int, w: int) -> str:
         """How to run kernels under the mesh: "pallas" (shard_map'd
@@ -1217,10 +1232,10 @@ class MeshEngine(JaxEngine):
             sp.finish()
         return out
 
-    def to_numpy(self, x) -> np.ndarray:
+    def to_numpy(self, x, span=None) -> np.ndarray:
         # Every inherited JaxEngine host conversion routes through here,
         # so allgather-aware fetching covers them all on multi-host.
-        return self._fetch(x)
+        return self._fetch(x, span)
 
     def gather_count_multi(self, op, row_matrix, idx):
         from pilosa_tpu.ops.pallas_kernels import rm_words

@@ -367,6 +367,56 @@ def sharded_set_plane_cells(mesh: SliceMesh, row_matrix, cells, planes):
 
 
 @functools.lru_cache(maxsize=None)
+def _sharded_repair_planes_kernel(mesh_obj, axis: str, rm_ndim: int, n: int):
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from pilosa_tpu.ops.bitwise import repair_planes
+
+    rest = [None] * (rm_ndim - 1)
+
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh_obj,
+        in_specs=(P(axis, *rest), P(None, None), P(None, *rest[1:])),
+        out_specs=(P(axis, *rest), P()),
+        check_vma=False,
+    )
+    def repair_planes_shards(rm_shard, cells, planes):
+        # This device's cells to the front, in the burst's order (the
+        # step runs a prefix, and the deltas of one slice telescope in
+        # that order); the others, and a bucket's tail, become (-1, -1).
+        si = cells[:, 0] - lax.axis_index(axis) * rm_shard.shape[0]
+        mine = (si >= 0) & (si < rm_shard.shape[0])
+        order = jnp.argsort(~mine, stable=True)
+        own = jnp.where(mine[:, None], jnp.stack([si, cells[:, 1]], axis=1), -1)[order]
+        rm_shard, delta = repair_planes(rm_shard, own, planes[order], n)
+        # Back to the burst's order; the rows of other devices' cells are
+        # zero here, so the psum hands every device the whole delta.
+        return rm_shard, lax.psum(jnp.zeros_like(delta).at[order].set(delta), axis)
+
+    return jax.jit(repair_planes_shards, donate_argnums=0)
+
+
+def sharded_repair_planes(mesh: SliceMesh, row_matrix, cells, planes, n: int):
+    """``ops.bitwise.repair_planes`` on a slice-sharded pool matrix,
+    DONATED: every device runs the step on its own shard for the cells
+    whose slice it holds - per cell one pass over that slice's ``n`` rows
+    and one plane written into the shard's own buffer, no copy of the
+    shard and no pass over it - and a psum of int32[C, n] gives every
+    device the whole delta (slices are disjoint column ranges, so the
+    devices' deltas add; a cell's row is zero on every device but one).
+    ``cells``: int32[C, 2] of (slice, slot), a bucket's tail (-1, -1);
+    ``planes``: [C, ...words]; both replicated.  Returns ``(row_matrix,
+    delta)``: the matrix with its sharding, the delta replicated."""
+    _require_divisible(row_matrix.shape[0], mesh.n_devices)
+    kernel = _sharded_repair_planes_kernel(mesh.mesh, mesh.AXIS, row_matrix.ndim, n)
+    return kernel(row_matrix, cells, planes)
+
+
+@functools.lru_cache(maxsize=None)
 def _sharded_pair_gram_kernel(mesh_obj, axis: str, rm_ndim: int):
     import jax
     from jax import lax

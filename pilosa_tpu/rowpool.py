@@ -130,10 +130,12 @@ class DeviceRowPool:
         self.stat_resets = 0
         self.stat_repairs = 0
         # Of those, the ones that updated the pool's array where it lay
-        # (no copy of the pool: the engine's compiled step, donated).
+        # (no copy of the pool: the jax or mesh engine's compiled step,
+        # donated).
         self.stat_repairs_in_place = 0
         # ... and the ones that took the copying form (set_plane_cells and
-        # gram_update_rows: every repair of the numpy and mesh engines).
+        # gram_update_rows: every repair of the numpy engine, the wide
+        # ones of the others).
         self.stat_repairs_composed = 0
         # (row, slice) planes actually fetched by the patch lane — the
         # per-(row, slice) granularity benches/tests assert on this.
@@ -266,15 +268,16 @@ class DeviceRowPool:
 
         Planes and Gram go through ONE engine call a repair
         (``engine.repair_planes``).  While the pool's array has never
-        been handed to a reader, the jax engine updates it IN PLACE (the
-        array is donated to one compiled step: no copy of the pool, and
-        the old array object is gone); otherwise, and on the other
-        engines (the mesh's composed form: a functional scatter over the
-        sharded pool, then the written rows counted on every device and
-        reduced), the update is functional and a reader's snapshot stays
-        whole.  A step that fails after taking the array leaves the pool
-        empty (``_drop``) and raises.  Row-major pools carry no Gram and
-        keep their functional scatter.
+        been handed to a reader, the jax and mesh engines update it IN
+        PLACE (the array is donated to one compiled step - on the mesh
+        the same step on every device's own shard, under ``shard_map``,
+        the deltas psummed: no copy of the pool or of a shard, and the
+        old array object is gone); otherwise, for the repairs the step
+        does not take (wide ones, over half the slices: the composed
+        form) and on the numpy engine, the update is functional and a
+        reader's snapshot stays whole.  A step that fails after taking
+        the array leaves the pool empty (``_drop``) and raises.  Row-major
+        pools carry no Gram and keep their functional scatter.
 
         ``span`` (the request's ``pool.repair``) gets the tag ``form``
         (``step`` or ``composed``: which form the engine ran) and a child

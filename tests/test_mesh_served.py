@@ -6,7 +6,9 @@ on one device's slices and on several devices', each followed by its
 read-back.  The same requests against ``engine = "jax"`` give the same
 answers: everything above the engine is shared.  Then the row pool's budget
 rule (2 GiB per device that shares the slice axis), and what the mesh's
-repair reports of itself: tags, the ``mesh.fetch`` span, counters, /status.
+repair reports of itself: tags, the ``mesh.fetch`` span, counters, /status;
+and the repair's compiled step under ``shard_map`` against the numpy engine's
+composed form, burst by burst.
 
 Pallas kernels run in interpret mode (``PILOSA_TPU_PALLAS_INTERPRET=1``, as
 ``tests/test_parallel.py`` runs the mesh tier); the mesh is the first four
@@ -114,6 +116,14 @@ class Served:
         (pool,) = self.server.executor._matrix_cache.values()
         return pool
 
+    def repair_of(self, calls):
+        """The answers of ``calls`` and the one ``pool.repair`` span the
+        read made on its way."""
+        results, root = _post(self.host, _pairs_body(calls), trace=True)
+        assert results == _want(self.ref, calls)
+        (repair,) = _find(root, "pool.repair")
+        return repair, root
+
 
 @pytest.fixture(scope="module")
 def mesh_of_four():
@@ -142,6 +152,11 @@ def mesh_of_four():
 @pytest.fixture(scope="module")
 def served(mesh_of_four, tmp_path_factory):
     out = {e: Served(e, str(tmp_path_factory.mktemp(e))) for e in ENGINES}
+    for s in out.values():
+        # The first repair after paging: the pager handed the pool's array
+        # to the reads that wanted rows, so this one may not donate it.
+        s.set_bit(0, 4 * SLICE_WIDTH + 7)
+        s.first_repair, _ = s.repair_of([("Intersect", 0, 1)])
     yield out
     for s in out.values():
         s.server.close()
@@ -191,17 +206,22 @@ def test_setbit_burst_then_its_read_back(served, engine, burst, placement):
     repairs = s.pool().stat_repairs
     for row, col in zip(rows, _burst_columns(burst, placement, rng)):
         s.set_bit(row, col)
-    results, root = _post(s.host, _pairs_body(calls), trace=True)
-    assert results == _want(s.ref, calls)
+    repair, root = s.repair_of(calls)
     assert s.pool().stat_repairs == repairs + 1 and s.pool().stat_misses == N_ROWS
-    (repair,) = _find(root, "pool.repair")
     assert repair["tags"]["planes"] == burst
+    # Eight slices written of eight: over half, the composed form; every
+    # other burst takes the compiled step, in place (these reads want no
+    # rows of the pool, so nobody holds its array), on either engine.
+    stepped = (burst, placement) != (8, "different_devices")
+    assert repair["tags"]["form"] == ("step" if stepped else "composed")
+    assert repair["tags"]["in_place"] is stepped
     if engine == "mesh":
-        assert repair["tags"]["form"] == "composed" and repair["tags"]["devices"] == DEVICES
-        assert repair["tags"]["in_place"] is False
+        assert repair["tags"]["devices"] == DEVICES
         (gram,) = _find(repair, "pool.gram")
         fetches = _find(gram, "mesh.fetch")
-        assert fetches and sum(f["ms"] for f in fetches) <= gram["ms"]
+        # The step: one wait for the psummed delta.
+        assert (len(fetches) == 1 if stepped else fetches)
+        assert sum(f["ms"] for f in fetches) <= gram["ms"]
     else:
         assert repair["tags"]["devices"] == 1 and not _find(root, "mesh.fetch")
 
@@ -224,20 +244,46 @@ def test_mesh_server_says_what_it_serves_from(served):
     assert one["engine"] == "jax" and all(d["pool_budget_bytes"] == 2 << 30 for d in one["devices"])
 
 
-def test_mesh_repairs_are_counted_as_composed(served):
+def test_mesh_repairs_are_counted_in_place(served):
     s = served["mesh"]
     before = _get(s.host, "/debug/vars")
     s.set_bit(3, 5 * SLICE_WIDTH + 11)
     calls = [("Intersect", 3, 4)]
     assert _post(s.host, _pairs_body(calls))[0] == _want(s.ref, calls)
     after = _get(s.host, "/debug/vars")
-    assert after["rowpool.repairs_composed"] == before.get("rowpool.repairs_composed", 0) + 1
+    assert after["rowpool.repairs_in_place"] == before.get("rowpool.repairs_in_place", 0) + 1
     assert after["rowpool.repairs"] == before.get("rowpool.repairs", 0) + 1
-    assert after.get("rowpool.repairs_in_place", 0) == before.get("rowpool.repairs_in_place", 0)
+    assert after.get("rowpool.repairs_composed", 0) == before.get("rowpool.repairs_composed", 0)
     assert after["rowpool.budget_bytes_per_device"] == 2 << 30
     # 8 GiB over four devices for 8 slices of 128 KiB planes
     assert after["rowpool.capacity_slots"] == DEVICES * (2 << 30) // (N_SLICES * 131072)
     assert after["rowpool.misses"] == before["rowpool.misses"] == N_ROWS
+
+
+def test_a_wide_mesh_repair_is_counted_as_composed(served):
+    """Two thirds of the rows written before one read (``2 * k >= n``): the
+    composed form, on the mesh as on one chip - a functional scatter, the
+    Gram recounted on every device."""
+    s = served["mesh"]
+    before = _get(s.host, "/debug/vars")
+    rows = list(range(0, N_ROWS, 3)) + list(range(1, N_ROWS, 3))
+    for row in rows:
+        s.set_bit(row, 6 * SLICE_WIDTH + 100 + row)
+    repair, _ = s.repair_of([("Xor", r, (r + 1) % N_ROWS) for r in rows[:16]])
+    assert repair["tags"]["planes"] == len(rows) and repair["tags"]["form"] == "composed"
+    assert repair["tags"]["in_place"] is False and repair["tags"]["devices"] == DEVICES
+    after = _get(s.host, "/debug/vars")
+    assert after["rowpool.repairs_composed"] == before.get("rowpool.repairs_composed", 0) + 1
+    assert after["rowpool.repairs_in_place"] == before["rowpool.repairs_in_place"]
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_the_first_repair_after_paging_patches_a_copy(served, engine):
+    """The reads that paged the rows in took the pool's array; the repair
+    that found it handed out ran the same step on a copy."""
+    tags = served[engine].first_repair["tags"]
+    assert (tags["form"], tags["in_place"], tags["planes"]) == ("step", False, 1)
+    assert tags["devices"] == (DEVICES if engine == "mesh" else 1)
 
 
 # -- (c): the pool's budget follows the devices that share the slice axis ----
@@ -385,3 +431,109 @@ def test_a_burst_over_many_slices_is_one_copy_of_the_pool(kind, monkeypatch):
         want[s, slot] = block[0, 0]
     np.testing.assert_array_equal(np.asarray(matrix).reshape(8, 8, 256), want)
     np.testing.assert_array_equal(finish(), np.einsum("srb,stb->rt", bits(want), bits(want)))
+
+
+# -- the repair's compiled step on every device's own shard ---------------------
+
+# (slice, slot) cells in the burst's order; 32 slices, eight a device.
+_STEP_BURSTS = {
+    "one_cell": [(5, 1)],
+    "two_cells_same_device": [(8, 1), (9, 2)],
+    "two_cells_different_devices": [(3, 1), (20, 2)],
+    "two_rows_of_one_slice": [(10, 3), (10, 4)],
+    "three_cells_and_a_tail": [(0, 1), (31, 2), (17, 1)],       # bucket 4: one (-1, -1)
+    "eight_cells_same_device": [(8 + i, i % 5) for i in range(8)],
+    "eight_cells_different_devices": [(8 * (i % 4) + i // 4, i % 5) for i in range(8)],
+    # rows of two slices on two devices, interleaved: each device's cells
+    # keep the burst's order, or the deltas of a slice would not telescope
+    "eight_cells_two_slices_interleaved": [(4 if i % 2 else 12, i // 2) for i in range(8)],
+    "five_cells_and_a_tail_on_one_slice": [(30, i) for i in range(5)],
+}
+
+
+def _pool_and_gram(rng, n_slices=32, cap=16, n=12, words=256):
+    """A pool matrix on the host (logical form) and the Gram of its first
+    ``n`` slots: narrower than the capacity, as a pool's Gram is."""
+    host = rng.integers(0, 1 << 32, size=(n_slices, cap, words), dtype=np.uint32)
+    host &= rng.integers(0, 1 << 32, size=host.shape, dtype=np.uint32)
+    bits = np.unpackbits(host[:, :n].view(np.uint8), axis=-1).astype(np.int64)
+    return host, np.einsum("srb,stb->rt", bits, bits)
+
+
+def _cells_as_groups(rng, cells):
+    return [([si], [slot], rng.integers(0, 1 << 32, size=(1, 1, 256), dtype=np.uint32))
+            for si, slot in cells]
+
+
+def _mesh_repair_equals_numpys(host, gram, groups, donate=True):
+    """``repair_planes`` of the mesh engine on ``host`` beside the numpy
+    engine's (the composed form): same matrix, same Gram, exactly.
+    Returns the array the mesh engine was given, the one it returned, and
+    what it said of the repair: ``(in_place, form)``."""
+    ref, eng = _engine("numpy"), _engine("mesh4")
+    want, want_finish, _, want_form = ref.repair_planes(ref.matrix(host.copy()), gram, groups)
+    assert want_form == "composed"
+    given = eng.matrix(host.copy())
+    matrix, finish, in_place, form = eng.repair_planes(given, gram, groups, donate=donate)
+    np.testing.assert_array_equal(np.asarray(matrix).reshape(host.shape), np.asarray(want))
+    if gram is None:
+        assert finish is None and want_finish is None
+    else:
+        np.testing.assert_array_equal(finish(), want_finish())
+    return given, matrix, (in_place, form)
+
+
+@pytest.mark.parametrize("burst", sorted(_STEP_BURSTS))
+def test_mesh_repair_step_equals_the_numpy_engines_composed_form(burst):
+    """Same matrix, same Gram, exactly - and the mesh ran the step, on the
+    pool's own shards: the array it was given is gone, the one it returns
+    lies where that one lay."""
+    rng = np.random.default_rng(len(burst))
+    host, gram = _pool_and_gram(rng)
+    given, matrix, said = _mesh_repair_equals_numpys(
+        host, gram, _cells_as_groups(rng, _STEP_BURSTS[burst]))
+    assert said == (True, "step") and given.is_deleted()
+    assert len(matrix.sharding.device_set) == 4 and matrix.sharding.spec[0] == "slice"
+
+
+def test_mesh_repair_step_takes_a_group_of_several_slices_and_slots():
+    """A group as the pool makes them: the product of its slices (on three
+    devices) and its slots, one block."""
+    rng = np.random.default_rng(29)
+    host, gram = _pool_and_gram(rng)
+    groups = [([2, 9, 27], [1, 7], rng.integers(0, 1 << 32, size=(3, 2, 256), dtype=np.uint32)),
+              ([9], [3], rng.integers(0, 1 << 32, size=(1, 1, 256), dtype=np.uint32))]
+    assert _mesh_repair_equals_numpys(host, gram, groups)[2] == (True, "step")
+
+
+@pytest.mark.parametrize("why,cells", [
+    ("over_half_the_slices", [(s, 1) for s in range(0, 32, 2)]),
+    ("wide", [(5, slot) for slot in range(6)]),
+    ("no_gram", [(5, 1)]),
+])
+def test_mesh_repairs_the_step_does_not_take_stay_composed(why, cells):
+    rng = np.random.default_rng(len(why))
+    host, gram = _pool_and_gram(rng)
+    given, _, said = _mesh_repair_equals_numpys(
+        host, None if why == "no_gram" else gram, _cells_as_groups(rng, cells))
+    assert said == (False, "composed") and not given.is_deleted()
+
+
+def test_a_slice_axis_the_mesh_cannot_shard_takes_the_one_chip_step():
+    rng = np.random.default_rng(30)
+    host, gram = _pool_and_gram(rng, n_slices=6)
+    _, matrix, said = _mesh_repair_equals_numpys(host, gram, _cells_as_groups(rng, [(4, 2)]))
+    assert said == (True, "step") and len(matrix.sharding.device_set) == 1
+
+
+def test_an_array_a_reader_holds_is_not_donated_on_the_mesh():
+    """Without ``donate`` the step runs on a copy made first, which keeps
+    the sharding: the reader's array is whole and unchanged, and
+    ``in_place`` is false."""
+    rng = np.random.default_rng(31)
+    host, gram = _pool_and_gram(rng)
+    held, matrix, said = _mesh_repair_equals_numpys(
+        host, gram, _cells_as_groups(rng, [(21, 4), (2, 0)]), donate=False)
+    assert said == (False, "step") and not held.is_deleted()
+    assert matrix is not held and matrix.sharding == held.sharding
+    np.testing.assert_array_equal(np.asarray(held).reshape(host.shape), host)

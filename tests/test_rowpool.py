@@ -395,14 +395,19 @@ def test_acquire_without_dirty_rows_still_resets_box():
 
 # -- the repair as one engine call (engine.repair_planes) -----------------
 
-ENGINES = ["numpy", "jax"]
+ENGINES = ["numpy", "jax", "mesh4"]
+STEPPING = ("jax", "mesh4")  # engines with the compiled in-place step
 
 
 def _engine(name):
     if name == "numpy":
         return NumpyEngine()
-    from pilosa_tpu.engine import JaxEngine
+    from pilosa_tpu.engine import JaxEngine, MeshEngine
 
+    if name == "mesh4":  # 16 slices: four a device, of conftest's eight
+        import jax
+
+        return MeshEngine(devices=jax.devices()[:4])
     return JaxEngine()
 
 
@@ -460,14 +465,14 @@ def test_repair_planes_gram_equals_the_full_recompute(engine, burst):
     """Whatever burst the journals produce, patched planes and repaired
     Gram equal storage and the full recompute: once on an array a reader
     holds (a copy is patched), once on the pool's own (in place, on the
-    jax engine, for the bursts its compiled step takes)."""
+    jax and mesh engines, for the bursts their compiled step takes)."""
     rng = np.random.default_rng(21)
     pool, live, gens, _, box = _warm_pool(_engine(engine), rng)
     for nth in (1, 2):
         _, _, box2 = _write(rng, pool, live, gens, _BURSTS[burst])
         assert box2 is box and pool.stat_repairs == nth
         _assert_pool_is(pool, live)
-    stepped = engine == "jax" and burst not in ("half_the_slices", "wide")
+    stepped = engine in STEPPING and burst not in ("half_the_slices", "wide")
     assert pool.stat_repairs_in_place == (1 if stepped else 0)
 
 
@@ -486,9 +491,10 @@ def test_snapshot_isolation_across_repair(engine):
     assert unread is not matrix
     _write(rng, pool, live, gens, {2: {1}, 6: {4}})
     assert pool.stat_repairs == 2
-    assert pool.stat_repairs_in_place == (1 if engine == "jax" else 0)
-    if engine == "jax":
+    assert pool.stat_repairs_in_place == (1 if engine in STEPPING else 0)
+    if engine in STEPPING:
         assert unread.is_deleted() and not matrix.is_deleted()
+        assert pool.matrix.sharding == matrix.sharding
     np.testing.assert_array_equal(np.asarray(matrix), snap)
     _assert_pool_is(pool, live)
     # A reader that wants rows takes the array again: hands off until the
@@ -496,14 +502,14 @@ def test_snapshot_isolation_across_repair(engine):
     _, taken, _ = _write(rng, pool, live, gens, {3: {0}}, want=[0])
     snap = np.array(taken)
     _write(rng, pool, live, gens, {3: {0}})
-    assert pool.stat_repairs_in_place == (2 if engine == "jax" else 0)
+    assert pool.stat_repairs_in_place == (2 if engine in STEPPING else 0)
     np.testing.assert_array_equal(np.asarray(taken), snap)
     _assert_pool_is(pool, live)
 
 
 @pytest.mark.parametrize("engine", ENGINES)
 def test_failed_repair_leaves_a_pool_the_next_acquire_rebuilds(engine, monkeypatch):
-    """A step that raises after it took the array (jax), or between
+    """A step that raises after it took the array (jax, mesh), or between
     planes and Gram (numpy), leaves nothing to trust: the pool drops to
     its empty state, the error surfaces, the next acquire pages in."""
     rng = np.random.default_rng(23)
@@ -512,12 +518,11 @@ def test_failed_repair_leaves_a_pool_the_next_acquire_rebuilds(engine, monkeypat
     _write(rng, pool, live, gens, {2: {1}})  # the pool's array is its own now
 
     def boom(matrix, *args, **kw):
-        if engine == "jax":
+        if engine in STEPPING:
             matrix.delete()  # what a donated argument is after the call
         raise RuntimeError("device lost")
 
-    monkeypatch.setattr(eng, "_repair_jit" if engine == "jax" else "gram_update_rows",
-                        boom, raising=False)
+    monkeypatch.setattr(eng, "_repair_step" if engine in STEPPING else "gram_update_rows", boom)
     with pytest.raises(RuntimeError, match="device lost"):
         _write(rng, pool, live, gens, {2: {1}})
     assert pool.matrix is None and pool.cap == 0 and not pool.slot_of

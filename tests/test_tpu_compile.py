@@ -358,3 +358,38 @@ def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, m
         assert "all-reduce" in text
         assert ("tpu_custom_call" in text) == program.startswith("gram_update")
         assert ("pool.gram_update" if program.startswith("gram_update") else "pool.pair_gram") in text
+
+
+@pytest.mark.parametrize("cells", [1, 8])
+def test_mesh256_repair_step_updates_every_shard_in_place(cells, slice_mesh):
+    """The mesh's write repair (``sharded_repair_planes``: the compiled step
+    under shard_map, the pool donated) at the four-chip deployment's shape:
+    each device's output is its own 2 GiB shard, aliased; no temporary near
+    a slice's rows (32 MiB), no copy of the shard (the composed form's
+    ``copy u32[64,256,256,128]``, 6.5 ms a repair on every device), and
+    nothing crosses the mesh but the all-reduce of the delta, int32[C, n]."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pilosa_tpu.parallel import sharded
+
+    def on(spec, shape, dtype="uint32"):
+        return _shape(shape, dtype, NamedSharding(slice_mesh, P(*spec)))
+
+    step = sharded._sharded_repair_planes_kernel(slice_mesh, "slice", 4, 256)
+    compiled = step.lower(
+        on(("slice", None, None, None), (256, 256, T, 128)),
+        on((None, None), (cells, 2), "int32"),
+        on((None, None, None), (cells, T, 128)),
+    ).compile()
+    mem = compiled.memory_analysis()
+    shard = 256 * 256 * W * 4 // 4
+    assert mem.alias_size_in_bytes == shard
+    assert mem.temp_size_in_bytes < 8 * 2**20
+    text = compiled.as_text()
+    assert "all-gather" not in text and "all-to-all" not in text
+    # The shape an all-reduce op returns: "%name = s32[C,256]{..} all-reduce(".
+    reduced = re.findall(r"= \(?(\w+\[[\d,]*\])[^=]*? all-reduce(?:-start)?\(", text)
+    assert reduced and set(reduced) == {f"s32[{cells},256]"}
+    assert not re.search(r"= u32\[64,256,256,128\][^=]*? copy(?:-start)?\(", text)
