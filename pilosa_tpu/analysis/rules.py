@@ -1032,7 +1032,7 @@ def rule_check_then_act(files, root: str) -> list[Finding]:
 
 # -- 10. env-knob-outside-config (generation 4) -------------------------------
 #
-# The knob-plumbing contract (planner PR): every tuning knob that
+# The knob-plumbing contract: every tuning knob that
 # ``config.py`` owns flows CLI > env > config file > default through a
 # Config field and arrives at its consumer as a constructor argument.
 # A raw ``os.environ`` read of an owned knob anywhere else creates a

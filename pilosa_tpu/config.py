@@ -76,30 +76,6 @@ class Config:
     slice_chunk: int = 2048
     matrix_cache_entries: int = 4
     matrix_rows_max: int = 1024
-    # -- cost-based planner ([planner] TOML section) ----------------------
-    # Closes the cost-ledger loop: per-(index, fingerprint) strategy-lane
-    # selection from measured EWMA costs (static ladder until confident),
-    # background serve-state pre-arming, and ledger-derived budgets.
-    # Requires the ledger (PILOSA_TPU_COSTS not disabled) to do anything.
-    planner_enabled: bool = True
-    # Observations every lane needs before a cost-based pick engages.
-    planner_min_samples: int = 3
-    # Fractional EWMA advantage a challenger lane must show to displace
-    # the incumbent (anti-flap band).
-    planner_hysteresis: float = 0.15
-    # Every Nth consult of an under-sampled key explores its least-
-    # sampled lane (deterministic — a counter modulus, no RNG).
-    planner_explore_every: int = 16
-    # Pin every decision to one lane ("gram"/"rmgather"); the debugging
-    # and bench-baseline lever.  "" = adaptive.
-    planner_pin_lane: str = ""
-    # Per-cycle wall budget for background serve-state re-arming after
-    # invalidating writes; 0 disables the pre-armer (the default: it
-    # burns device time speculatively).
-    planner_prearm_budget_ms: float = 0.0
-    # Derive qcache admission floor / catch-up drain batch / resync chunk
-    # size from measured costs instead of their static values.
-    planner_adaptive_budgets: bool = True
     # -- HTTP serving ([server] TOML section) -----------------------------
     # Connection worker-pool bound: accepted connections queue to this
     # many pre-spawned handler threads (brief overflow wait, then a
@@ -280,20 +256,6 @@ class Config:
             raw.get("matrix-cache-entries", cfg.matrix_cache_entries)
         )
         cfg.matrix_rows_max = int(raw.get("matrix-rows-max", cfg.matrix_rows_max))
-        pl = raw.get("planner", {})
-        cfg.planner_enabled = bool(pl.get("enabled", cfg.planner_enabled))
-        cfg.planner_min_samples = int(pl.get("min-samples", cfg.planner_min_samples))
-        cfg.planner_hysteresis = float(pl.get("hysteresis", cfg.planner_hysteresis))
-        cfg.planner_explore_every = int(
-            pl.get("explore-every", cfg.planner_explore_every)
-        )
-        cfg.planner_pin_lane = str(pl.get("pin-lane", cfg.planner_pin_lane))
-        cfg.planner_prearm_budget_ms = float(
-            pl.get("prearm-budget-ms", cfg.planner_prearm_budget_ms)
-        )
-        cfg.planner_adaptive_budgets = bool(
-            pl.get("adaptive-budgets", cfg.planner_adaptive_budgets)
-        )
         srv = raw.get("server", {})
         cfg.server_max_threads = int(srv.get("max-threads", cfg.server_max_threads))
         cfg.server_workers = int(srv.get("workers", cfg.server_workers))
@@ -428,26 +390,6 @@ class Config:
             self.matrix_cache_entries = int(env["PILOSA_TPU_MATRIX_CACHE_ENTRIES"])
         if "PILOSA_TPU_MATRIX_ROWS_MAX" in env:
             self.matrix_rows_max = int(env["PILOSA_TPU_MATRIX_ROWS_MAX"])
-        if "PILOSA_TPU_PLANNER" in env:
-            self.planner_enabled = env["PILOSA_TPU_PLANNER"].lower() in (
-                "1", "true", "yes",
-            )
-        if "PILOSA_TPU_PLANNER_MIN_SAMPLES" in env:
-            self.planner_min_samples = int(env["PILOSA_TPU_PLANNER_MIN_SAMPLES"])
-        if "PILOSA_TPU_PLANNER_HYSTERESIS" in env:
-            self.planner_hysteresis = float(env["PILOSA_TPU_PLANNER_HYSTERESIS"])
-        if "PILOSA_TPU_PLANNER_EXPLORE_EVERY" in env:
-            self.planner_explore_every = int(env["PILOSA_TPU_PLANNER_EXPLORE_EVERY"])
-        if "PILOSA_TPU_PLANNER_PIN_LANE" in env:
-            self.planner_pin_lane = env["PILOSA_TPU_PLANNER_PIN_LANE"]
-        if "PILOSA_TPU_PLANNER_PREARM_BUDGET_MS" in env:
-            self.planner_prearm_budget_ms = float(
-                env["PILOSA_TPU_PLANNER_PREARM_BUDGET_MS"]
-            )
-        if "PILOSA_TPU_PLANNER_ADAPTIVE_BUDGETS" in env:
-            self.planner_adaptive_budgets = env[
-                "PILOSA_TPU_PLANNER_ADAPTIVE_BUDGETS"
-            ].lower() in ("1", "true", "yes")
         if "PILOSA_TPU_SERVER_MAX_THREADS" in env:
             self.server_max_threads = int(env["PILOSA_TPU_SERVER_MAX_THREADS"])
         if "PILOSA_TPU_SERVER_WORKERS" in env:

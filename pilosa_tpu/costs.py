@@ -1,10 +1,10 @@
 """Per-fingerprint cost ledger + device-dispatch cost attribution.
 
 No reference analog — the reference's observability stops at aggregate
-expvar counters.  This module is the feedback substrate ROADMAP item 4's
-trace-driven adaptive planner consumes: per-(index, frame, query
+expvar counters.  This module is telemetry: per-(index, frame, query
 fingerprint, strategy lane) observed costs and fetch bandwidth, in one
-queryable place (``/debug/costs``).
+queryable place (``/debug/costs``).  Nothing in the program decides
+from it.
 
 Two halves:
 
@@ -129,12 +129,9 @@ class CostLedger:
     payload and the per-tenant ledger rows /debug/tenants bills from.
 
     The tenant dimension is real (not ``tenant or index`` conflated):
-    two tenants sharing one index keep separate estimates.  Readers
-    that don't know the tenant (the planner's peeks) resolve through a
-    secondary (index, frame, fp, lane) -> full-key map that tracks the
-    most recently observed tenant for each 4-tuple."""
+    two tenants sharing one index keep separate estimates."""
 
-    _guarded_by_ = {"_entries": "costs._mu", "_by4": "costs._mu"}
+    _guarded_by_ = {"_entries": "costs._mu"}
 
     def __init__(self, cap: int = DEFAULT_CAP, alpha: float = DEFAULT_ALPHA,
                  stats=None):
@@ -145,8 +142,6 @@ class CostLedger:
         self.stats = stats if stats is not None else NOP_STATS
         self._mu = lockcheck.named_lock("costs._mu")
         self._entries: "OrderedDict[tuple, dict]" = OrderedDict()
-        # (index, frame, fp, lane) -> full 5-tuple key, MRU tenant wins.
-        self._by4: dict[tuple, tuple] = {}
 
     def observe(
         self,
@@ -166,7 +161,6 @@ class CostLedger:
         actually moved bytes, so transfer-free warm hits don't decay
         it."""
         key = (tenant, index, frame, fp, lane)
-        # analysis-ok: lockstep-determinism: display-only last_ts metadata; lockstep folds happen on rank 0 alone (workers carry no planner) and never feed a wire decision
         ts = wall_ts if wall_ts is not None else time.time()
         a = self.alpha
         with self._mu:
@@ -181,9 +175,7 @@ class CostLedger:
                     "last_ts": 0.0,
                 }
                 while len(self._entries) > self.cap:
-                    old_key, _ = self._entries.popitem(last=False)
-                    if self._by4.get(old_key[1:]) == old_key:
-                        del self._by4[old_key[1:]]
+                    self._entries.popitem(last=False)
                     self.stats.count("costs.evict")
             e["n"] += 1
             e["ewma_ms"] += a * (float(ms) - e["ewma_ms"])
@@ -198,7 +190,6 @@ class CostLedger:
             e["last_ms"] = round(float(ms), 3)
             e["last_ts"] = round(ts, 3)
             self._entries.move_to_end(key)
-            self._by4[key[1:]] = key
             n_entries = len(self._entries)
         self.stats.count("costs.fold")
         self.stats.gauge("costs.entries", n_entries)
@@ -254,25 +245,17 @@ class CostLedger:
         )
 
     def peek(
-        self, *, tenant: Optional[str] = None, index: str = "",
+        self, *, tenant: str = "", index: str = "",
         frame: str = "", fp: str = "", lane: str = ""
     ) -> Optional[dict]:
         """One entry's current estimates (a copy), or None.  Pure read:
-        the LRU order is NOT bumped — the planner consults on every
-        request and must not pin its own keys hot.  ``tenant=None``
-        (the planner's tenant-agnostic peeks) resolves through the
-        MRU-tenant map for the 4-tuple."""
+        the LRU order is NOT bumped."""
         with self._mu:
-            if tenant is not None:
-                e = self._entries.get((tenant, index, frame, fp, lane))
-            else:
-                full = self._by4.get((index, frame, fp, lane))
-                e = self._entries.get(full) if full is not None else None
+            e = self._entries.get((tenant, index, frame, fp, lane))
             return dict(e) if e is not None else None
 
     def entries(self, lane: Optional[str] = None) -> list[dict]:
-        """Entry copies (optionally one lane's), unsorted and unrounded
-        — the adaptive-budget derivations read these."""
+        """Entry copies (optionally one lane's), unsorted and unrounded."""
         with self._mu:
             return [
                 {"tenant": k[0], "index": k[1], "frame": k[2], "fp": k[3],
@@ -314,18 +297,16 @@ class CostLedger:
         self.alpha = min(1.0, max(0.01, float(st.get("alpha", self.alpha))))
         with self._mu:
             self._entries.clear()
-            self._by4.clear()
             for k, v in st.get("entries", []):
                 key = tuple(k)
                 if len(key) == 4:
                     # Pre-tenancy snapshot: pad with an empty tenant.
                     key = ("",) + key
                 self._entries[key] = dict(v)
-                self._by4[key[1:]] = key
 
     def snapshot(self, limit: int = 0) -> dict:
         """The /debug/costs payload: entries sorted by EWMA cost
-        descending (the planner's priority order)."""
+        descending."""
         with self._mu:
             items = [
                 {"tenant": k[0], "index": k[1], "frame": k[2], "fp": k[3],

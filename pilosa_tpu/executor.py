@@ -23,9 +23,7 @@ from __future__ import annotations
 import os
 import re
 import threading
-import time
 from collections import OrderedDict
-from functools import partial
 from dataclasses import dataclass, field, replace as dc_replace
 from datetime import datetime
 from typing import Any, Optional, Sequence
@@ -178,13 +176,6 @@ class ExecOptions:
     # instrumentation site a single branch — the tracing-off path adds
     # no objects and no calls.
     span: Any = None
-    # Strategy plan from the cost-based planner (planner.Planner
-    # plan_for): {"fp", "lane", "src", "confidence"}, JSON-clean so the
-    # lockstep service ships it on the batch wire entry like the expiry
-    # and sampling flags — the executor APPLIES plans but never makes
-    # them, so every rank runs rank 0's decision.  None (and a plan
-    # whose lane is None) keeps the static strategy ladder bit-exact.
-    plan: Any = None
 
 
 class QueryBitmap:
@@ -353,15 +344,6 @@ class Executor:
         self._no_gram_cfg = no_gram
         self._stream_bytes_cfg = int(stream_bytes)
         self._slice_chunk_cfg = int(slice_chunk)
-        # Cost-based strategy planner (planner.Planner) and background
-        # pre-armer (planner.PreArmer).  The executor never CONSULTS the
-        # planner — plans arrive on ExecOptions.plan from the front door
-        # — it only folds outcomes back (record) and signals the
-        # pre-armer from its serve/invalidate seams.  None (the default
-        # everywhere but the configured server) keeps each seam one
-        # branch, the same contract as the meter and tracing.
-        self.planner = None
-        self.prearmer = None
         # Per-(index, frame) dirty-row ledger fed by the write paths: the
         # serve-state patch lane's cheap budget precheck (the exact
         # generation-anchored delta comes from the fragment dirty-row
@@ -975,17 +957,6 @@ class Executor:
             return None
         opt = opt or ExecOptions()
         local = slices is None and not self._is_distributed(opt)
-        # Planner plan, applied at every exit of this lane: the armed
-        # native serve path IS the gram strategy family, so a forced
-        # "rmgather" plan must skip it (or the alternate lane could
-        # never run once a state arms) and every native answer folds
-        # back under lane "gram" — steady-state costs keep flowing into
-        # the ledger after arming, not just the cold passes.  A lane of
-        # None (static/empty ledger) leaves every branch below exactly
-        # as it was — the static-parity contract.
-        plan = opt.plan
-        forced = plan.get("lane") if plan is not None else None
-        rec = self.planner is not None and plan is not None
         # Single-call serving lane: with a valid cached serve state the
         # WHOLE request — parse, frame/row-label validation, Gram count
         # identities — runs inside one GIL-released native call
@@ -999,7 +970,7 @@ class Executor:
         # decline falls through to the general lane, which refreshes the
         # state.  The serve QUEUE below only coalesces the cold/unarmed
         # path, where per-request Python still dominates.
-        if local and self._serve_states and forced != "rmgather":
+        if local and self._serve_states:
             # Pick the candidate state by SNIFFING the first frame
             # reference (cheap regex over the request head) instead of
             # trying every armed state — each native attempt re-parses
@@ -1034,7 +1005,6 @@ class Executor:
                     with self._matrix_mu:
                         self._serve_states.pop((index, fname), None)
             if st is not None:
-                t0 = time.perf_counter() if rec else 0.0
                 if self.meter is not None:
                     with self.meter.measure("native", opt.span) as d:
                         counts = native.serve_pairs(
@@ -1060,11 +1030,6 @@ class Executor:
                     with self._matrix_mu:
                         if (index, fname) in self._serve_states:
                             self._serve_states.move_to_end((index, fname))
-                    if rec:
-                        self.planner.record(
-                            index=index, fp=plan.get("fp", ""), lane="gram",
-                            ms=(time.perf_counter() - t0) * 1e3, plan=plan,
-                        )
                     return counts.tolist()
             # Multi-frame breadth: a batch spanning SEVERAL armed frames
             # (the single-state path above only ever serves one) still
@@ -1077,14 +1042,8 @@ class Executor:
             if len(self._serve_states) > 1 and os.environ.get(
                 "PILOSA_TPU_NO_SERVEMULTI", ""
             ).lower() not in ("1", "true", "yes"):
-                t0 = time.perf_counter() if rec else 0.0
                 counts = self._serve_multi_counts(index, raw, opt)
                 if counts is not None:
-                    if rec:
-                        self.planner.record(
-                            index=index, fp=plan.get("fp", ""), lane="gram",
-                            ms=(time.perf_counter() - t0) * 1e3, plan=plan,
-                        )
                     return counts
         m = native.pql_match_pairs(raw)
         if m is None:
@@ -1130,12 +1089,7 @@ class Executor:
             # against per-chunk upload costs anyway.
             return None
 
-        # A plan with a FORCED lane bypasses the coalescing queue: the
-        # queue's fused evaluation is shared across requests (so it runs
-        # planless, like the lockstep multi-request join), and a
-        # planner-made pick must actually run — and fold back — on its
-        # own lane.  Static plans (lane None) keep the queue, bit-exact.
-        if self._serve_queue is not None and local and forced is None:
+        if self._serve_queue is not None and local:
             # Read coalescing: hand the matched arrays to the serve queue;
             # the current leader concatenates every queued request with
             # the same (index, name tables, slice set) into one vectorized
@@ -1166,12 +1120,12 @@ class Executor:
                 index, idxs, std_slices, opt,
                 lambda: pql.parse_cached(src),
                 lambda node_slices: self._fused_local_counts(
-                    index, matched, idxs, node_slices, plan=opt.plan, span=opt.span
+                    index, matched, idxs, node_slices, span=opt.span
                 ),
             )
         return self._fused_local_counts_arrays(
             index, frame_names, op_ids, frame_ids, r1, r2, std_slices,
-            plan=opt.plan, span=opt.span,
+            span=opt.span,
         )
 
     def _serve_state_valid(self, st: dict) -> bool:
@@ -1370,11 +1324,6 @@ class Executor:
         (pure-ingest workloads pay zero here) and when repair is
         disabled (the ledger's only consumer, _serve_state_repair, can
         never use it with a zero budget)."""
-        if self.prearmer is not None:
-            # Queue a background re-arm for this shape (cheap no-op when
-            # the shape was never registered) BEFORE the repair gates:
-            # pre-arming covers exactly the writes repair can't absorb.
-            self.prearmer.note_invalidate(index, fname)
         if self._repair_rows_max <= 0:
             return
         if not self._serve_states and not self._matrix_cache:
@@ -1506,8 +1455,6 @@ class Executor:
             self._lane_epoch += 1
         with self._dirty_mu:
             self._dirty_rows.pop((index, frame), None)
-        if self.prearmer is not None:
-            self.prearmer.forget(index, frame)
         if self.qcache is not None:
             # A recreated namesake frame gets fresh generations (the
             # counter never repeats), so validity already prevents stale
@@ -1527,8 +1474,6 @@ class Executor:
         with self._dirty_mu:
             for k in [k for k in self._dirty_rows if k[0] == index]:
                 del self._dirty_rows[k]
-        if self.prearmer is not None:
-            self.prearmer.forget_index(index)
         if self.qcache is not None:
             self.qcache.purge_index(index)
 
@@ -1632,7 +1577,7 @@ class Executor:
 
     def _fused_local_counts_arrays(
         self, index: str, frame_names, op_ids, frame_ids, r1, r2, slices,
-        plan=None, _prearm=False, span=None,
+        span=None,
     ) -> list[int]:
         """Vectorized local evaluator for the compiled-query lane: group by
         (frame, op) with numpy masks, map row ids to matrix positions via
@@ -1641,20 +1586,10 @@ class Executor:
         whole batch collapses further into ONE native call
         (pn_gram_counts: binary-search position mapping + count
         identities in C++), the steady-state serving loop.
-
-        ``plan`` is the front door's planner decision (ExecOptions.plan):
-        a forced lane overrides the static rm_pool ladder below (the
-        eligibility gates still apply), lane None changes nothing, and
-        either way each chunk's observed cost folds back through
-        Planner.record under the lane that actually ran.  ``_prearm``
-        marks the PreArmer's background replay so it doesn't re-register
-        itself as a hot shape.
         """
         from pilosa_tpu import native
         from pilosa_tpu.native import PQL_PAIR_OPS
 
-        forced = plan.get("lane") if plan is not None else None
-        rec = self.planner is not None and plan is not None and not _prearm
         out = np.zeros(len(op_ids), dtype=np.int64)
         for f_id in np.unique(frame_ids):
             fmask0 = frame_ids == f_id
@@ -1676,46 +1611,15 @@ class Executor:
                     )
                 ]
             for qpart in qparts:
-                t0 = time.perf_counter() if rec else 0.0
                 fmask = np.zeros(len(op_ids), dtype=bool)
                 fmask[qpart] = True
                 fr1, fr2 = r1[fmask], r2[fmask]
                 rows = np.unique(np.concatenate([fr1, fr2]))
-                # Tall working sets relative to this chunk's batch hit the
-                # gather kernels — page them through the ROW-MAJOR pool
-                # lane (one contiguous DMA descriptor per operand row;
-                # same choice as the AST fused path), UNLESS the Gram
-                # could serve this working set (warm Gram lookups beat
-                # any kernel; _gram_could_serve mirrors its gates).  In
-                # the paging regime (multiple qparts) the Gram can never
-                # WARM — each part switch remaps pool slots and kills the
-                # cache box — so only a single-part working set may veto
-                # the row-major lane.  Effective rows mirror the
-                # slice-major pool's cap (dispatch sees the full matrix).
-                # A planner-forced lane replaces this ladder (pin/ledger
-                # decisions); the eligibility gates below still apply.
-                if forced == "gram":
-                    rm_pool = False  # slice-major family: always feasible
-                elif forced == "rmgather":
-                    rm_pool = getattr(
-                        self.engine, "supports_row_major_gather", False
-                    )
-                else:
-                    rm_pool = (
-                        getattr(self.engine, "supports_row_major_gather", False)
-                        and (
-                            len(qparts) > 1
-                            or not self._gram_could_serve(len(rows), len(slices))
-                        )
-                        and self.engine.prefer_rowmajor(
-                            max(len(rows), pool.cap), len(slices), _WORDS,
-                            int(fmask.sum()), 2,
-                        )
-                    )
-                if rm_pool and len(rows) > self._peek_pool_cap(
-                    index, fname, VIEW_STANDARD, slices, lane="rmgather"
-                ):
-                    rm_pool = False  # diverged lane caps: stay chunkable
+                rm_pool = self._resident_row_major(
+                    index, fname, VIEW_STANDARD, slices, n_rows=len(rows),
+                    n_parts=len(qparts), n_pairs=len(qpart), max_k=2,
+                    has_tree=False, pool_cap=pool.cap,
+                )
                 id_pos, matrix, box = self._frame_matrix(
                     index, fname, slices, set(rows.tolist()),
                     lane="rmgather" if rm_pool else "", span=span,
@@ -1751,11 +1655,6 @@ class Executor:
                             and (st is None or st["glut_id"] is not glut)
                         ):
                             self._capture_serve_state(index, fname, slices, glut, box)
-                        if rec:
-                            self.planner.record(
-                                index=index, fp=plan["fp"], lane="gram",
-                                ms=(time.perf_counter() - t0) * 1e3, plan=plan,
-                            )
                         continue
                 lut = np.fromiter(
                     (id_pos[int(rv)] for rv in rows), dtype=np.int32, count=len(rows)
@@ -1780,26 +1679,6 @@ class Executor:
                         counts = self.engine.gather_count(op, matrix, pairs)
                     fout[om] = counts
                 out[fmask] = fout
-                if rec:
-                    # Fold the chunk's cost back under the lane that
-                    # ACTUALLY ran (an eligibility veto self-corrects).
-                    self.planner.record(
-                        index=index, fp=plan["fp"],
-                        lane="rmgather" if rm_pool else "gram",
-                        ms=(time.perf_counter() - t0) * 1e3, plan=plan,
-                    )
-        if self.prearmer is not None and not _prearm:
-            # Register/refresh this batch as the (index, frame) replay
-            # thunk: re-running it through the ordinary path re-arms
-            # matrix, Gram, and serve state after an invalidating write.
-            thunk = partial(
-                self._fused_local_counts_arrays,
-                index, frame_names, np.array(op_ids), np.array(frame_ids),
-                np.array(r1), np.array(r2), list(slices), _prearm=True,
-            )
-            for f_id in np.unique(frame_ids):
-                fname = frame_names[f_id] if f_id >= 0 else DEFAULT_FRAME
-                self.prearmer.note_shape(index, str(fname), thunk)
         return out.tolist()
 
     def _tree_build(self, index: str, c: pql.Call, fv_box: dict):
@@ -1966,7 +1845,7 @@ class Executor:
             index, idxs, slices, opt,
             lambda: pql.Query(calls=[calls[i] for i in idxs]),
             lambda node_slices: self._fused_local_counts(
-                index, matched, idxs, node_slices, plan=opt.plan, span=opt.span
+                index, matched, idxs, node_slices, span=opt.span
             ),
         )
         return dict(zip(idxs, totals))
@@ -2276,8 +2155,7 @@ class Executor:
         )
 
     def _fused_local_counts(
-        self, index: str, matched: dict, idxs: list[int], slices, plan=None,
-        span=None,
+        self, index: str, matched: dict, idxs: list[int], slices, span=None,
     ) -> list[int]:
         """Fused counts for the given slice batch, aligned with idxs.
 
@@ -2287,15 +2165,7 @@ class Executor:
         and/or, the second for andnot) so jitted shapes stay stable.
         Batches whose unique row set exceeds the pool capacity are chunked
         (rows page through HBM per chunk) instead of falling back to host.
-
-        ``plan`` (ExecOptions.plan, see _fused_local_counts_arrays): a
-        forced lane overrides the resident-regime rm_pool ladder, and
-        each resident part's cost folds back through Planner.record.
-        The streaming regime has no lane choice to plan, so it neither
-        applies nor records plans.
         """
-        forced = plan.get("lane") if plan is not None else None
-        rec = self.planner is not None and plan is not None
         slices = list(slices or [])
         out: dict[int, int] = {}
         if not slices:
@@ -2342,54 +2212,15 @@ class Executor:
                     # full density — those shapes stream the slice axis
                     # below, which chunks to the safe bound and sums in
                     # int64 host-side.)
-                    # Tall working sets relative to the request batch hit
-                    # the GATHER kernels, which on v5e are DMA-descriptor
-                    # -bound: those parts page through a ROW-MAJOR pool
-                    # lane (one contiguous descriptor per operand row)
-                    # instead.  The Gram never engages at these row
-                    # counts (its all-pairs work would dwarf the batch).
-                    n_pairs = sum(
-                        len(v) for (_o, kb), v in groups.items() if kb == 2
+                    rm_pool = self._resident_row_major(
+                        index, frame, view, slices, n_rows=len(want),
+                        n_parts=len(parts),
+                        n_pairs=sum(
+                            len(v) for (_o, kb), v in groups.items() if kb == 2
+                        ),
+                        max_k=max(kb for _, kb in groups),
+                        has_tree=has_tree, pool_cap=pool.cap,
                     )
-                    # Effective row count mirrors what dispatch will see:
-                    # the slice-major pool dispatches over its FULL cap
-                    # (not just this part's rows), so a grown pool forces
-                    # the gather kernels even for small wants.  Never
-                    # displace a Gram-eligible working set — warm Gram
-                    # serving (host lookups) beats any per-query kernel —
-                    # but only a SINGLE-part working set may veto: in the
-                    # paging regime each part switch remaps pool slots
-                    # and kills the cache box, so the Gram never warms.
-                    # A planner-forced lane replaces this ladder; tree
-                    # groups (no row-major kernel) and engine support
-                    # still gate it.
-                    t0 = time.perf_counter() if rec else 0.0
-                    if forced == "gram":
-                        rm_pool = False  # slice-major: always feasible
-                    elif forced == "rmgather":
-                        rm_pool = not has_tree and getattr(
-                            self.engine, "supports_row_major_gather", False
-                        )
-                    else:
-                        rm_pool = (
-                            not has_tree
-                            and getattr(self.engine, "supports_row_major_gather", False)
-                            and (
-                                len(parts) > 1
-                                or not self._gram_could_serve(len(want), len(slices))
-                            )
-                            and self.engine.prefer_rowmajor(
-                                max(len(want), pool.cap), len(slices), _WORDS,
-                                n_pairs, max(kb for _, kb in groups),
-                            )
-                        )
-                    if rm_pool and len(want) > self._peek_pool_cap(
-                        index, frame, view, slices, lane="rmgather"
-                    ):
-                        # Lane caps can diverge when one is overridden;
-                        # never let the lane switch turn a chunkable part
-                        # into an over-capacity error.
-                        rm_pool = False
                     id_pos, matrix, box = self._frame_matrix(
                         index, frame, slices, set(want), view,
                         lane="rmgather" if rm_pool else "", span=span,
@@ -2411,13 +2242,6 @@ class Executor:
                         )
                         for k2, i in enumerate(op_idxs):
                             out[i] = int(counts[k2])
-                    if rec:
-                        # Lane that ACTUALLY ran (a veto self-corrects).
-                        self.planner.record(
-                            index=index, fp=plan["fp"],
-                            lane="rmgather" if rm_pool else "gram",
-                            ms=(time.perf_counter() - t0) * 1e3, plan=plan,
-                        )
                 else:
                     # Streaming regime (SURVEY §7 hard part (d) at scale):
                     # the working set exceeds the HBM pool budget, so the
@@ -2699,6 +2523,41 @@ class Executor:
             return gram
         finally:
             mu.release()
+
+    def _resident_row_major(
+        self, index: str, frame: str, view: str, slices, *, n_rows: int,
+        n_parts: int, n_pairs: int, max_k: int, has_tree: bool, pool_cap: int,
+    ) -> bool:
+        """Whether one part of a RESIDENT working set (``n_rows`` unique
+        rows, the part's pair count and widest operand group) pages
+        through the ROW-MAJOR pool lane instead of the slice-major one.
+
+        Tall working sets relative to the part's batch hit the GATHER
+        kernels, which on v5e are DMA-descriptor-bound: a row-major pool
+        gives one contiguous descriptor per operand row.  The effective
+        row count mirrors what dispatch will see — the slice-major pool
+        dispatches over its FULL cap (``pool_cap``), not just this
+        part's rows, so a grown pool forces the gather kernels even for
+        small wants.  Never displace a Gram-eligible working set (warm
+        Gram serving is host lookups, faster than any per-query kernel;
+        _gram_could_serve mirrors its gates) — but only a SINGLE-part
+        working set may veto: in the paging regime each part switch
+        remaps pool slots and kills the cache box, so the Gram never
+        warms.  Tree groups have no row-major kernel.  Lane caps can
+        diverge when one is overridden; the lane switch must never turn
+        a chunkable part into an over-capacity error, hence the last
+        gate.
+        """
+        return bool(
+            not has_tree
+            and getattr(self.engine, "supports_row_major_gather", False)
+            and (n_parts > 1 or not self._gram_could_serve(n_rows, len(slices)))
+            and self.engine.prefer_rowmajor(
+                max(n_rows, pool_cap), len(slices), _WORDS, n_pairs, max_k
+            )
+            and n_rows
+            <= self._peek_pool_cap(index, frame, view, slices, lane="rmgather")
+        )
 
     def _peek_pool_cap(
         self, index: str, frame: str, view: str, slices, lane: str = ""

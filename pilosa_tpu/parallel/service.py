@@ -88,7 +88,7 @@ from urllib.parse import parse_qs, urlparse
 _now = time.perf_counter
 
 from pilosa_tpu.engine import MeshEngine
-from pilosa_tpu.executor import ExecOptions, Executor
+from pilosa_tpu.executor import Executor
 from pilosa_tpu.pilosa import ErrFrameNotFound, ErrIndexNotFound, PilosaError
 from pilosa_tpu.qos import DeadlineExceeded, ShedError, deadline_from_headers
 from pilosa_tpu.server.handler import result_to_json
@@ -257,25 +257,6 @@ class LockstepService:
             holder, engine=self.engine, qcache=qc,
             stats=self.stats if self.costs is not None else None,
         )
-        # Cost-based planner, RANK 0 ONLY: plans are computed once at
-        # ship time and ride the batch wire entry exactly like the
-        # expiry and trace flags, so every rank applies rank 0's lane
-        # and no rank ever consults rank-local state.  Workers carry
-        # planner=None (they read plans off the wire); the EXECUTOR
-        # planner is also rank-0-only so the ledger fold-back (wall
-        # timestamps, win/loss tallies) stays telemetry, never control
-        # flow on a worker.  PILOSA_TPU_PLANNER=0 disables.
-        self.planner = None
-        if (
-            self.rank == 0
-            and self.costs is not None
-            and os.environ.get("PILOSA_TPU_PLANNER", "").lower()  # analysis-ok: env-knob-outside-config: rank-process fallback; ctor args win, ranks inherit the launcher's env
-            not in ("0", "false", "no")
-        ):
-            from pilosa_tpu import planner as planner_mod
-
-            self.planner = planner_mod.Planner(self.costs, stats=self.stats)
-            self.executor.planner = self.planner
         self.control_addr = control_addr
         self.http_addr = http_addr
         self._workers: list[socket.socket] = []
@@ -373,7 +354,7 @@ class LockstepService:
         # Per-tenant request accounting off the wire entries: the tenant
         # is resolved ONCE on rank 0 at ship time (header > [tenancy]
         # map > index name > "default" — the tenancy.resolve seam) and
-        # rides the batch entry like the expired/trace/plan flags, so
+        # rides the batch entry like the expired/trace flags, so
         # every rank tallies identical per-tenant counts from the flag
         # alone.  tenant -> {"requests": n, "expired": m}.
         from pilosa_tpu import tenancy as tenancy_mod
@@ -516,10 +497,8 @@ class LockstepService:
                     if shipped is not None:
                         self._q_cv.release()
                         try:
-                            self._run_batch(
-                                shipped[0], batch, shipped[1], shipped[2],
-                                shipped[3], shipped[4],
-                            )
+                            seq, expired, traces, tenants = shipped
+                            self._run_batch(seq, batch, expired, traces, tenants)
                         finally:
                             self._q_cv.acquire()
                     self._inflight -= 1
@@ -660,7 +639,7 @@ class LockstepService:
             ingress.complete_bulk(fr, self.bulk_materialize_budget_ms)
         return True
 
-    def _ship_batch(self, items) -> tuple[int, list[bool], list, list, list]:
+    def _ship_batch(self, items) -> tuple[int, list[bool], list, list]:
         """Assign the batch's slot in the total order and replicate it:
         one control-plane send per worker plus one ack round for the
         WHOLE batch (the per-request fixed cost this coalescing
@@ -700,7 +679,6 @@ class LockstepService:
         reqs = []
         expired: list[bool] = []
         traces: list = []
-        plans: list = []
         tenants: list = []
         t_ship = _now()
         for index, query, d, tforce, thdr, t_enq in items:
@@ -719,17 +697,6 @@ class LockstepService:
                      "trace": traced, "tenant": tenant}
             if d is not None:
                 entry["deadline_ms"] = max(0, int(d.remaining_ms()))
-            # Planner decision, made ONCE here on rank 0 and shipped on
-            # the wire like the expiry/trace flags: every rank applies
-            # the same lane, no rank consults rank-local ledger state.
-            plan = (
-                self.planner.plan_for(index, query.encode())
-                if self.planner is not None and not exp
-                else None
-            )
-            plans.append(plan)
-            if plan is not None:
-                entry["plan"] = plan
             reqs.append(entry)
             tr = None
             if traced:
@@ -776,7 +743,7 @@ class LockstepService:
                 # Covers the worker fan-out sends plus the receipt-ack
                 # barrier — the control-plane cost the batch amortizes.
                 sp.finish().annotate(ranks=self.n_ranks, batch=len(items))
-        return seq, expired, traces, plans, tenants
+        return seq, expired, traces, tenants
 
     def _exec_batch_entries(self, entries, deliver) -> None:
         """Drop expired entries (the flag decided at ship time — every
@@ -786,7 +753,7 @@ class LockstepService:
         DeadlineExceeded — deterministic, so it is safe as a
         per-request result on every rank (batch siblings unaffected).
         """
-        live: list = []  # (original position, (index, query), plan)
+        live: list = []  # (original position, (index, query))
         for pos, e in enumerate(entries):
             if e.get("trace"):
                 # Ship-time sampling flag off the wire: every rank sees
@@ -809,14 +776,11 @@ class LockstepService:
                 self.stat_expired += 1
                 deliver(pos, DeadlineExceeded("dropped at lockstep replay"))
             else:
-                # Planner plan off the wire (rank 0's ship-time decision;
-                # absent = static ladder) — applied, never re-derived.
-                live.append((pos, (e["index"], e["query"]), e.get("plan")))
+                live.append((pos, (e["index"], e["query"])))
         if live:
             self._exec_batch_units(
-                [it for _, it, _ in live],
+                [it for _, it in live],
                 lambda i, result: deliver(live[i][0], result),
-                plans=[p for _, _, p in live],
             )
 
     def _batch_units(self, items):
@@ -864,7 +828,7 @@ class LockstepService:
         flush()
         return units
 
-    def _exec_batch_units(self, items, deliver, plans=None) -> None:
+    def _exec_batch_units(self, items, deliver) -> None:
         """Execute one batch's units in order, reporting each request's
         result (or isolated PilosaError) through ``deliver(pos, r)``.
 
@@ -875,19 +839,7 @@ class LockstepService:
         are side-effect-free, so the partial re-execution is safe and
         every rank repeats the same fallback.  Any OTHER exception
         propagates to the caller (rank-local failure — fail-stop).
-
-        ``plans`` (aligned with items) carries rank 0's ship-time
-        planner decisions: solo and single-read units apply theirs via
-        ExecOptions.plan; MULTI-REQUEST fused runs execute without one
-        (the join is its own shape — no per-request fingerprint fits),
-        which is replicated because _batch_units is a pure function of
-        the request strings and the plans came off the wire.
         """
-
-        def _opt(pos):
-            p = plans[pos] if plans is not None else None
-            return ExecOptions(plan=p) if p is not None else None
-
         for unit in self._batch_units(items):
             if unit[0] == "solo":
                 _, pos, index, query = unit
@@ -914,7 +866,7 @@ class LockstepService:
                     ))
                     continue
                 try:
-                    deliver(pos, self.executor.execute(index, query, opt=_opt(pos)))
+                    deliver(pos, self.executor.execute(index, query))
                 except PilosaError as e:
                     deliver(pos, e)  # isolated: every rank resolved it too
                 continue
@@ -933,12 +885,12 @@ class LockstepService:
                     continue
             for pos, query, _n in run:
                 try:
-                    deliver(pos, self.executor.execute(index, query, opt=_opt(pos)))
+                    deliver(pos, self.executor.execute(index, query))
                 except PilosaError as e:
                     deliver(pos, e)
 
     def _run_batch(self, seq: int, batch, expired=None, traces=None,
-                   plans=None, tenants=None) -> None:
+                   tenants=None) -> None:
         """Execute one shipped batch in its slot of the total order and
         fill every submitter's result slot; never raises (siblings would
         hang on an unfilled slot otherwise).  ``expired`` carries the
@@ -982,12 +934,10 @@ class LockstepService:
 
                 flags = expired or [False] * len(batch)
                 trs = traces or [None] * len(batch)
-                pls = plans or [None] * len(batch)
                 tens = tenants or [None] * len(batch)
                 entries = [
                     {"index": it[0], "query": it[1], "expired": flags[i],
-                     "trace": trs[i] is not None, "plan": pls[i],
-                     "tenant": tens[i]}
+                     "trace": trs[i] is not None, "tenant": tens[i]}
                     for i, (it, _) in enumerate(batch)
                 ]
                 exec_spans = [

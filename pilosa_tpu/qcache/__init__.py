@@ -232,13 +232,6 @@ class QueryCache:
 
         self.max_bytes = int(max_bytes)
         self.min_cost_ms = float(min_cost_ms)
-        # Adaptive admission floor (planner.AdaptiveBudgets): when the
-        # server wires one, commit() derives the floor from the measured
-        # cost distribution instead of the static min_cost_ms (which
-        # stays the anchor the adaptive value is clamped around).  The
-        # lockstep service NEVER sets this — its floor is forced to 0
-        # for determinism and must not regrow from rank-local wall time.
-        self.budgets = None
         self.stats = stats if stats is not None else NOP_STATS
         # TenancyState: per-tenant byte quotas ([tenancy] qcache-share).
         # Entries bill to the index's tenant; over-quota tenants reclaim
@@ -378,12 +371,7 @@ class QueryCache:
         pre-write results with post-write tokens).  Returns True when
         the entry was stored."""
         cost_ms = (self._clock() - pending.t0) * 1e3
-        floor = (
-            self.budgets.qcache_min_cost_ms()
-            if self.budgets is not None
-            else self.min_cost_ms
-        )
-        if cost_ms < floor:
+        if cost_ms < self.min_cost_ms:
             return False
         vec1 = generation_vector(holder, pending.index, pending.frames)
         if vec1 is None or vec1 != pending.vec0:

@@ -124,16 +124,10 @@ class CatchupManager:
     """Streams the missed WAL suffix to recovering groups (router side)."""
 
     def __init__(self, router, wal, stats=None, drain_batch: int = 64,
-                 locked_drain_s: float = 5.0, budgets=None):
+                 locked_drain_s: float = 5.0):
         self.router = router
         self.wal = wal
         self.stats = stats if stats is not None else NOP_STATS
-        # Adaptive drain budget (planner.AdaptiveBudgets): when the
-        # router wires one, each round sizes the locked phase from the
-        # MEASURED per-record replay cost (observed below) instead of
-        # the static drain_batch — fast links drain more under the lock,
-        # slow ones less, both inside locked_drain_s.
-        self.budgets = budgets
         # Records replayed per loop iteration OUTSIDE the sequencer
         # lock; the final <= drain_batch records replay under it so the
         # rejoin flip races no concurrent write.  That locked phase is
@@ -166,7 +160,6 @@ class CatchupManager:
         headers = {WRITE_SEQ_HEADER: str(rec.seq), REPLAY_HEADER: "1"}
         if rec.ctype:
             headers["content-type"] = rec.ctype
-        t_fwd = time.perf_counter()
         try:
             status, _ctype, _payload, rheaders = self.router._forward(
                 g, rec.method, rec.path, rec.body, headers,
@@ -174,14 +167,6 @@ class CatchupManager:
             )
         except OSError:
             return False
-        finally:
-            if self.budgets is not None:
-                # Feed the measured replay cost back under the "catchup"
-                # budget lane — the next round's drain batch reads it.
-                self.budgets.observe_transfer(
-                    "catchup", (time.perf_counter() - t_fwd) * 1e3,
-                    len(rec.body or b""),
-                )
         hdr_epoch = rheaders.get(GROUP_HEADER)
         if (start_epoch is not None and hdr_epoch is not None
                 and hdr_epoch != start_epoch):
@@ -216,19 +201,12 @@ class CatchupManager:
         start_epoch = g.epoch
         self.stats.count("replica.catchup_rounds")
         t0 = time.perf_counter()
-        # Effective locked-phase record budget: measured (clamped) when
-        # the adaptive budgets have replay samples, static otherwise.
-        batch = (
-            self.budgets.catchup_drain_batch()
-            if self.budgets is not None
-            else self.drain_batch
-        )
         # Phase 1: drain the bulk of the suffix without blocking writes.
         while True:
             recs = self.wal.records(g.applied_seq + 1)
-            if len(recs) <= batch:
+            if len(recs) <= self.drain_batch:
                 break
-            for rec in recs[: -batch]:
+            for rec in recs[: -self.drain_batch]:
                 if not self._replay_one(g, rec, start_epoch):
                     return False
         # Phase 2: the short remainder under the sequencer lock — no new

@@ -83,8 +83,7 @@ class ResyncManager:
     """Drives fragment-level resync rounds for the router (probe thread)."""
 
     def __init__(self, router, wal, stats=None, chunk_bytes: int = 256 << 10,
-                 locked_seed_s: float = 5.0, columnar: bool = False,
-                 budgets=None):
+                 locked_seed_s: float = 5.0, columnar: bool = False):
         self.router = router
         self.wal = wal
         self.stats = stats if stats is not None else NOP_STATS
@@ -92,12 +91,6 @@ class ResyncManager:
         # transfer loses little, large enough that the per-chunk HTTP
         # round trip amortizes.
         self.chunk_bytes = max(1, chunk_bytes)
-        # Adaptive chunk sizing (planner.AdaptiveBudgets): when the
-        # router wires one, each push reads the chunk size from the
-        # MEASURED stream bandwidth (fed back below) — fast links get
-        # larger chunks, slow links keep resume granularity fine.  The
-        # configured chunk_bytes stays the static fallback and anchor.
-        self.budgets = budgets
         # Bound on the seed-seq exchange under the sequencer lock —
         # same rationale as CatchupManager.locked_drain_s: a laggard
         # that hangs mid-handoff must not stall every write.
@@ -309,24 +302,12 @@ class ResyncManager:
             off = 0
         sent = 0
         while True:
-            step = (
-                self.budgets.resync_chunk_bytes()
-                if self.budgets is not None
-                else self.chunk_bytes
-            )
-            chunk = bytes(data[off : off + step])
+            chunk = bytes(data[off : off + self.chunk_bytes])
             self.router.faults.hit("resync.chunk", key=g.name)
-            t_push = time.perf_counter()
             status, payload = self._push(
                 g, "POST", f"{base}&off={off}", chunk, start_epoch,
                 ctype="application/octet-stream",
             )
-            if self.budgets is not None and chunk:
-                # Measured push bandwidth feeds the next chunk's sizing
-                # (the "resync" budget lane).
-                self.budgets.observe_transfer(
-                    "resync", (time.perf_counter() - t_push) * 1e3, len(chunk)
-                )
             if status == 409:
                 # Offset disagreement: adopt the group's staged size
                 # and resume (covers an idempotent re-send after a lost

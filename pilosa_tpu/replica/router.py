@@ -140,7 +140,6 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 from urllib.parse import parse_qs, urlencode, urlparse
 
-from pilosa_tpu import costs as costs_mod
 from pilosa_tpu import metrics as metrics_mod
 from pilosa_tpu import pql
 from pilosa_tpu import qos
@@ -387,13 +386,11 @@ class ShardRuntime:
         # Per-group compaction floors for in-flight resync rounds on
         # THIS shard (guarded by the shared table lock).
         self._resync_floor: dict[str, int] = {}
-        self.catchup = CatchupManager(self, wal, stats=router.stats,
-                                      budgets=router.budgets)
+        self.catchup = CatchupManager(self, wal, stats=router.stats)
         self.resync = ResyncManager(
             self, wal, stats=router.stats,
             chunk_bytes=router.resync_chunk_bytes,
             columnar=router.resync_columnar,
-            budgets=router.budgets,
         )
         # A (re)start over a non-empty log: no group may be assumed
         # current (see ReplicaRouter.__init__).
@@ -801,22 +798,6 @@ class ReplicaRouter:
             FaultInjector.from_env() or NOP_FAULTS
         )
         self.resync_chunk_bytes = resync_chunk_bytes
-        # Router-local adaptive-budget loop (planner.AdaptiveBudgets over
-        # a router-local CostLedger): catch-up replay and resync push
-        # costs observed by the managers feed back into the drain-batch
-        # and chunk sizes they use next round.  Same gate as serve-side
-        # cost accounting (PILOSA_TPU_COSTS) so a cost-free deploy stays
-        # cost-free here too; the static knobs above remain the floor
-        # and the fallback.
-        self.budgets = None
-        if costs_mod.enabled_from_env():
-            from pilosa_tpu import planner as planner_mod
-
-            self.budgets = planner_mod.AdaptiveBudgets(
-                costs_mod.CostLedger(stats=self.stats),
-                resync_chunk_bytes=resync_chunk_bytes,
-                stats=self.stats,
-            )
         # Columnar resync negotiation: movers may fetch a fragment the
         # laggard lacks ENTIRELY as Arrow record batches and push it
         # through the laggard's device-build /bulk door (the bulk OR

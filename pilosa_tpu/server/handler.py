@@ -118,7 +118,6 @@ class Handler:
                  admission=None, default_deadline_ms: float = 0.0, tracer=None,
                  group: str = "", applied_seq=None,
                  ingest_chunk_bytes: int = 4 << 20, costs=None,
-                 planner=None,
                  bulk_batch_slices: int = 8,
                  bulk_materialize_budget_ms: float = 0.0,
                  tenancy=None):
@@ -142,11 +141,6 @@ class Handler:
         # Per-fingerprint cost ledger (costs.CostLedger), served at
         # /debug/costs; None = ledger disabled (endpoint answers empty).
         self.costs = costs
-        # Cost-based planner (planner.Planner): this handler is the
-        # CONSULTATION point — post_query fingerprints the body and
-        # attaches the plan to ExecOptions; the executor only applies.
-        # None = static strategy ladder everywhere (the default).
-        self.planner = planner
         # Multi-tenant isolation (tenancy.TenancyState): the resolution
         # seam + fair-share/quota/pacer state.  None = isolation off —
         # attribution falls back to the index name and no door enforces.
@@ -229,7 +223,6 @@ class Handler:
             ("GET", re.compile(r"^/debug/vars$"), self.get_expvar),
             ("GET", re.compile(r"^/debug/traces$"), self.get_debug_traces),
             ("GET", re.compile(r"^/debug/costs$"), self.get_debug_costs),
-            ("GET", re.compile(r"^/debug/planner$"), self.get_debug_planner),
             ("GET", re.compile(r"^/debug/tenants$"), self.get_debug_tenants),
             ("GET", re.compile(r"^/metrics$"), self.get_metrics),
             ("GET", re.compile(r"^/debug/pprof(?:/(?P<path>.*))?$"), self.get_pprof),
@@ -717,20 +710,6 @@ class Handler:
             return self._json({"cap": 0, "alpha": 0.0, "entries": []})
         return self._json(self.costs.snapshot(limit=limit))
 
-    def get_debug_planner(self, params=None, **kw):
-        """The planner's decision state (planner.Planner snapshot):
-        per-(index, fingerprint) chosen lane, confidence, consult/decided
-        counts, and win/loss tallies joined with the per-lane ledger
-        estimates, most-consulted first.  ``?limit=`` caps the page."""
-        from pilosa_tpu import metrics as metrics_mod
-
-        limit = metrics_mod.clamp_int(
-            self._param(params or {}, "limit"), 0, lo=0
-        )
-        if self.planner is None:
-            return self._json({"lanes": [], "keys": []})
-        return self._json(self.planner.snapshot(limit=limit))
-
     def get_debug_tenants(self, **kw):
         """Per-tenant isolation state: fair-share door accounting
         (inflight / share / debt / admitted / shed per QoS class),
@@ -965,12 +944,6 @@ class Handler:
         )
         opt = ExecOptions(remote=remote, deadline=deadline, no_cache=no_cache,
                           span=span)
-        if self.planner is not None and not remote:
-            # Front-door planner consultation (remote hops carry no plan:
-            # the originating door already decided for the whole query).
-            # Keyed on the decoded query text so protobuf and JSON
-            # transports share one fingerprint.
-            opt.plan = self.planner.plan_for(index, query_str.encode())
         try:
             results = self.executor.execute(index, query_str, slices=slices, opt=opt)
         except qos.DeadlineExceeded:

@@ -99,40 +99,6 @@ class Server:
             if costs_mod.enabled_from_env()
             else None
         )
-        # Cost-based adaptive planner ([planner]): turns the ledger from
-        # telemetry into control flow — per-fingerprint lane selection
-        # (consulted by the handler front door, applied by the executor),
-        # ledger-derived budgets, and optional background pre-arming.
-        # All three require the ledger; PILOSA_TPU_COSTS=0 or [planner]
-        # enabled=false keeps the pre-planner static behavior exactly.
-        self.planner = None
-        self.budgets = None
-        self.prearmer = None
-        if self.costs is not None and self.config.planner_enabled:
-            from pilosa_tpu import planner as planner_mod
-
-            self.planner = planner_mod.Planner(
-                self.costs,
-                min_samples=self.config.planner_min_samples,
-                hysteresis=self.config.planner_hysteresis,
-                explore_every=self.config.planner_explore_every,
-                pin=self.config.planner_pin_lane,
-                stats=stats,
-            )
-            if self.config.planner_adaptive_budgets:
-                self.budgets = planner_mod.AdaptiveBudgets(
-                    self.costs,
-                    qcache_min_cost_ms=self.config.qcache_min_cost_ms,
-                    resync_chunk_bytes=self.config.replica_resync_chunk_bytes,
-                    stats=stats,
-                )
-                if self.qcache is not None:
-                    self.qcache.budgets = self.budgets
-            if self.config.planner_prearm_budget_ms > 0:
-                self.prearmer = planner_mod.PreArmer(
-                    budget_ms=self.config.planner_prearm_budget_ms,
-                    stats=stats,
-                )
         self.executor = Executor(
             self.holder,
             engine=self.config.engine,
@@ -156,10 +122,6 @@ class Server:
             not in ("0", "false", "no"),
             stats=stats if self.costs is not None else None,
         )
-        # The executor APPLIES plans (ExecOptions.plan) and folds
-        # outcomes back; it never consults — see executor.__init__.
-        self.executor.planner = self.planner
-        self.executor.prearmer = self.prearmer
         self.broadcaster, self.receiver = self._build_broadcast()
         # Request-scoped span tracer ([trace] sample-rate / slow-ms /
         # ring).  Always constructed: the zero-rate default costs one
@@ -212,9 +174,6 @@ class Server:
             # per-chunk ceiling.
             ingest_chunk_bytes=self.config.ingest_chunk_bytes,
             costs=self.costs,
-            # [planner]: the front-door consultation point (plan_for per
-            # query request) and the /debug/planner payload.
-            planner=self.planner,
             # [bulk]: device bulk build door (POST .../bulk) commit
             # batching + lazy-materialization drain budget.
             bulk_batch_slices=self.config.bulk_batch_slices,
@@ -314,8 +273,6 @@ class Server:
         self._start_loop(self._monitor_anti_entropy, self.config.anti_entropy_interval)
         self._start_loop(self._monitor_max_slices, self.config.cluster.polling_interval)
         self._start_loop(self._flush_caches, CACHE_FLUSH_INTERVAL)
-        if self.prearmer is not None:
-            self.prearmer.start()
 
     def close(self) -> None:
         self._closing.set()
@@ -327,8 +284,6 @@ class Server:
             self._httpd = None
         if self.receiver is not None:
             self.receiver.close()
-        if self.prearmer is not None:
-            self.prearmer.close()
         self.holder.close()
 
     @staticmethod

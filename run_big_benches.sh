@@ -240,11 +240,4 @@ run BENCH_CONFIG=shard BENCH_THREADS=24 BENCH_SHARD_SECS=10
 #    span so the per-slice commit and egress paths dominate the sort.
 run BENCH_CONFIG=bulk
 run BENCH_CONFIG=bulk BENCH_BULK_PAIRS=4000000 BENCH_BULK_SLICES=16 BENCH_BULK_ROWS=256
-# 16) Cost-based adaptive planner: three query shapes, ground-truth
-#    lanes from pinned runs, >= 90% of post-warmup dispatches on the
-#    empirically fastest lane asserted in-run; BENCH_STRICT=1 also
-#    asserts the mixed-schedule p50 within 5% of the best pinned
-#    static.  The second line runs the strict gate with longer phases.
-run BENCH_CONFIG=planner
-run BENCH_CONFIG=planner BENCH_STRICT=1 BENCH_ITERS=48 BENCH_QUERY_POOL=6
 echo "ALL DONE $(date +%H:%M:%S)" >> $OUT

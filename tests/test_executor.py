@@ -1234,6 +1234,131 @@ def test_rowmajor_pool_lane(tmp_path, monkeypatch):
     h.close()
 
 
+# The resident layout rule (Executor._resident_row_major): two slices of
+# 128 KiB planes, so the row-major kernels buffer a widest group of 16
+# (rowmajor_ok: 2 * k * slices * 128 KiB <= 8 MiB) and the resident
+# kernel takes a pair batch whose rows are fewer than twice its pairs.
+_TALL = dict(n_rows=160, n_parts=2, n_pairs=64, max_k=2, has_tree=False, pool_cap=160)
+_LADDER = {
+    # An engine without row-major support never picks it.
+    "numpy_engine_never": ("numpy", {}, None, _TALL, False),
+    "mesh_engine_never": ("mesh", {}, None, _TALL, False),
+    # Gram-eligible (bucket 256 <= 4096 rows) and unpaged: the Gram keeps it.
+    "gram_eligible_single_part": ("jax", {}, None, {**_TALL, "n_parts": 1}, False),
+    # The same working set paged: the Gram can never warm, so no veto.
+    "paging_regime_two_parts": ("jax", {}, None, _TALL, True),
+    "above_gram_rows_max": ("jax", {"gram_rows_max": 128}, None, {**_TALL, "n_parts": 1}, True),
+    "no_gram_set": ("jax", {"no_gram": True}, None, {**_TALL, "n_parts": 1}, True),
+    # 8 rows under 64 pairs: dispatch keeps the resident kernel.
+    "prefer_rowmajor_false": ("jax", {}, None, {**_TALL, "n_rows": 8, "pool_cap": 8}, False),
+    # ... unless the slice-major pool has grown: dispatch sees its full cap.
+    "grown_pool_forces_gather": ("jax", {}, None, {**_TALL, "n_rows": 8}, True),
+    "tree_group": ("jax", {}, None, {**_TALL, "has_tree": True}, False),
+    "rows_past_the_rowmajor_pools_cap": ("jax", {}, 64, _TALL, False),
+    "widest_group_fits_the_row_buffers": ("jax", {}, None, {**_TALL, "n_pairs": 0, "max_k": 16}, True),
+    "widest_group_past_rowmajor_ok": ("jax", {}, None, {**_TALL, "n_pairs": 0, "max_k": 32}, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LADDER))
+def test_resident_layout_rule(tmp_path, monkeypatch, case):
+    """One function picks the layout of a resident working set from what
+    the code can observe: the engine's support, the Gram's gates, the
+    part count, dispatch's own kernel predicate and the pools' caps."""
+    import jax
+
+    import pilosa_tpu.engine as engine_mod
+
+    kind, ctor, rm_cap, args, want = _LADDER[case]
+    monkeypatch.delenv("PILOSA_TPU_NO_GRAM", raising=False)
+    monkeypatch.delenv("PILOSA_TPU_GRAM_ROWS_MAX", raising=False)
+    monkeypatch.delenv("PILOSA_TPU_POOL_BYTES", raising=False)
+    monkeypatch.setattr(
+        engine_mod.JaxEngine, "supports_row_major_gather", property(lambda self: True)
+    )
+    engine = engine_mod.MeshEngine(devices=jax.devices()[:4]) if kind == "mesh" else kind
+    h = Holder(str(tmp_path / "data"))
+    h.open()
+    e = Executor(h, engine=engine, **ctor)
+    assert e.engine.name == kind
+    if rm_cap is not None:
+        e._pool_for("i", "f", "standard", [0, 1], lane="rmgather").cap_max = rm_cap
+    assert e._resident_row_major("i", "f", "standard", [0, 1], **args) is want
+    # The probe instantiates no pool for a lane it may not take.
+    assert len(e._matrix_cache) == (0 if rm_cap is None else 1)
+    h.close()
+
+
+@pytest.mark.parametrize(
+    "n_pairs,hot_rows,no_gram,cap_max,want_rm",
+    [
+        (64, None, True, None, True),    # tall, distinct operands, no Gram: row-major
+        (64, 8, True, None, False),      # 8 hot rows under 64 pairs: resident kernel
+        (64, None, False, None, False),  # Gram-eligible and unpaged: the Gram keeps it
+        (64, None, False, 64, True),     # the same, paged in two parts: no veto
+    ],
+    ids=["tall", "hot_rows", "gram_eligible", "gram_eligible_paged"],
+)
+def test_arrays_and_ast_paths_pick_one_layout(
+    tmp_path, monkeypatch, n_pairs, hot_rows, no_gram, cap_max, want_rm
+):
+    """The compiled-query (arrays) path and the AST path ask the same rule:
+    for one batch they page through the same pool layout and count what
+    the numpy engine counts (row-major support forced on, as in
+    test_rowmajor_pool_lane)."""
+    import pilosa_tpu.engine as engine_mod
+    from pilosa_tpu.native import PQL_PAIR_OPS
+
+    h = Holder(str(tmp_path / "data"))
+    h.open()
+    idx = h.create_index("i")
+    idx.create_frame("f", FrameOptions())
+    fr = idx.frame("f")
+    rng = np.random.default_rng(30)
+    n_rows, slices = 160, [0, 1]
+    rows = np.repeat(np.arange(n_rows, dtype=np.uint64), 12)
+    for s in slices:
+        cols = rng.integers(0, SLICE_WIDTH, size=len(rows)).astype(np.uint64)
+        fr.import_bits(rows, cols + np.uint64(s * SLICE_WIDTH))
+    monkeypatch.setattr(
+        engine_mod.JaxEngine, "supports_row_major_gather", property(lambda self: True)
+    )
+    monkeypatch.delenv("PILOSA_TPU_NO_GRAM", raising=False)
+
+    perm = rng.permutation(hot_rows or n_rows)
+    r1 = np.array([perm[(2 * i) % len(perm)] for i in range(n_pairs)], dtype=np.int64)
+    r2 = np.array([perm[(2 * i + 1) % len(perm)] for i in range(n_pairs)], dtype=np.int64)
+    op_ids = (np.arange(n_pairs) % len(PQL_PAIR_OPS)).astype(np.uint8)
+    names = {"and": "Intersect", "or": "Union", "xor": "Xor", "andnot": "Difference"}
+    want = Executor(h, engine="numpy").execute("i", " ".join(
+        f'Count({names[PQL_PAIR_OPS[o]]}(Bitmap(rowID={a}, frame="f"), Bitmap(rowID={b}, frame="f")))'
+        for o, a, b in zip(op_ids, r1, r2)
+    ))
+
+    def fresh():
+        e = Executor(h, engine="jax", no_gram=no_gram)
+        if cap_max is not None:
+            # Both lanes' pools share one budget formula in production.
+            for lane in ("", "rmgather"):
+                e._pool_for("i", "f", "standard", slices, lane=lane).cap_max = cap_max
+        return e
+
+    def layouts(e):
+        return {k[4] for k, pool in e._matrix_cache.items() if pool.matrix is not None}
+
+    arrays, ast = fresh(), fresh()
+    frame_ids = np.zeros(n_pairs, dtype=np.int64)
+    assert arrays._fused_local_counts_arrays(
+        "i", ["f"], op_ids, frame_ids, r1, r2, slices) == want
+    matched = {
+        i: ("f", "standard", PQL_PAIR_OPS[op_ids[i]], (int(r1[i]), int(r2[i])))
+        for i in range(n_pairs)
+    }
+    assert ast._fused_local_counts("i", matched, list(range(n_pairs)), slices) == want
+    assert layouts(arrays) == layouts(ast) == {"rmgather" if want_rm else ""}
+    h.close()
+
+
 def test_gram_eligibility_covers_tall_row_sets(env, monkeypatch):
     """The chunked Gram builder (bitwise.pair_gram word-axis subdivision)
     removed the per-slice unpack ceiling: eligibility is now a rows gate

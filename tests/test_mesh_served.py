@@ -43,11 +43,13 @@ def _frame_bits(seed=28):
     return ref
 
 
-def _post(host, body, trace=False):
+def _post(host, body, trace=False, no_cache=False):
     conn = http.client.HTTPConnection(host, timeout=120)
     try:
-        conn.request("POST", "/index/i/query", body.encode(),
-                     {"X-Pilosa-Trace": "1"} if trace else {})
+        headers = {"X-Pilosa-Trace": "1"} if trace else {}
+        if no_cache:
+            headers["X-Pilosa-No-Cache"] = "1"
+        conn.request("POST", "/index/i/query", body.encode(), headers)
         resp = conn.getresponse()
         payload = json.loads(resp.read())
         assert resp.status == 200, payload
@@ -284,6 +286,36 @@ def test_the_first_repair_after_paging_patches_a_copy(served, engine):
     tags = served[engine].first_repair["tags"]
     assert (tags["form"], tags["in_place"], tags["planes"]) == ("step", False, 1)
     assert tags["devices"] == (DEVICES if engine == "mesh" else 1)
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_repeated_body_stays_on_the_armed_lane(served, engine):
+    """A dashboard that polls: one body of pair counts sent 48 times past
+    the query cache, one of its rows written before every eighth send.
+    Every read is answered from the armed serve state, so no read takes
+    the pool's array and every repair updates it in place."""
+    s = served[engine]
+    rows = [5, 11, 17, 23, 29, 35]
+    calls = [(op, r, (r + 13) % N_ROWS) for r in rows for op in sorted(OPS)]
+    body = _pairs_body(calls)
+    before = _get(s.host, "/debug/vars")
+    writes = 0
+    for send in range(1, 49):
+        if send % 8 == 0:
+            s.set_bit(rows[writes % len(rows)], 1 * SLICE_WIDTH + 3000 + send)
+            writes += 1
+        results, (root,) = _post(s.host, body, trace=True, no_cache=True)
+        assert results == _want(s.ref, calls), send
+        assert root["tags"]["qcache"] == "bypass"
+        assert [d["tags"]["lane"] for d in _find(root, "device")] == ["native"], send
+        for repair in _find(root, "pool.repair"):
+            assert (repair["tags"]["form"], repair["tags"]["in_place"]) == ("step", True), send
+        assert not s.pool()._handed_out, send
+    after = _get(s.host, "/debug/vars")
+    assert writes == 6
+    assert after["rowpool.repairs_in_place"] == before.get("rowpool.repairs_in_place", 0) + writes
+    assert after["rowpool.repairs"] == before["rowpool.repairs"] + writes
+    assert after.get("rowpool.repairs_composed", 0) == before.get("rowpool.repairs_composed", 0)
 
 
 # -- (c): the pool's budget follows the devices that share the slice axis ----

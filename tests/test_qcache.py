@@ -456,6 +456,53 @@ def test_server_wiring_and_debug_vars(tmp_path):
     assert s2.qcache is None and s2.executor.qcache is None
 
 
+@pytest.mark.parametrize(
+    "min_cost_ms,ledger_ms,stored",
+    [(10.0, 1.0, False), (2.0, 15.0, True)],
+    ids=["cheaper_than_the_floor_is_declined", "dearer_than_the_floor_is_stored"],
+)
+def test_admission_floor_is_the_configured_one_with_a_full_ledger(
+    tmp_path, min_cost_ms, ledger_ms, stored
+):
+    """The server's query cache admits by [qcache] min-cost-ms, whatever
+    the cost ledger has seen: a miss costs 5 ms here (a stepped clock)
+    beside a full ledger of cheaper, or of dearer, entries."""
+    import itertools
+    import urllib.request
+
+    from pilosa_tpu.config import Config
+    from pilosa_tpu.server.server import Server
+
+    cfg = Config(
+        data_dir=str(tmp_path / "d"), host="127.0.0.1:0", engine="numpy",
+        qcache_min_cost_ms=min_cost_ms,
+    )
+    s = Server(cfg)
+    s.open()
+    try:
+        base = f"http://{s.host}"
+
+        def post(path, data):
+            req = urllib.request.Request(base + path, data=data.encode(), method="POST")
+            return json.loads(urllib.request.urlopen(req, timeout=30).read())
+
+        post("/index/i", "{}")
+        post("/index/i/frame/f", "{}")
+        post("/index/i/query", 'SetBit(rowID=0, frame="f", columnID=1)')
+        post("/index/i/query", 'SetBit(rowID=1, frame="f", columnID=1)')
+        for i in range(s.costs.cap):
+            s.costs.observe(index="i", fp=f"fp{i}", lane="flat", ms=ledger_ms)
+        assert len(s.costs) == s.costs.cap
+        steps = itertools.count()
+        s.qcache._clock = lambda: next(steps) * 0.005
+        assert s.qcache.min_cost_ms == min_cost_ms
+        for _ in range(2):
+            assert post("/index/i/query", Q_PAIR)["results"] == [1]
+        assert (s.qcache.stores, s.qcache.hits) == (int(stored), int(stored))
+    finally:
+        s.close()
+
+
 # -- config surface ---------------------------------------------------------
 
 

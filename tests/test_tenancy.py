@@ -359,7 +359,7 @@ def test_qcache_no_tenancy_no_tenant_accounting(tmp_path):
 # -- cost-ledger tenant dimension -------------------------------------------
 
 
-def test_costs_five_tuple_keys_and_peek_fallback():
+def test_costs_five_tuple_keys():
     from pilosa_tpu.costs import CostLedger
 
     led = CostLedger()
@@ -368,16 +368,15 @@ def test_costs_five_tuple_keys_and_peek_fallback():
     # Exact peek with the tenant.
     e = led.peek(tenant="gold", index="i", frame="f", fp="fp", lane="exec")
     assert e is not None and e["ewma_ms"] == pytest.approx(10.0)
-    # Tenant-agnostic peek (the planner's call shape) falls back to the
-    # MRU tenant for the same (index, frame, fp, lane).
-    e = led.peek(index="i", frame="f", fp="fp", lane="exec")
-    assert e is not None and e["ewma_ms"] == pytest.approx(10.0)
-    # A different tenant, same 4-tuple: separate entries, fallback
-    # follows recency.
+    # The tenant is part of the key: a peek that names none finds none.
+    assert led.peek(index="i", frame="f", fp="fp", lane="exec") is None
+    # A different tenant, same 4-tuple: separate entries.
     led.observe(tenant="free", index="i", frame="f", fp="fp",
                 lane="exec", ms=30.0)
-    e = led.peek(index="i", frame="f", fp="fp", lane="exec")
+    e = led.peek(tenant="free", index="i", frame="f", fp="fp", lane="exec")
     assert e["ewma_ms"] == pytest.approx(30.0)
+    e = led.peek(tenant="gold", index="i", frame="f", fp="fp", lane="exec")
+    assert e["ewma_ms"] == pytest.approx(10.0)
     rows = led.entries()
     assert {r["tenant"] for r in rows} == {"gold", "free"}
     by = led.by_tenant()
