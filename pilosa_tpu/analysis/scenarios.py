@@ -345,10 +345,19 @@ class _QcacheStoreVsWriteCtx:
     referenced fragment's generation: commit must decline whenever the
     write landed mid-execution, and the explored history must
     linearize against the sequential store/bump/get spec — a stale
-    stored result under ANY interleaving is a read-your-writes break."""
+    stored result under ANY interleaving is a read-your-writes break.
+
+    The writer stamps the way every fragment does, through a write
+    epoch (one of this run's own, so its lock is a scheduling point).
+    ``memoized`` picks the token the reader carries: a ``_Pending``
+    with its pre-execution vector (the string is in the memo), or the
+    ``_Deferred`` of a never-seen string, which has only the epoch."""
+
+    memoized = True
 
     def __init__(self):
         from pilosa_tpu import qcache
+        from pilosa_tpu.core.fragment import _WriteEpoch
         # Warm the executor import on the driver thread: a first-thread
         # import inside the reader would give execution #1 a different
         # yield structure than #2..N.  The parse memo needs no warm-up
@@ -359,7 +368,10 @@ class _QcacheStoreVsWriteCtx:
 
         self.frag = _FakeFragment()
         self.holder = _FakeHolder(self.frag)
-        self.cache = qcache.QueryCache(min_cost_ms=0)
+        self.epoch = _WriteEpoch()
+        self.cache = qcache.QueryCache(min_cost_ms=0, epoch=self.epoch.read)
+        if self.memoized:
+            self.cache._canonical(_QUERY)
         self.history = spec.LinHistory()
 
         def reader():
@@ -367,6 +379,7 @@ class _QcacheStoreVsWriteCtx:
                 self.holder, "i", _QUERY, None
             )
             assert results is None  # cold cache: always a miss
+            assert pending.deferred is not self.memoized
             gen = self.frag.generation  # the "execution" reads state here
             value = f"v{gen}"
             opid = self.history.invoke(0, "store", (value, gen))
@@ -377,7 +390,7 @@ class _QcacheStoreVsWriteCtx:
 
         def writer():
             opid = self.history.invoke(1, "bump")
-            self.frag.generation += 1
+            self.epoch.stamp(self.frag)
             self.history.respond(opid, None)
 
         self.threads = [reader, writer]
@@ -399,6 +412,10 @@ class _QcacheStoreVsWriteCtx:
 
     def close(self):
         pass
+
+
+class _QcacheDeferredStoreVsWriteCtx(_QcacheStoreVsWriteCtx):
+    memoized = False
 
 
 # -- ingest stager scenario --------------------------------------------------
@@ -613,6 +630,8 @@ SCENARIOS: dict[str, Scenario] = {
         Scenario("applied_seq_notes", _AppliedSeqNotesCtx,
                  trace_check=True, bound=2, max_schedules=800),
         Scenario("qcache_store_vs_write", _QcacheStoreVsWriteCtx,
+                 bound=2, max_schedules=800),
+        Scenario("qcache_deferred_store_vs_write", _QcacheDeferredStoreVsWriteCtx,
                  bound=2, max_schedules=800),
         Scenario("ingest_resume_vs_apply", _IngestResumeVsApplyCtx,
                  bound=2, max_schedules=800),

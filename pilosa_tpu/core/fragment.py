@@ -22,7 +22,6 @@ TPU-first departures from the reference:
 from __future__ import annotations
 
 import hashlib
-import itertools
 import math
 import os
 import tempfile
@@ -60,8 +59,46 @@ TOPN_SCORE_CHUNK = 256
 
 _WORDS = SLICE_WIDTH // 32
 
-# Process-global write-generation source (see Fragment.generation).
-_generation_counter = itertools.count(1)
+
+class _WriteEpoch:
+    """Process-global write-generation source (see Fragment.generation)
+    that can also be READ: the last value drawn is the process's write
+    epoch, which the query cache snapshots where it cannot yet name the
+    frames a body touches (qcache: deferred tokens).
+
+    Drawing a generation and assigning it to the fragment are one step
+    under ``_mu``, and a reader takes ``_mu`` too, so an epoch read
+    twice with the same value proves that no fragment's ``generation``
+    was assigned in between - a drawn-but-unassigned generation cannot
+    hide behind the first read.  A leaf lock: nothing else is taken
+    under it.
+    """
+
+    def __init__(self):
+        self._mu = lockcheck.named_lock("core.fragment.write_epoch._mu")
+        self._last = 0
+
+    def stamp(self, frag: "Fragment") -> None:
+        """Give ``frag`` the next generation (its lock held, as for
+        every rebind of ``generation``)."""
+        with self._mu:
+            self._last = frag.generation = self._last + 1
+
+    def bump(self) -> None:
+        """Move the epoch for an edit that no fragment sees: a schema
+        field that enters the cache's validity vector (labels, time
+        quantum, inverse flag, remote max slice, a frame's deletion)."""
+        with self._mu:
+            self._last += 1
+
+    def read(self) -> int:
+        with self._mu:
+            return self._last
+
+
+_WRITE_EPOCH = _WriteEpoch()
+write_epoch = _WRITE_EPOCH.read
+bump_write_epoch = _WRITE_EPOCH.bump
 
 # Read-only singleton changed-vectors for the scalar write-lane path
 # (np.full costs ~0.7 us per singleton request).
@@ -222,7 +259,7 @@ class Fragment:
         # storage.  Global (not per-object) so a deleted+recreated
         # fragment can never repeat an old fragment's generation and
         # revive its cache entries.
-        self.generation = next(_generation_counter)
+        _WRITE_EPOCH.stamp(self)
         # Dirty-row journal: one (generation, rows) entry per generation
         # bump, so warm device state (executor serve states, row-pool
         # matrices, Grams) can be PATCHED after small writes instead of
@@ -528,7 +565,7 @@ class Fragment:
                 # (_flush_row_bookkeeping).  Storage itself is always
                 # current, and the write generation bumps eagerly so
                 # engine-side matrices never serve stale hits.
-                self.generation = next(_generation_counter)
+                _WRITE_EPOCH.stamp(self)
                 self._log_dirty((row_id,))
                 p = self._pending_rows
                 p[row_id] = p.get(row_id, 0) + 1
@@ -567,7 +604,7 @@ class Fragment:
                         added.append(v)
                 if added:
                     self.stats.count("setN", len(added))
-                    self.generation = next(_generation_counter)
+                    _WRITE_EPOCH.stamp(self)
                     self._log_dirty({v // SLICE_WIDTH for v in added})
                     p = self._pending_rows
                     for v in added:
@@ -588,7 +625,7 @@ class Fragment:
             added = self.storage.add_many_unlogged(positions)
             if len(added):
                 self.stats.count("setN", len(added))
-                self.generation = next(_generation_counter)
+                _WRITE_EPOCH.stamp(self)
                 rows_added, per_row = np.unique(
                     added // np.uint64(SLICE_WIDTH), return_counts=True
                 )
@@ -614,7 +651,7 @@ class Fragment:
             self._materialize_bulk_locked()
             changed = self.storage.remove(self.pos(row_id, column_id))
             if changed:
-                self.generation = next(_generation_counter)
+                _WRITE_EPOCH.stamp(self)
                 self._log_dirty((row_id,))
                 p = self._pending_rows
                 p[row_id] = p.get(row_id, 0) - 1
@@ -862,7 +899,7 @@ class Fragment:
                 # Same deferred bookkeeping as the scalar mutators: bump
                 # the generation eagerly, journal the touched rows, and
                 # leave rank/row-cache updates to the next reader.
-                self.generation = next(_generation_counter)
+                _WRITE_EPOCH.stamp(self)
                 crow = (cpos // W).astype(np.int64)
                 deltas = np.where(ctyp == 0, 1, -1)
                 uro, inv = np.unique(crow, return_inverse=True)
@@ -919,7 +956,7 @@ class Fragment:
                 self.stats.count("setN", 1)
             else:
                 self.stats.count("clearN", 1)
-            self.generation = next(_generation_counter)
+            _WRITE_EPOCH.stamp(self)
             self._log_dirty((row0,))
             p = self._pending_rows
             p[row0] = p.get(row0, 0) + (1 if t0 == 0 else -1)
@@ -1343,7 +1380,7 @@ class Fragment:
             self.storage.add_many(positions)
         finally:
             self.storage.op_writer = self._wal
-        self.generation = next(_generation_counter)
+        _WRITE_EPOCH.stamp(self)
         self._log_dirty(None)  # bulk load: delta unenumerable by design
         self._row_cache.clear()
         self._row_dev_cache.clear()
@@ -1452,7 +1489,7 @@ class Fragment:
         vectors keyed on the old generation must not serve pre-overlay
         state), dirty-row journal, stats, and the lazy ledger's pending
         note on the empty -> non-empty transition."""
-        self.generation = next(_generation_counter)
+        _WRITE_EPOCH.stamp(self)
         self._log_dirty(rows)
         self.stats.count("bulk.commit_rows", len(rows))
         if was_empty:
@@ -1495,7 +1532,7 @@ class Fragment:
         )
         added = self.storage.add_many_unlogged(positions)
         if len(added):
-            self.generation = next(_generation_counter)
+            _WRITE_EPOCH.stamp(self)
             self._log_dirty(rows)
             if len(added) >= self._effective_max_opn():
                 self._snapshot()
@@ -1677,7 +1714,7 @@ class Fragment:
             LEDGER.note_materialized(self)
         self.storage = roaring.Bitmap.from_bytes(data)
         self.storage.op_n = 0
-        self.generation = next(_generation_counter)
+        _WRITE_EPOCH.stamp(self)
         self._log_dirty(None)  # wholesale restore: delta unenumerable
         self._row_cache.clear()
         self._row_dev_cache.clear()

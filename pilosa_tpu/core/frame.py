@@ -27,7 +27,7 @@ import numpy as np
 from pilosa_tpu.core import cache as cache_mod
 from pilosa_tpu.core import timequantum as tq
 from pilosa_tpu.core.attr import AttrStore
-from pilosa_tpu.core.fragment import DEFAULT_CACHE_SIZE
+from pilosa_tpu.core.fragment import DEFAULT_CACHE_SIZE, bump_write_epoch
 from pilosa_tpu.core.view import VIEW_INVERSE, VIEW_STANDARD, View, is_inverse_view, is_valid_view
 from pilosa_tpu.pilosa import (
     ErrFrameInverseDisabled,
@@ -173,10 +173,15 @@ class Frame:
             self.cache_size = opt.cache_size
         if opt.time_quantum:
             self.time_quantum = tq.parse_time_quantum(opt.time_quantum)
+        # Row label, inverse flag and quantum enter the query cache's
+        # validity vector without touching a fragment: move the write
+        # epoch for them.
+        bump_write_epoch()
         self.save_meta()
 
     def set_time_quantum(self, q: str) -> None:
         self.time_quantum = tq.parse_time_quantum(q)
+        bump_write_epoch()
         self.save_meta()
 
     def schema_json(self) -> dict:

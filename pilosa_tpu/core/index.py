@@ -18,6 +18,7 @@ from typing import Optional
 
 from pilosa_tpu.core import timequantum as tq
 from pilosa_tpu.core.attr import AttrStore
+from pilosa_tpu.core.fragment import bump_write_epoch
 from pilosa_tpu.core.frame import Frame, FrameOptions
 from pilosa_tpu.pilosa import (
     ErrColumnRowLabelEqual,
@@ -130,10 +131,14 @@ class Index:
             self.column_label = opt.column_label
         if opt.time_quantum:
             self.time_quantum = tq.parse_time_quantum(opt.time_quantum)
+        # Labels and the quantum enter the query cache's validity vector
+        # without touching a fragment: move the write epoch for them.
+        bump_write_epoch()
         self.save_meta()
 
     def set_time_quantum(self, q: str) -> None:
         self.time_quantum = tq.parse_time_quantum(q)
+        bump_write_epoch()
         self.save_meta()
 
     # -- slices ---------------------------------------------------------
@@ -148,10 +153,14 @@ class Index:
         return max(local, self.remote_max_inverse_slice)
 
     def set_remote_max_slice(self, v: int) -> None:
-        self.remote_max_slice = max(self.remote_max_slice, v)
+        if v > self.remote_max_slice:
+            self.remote_max_slice = v
+            bump_write_epoch()  # max_slice() is in the validity vector
 
     def set_remote_max_inverse_slice(self, v: int) -> None:
-        self.remote_max_inverse_slice = max(self.remote_max_inverse_slice, v)
+        if v > self.remote_max_inverse_slice:
+            self.remote_max_inverse_slice = v
+            bump_write_epoch()
 
     # -- frames ----------------------------------------------------------
 
@@ -207,6 +216,7 @@ class Index:
             if f is None:
                 raise ErrFrameNotFound(name)
             self.stats.count("frameN", -1)  # index.go:474
+            bump_write_epoch()  # the vector now reads (name, None)
             f.close()
             shutil.rmtree(f.path, ignore_errors=True)
 

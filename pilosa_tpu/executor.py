@@ -422,9 +422,11 @@ class Executor:
         if isinstance(query, str):
             # Query result cache: a valid generation-keyed entry answers
             # the whole request here — no parse, no dispatch, no device
-            # work.  A cacheable miss carries a _Pending token through
-            # execution; the read return paths below commit it (errors
-            # propagate past the commit, so they are never cached).
+            # work.  A cacheable miss carries a token through execution
+            # (_Pending; _Deferred for a string the cache has not parsed
+            # and will not unless the result is worth storing); the read
+            # return paths below commit it (errors propagate past the
+            # commit, so they are never cached).
             if self.qcache is not None:
                 remote = bool(opt is not None and opt.remote)
                 if opt is not None and opt.no_cache:
@@ -465,8 +467,9 @@ class Executor:
                         # the query ineligible (write-bearing tree, ...).
                         span.tags["qcache"] = (
                             "hit" if cached is not None
-                            else "miss" if qtoken is not None
-                            else "ineligible"
+                            else "ineligible" if qtoken is None
+                            else "deferred" if qtoken.deferred
+                            else "miss"
                         )
                     if cached is not None:
                         return cached
@@ -485,7 +488,7 @@ class Executor:
                 if span is not None:
                     span.tags["lane"] = "write_native"
                 return w
-            fast = self._flat_fast_path(index, query, slices, opt)
+            fast = self._flat_fast_path(index, query, slices, opt, qtoken)
             if fast is not None:
                 if span is not None:
                     # The compiled-query lane answered (native serve /
@@ -937,14 +940,17 @@ class Executor:
             self._note_dirty_rows(index, fname, (row_id,))
         return [ch]
 
-    def _flat_fast_path(self, index: str, src: str, slices, opt) -> Optional[list]:
+    def _flat_fast_path(
+        self, index: str, src: str, slices, opt, qtoken=None
+    ) -> Optional[list]:
         """Compiled-query lane: serve an all-``Count(<op>(Bitmap,Bitmap))``
         request straight from the native matcher's pair arrays — no Token
         stream, no Call objects, no per-call Python work (the dominant
         host costs of a large batched request).  Returns None for
         ANYTHING outside the exact shape — other calls, inverse views,
         unusual args, parse errors — so the normal parse path keeps every
-        behavior and error message.
+        behavior and error message.  ``qtoken`` is the request's query
+        cache token, told how long the request queued for a repair.
         """
         # analysis-ok: lockstep-determinism: deployment config, launcher sets identical env on every rank
         if os.environ.get("PILOSA_TPU_NO_FASTLANE", "").lower() in ("1", "true", "yes"):
@@ -994,7 +1000,13 @@ class Executor:
                 # only structural or over-budget deltas pop the entry
                 # and pay the full rebuild through the general lane.
                 sp = span.child("serve.repair") if span is not None else None
+                t_rep = self.qcache.now() if qtoken is not None else 0.0
                 st = self._serve_state_repair((index, fname), st, sp)
+                if qtoken is not None:
+                    # The wait for the pool's lock and the repair are
+                    # not this body's evaluation: a cache hit could not
+                    # have saved them, so they buy no admission.
+                    qtoken.queued += self.qcache.now() - t_rep
                 if sp is not None:
                     # False where another request had repaired the pool
                     # while this one waited for its lock.

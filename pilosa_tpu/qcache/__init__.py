@@ -28,12 +28,32 @@ Design:
   landing mid-execution skips the store rather than stamping post-write
   tokens onto possibly pre-write results (the same rule as the
   executor's serve-state capture).
+- **Deferred canonicalisation**: the key and the frames come from a
+  parse, and a parse is the dearest thing the cache does.  A request
+  string the ``_canon`` memo has never seen is therefore NOT parsed at
+  lookup: it takes a ``_Deferred`` token holding the raw string and the
+  process-wide write epoch (``core.fragment.write_epoch``: every
+  fragment generation is drawn from it, and schema edits that enter
+  the vector's header bump it), and only a commit whose measured cost
+  clears ``min_cost_ms`` canonicalises it - filling the memo, so the
+  body's next send takes the memoized path and hits.  An unchanged
+  epoch round the commit's vector proves what the pre-execution vector
+  proves on the memoized path, for every frame at once: no generation
+  moved since the lookup.  A body cheaper than the floor is never
+  parsed by the cache, however often it is sent.  Given up: the FIRST
+  send of a new spelling of an already-cached call tree misses (the
+  memo cannot know a spelling without parsing it); its second hits.
 - **Store**: byte-accounted LRU with cost-aware admission — only
   results whose measured execution cost clears ``min_cost_ms`` are
   admitted (cheap requests would pay more in cache bookkeeping than
-  they save); errors are never cached (an exception never reaches the
-  commit), and write-bearing or non-deterministic trees are never
-  cached (see CACHEABLE_CALLS).
+  they save).  The cost is the evaluation's, not the queue's: time a
+  request spent waiting for a serve state's repair (the row pool's
+  lock, another request's repair or its own) is reported by the
+  executor in the token's ``queued`` and taken out, because no hit
+  could have saved it - an entry is valid only while no generation
+  moved, and then there is nothing to repair.  Errors are never cached
+  (an exception never reaches the commit), and write-bearing or
+  non-deterministic trees are never cached (see CACHEABLE_CALLS).
 
 **What is cacheable**: every top-level call must be one of
 ``Count / Intersect / Union / Difference / Xor / Range``.  ``Bitmap``
@@ -75,6 +95,8 @@ from pilosa_tpu.analysis import lockcheck
 import time
 from collections import OrderedDict
 from typing import Optional
+
+from pilosa_tpu.core.fragment import write_epoch
 
 # Per-request cache bypass header: the request neither reads nor stores
 # a cache entry (A/B measurement, stale-read debugging).
@@ -164,9 +186,12 @@ def result_nbytes(results) -> int:
 
 class _Pending:
     """A cacheable miss in flight: key + pre-execution validity tokens.
-    Returned by :meth:`QueryCache.lookup`, consumed by :meth:`commit`."""
+    Returned by :meth:`QueryCache.lookup`, consumed by :meth:`commit`.
+    ``queued`` is the executor's to add to: seconds of the execution
+    that were a wait, not an evaluation (see the module docstring)."""
 
-    __slots__ = ("key", "index", "frames", "vec0", "t0")
+    __slots__ = ("key", "index", "frames", "vec0", "t0", "queued")
+    deferred = False
 
     def __init__(self, key, index, frames, vec0, t0):
         self.key = key
@@ -174,6 +199,25 @@ class _Pending:
         self.frames = frames
         self.vec0 = vec0
         self.t0 = t0
+        self.queued = 0.0
+
+
+class _Deferred:
+    """A miss in flight on a request string nobody has parsed: the raw
+    string and the write epoch stand in for the key and the vector
+    until :meth:`QueryCache.commit` finds the result worth storing."""
+
+    __slots__ = ("query_str", "index", "slices_key", "remote", "epoch0", "t0", "queued")
+    deferred = True
+
+    def __init__(self, query_str, index, slices_key, remote, epoch0, t0):
+        self.query_str = query_str
+        self.index = index
+        self.slices_key = slices_key
+        self.remote = remote
+        self.epoch0 = epoch0
+        self.t0 = t0
+        self.queued = 0.0
 
 
 class _Entry:
@@ -195,12 +239,16 @@ class QueryCache:
     """The byte-accounted, generation-validated query result LRU.
 
     Thread-safe.  Counters (``hits / misses / bypasses / ineligible /
-    evictions / stores`` and the ``bytes`` gauge) are exposed both as
-    attributes (tests, bench) and through the optional stats client
-    (``qcache.hit`` etc. at /debug/vars).  ``bypasses`` counts ONLY
-    client-requested skips (X-Pilosa-No-Cache) so the A/B hit-rate
-    denominator stays clean; writes, unparseable queries, and
-    cluster-scope requests count as ``ineligible``.
+    evictions / stores / deferred / deferred_parsed`` and the ``bytes``
+    gauge) are exposed both as attributes (tests, bench) and through
+    the optional stats client (``qcache.hit`` etc. at /debug/vars).
+    ``bypasses`` counts ONLY client-requested skips (X-Pilosa-No-Cache)
+    so the A/B hit-rate denominator stays clean; writes, non-cacheable
+    trees and cluster-scope requests count as ``ineligible``.
+    ``deferred`` counts the lookups (misses all) that took a deferred
+    token, ``deferred_parsed`` the commits that canonicalised one - a
+    non-cacheable tree sent as a never-seen string is judged, and
+    counted ``ineligible``, there.
     """
 
     # Lockset race detector declarations: the store/canon LRUs and the
@@ -218,6 +266,8 @@ class QueryCache:
         "ineligible": "qcache._mu",
         "evictions": "qcache._mu",
         "stores": "qcache._mu",
+        "deferred": "qcache._mu",
+        "deferred_parsed": "qcache._mu",
     }
 
     def __init__(
@@ -227,6 +277,7 @@ class QueryCache:
         stats=None,
         clock=time.perf_counter,
         tenancy=None,
+        epoch=write_epoch,
     ):
         from pilosa_tpu.stats import NOP_STATS
 
@@ -239,6 +290,10 @@ class QueryCache:
         # flush another tenant's working set.  None = no quotas.
         self.tenancy = tenancy
         self._clock = clock
+        # The process's write epoch (core.fragment): what a deferred
+        # token snapshots.  An argument for the interleaving explorer,
+        # whose fake fragments stamp from an epoch of their own.
+        self._epoch = epoch
         self._mu = lockcheck.named_lock("qcache._mu")
         self._store: "OrderedDict[tuple, _Entry]" = OrderedDict()
         # Raw request string -> (fingerprint, frames) for eligible
@@ -255,6 +310,8 @@ class QueryCache:
         self.ineligible = 0
         self.evictions = 0
         self.stores = 0
+        self.deferred = 0
+        self.deferred_parsed = 0
 
     # -- fingerprinting ---------------------------------------------------
 
@@ -332,8 +389,20 @@ class QueryCache:
         local slices only, never a coordinator's global answer (remote
         reads always carry explicit slices today — this keys the
         invariant rather than assuming it).
+
+        A string the memo has never seen is not parsed here: it counts
+        a miss and yields ``(None, _Deferred)`` (module docstring).
         """
-        info = self._canonical(query_str)
+        info = self._canon.get(query_str, self._CANON_MISS)
+        if info is self._CANON_MISS:
+            with self._mu:
+                self.misses += 1
+                self.deferred += 1
+            self.stats.count("qcache.miss")
+            self.stats.count("qcache.deferred")
+            return None, _Deferred(
+                query_str, index, slices_key, remote, self._epoch(), self._clock()
+            )
         if info is None:
             self.note_ineligible()
             return None, None
@@ -364,18 +433,55 @@ class QueryCache:
             return None, None  # index missing: the execution will raise
         return None, _Pending(key, index, frames, vec, self._clock())
 
-    def commit(self, holder, pending: _Pending, results) -> bool:
+    def now(self) -> float:
+        """The admission clock, for the executor's ``queued`` spans."""
+        return self._clock()
+
+    def _resolve(self, holder, d: _Deferred) -> Optional[tuple]:
+        """Canonicalise a deferred token whose result is worth storing:
+        ``(key, frames, vec)``, or None where the tree is not cacheable
+        (counted ``ineligible`` here) or the write epoch moved since
+        the lookup.  The memo is filled before the epoch is looked at,
+        so under a steady stream of writes the body's next send still
+        takes the memoized path, whose validity is per frame."""
+        with self._mu:
+            self.deferred_parsed += 1
+        self.stats.count("qcache.deferred_parsed")
+        info = self._canonical(d.query_str)
+        if info is None:
+            self.note_ineligible()
+            return None
+        if self._epoch() != d.epoch0:
+            return None
+        fp, frames = info
+        vec = generation_vector(holder, d.index, frames)
+        # The epoch after the vector is the proof: a generation is
+        # assigned under the epoch's lock, so one this vector saw that
+        # the lookup could not have has moved it.
+        if vec is None or self._epoch() != d.epoch0:
+            return None
+        return (d.index, fp, d.slices_key, d.remote), frames, vec
+
+    def commit(self, holder, pending, results) -> bool:
         """Admit one executed miss.  Declines when the measured cost is
-        under ``min_cost_ms`` (not worth the bookkeeping) or a write
-        landed mid-execution (the vector moved — storing would stamp
-        pre-write results with post-write tokens).  Returns True when
-        the entry was stored."""
-        cost_ms = (self._clock() - pending.t0) * 1e3
+        under ``min_cost_ms`` (not worth the bookkeeping — a deferred
+        token's string is then never parsed) or a write landed
+        mid-execution (the vector, or a deferred token's epoch, moved —
+        storing would stamp pre-write results with post-write tokens).
+        Returns True when the entry was stored."""
+        cost_ms = (self._clock() - pending.t0 - pending.queued) * 1e3
         if cost_ms < self.min_cost_ms:
             return False
-        vec1 = generation_vector(holder, pending.index, pending.frames)
-        if vec1 is None or vec1 != pending.vec0:
-            return False
+        if pending.deferred:
+            resolved = self._resolve(holder, pending)
+            if resolved is None:
+                return False
+            key, frames, vec = resolved
+        else:
+            key, frames = pending.key, pending.frames
+            vec = generation_vector(holder, pending.index, frames)
+            if vec is None or vec != pending.vec0:
+                return False
         nbytes = result_nbytes(results)
         if nbytes > self.max_bytes:
             return False
@@ -385,15 +491,14 @@ class QueryCache:
             else None
         )
         entry = _Entry(
-            pending.index, pending.frames, pending.vec0, list(results), nbytes,
-            tenant=tenant,
+            pending.index, frames, vec, list(results), nbytes, tenant=tenant,
         )
         with self._mu:
-            old = self._store.pop(pending.key, None)
+            old = self._store.pop(key, None)
             if old is not None:
                 self.bytes -= old.nbytes
                 self._tenant_debit(old)
-            self._store[pending.key] = entry
+            self._store[key] = entry
             self.bytes += nbytes
             if tenant is not None:
                 self.tenant_bytes[tenant] = (

@@ -417,7 +417,7 @@ class Tracer:
             **fingerprint(body),
             "stages": trace.root.stage_breakdown(),
             # Cache/QoS disposition tags land on the root span
-            # (qcache=hit/miss/bypass/ineligible, qos=shed/expired,
+            # (qcache=hit/miss/deferred/bypass/ineligible, qos=shed/expired,
             # lane=...) — surfaced flat so the log line is greppable.
             "tags": {k: v for k, v in trace.root.tags.items()},
         }

@@ -255,10 +255,16 @@ def test_delete_frame_route_purges(env):
 def test_canonical_fingerprint_shares_entry(env):
     h, fr, ex, qc = env
     ex.execute("i", Q_PAIR)
-    # Same call tree, different formatting: one entry, served as a hit.
+    # Same call tree, different formatting: one entry.  The cache learns
+    # a spelling by parsing it, and parses only at a commit, so the new
+    # spelling's FIRST send misses (the one hit deferral gives up: it
+    # stores under the same key) and every later one is served as a hit.
     variant = 'Count(Intersect(Bitmap(rowID=0,frame="f"),Bitmap(rowID=1,frame="f")))'
     assert ex.execute("i", variant) == [5]
-    assert qc.hits == 1 and len(qc) == 1
+    assert (qc.hits, qc.misses, qc.deferred_parsed) == (0, 2, 2) and len(qc) == 1
+    assert ex.execute("i", variant) == [5]
+    assert ex.execute("i", Q_PAIR) == [5]
+    assert (qc.hits, qc.misses) == (2, 2) and len(qc) == 1
 
 
 def test_slices_key_separates_partial_requests(env):
@@ -368,6 +374,9 @@ def test_stats_counters_at_debug_vars(tmp_path):
     snap = stats.snapshot()
     assert snap["qcache.hit"] == 1
     assert snap["qcache.miss"] == 1
+    # The one miss was a never-seen string, canonicalised at its commit.
+    assert snap["qcache.deferred"] == 1
+    assert snap["qcache.deferred_parsed"] == 1
     assert snap["qcache.store"] == 1
     assert snap["qcache.bypass"] == 1
     assert snap["qcache.ineligible"] == 1  # the write, not a bypass
@@ -501,6 +510,289 @@ def test_admission_floor_is_the_configured_one_with_a_full_ledger(
         assert (s.qcache.stores, s.qcache.hits) == (int(stored), int(stored))
     finally:
         s.close()
+
+
+# -- deferred canonicalisation: the repeated body, and the body never parsed --
+
+ENGINES = ("numpy", "jax", "mesh")
+_FLOOR_MS = 2.0
+
+
+def _pairs(rows, pad=""):
+    return pad + " ".join(
+        f'Count(Intersect(Bitmap(rowID={a}, frame="f"), Bitmap(rowID={a + 1}, frame="f")))'
+        for a in rows
+    )
+
+
+class _Clock:
+    """The cache's clock in a test's hand: it moves only where told."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+    def during(self, fn, seconds):
+        def slowed(*a, **kw):
+            self.t += seconds
+            return fn(*a, **kw)
+
+        return slowed
+
+
+@pytest.fixture(params=ENGINES)
+def dash(request, tmp_path, monkeypatch):
+    """A dashboard index of 4 slices and two frames behind each engine,
+    its query cache at a 2 ms floor on a clock the test moves.  On the
+    jax and the mesh engine (four of the eight virtual devices, Pallas
+    interpreted) four warm-up reads arm frame f's serve state, so reads
+    are answered natively from the Gram and a write is repaired."""
+    kind = request.param
+    monkeypatch.setenv("PILOSA_TPU_PALLAS_INTERPRET", "1")
+    h = Holder(str(tmp_path / "d"))
+    h.open()
+    idx = h.create_index("i")
+    fr, other = idx.create_frame("f", FrameOptions()), idx.create_frame("g", FrameOptions())
+    rng = np.random.default_rng(31)
+    for s in range(4):
+        for r in range(6):
+            for c in rng.choice(512, size=20, replace=False):
+                fr.set_bit("standard", r, s * SLICE_WIDTH + int(c))
+    other.set_bit("standard", 0, 0)
+    if kind == "mesh":
+        import jax
+
+        from pilosa_tpu.engine import MeshEngine
+
+        engine = MeshEngine(devices=jax.devices()[:4])
+    else:
+        engine = kind
+    clock = _Clock()
+    qc = QueryCache(min_cost_ms=_FLOOR_MS, clock=clock)
+    ex = Executor(h, engine=engine, qcache=qc)
+    fresh = Executor(h, engine="numpy", qcache=None)
+    if kind != "numpy":
+        for _ in range(4):
+            ex.execute("i", _pairs(range(5)), opt=ExecOptions(no_cache=True))
+        assert ("i", "f") in ex._serve_states
+    yield h, ex, qc, clock, fresh
+    h.close()
+
+
+def _dear(ex, clock, seconds=0.005):
+    """Make every evaluation cost ``seconds`` on the cache's clock: the
+    door every string request passes once, after the lookup."""
+    ex._singleton_write_fast = clock.during(ex._singleton_write_fast, seconds)
+
+
+def test_dear_repeated_body_hits_from_its_second_send(dash):
+    """The polled dashboard (ROADMAP D13's condition): a body dearer
+    than the floor is parsed once, at its first commit, hits on its
+    second send, and goes on hitting after another frame's write."""
+    h, ex, qc, clock, fresh = dash
+    _dear(ex, clock)
+    body = _pairs(range(5))
+    want = fresh.execute("i", body)
+    assert ex.execute("i", body) == want
+    assert (qc.hits, qc.misses, qc.deferred, qc.deferred_parsed, qc.stores) == (0, 1, 1, 1, 1)
+    assert ex.execute("i", body) == want
+    assert (qc.hits, qc.misses, qc.deferred) == (1, 1, 1)
+    h.index("i").frame("g").set_bit("standard", 1, 9)  # moves the epoch, not f's vector
+    assert ex.execute("i", body) == want
+    assert (qc.hits, qc.misses, qc.deferred, qc.deferred_parsed, qc.stores) == (2, 1, 1, 1, 1)
+    # Its own frame's write: a miss on the memoized path, the fresh answer stored.
+    h.index("i").frame("f").set_bit("standard", 0, 3 * SLICE_WIDTH + 999)
+    want = fresh.execute("i", body)
+    assert ex.execute("i", body) == want and ex.execute("i", body) == want
+    assert (qc.hits, qc.misses, qc.deferred, qc.stores) == (3, 2, 1, 2)
+
+
+def test_cheap_body_is_never_parsed_by_the_cache(dash, monkeypatch):
+    """Below the floor a commit returns before it canonicalises: however
+    often the body is sent, the cache parses nothing and remembers
+    nothing; where the armed native lane answers, nobody parses."""
+    from pilosa_tpu.pql import parser
+
+    h, ex, qc, clock, fresh = dash
+    parses, canon = [], []
+    monkeypatch.setattr(parser, "parse", lambda src, _p=parser.parse: parses.append(src) or _p(src))
+    monkeypatch.setattr(qc, "_canonical", lambda s, _c=qc._canonical: canon.append(s) or _c(s))
+    body = _pairs([4, 2, 0], pad="  ")
+    want = fresh.execute("i", body)
+    parses.clear()
+    for send in range(1, 7):
+        assert ex.execute("i", body) == want
+        assert (qc.misses, qc.deferred) == (send, send)
+    assert canon == [] and len(qc._canon) == 0
+    assert (qc.deferred_parsed, qc.stores, qc.ineligible, qc.hits, len(qc)) == (0, 0, 0, 0, 0)
+    if ("i", "f") in ex._serve_states:
+        assert parses == []
+
+
+@pytest.mark.parametrize("dash", ["jax", "mesh"], indirect=True)  # numpy arms no serve state
+def test_queueing_for_a_repair_buys_no_admission(dash):
+    """Admission is by the evaluation's cost: 10 ms inside
+    _serve_state_repair (the pool's lock, another request's repair or
+    its own) leave a read cheap, and it is neither parsed nor stored;
+    the same 10 ms of evaluation store it."""
+    h, ex, qc, clock, fresh = dash
+    fr = h.index("i").frame("f")
+    repairs = []
+    real = ex._serve_state_repair
+
+    def repair(key, st, span=None):
+        repairs.append(key)
+        return real(key, st, span)
+
+    ex._serve_state_repair = clock.during(repair, 0.010)
+    body = _pairs(range(5), pad="   ")
+    fr.set_bit("standard", 2, 2 * SLICE_WIDTH + 777)
+    assert ex.execute("i", body) == fresh.execute("i", body)
+    assert repairs == [("i", "f")]
+    assert (qc.deferred, qc.deferred_parsed, qc.stores, len(qc._canon)) == (1, 0, 0, 0)
+    _dear(ex, clock, 0.010)
+    fr.set_bit("standard", 2, 2 * SLICE_WIDTH + 778)
+    assert ex.execute("i", body) == fresh.execute("i", body)
+    assert len(repairs) == 2
+    assert (qc.deferred, qc.deferred_parsed, qc.stores) == (2, 1, 1)
+    assert ex.execute("i", body) == fresh.execute("i", body) and qc.hits == 1
+
+
+_BETWEEN = {
+    "nothing": (lambda h: None, True),
+    "a_write_that_changed_nothing": (
+        lambda h: h.index("i").frame("f").set_bit("standard", 0, 0), True),
+    "same_frame": (lambda h: h.index("i").frame("f").set_bit("standard", 0, 70), False),
+    "another_frame": (lambda h: h.index("i").frame("g").set_bit("standard", 0, 70), False),
+    "a_new_fragment": (
+        lambda h: h.index("i").frame("g").set_bit("standard", 0, 5 * SLICE_WIDTH), False),
+    "a_cleared_bit": (lambda h: h.index("i").frame("f").clear_bit("standard", 0, 0), False),
+    "frame_time_quantum": (lambda h: h.index("i").frame("f").set_time_quantum("YMD"), False),
+    "index_time_quantum": (lambda h: h.index("i").set_time_quantum("YM"), False),
+    "frame_options": (
+        lambda h: h.index("i").frame("f").apply_options(FrameOptions(inverse_enabled=True)),
+        False),
+    "remote_max_slice": (lambda h: h.index("i").set_remote_max_slice(9), False),
+    "remote_max_slice_unmoved": (lambda h: h.index("i").set_remote_max_slice(0), True),
+    "a_frame_created": (lambda h: h.index("i").create_frame("n", FrameOptions()), False),
+    "a_frame_deleted": (lambda h: h.index("i").delete_frame("g"), False),
+}
+
+
+@pytest.mark.parametrize("between", sorted(_BETWEEN))
+def test_write_between_deferred_lookup_and_commit_declines_the_store(env, between):
+    """A deferred token cannot name its frames before the parse, so it
+    holds the process's write epoch: anything that could move any
+    validity vector between the lookup and the commit - a bit, on any
+    frame; a fragment's creation; a schema field of the vector's header
+    - declines the store.  The memo is filled all the same, so the next
+    send is judged by its own frames' vector."""
+    h, fr, ex, qc = env
+    h.index("i").create_frame("g", FrameOptions()).set_bit("standard", 0, 1)
+    edit, stored = _BETWEEN[between]
+    cached, tok = qc.lookup(h, "i", Q_PAIR, None)
+    assert cached is None and tok.deferred
+    edit(h)
+    assert qc.commit(h, tok, [5]) is stored
+    assert (qc.stores, len(qc), qc.deferred_parsed) == (int(stored), int(stored), 1)
+    cached, tok = qc.lookup(h, "i", Q_PAIR, None)
+    assert qc.deferred == 1  # the memo knows the string now
+    assert (cached == [5]) if stored else (cached is None and not tok.deferred)
+
+
+def test_non_cacheable_body_counts_ineligible_once_at_commit(env):
+    h, fr, ex, qc = env
+    topn = 'TopN(frame="f", n=2)'
+    cached, tok = qc.lookup(h, "i", topn, None)
+    assert cached is None and tok.deferred
+    assert (qc.ineligible, qc.misses, qc.deferred) == (0, 1, 1)  # not judged yet
+    assert not qc.commit(h, tok, [[]])
+    assert (qc.ineligible, qc.deferred_parsed, qc.stores) == (1, 1, 0)
+    # The memo holds the verdict: later sends are ineligible at lookup.
+    assert qc.lookup(h, "i", topn, None) == (None, None)
+    assert (qc.ineligible, qc.misses, qc.deferred) == (2, 1, 1)
+    # Below the floor nobody asks: the body stays a deferred miss.
+    qc.min_cost_ms = 1e9
+    other = 'TopN(frame="f", n=3)'
+    for _ in range(3):
+        _, tok = qc.lookup(h, "i", other, None)
+        assert not qc.commit(h, tok, [[]])
+    assert (qc.ineligible, qc.deferred, qc.deferred_parsed) == (2, 4, 1)
+
+
+def test_deferred_request_is_tagged_on_its_root_span(env):
+    from pilosa_tpu.trace import Span
+
+    h, fr, ex, qc = env
+    tags = []
+    for _ in range(2):
+        root = Span("root")
+        ex.execute("i", Q_PAIR, opt=ExecOptions(span=root))
+        tags.append(root.tags["qcache"])
+        assert [c.name for c in root.children][0] == "qcache.lookup"
+    assert tags == ["deferred", "hit"]
+    fr.set_bit("standard", 0, 40)
+    root = Span("root")
+    ex.execute("i", Q_PAIR, opt=ExecOptions(span=root))
+    assert root.tags["qcache"] == "miss"
+    assert [c.name for c in root.children][-1] == "qcache.commit"
+
+
+def test_write_epoch_read_twice_alike_means_no_generation_moved():
+    """The epoch's contract under threads: every stamp is a distinct
+    generation, and an epoch that reads the same before and after two
+    looks at the fragments' generations saw them unchanged.  More
+    writers than cores, a short switch interval."""
+    import sys
+    import threading
+
+    from pilosa_tpu.core.fragment import _WriteEpoch
+
+    class Frag:
+        generation = 0
+
+    epoch = _WriteEpoch()
+    frags = [Frag() for _ in range(16)]
+    seen = [[] for _ in frags]
+    torn, quiet = [], [0]
+    stop = threading.Event()
+
+    def writer(i):
+        for _ in range(1500):
+            epoch.stamp(frags[i])
+            seen[i].append(frags[i].generation)
+
+    def reader():
+        while not stop.is_set():
+            e0 = epoch.read()
+            a = [f.generation for f in frags]
+            b = [f.generation for f in frags]
+            if epoch.read() == e0:
+                quiet[0] += 1
+                if a != b:
+                    torn.append((a, b))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        readers = [threading.Thread(target=reader) for _ in range(2)]
+        writers = [threading.Thread(target=writer, args=(i,)) for i in range(len(frags))]
+        for t in readers + writers:
+            t.start()
+        for t in writers:
+            t.join(timeout=60)
+        stop.set()
+        for t in readers:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in readers + writers)
+    finally:
+        sys.setswitchinterval(interval)
+    drawn = [g for gens in seen for g in gens]
+    assert len(set(drawn)) == len(drawn) == 16 * 1500
+    assert epoch.read() == max(drawn) == 16 * 1500
+    assert torn == []
 
 
 # -- config surface ---------------------------------------------------------

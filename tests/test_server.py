@@ -320,7 +320,7 @@ def test_debug_traces_and_slow_query_log(tmp_path):
         assert "qos.admit" in names and "qcache.lookup" in names
         # The miss before it carried the execution stages.
         miss = query_traces[1]
-        assert miss["spans"]["tags"]["qcache"] == "miss"
+        assert miss["spans"]["tags"]["qcache"] == "deferred"  # a miss on a never-seen string
         # min-ms filter: an impossible floor returns nothing.
         with urllib.request.urlopen(
             f"http://{s.host}/debug/traces?min-ms=1e9", timeout=30
@@ -339,7 +339,7 @@ def test_debug_traces_and_slow_query_log(tmp_path):
         # The miss's breakdown attributed the execution: the compiled
         # serve lane (lane=flat) times its single native crossing as a
         # "device" stage; the general lane emits fused/per-call spans.
-        miss_rec = next(r for r in qrecs if r["tags"].get("qcache") == "miss")
+        miss_rec = next(r for r in qrecs if r["tags"].get("qcache") == "deferred")
         if miss_rec["tags"].get("lane") == "flat":
             assert "device" in miss_rec["stages"]
         else:

@@ -276,7 +276,7 @@ def test_executor_qcache_span_outcomes(holder):
     q = 'Count(Bitmap(rowID=1, frame="f"))'
     r1 = Span("r1")
     ex.execute("i", q, opt=ExecOptions(span=r1))
-    assert r1.tags["qcache"] == "miss"
+    assert r1.tags["qcache"] == "deferred"  # a never-seen string: a miss, parsed at its commit
     names1 = [c.name for c in r1.children]
     assert names1.index("qcache.lookup") < names1.index("qcache.commit")  # the miss's admission
     r2 = Span("r2")
@@ -286,6 +286,10 @@ def test_executor_qcache_span_outcomes(holder):
     r3 = Span("r3")
     ex.execute("i", q, opt=ExecOptions(span=r3, no_cache=True))
     assert r3.tags["qcache"] == "bypass"
+    holder.index("i").frame("f").set_bit("standard", 1, 77)
+    r4 = Span("r4")
+    ex.execute("i", q, opt=ExecOptions(span=r4))
+    assert r4.tags["qcache"] == "miss"  # the memo knows the string; its frame moved
 
 
 def test_executor_untraced_requests_build_no_spans(holder):
