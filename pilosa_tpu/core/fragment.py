@@ -1165,6 +1165,49 @@ class Fragment:
                 self._row_cache.popitem(last=False)
             return words
 
+    def rows_dense_into(self, row_ids: Sequence[int], out: np.ndarray) -> None:
+        """``row_dense`` of every row of ``row_ids`` into ``out``
+        (uint32[len(row_ids), W], zeroed by the caller; a strided view of
+        a larger block will do), in one pass over the rows' containers: a
+        row's 16 container keys are probed, the array containers' set
+        bits of all rows go into ``out`` as words in one numpy scatter,
+        a bitmap container is copied, the pending bulk overlay is merged.
+        A negative row id is no row: its plane stays zero.  What a pool
+        fetch runs (a block of many rows, most of them a few bits a
+        slice): no dense plane per row is built on the way and the row
+        cache is neither read nor filled."""
+        per_row = SLICE_WIDTH >> 16  # containers a row spans
+        with self._mu:
+            self._assert_open()
+            get = self.storage.containers.get
+            at, lows = [], []  # per array container: first word in out.ravel() terms, values
+            for k, row_id in enumerate(row_ids):
+                if row_id < 0:
+                    continue
+                key0 = row_id * per_row
+                for j, c in enumerate(map(get, range(key0, key0 + per_row))):
+                    if c is None:
+                        continue
+                    if c.bitmap is not None:
+                        out[k, j * 2048 : (j + 1) * 2048] = c.bitmap.view(np.uint32)[: 2 * roaring.BITMAP_N]
+                    elif len(c.array):
+                        at.append(k * _WORDS + j * 2048)
+                        lows.append(c.array)
+                ov = self._bulk_planes.get(row_id)
+                if ov is not None:
+                    out[k] |= ov
+            if not lows:
+                return
+            v = np.concatenate(lows)
+            word = np.repeat(np.asarray(at, dtype=np.int64), [len(a) for a in lows]) + (v >> 5)
+            bit = np.uint32(1) << (v & np.uint32(31))
+            # Values ascend inside a container and containers were taken
+            # in block order, so equal words are neighbours: OR each run.
+            first = np.flatnonzero(np.concatenate(([True], word[1:] != word[:-1])))
+            word, bit = word[first], np.bitwise_or.reduceat(bit, first)
+            k_idx, w_idx = np.divmod(word, _WORDS)
+            out[k_idx, w_idx] |= bit  # beside an overlay's bits, where there is one
+
     def row_device(self, row_id: int, engine):
         """Dense row as an ENGINE array, cached device-side.
 

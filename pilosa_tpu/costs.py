@@ -71,6 +71,12 @@ class _Measure:
         # analysis-ok: check-then-act: _Measure is a per-request stack object; it never crosses threads
         self.extra_bytes += int(n)
 
+    def tag(self, **tags) -> None:
+        """Tags for the traced request's ``device`` span (a gather
+        dispatch says what it gathered); nothing where none is sampled."""
+        if self.dev_span is not None:
+            self.dev_span.tags.update(tags)
+
     def __enter__(self) -> "_Measure":
         if self.span is not None:
             self.dev_span = self.span.child("device")

@@ -62,8 +62,30 @@ def nbytes(*arrays) -> int:
 
 
 def _pow2(n: int) -> int:
-    """The power-of-two bucket of a count (jitted shapes stay few)."""
+    """The power-of-two bucket of a count (jitted shapes stay few): the
+    one rule for what is uploaded - a repair's cells, a pool miss's rows
+    (8 MiB of zeros a padded row at 64 slices: the ladder is fine)."""
     return 1 << (n - 1).bit_length() if n > 1 else 1
+
+
+def _pow4(n: int) -> int:
+    """The power-of-four bucket of a gather dispatch's batch.  Coarser
+    than ``_pow2`` because a program here is a Pallas kernel per (op,
+    bucket) and a padded pair costs only its own two row reads: the
+    served lanes then meet two buckets where they met four, and a
+    warm-up that runs the traffic has met them all."""
+    return 1 << (2 * (((n - 1).bit_length() + 1) // 2)) if n > 1 else 1
+
+
+def _padded_batch(idx) -> np.ndarray:
+    """A gather dispatch's index tuples int32[B, K] padded to their
+    ``_pow4`` bucket with copies of the first tuple; the caller drops the
+    tail of the counts."""
+    idx = np.asarray(idx, dtype=np.int32)
+    pad = _pow4(len(idx)) - len(idx) if len(idx) else 0
+    if not pad:
+        return idx
+    return np.concatenate([idx, np.broadcast_to(idx[:1], (pad,) + idx.shape[1:])])
 
 
 def _padded_cells(groups):
@@ -155,6 +177,11 @@ class NumpyEngine:
         b = row_matrix[:, pairs[:, 1], :]
         r = _NP_OPS[op](a, b)
         return self.count(r).sum(axis=0)
+
+    def gather_bucket(self, n: int) -> int:
+        """The batch a gather dispatch of ``n`` index tuples runs at:
+        nothing compiles here, so ``n`` itself."""
+        return n
 
     def gather_count_multi(self, op: str, row_matrix, idx) -> np.ndarray:
         """Batched Count over a left-fold of K gathered rows — N-operand
@@ -288,12 +315,19 @@ class NumpyEngine:
         out[:, row_start : row_start + block.shape[1], :] = block
         return out
 
-    def set_rows_at(self, matrix, slots, block):
-        """Functionally write rows into ARBITRARY slots (row-pool paging:
-        a miss batch scatters into freed slots in one call)."""
-        out = matrix.copy()
+    def set_rows_at(self, matrix, slots, block, donate: bool = False):
+        """Write rows into ARBITRARY slots (row-pool paging: a miss's
+        chunk scatters into freed slots in one call): into a copy, or
+        with ``donate`` into ``matrix`` itself (the caller holds the only
+        reference: the copy an earlier chunk of the same miss made).  The
+        pool pads a chunk only for engines that compile
+        (``wants_static_shapes``): never here."""
+        out = matrix if donate else matrix.copy()
         out[:, list(slots), :] = block
         return out
+
+    def warm_set_rows(self, matrix, max_rows: int, row_major: bool = False) -> None:
+        """Nothing compiles here."""
 
     def grow_rows(self, matrix, n: int):
         """Append n zero rows of capacity (row-pool doubling)."""
@@ -485,14 +519,23 @@ class JaxEngine:
         """Batched Count(Intersect) in ONE device dispatch (Pallas on TPU)."""
         return self.gather_count("and", row_matrix, pairs)
 
+    def gather_bucket(self, n: int) -> int:
+        """The batch a pair dispatch of ``n`` pairs runs at: ``gather_count``
+        and its ``_dev`` forms pad the pairs on the host to this bucket
+        (``_padded_batch``: copies of the first pair), one program a
+        bucket; ``gather_count`` drops the tail itself, the ``_dev``
+        forms return the padded counts un-fetched and the caller does."""
+        return _pow4(n)
+
     def gather_count(self, op: str, row_matrix, pairs) -> np.ndarray:
         # allow_gram=False: eager per-request dispatch can't amortize the
         # all-pairs matmul; the executor's generation-cached Gram
         # (pair_gram) is the product-path version of that strategy.
         out = self._dispatch.gather_count(
-            op, self._jnp.asarray(row_matrix), self._jnp.asarray(pairs), allow_gram=False
+            op, self._jnp.asarray(row_matrix), self._jnp.asarray(_padded_batch(pairs)),
+            allow_gram=False,
         )
-        return self.to_numpy(out).astype(np.int64)
+        return self.to_numpy(out)[: len(pairs)].astype(np.int64)
 
     def gather_count_multi(self, op: str, row_matrix, idx) -> np.ndarray:
         out = self._dispatch.gather_count_multi(
@@ -508,7 +551,8 @@ class JaxEngine:
         returned un-fetched, so a streaming loop pipelines chunk k+1's
         host->device upload behind chunk k's kernel."""
         return self._dispatch.gather_count(
-            op, self._jnp.asarray(row_matrix), self._jnp.asarray(pairs), allow_gram=False
+            op, self._jnp.asarray(row_matrix), self._jnp.asarray(_padded_batch(pairs)),
+            allow_gram=False,
         )
 
     # -- row-major gather lane (streaming regime's tall row sets) --------
@@ -546,7 +590,7 @@ class JaxEngine:
 
     def gather_count_rowmajor_dev(self, op: str, row_major, pairs):
         return self._dispatch.gather_count_rowmajor(
-            op, self._jnp.asarray(row_major), self._jnp.asarray(pairs)
+            op, self._jnp.asarray(row_major), self._jnp.asarray(_padded_batch(pairs))
         )
 
     def gather_count_multi_rowmajor_dev(self, op: str, row_major, idx):
@@ -559,10 +603,10 @@ class JaxEngine:
         z = self._jnp.zeros((n,) + matrix.shape[1:], dtype=matrix.dtype)
         return self._jnp.concatenate([matrix, z], axis=0)
 
-    def set_rows_at_rm(self, matrix, slots, block):
-        """Scatter a row-major miss batch [k, S, W] into slots (axis 0)."""
-        idx = self._jnp.asarray(np.asarray(slots, dtype=np.int32))
-        return matrix.at[idx].set(self._match_block(matrix, block))
+    def set_rows_at_rm(self, matrix, slots, block, donate: bool = False):
+        """Scatter a row-major miss chunk [k, S, W] into slots (axis 0):
+        ``set_rows_at``'s program over the other axis."""
+        return self._set_rows(matrix, slots, block, 0, donate)
 
     def set_plane_rows_rm(self, matrix, slice_idxs, slots, block):
         """Refresh (slot, stale-slice) cells of a row-major matrix;
@@ -687,11 +731,49 @@ class JaxEngine:
             self._match_block(matrix, block)
         )
 
-    def set_rows_at(self, matrix, slots, block):
-        """Scatter a miss batch into arbitrary pool slots: only the new
-        rows cross host->device; the scatter itself is HBM->HBM."""
-        idx = self._jnp.asarray(np.asarray(slots, dtype=np.int32))
-        return matrix.at[:, idx].set(self._match_block(matrix, block))
+    def set_rows_at(self, matrix, slots, block, donate: bool = False):
+        """Scatter a miss's chunk into arbitrary pool slots: only the new
+        rows cross host->device (the upload is enqueued, not waited for);
+        the scatter itself is HBM->HBM, into a copy of the pool (a reader
+        may hold ``matrix``) or, with ``donate``, into ``matrix`` itself
+        (the copy an earlier chunk of the same miss made: the caller
+        holds the only reference, and the array is gone afterwards).  One
+        program a block size and form (``ops.bitwise.set_rows``): the pool
+        pads a chunk to its power-of-two bucket, slot -1 over a zero
+        plane, which the scatter drops."""
+        return self._set_rows(matrix, slots, block, 1, donate)
+
+    def _set_rows(self, matrix, slots, block, axis: int, donate: bool):
+        if not hasattr(self, "_set_rows_jit"):
+            import jax
+
+            from pilosa_tpu.ops.bitwise import set_rows
+
+            self._set_rows_jit = {
+                d: jax.jit(set_rows, static_argnames="axis", donate_argnums=(0,) if d else ())
+                for d in (False, True)
+            }
+        return self._set_rows_jit[donate](
+            matrix, np.asarray(slots, dtype=np.int32),
+            self._match_block(matrix, block), axis=axis,
+        )
+
+    def warm_set_rows(self, matrix, max_rows: int, row_major: bool = False) -> None:
+        """Compile ``set_rows_at``'s programs for every bucket a miss's
+        chunk on this pool can pad to (1 .. ``max_rows``), copying and
+        donating, by running each once with every slot dropped (nothing
+        is written).  A pool calls this when it first evicts: from then
+        on it pages for as long as it lives, and no later miss count
+        compiles."""
+        axis, k = (0 if row_major else 1), 1
+        shape = list(matrix.shape[: 2]) + [int(np.prod(matrix.shape[2:]))]
+        while k <= min(max_rows, matrix.shape[axis]):
+            shape[axis] = k
+            drop = np.full(k, -1, dtype=np.int32)
+            block = np.zeros(shape, dtype=np.uint32)
+            copy = self._set_rows(matrix, drop, block, axis, False)
+            self._set_rows(copy, drop, block, axis, True).block_until_ready()
+            k *= 2
 
     def grow_rows(self, matrix, n: int):
         """Append n zero capacity rows DEVICE-side (no host transfer)."""
@@ -1092,8 +1174,20 @@ class MeshEngine(JaxEngine):
     def set_rows(self, matrix, row_start, block):
         return self._repin(super().set_rows(matrix, row_start, block), matrix)
 
-    def set_rows_at(self, matrix, slots, block):
-        return self._repin(super().set_rows_at(matrix, slots, block), matrix)
+    def _set_rows(self, matrix, slots, block, axis: int, donate: bool):
+        if axis != 1 or self.slice_axis_devices(matrix.shape[0]) == 1:
+            sharding = matrix.sharding  # read before a donation takes the array
+            return self._jax.device_put(
+                super()._set_rows(matrix, slots, block, axis, donate), sharding
+            )
+        from pilosa_tpu.parallel.sharded import sharded_set_rows
+
+        # Every device scatters its own slices of the block into its own
+        # shard (a copy of it, or with ``donate`` the shard itself).
+        return sharded_set_rows(
+            self.mesh, self._shard_stack(matrix), np.asarray(slots, dtype=np.int32),
+            self._match_block(matrix, block), donate,
+        )
 
     def grow_rows(self, matrix, n):
         # The zero rows are born with the matrix's sharding: made on the
@@ -1202,16 +1296,16 @@ class MeshEngine(JaxEngine):
 
         rm = self._shard_stack(self._jnp.asarray(row_matrix))
         mode = self._pallas_mode(rm.shape[0], rm_words(rm))
+        n, pairs = len(pairs), self._jnp.asarray(_padded_batch(pairs))
         if mode:
             from pilosa_tpu.parallel.sharded import sharded_gather_count
 
             out = sharded_gather_count(
-                self.mesh, op, rm, self._jnp.asarray(pairs),
-                interpret=(mode == "interpret"),
+                self.mesh, op, rm, pairs, interpret=(mode == "interpret"),
             )
-            return self._fetch(out).astype(np.int64)
-        out = self._gather_jit(op, rm, self._jnp.asarray(pairs))
-        return self._fetch(out).astype(np.int64)
+            return self._fetch(out)[:n].astype(np.int64)
+        out = self._gather_jit(op, rm, pairs)
+        return self._fetch(out)[:n].astype(np.int64)
 
     def _fetch(self, arr, span=None) -> np.ndarray:
         """Fetch an engine array to host, allgathering when its shards

@@ -1675,6 +1675,7 @@ class Executor:
                 p2 = lut[np.searchsorted(rows, fr2)]
                 fops = op_ids[fmask]
                 fout = np.zeros(len(fr1), dtype=np.int64)
+                pending = []  # every op's dispatch goes out before the first fetch blocks
                 for op_id in np.unique(fops):
                     om = fops == op_id
                     pairs = np.stack([p1[om], p2[om]], axis=1).astype(np.int32)
@@ -1682,14 +1683,14 @@ class Executor:
                     if gram is not None:
                         from pilosa_tpu.ops.bitwise import gram_pair_counts
 
-                        counts = gram_pair_counts(op, gram, pairs)
-                    elif rm_pool:
-                        counts = self.engine.to_numpy(
-                            self.engine.gather_count_rowmajor_dev(op, matrix, pairs)
-                        ).astype(np.int64)
+                        fout[om] = gram_pair_counts(op, gram, pairs)
                     else:
-                        counts = self.engine.gather_count(op, matrix, pairs)
-                    fout[om] = counts
+                        pending.append((
+                            om, len(pairs),
+                            self._gather_pairs(op, matrix, pairs, rm_pool, span),
+                        ))
+                for om, n, counts in pending:
+                    fout[om] = self.engine.to_numpy(counts)[:n].astype(np.int64)
                 out[fmask] = fout
         return out.tolist()
 
@@ -2307,8 +2308,9 @@ class Executor:
         """One fused dispatch for an (op, arity-bucket) call group; returns
         the engine-native count array (fetch deferred to the caller).
         Metered as the "gather" lane (cost attribution): dispatch wall
-        time + any host->device operand bytes the engine ledger sees."""
-        if self.meter is not None:
+        time + any host->device operand bytes the engine ledger sees (a
+        pair group's kernel dispatch meters itself: ``_gather_pairs``)."""
+        if self.meter is not None and not (gk[1] == 2 and gram is None):
             with self.meter.measure("gather", span):
                 return self._group_counts_inner(
                     gk, op_idxs, matched, id_pos, matrix, static, gram,
@@ -2316,11 +2318,37 @@ class Executor:
                 )
         return self._group_counts_inner(
             gk, op_idxs, matched, id_pos, matrix, static, gram,
-            row_major=row_major,
+            row_major=row_major, span=span,
         )
 
+    def _gather_pairs(self, op: str, matrix, pairs, row_major: bool, span=None):
+        """One pair group's gather dispatch over a pool or transient
+        matrix: the engine-native counts un-fetched, at the engine's
+        bucket for ``len(pairs)`` (``engine.gather_bucket``: the caller
+        drops the tail).  Metered as the "gather" lane; the traced
+        request's ``device`` span says what was gathered (``pairs``,
+        ``unique_rows``, ``layout``, ``bucket``), counters
+        ``gather.dispatches`` / ``gather.pairs``."""
+        def dispatch():
+            if row_major:
+                return self.engine.gather_count_rowmajor_dev(op, matrix, pairs)
+            return self.engine.gather_count_dev(op, matrix, pairs)
+
+        if self.meter is None:
+            return dispatch()
+        with self.meter.measure("gather", span) as d:
+            self.meter.stats.count("gather.dispatches")
+            self.meter.stats.count("gather.pairs", len(pairs))
+            d.tag(
+                pairs=len(pairs), unique_rows=len(np.unique(pairs)),
+                layout="row_major" if row_major else "slice_major",
+                bucket=self.engine.gather_bucket(len(pairs)),
+            )
+            return dispatch()
+
     def _group_counts_inner(
-        self, gk, op_idxs, matched, id_pos, matrix, static, gram, row_major=False
+        self, gk, op_idxs, matched, id_pos, matrix, static, gram, row_major=False,
+        span=None,
     ):
         op, kb = gk
         if isinstance(op, tuple):  # ("tree", K): nested expression trees
@@ -2349,9 +2377,7 @@ class Executor:
                 from pilosa_tpu.ops.bitwise import gram_pair_counts
 
                 return gram_pair_counts(op, gram, pairs)
-            if row_major:
-                return self.engine.gather_count_rowmajor_dev(op, matrix, pairs)
-            return self.engine.gather_count_dev(op, matrix, pairs)
+            return self._gather_pairs(op, matrix, pairs, row_major, span)
         # Jitted engines get a padded batch bucket too (pad rows repeat
         # the first call's operands; extra counts discarded) — ragged B
         # recompiles per group size.
@@ -2395,7 +2421,10 @@ class Executor:
         (slice-major — pool fetches and transient streaming matrices), or
         [len(rows), len(chunk_slices), W] with ``row_major=True`` (the
         streaming gather lane: each row's slices contiguous for one-descriptor
-        DMAs).  Filled directly in target order — no transpose copy."""
+        DMAs).  Filled directly in target order — no transpose copy — by
+        one pass per fragment over the rows' containers
+        (``Fragment.rows_dense_into``); a negative row id (the tail of a
+        pool miss's bucket) is a zero plane."""
         if row_major:
             block = np.zeros((len(rows), len(chunk_slices), _WORDS), dtype=np.uint32)
         else:
@@ -2403,11 +2432,7 @@ class Executor:
         for bi, s in enumerate(chunk_slices):
             f = self.holder.fragment(index, frame, view, s)
             if f is not None:
-                for k, r in enumerate(rows):
-                    if row_major:
-                        block[k, bi] = f.row_dense(r)
-                    else:
-                        block[bi, k] = f.row_dense(r)
+                f.rows_dense_into(rows, block[:, bi] if row_major else block[bi])
         return block
 
     def _transient_matrix(

@@ -385,6 +385,28 @@ def set_plane_cells(row_matrix, cells, planes, first_slice=0):
         return row_matrix.at[si, cells[:, 1]].set(planes, mode="drop")
 
 
+def set_rows(row_matrix, slots, block, axis: int = 1):
+    """A pool matrix with ``block``'s rows written into ``slots``
+    (int32[k]) along ``axis`` (1: slice-major ``[S, cap, ...]`` and
+    ``block`` ``[S, k, ...]``; 0: row-major): a pool miss's scatter (a new
+    array; jitted by the engines, one program a ``k``).  A negative slot -
+    the tail of a miss's bucket - is dropped.  One row after another into
+    one copy of the pool: XLA's own scatter kept a second copy of a block
+    of 64 rows or more (found by compiling for a described v5e).  Its ops
+    carry ``pool.set_rows`` in a device trace."""
+    with jax.named_scope("pool.set_rows"):
+
+        def write(i, m):
+            slot = slots[i]
+            at = jnp.maximum(slot, 0)
+            row = lax.dynamic_slice_in_dim(block, i, 1, axis)
+            # a dropped row puts slot 0's own contents back
+            row = jnp.where(slot >= 0, row, lax.dynamic_slice_in_dim(m, at, 1, axis))
+            return lax.dynamic_update_slice_in_dim(m, row, at, axis)
+
+        return lax.fori_loop(0, slots.shape[0], write, row_matrix)
+
+
 def repair_planes(row_matrix, cells, planes, n: int):
     """Write new planes into a pool matrix and return what each write
     does to the AND-count Gram over the first ``n`` slots.

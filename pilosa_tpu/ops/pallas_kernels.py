@@ -184,6 +184,7 @@ def resident_strategy(n_rows: int, w: int, batch: int) -> bool:
 
 
 @functools.partial(jax.jit, static_argnames=("op", "interpret"))
+@jax.named_scope("gather.count")  # the pair kernels' name in a device trace
 def fused_resident_count2(op: str, row_matrix, pairs, interpret: bool = False):
     """Row-resident variant of :func:`fused_gather_count2` for small row
     working sets (the common case: a hot frame has far fewer distinct rows
@@ -238,6 +239,7 @@ def _gather_count_kernel(op, pairs_ref, a_ref, b_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("op", "interpret"))
+@jax.named_scope("gather.count")  # the pair kernels' name in a device trace
 def fused_gather_count2(op: str, row_matrix, pairs, interpret: bool = False):
     """Per-query ``sum_s popcount(op(rm[s, p0], rm[s, p1]))`` without
     materializing the gathered operands.
@@ -412,6 +414,7 @@ def _gather_rowmajor_kernel(op, depth, pairs_ref, rm_ref, out_ref, buf, sems):
 
 
 @functools.partial(jax.jit, static_argnames=("op", "depth", "interpret"))
+@jax.named_scope("gather.count")  # the pair kernels' name in a device trace
 def fused_gather_count2_rowmajor(
     op: str, row_major, pairs, depth: int = 2, interpret: bool = False
 ):

@@ -367,6 +367,40 @@ def sharded_set_plane_cells(mesh: SliceMesh, row_matrix, cells, planes):
 
 
 @functools.lru_cache(maxsize=None)
+def _sharded_set_rows_kernel(mesh_obj, axis: str, rm_ndim: int, donate: bool = False):
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from pilosa_tpu.ops.bitwise import set_rows
+
+    rest = [None] * (rm_ndim - 1)
+
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh_obj,
+        in_specs=(P(axis, *rest), P(None), P(axis, *rest)),
+        out_specs=P(axis, *rest),
+        check_vma=False,
+    )
+    def set_rows_shards(rm_shard, slots, block_shard):
+        return set_rows(rm_shard, slots, block_shard)
+
+    return jax.jit(set_rows_shards, donate_argnums=(0,) if donate else ())
+
+
+def sharded_set_rows(mesh: SliceMesh, row_matrix, slots, block, donate: bool = False):
+    """``ops.bitwise.set_rows`` on a slice-sharded pool matrix: a pool
+    miss's block ``[S, k, ...]`` arrives sharded like the matrix, and every
+    device scatters its own slices' rows into a copy of its own shard (with
+    ``donate`` into the shard itself) - no communication, the result born
+    with the matrix's sharding (GSPMD is not asked: it gathered the whole
+    pool for ``set_plane_cells``)."""
+    _require_divisible(row_matrix.shape[0], mesh.n_devices)
+    kernel = _sharded_set_rows_kernel(mesh.mesh, mesh.AXIS, row_matrix.ndim, donate)
+    return kernel(row_matrix, slots, block)
+
+
+@functools.lru_cache(maxsize=None)
 def _sharded_repair_planes_kernel(mesh_obj, axis: str, rm_ndim: int, n: int):
     import jax
     import jax.numpy as jnp

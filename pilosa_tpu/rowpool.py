@@ -34,6 +34,7 @@ import os
 import threading
 
 from pilosa_tpu.analysis import lockcheck
+from pilosa_tpu.engine import _pow2
 from pilosa_tpu.stats import NOP_STATS
 from collections import OrderedDict
 from typing import Callable, Optional, Sequence
@@ -41,6 +42,11 @@ from typing import Callable, Optional, Sequence
 import numpy as np
 
 POOL_BYTES_PER_DEVICE = 2 * 1024 * 1024 * 1024
+# A miss pages its rows in chunks of this many: the host builds chunk k+1
+# while chunk k's upload (64 MiB at 64 slices) is in flight, the block a
+# chunk is padded to wastes at most 3 rows, and a pool's scatter programs
+# are the buckets up to here (1, 2, 4, 8), copying and donating.
+MISS_CHUNK_ROWS = 8
 
 
 def pool_bytes(engine=None, n_slices: int = 0) -> tuple[int, int]:
@@ -52,7 +58,9 @@ def pool_bytes(engine=None, n_slices: int = 0) -> tuple[int, int]:
     set, is one pool's whole budget whatever the engine (read per call:
     benches and tests tune it).  Total pool memory is bounded by this times
     the executor's matrix-cache entry count; transient peaks reach 2x one
-    pool during a functional scatter (old + new array alive)."""
+    pool plus a miss's chunks in flight during a miss (the pool is copied
+    once a miss, not donated: old + new array alive, and the blocks of
+    ``MISS_CHUNK_ROWS`` rows being uploaded)."""
     devices = engine.slice_axis_devices(n_slices) if engine is not None else 1
     # analysis-ok: lockstep-determinism: deployment config, launcher sets identical env on every rank
     env = os.environ.get("PILOSA_TPU_POOL_BYTES")
@@ -74,10 +82,6 @@ def pool_capacity(n_slices: int, words: int, engine=None, budget_bytes: int = 0)
     times the slots of one device at the same bytes per device."""
     budget = budget_bytes or pool_bytes(engine, n_slices)[0]
     return max(0, budget // max(1, n_slices * words * 4))
-
-
-def _pow2(n: int) -> int:
-    return 1 << (n - 1).bit_length() if n > 1 else 1
 
 
 class DeviceRowPool:
@@ -140,6 +144,10 @@ class DeviceRowPool:
         # (row, slice) planes actually fetched by the patch lane — the
         # per-(row, slice) granularity benches/tests assert on this.
         self.stat_patch_planes = 0
+        # Block sizes that misses' chunks were padded to
+        # (``rowpool.miss_buckets`` counts them as they appear): each is
+        # one scatter program, copying and donating.
+        self.miss_buckets: set[int] = set()
 
     @staticmethod
     def default_cap(n_slices: int, words: int, engine=None) -> int:
@@ -361,6 +369,40 @@ class DeviceRowPool:
                 self.box["gram_lut"] = (glut[0], np.ascontiguousarray(gram), glut[2])
         return True
 
+    def _page_in(self, missing: list[int], slots: list[int], span=None):
+        """The pool's array with ``missing`` in ``slots``, and the rows
+        uploaded for it: chunk after chunk of ``MISS_CHUNK_ROWS``, each
+        built on the host (``fetch``; span ``pool.miss.fetch``) while the
+        one before uploads, then enqueued (``pool.miss.scatter``).  The
+        first chunk is scattered into a COPY of the pool (a reader may
+        hold the array), the others into that copy itself (donated: no
+        other reference exists).  An engine that compiles gets each block
+        in the power-of-two bucket of its rows (one scatter program a
+        bucket and form): row -1 is a zero plane, slot -1 is dropped."""
+        static = getattr(self.engine, "wants_static_shapes", False)
+        set_rows = self.engine.set_rows_at_rm if self.row_major else self.engine.set_rows_at
+        all_slices = list(range(self.n_slices))
+        matrix, uploaded = self.matrix, 0
+        for at in range(0, len(missing), MISS_CHUNK_ROWS):
+            rows = missing[at : at + MISS_CHUNK_ROWS]
+            tail = [-1] * (_pow2(len(rows)) - len(rows) if static else 0)
+            bucket = len(rows) + len(tail)
+            if bucket not in self.miss_buckets:
+                self.miss_buckets.add(bucket)
+                self.stats.count("rowpool.miss_buckets")
+            sp = span.child("pool.miss.fetch") if span is not None else None
+            block = self.fetch(rows + tail, all_slices)  # layout per self.row_major
+            if sp is not None:
+                sp.finish()
+                sp = span.child("pool.miss.scatter")
+            matrix = set_rows(
+                matrix, slots[at : at + MISS_CHUNK_ROWS] + tail, block, donate=at > 0
+            )
+            if sp is not None:
+                sp.finish()
+            uploaded += bucket
+        return matrix, uploaded
+
     def _repair_spanned(self, stale: list[int], dirty_rows, span) -> bool:
         """``_repair_dirty`` under the request's ``pool.repair`` span."""
         if span is None:
@@ -406,6 +448,20 @@ class DeviceRowPool:
         held), then whichever of ``pool.repair`` (the patch lane),
         ``pool.refresh`` (the blind refresh) and ``pool.miss`` (paging)
         this call ran under the lock.
+
+        What a miss costs, under the lock: the LRU's victims leave
+        (host bookkeeping), then the missing rows page in by chunks of
+        ``MISS_CHUNK_ROWS`` (``_page_in``): ``fetch`` builds a chunk's
+        block on the host (padded to the power-of-two bucket of its rows
+        for an engine that compiles; exact on numpy) - a span
+        ``pool.miss.fetch`` a chunk - and the engine enqueues its upload
+        and its scatter (``pool.miss.scatter``), into a COPY of the pool
+        for the first chunk (the pool is not donated, for a reader may
+        hold it) and into that copy for the rest.  ``pool.miss`` carries
+        ``rows``, ``bucket`` (rows uploaded, padding included),
+        ``evicted`` and ``upload_bytes``.  The first miss that evicts has
+        the engine compile every bucket's programs first
+        (``warm_set_rows``): a second or two, once a pool.
         """
         want = list(dict.fromkeys(want))  # de-dup, keep order
         if len(want) > self.cap_max:
@@ -442,7 +498,6 @@ class DeviceRowPool:
             missing = [r for r in want if r not in self.slot_of]
             if missing:
                 sp = span.child("pool.miss") if span is not None else None
-                up0, ev0 = self.engine.stat_upload_bytes, self.stat_evictions
                 self.stat_misses += len(missing)
                 self.stats.count("rowpool.misses", len(missing))
                 changed = True
@@ -450,6 +505,11 @@ class DeviceRowPool:
                 if need > self.cap:
                     self._grow_to(need)
                 free = [s for s in range(self.cap) if self.row_at[s] is None]
+                if len(free) < len(missing) and not self.stat_evictions:
+                    # From here on this pool pages for as long as it
+                    # lives: no later miss count may compile.
+                    self.engine.warm_set_rows(self.matrix, MISS_CHUNK_ROWS, self.row_major)
+                up0, ev0 = self.engine.stat_upload_bytes, self.stat_evictions
                 if len(free) < len(missing):
                     want_set = set(want)
                     for victim in list(self.lru):
@@ -463,13 +523,7 @@ class DeviceRowPool:
                         free.append(s)
                         self.stat_evictions += 1
                 slots = free[: len(missing)]
-                block = self.fetch(missing, list(range(self.n_slices)))
-                if self.row_major:  # block: [len(missing), S, W]
-                    self.matrix = self.engine.set_rows_at_rm(
-                        self.matrix, slots, block
-                    )
-                else:
-                    self.matrix = self.engine.set_rows_at(self.matrix, slots, block)
+                self.matrix, bucket = self._page_in(missing, slots, sp)
                 for r, s in zip(missing, slots):
                     self.slot_of[r] = s
                     self.row_at[s] = r
@@ -478,7 +532,7 @@ class DeviceRowPool:
                     self.stats.count("rowpool.evictions", evicted)
                 if sp is not None:
                     sp.finish().annotate(
-                        rows=len(missing), evicted=evicted,
+                        rows=len(missing), evicted=evicted, bucket=bucket,
                         upload_bytes=self.engine.stat_upload_bytes - up0,
                     )
             for r in want:

@@ -65,8 +65,9 @@ Design:
   ``qcache.lookup`` / ``qcache.commit``, ``serve.validate``, ``serve.repair`` >
   ``pool.lock_wait``, ``pool.repair`` > ``pool.fetch`` / ``pool.scatter``
   / ``pool.gram`` (> ``mesh.fetch``: the mesh engine's wait for a reduced
-  result), ``pool.refresh``, ``pool.miss``, ``device`` (tag
-  ``lane``), ``write.apply``, ``parse``, ``fused``, ``call.<Name>``,
+  result), ``pool.refresh``, ``pool.miss`` > ``pool.miss.fetch`` /
+  ``pool.miss.scatter``, ``device`` (tag ``lane``; a gather dispatch
+  says what it gathered), ``write.apply``, ``parse``, ``fused``, ``call.<Name>``,
   ``slices`` / ``slice_chunk``, ``remote``, ``encode``.
 
 Finished traces land in a bounded in-memory ring served at

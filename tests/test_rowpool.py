@@ -550,3 +550,236 @@ def test_repair_planes_on_a_tiled_matrix():
     host[[1, 6], 3] = block[:, 0]
     np.testing.assert_array_equal(np.asarray(matrix).reshape(S, R, w), host)
     np.testing.assert_array_equal(finish(), eng.pair_gram(eng.matrix(host[:, :n])))
+
+
+# -- a tall frame paged through the pool (ISSUE 32) ---------------------------
+#
+# A fixed set of programs and no Python per plane.  Small and seeded: 128 rows
+# x 2 slices (4 on the mesh), a pool of 16 slots by ``PILOSA_TPU_POOL_BYTES``,
+# a hot set that fits and a cold tail that evicts - what ``seg64.tall_pairs``
+# does on the chip with 8,192 rows, 64 slices and 256 slots.
+
+import jax  # noqa: E402
+
+from pilosa_tpu.core.frame import FrameOptions  # noqa: E402
+from pilosa_tpu.core.holder import Holder  # noqa: E402
+from pilosa_tpu.executor import ExecOptions, Executor  # noqa: E402
+from pilosa_tpu.pilosa import SLICE_WIDTH  # noqa: E402
+from pilosa_tpu.stats import ExpvarStatsClient  # noqa: E402
+from pilosa_tpu.trace import Span  # noqa: E402
+
+TALL_ROWS, SLOTS, HOT = 128, 16, 10
+PLANE_WORDS = SLICE_WIDTH // 32
+OPS = ("Intersect", "Union", "Difference", "Xor")
+SET_OPS = {"Intersect": set.__and__, "Union": set.__or__,
+           "Difference": set.__sub__, "Xor": set.__xor__}
+
+
+def _paging_engine(kind):
+    if kind != "mesh":
+        return kind
+    from pilosa_tpu.engine import MeshEngine
+
+    return MeshEngine(devices=jax.devices()[:4])
+
+
+def _tall_frame(tmp_path, monkeypatch, kind, n_slices=0):
+    """A holder with one frame of TALL_ROWS rows, 1-5 bits per (row, slice) from
+    a pool of 64 columns a slice (so that some pairs intersect), an
+    executor on ``kind`` whose pool holds SLOTS rows, and the plain
+    reference: a set of columns per row."""
+    n_slices = n_slices or (4 if kind == "mesh" else 2)
+    monkeypatch.setenv("PILOSA_TPU_POOL_BYTES", str(n_slices * SLOTS * PLANE_WORDS * 4))
+    h = Holder(str(tmp_path / "data"))
+    h.open()
+    h.create_index("i").create_frame("stargazer", FrameOptions())
+    fr = h.index("i").frame("stargazer")
+    rng = np.random.default_rng(32)
+    cols_of = {}
+    for r in range(TALL_ROWS):
+        cols = set()
+        for s in range(n_slices):
+            local = rng.choice(64, size=1 + (r * 3) % 5, replace=False) * 1021
+            cols |= {int(s * SLICE_WIDTH + c) for c in local}
+        cols_of[r] = cols
+        for c in sorted(cols):
+            fr.set_bit("standard", r, c)
+    stats = ExpvarStatsClient()
+    return h, Executor(h, engine=_paging_engine(kind), stats=stats), cols_of, stats
+
+
+def _body(rng, n_pairs=8):
+    """(PQL, [(op, a, b)]): pairs whose rows are hot 9 draws in 10."""
+    calls = []
+    for i in range(n_pairs):
+        a, b = (int(rng.integers(0, HOT)) if rng.random() < 0.9 else int(rng.integers(HOT, TALL_ROWS))
+                for _ in range(2))
+        calls.append((OPS[i % 4], a, b if b != a else (a + 1) % TALL_ROWS))
+    return " ".join(
+        f'Count({op}(Bitmap(rowID={a}, frame="stargazer"), Bitmap(rowID={b}, frame="stargazer")))'
+        for op, a, b in calls), calls
+
+
+def _counter(stats, name):
+    return stats.snapshot().get(name, 0)
+
+
+@pytest.mark.parametrize("kind", ["numpy", "jax", "mesh"])
+def test_paged_pair_bodies_equal_the_set_reference(tmp_path, monkeypatch, kind):
+    """(a) A few hundred pair bodies with a cold tail, through
+    ``Executor.execute``, equal plain set arithmetic whichever rows were
+    resident, and the pool evicted on the way."""
+    h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, kind)
+    rng = np.random.default_rng(7)
+    for _ in range(60 if kind == "mesh" else 240):
+        body, calls = _body(rng)
+        want = [len(SET_OPS[op](cols_of[a], cols_of[b])) for op, a, b in calls]
+        assert ex.execute("i", body) == want
+    assert _counter(stats, "rowpool.evictions") > 0
+    assert _counter(stats, "rowpool.misses") > _counter(stats, "rowpool.evictions")
+    assert _counter(stats, "gather.dispatches") > 0
+    assert _counter(stats, "gather.pairs") >= _counter(stats, "gather.dispatches")
+    h.close()
+
+
+@pytest.mark.parametrize("kind", ["jax", "mesh"])
+def test_miss_counts_compile_one_program_a_bucket(tmp_path, monkeypatch, kind):
+    """(b) Miss counts 1-9 in turn run the scatter at four block sizes (1,
+    2, 4, 8: a miss pages in chunks of 8, each padded to its power-of-two
+    bucket), copying for a miss's first chunk and donating for the rest:
+    eight programs, all compiled at the pool's first eviction, and a
+    second pass compiles none.  Counted where jax counts them: the jitted
+    scatter's own cache (which every jit of the function shares, so this
+    test has a slice count of its own)."""
+    n_slices = 8 if kind == "mesh" else 3
+    h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, kind, n_slices)
+    engine = ex.engine
+    pool = ex._pool_for("i", "stargazer", "standard", list(range(n_slices)))
+    gens = tuple(0 for _ in range(pool.n_slices))
+
+    def programs():
+        if kind == "mesh":
+            from pilosa_tpu.parallel import sharded
+
+            return sum(sharded._sharded_set_rows_kernel(
+                engine.mesh.mesh, engine.mesh.AXIS, 4, d)._cache_size() for d in (False, True))
+        from pilosa_tpu.ops.bitwise import set_rows
+
+        return sum(jax.jit(set_rows, static_argnames="axis", donate_argnums=d)._cache_size()
+                   for d in ((), (0,)))
+
+    before = programs()
+    pool.acquire(list(range(SLOTS)), gens)           # the pool is full: every later miss evicts
+    assert pool.cap == SLOTS and pool.stat_evictions == 0
+    at_full = programs()
+    assert at_full - before == 2                     # the fill: a chunk of 8 copied, one donated
+    nxt = SLOTS
+    for n in range(1, 10):
+        pool.acquire(list(range(nxt, nxt + n)), gens)
+        nxt += n
+    # The first eviction compiled the whole ladder; 8 and 8 were there already.
+    assert programs() - at_full == 6
+    assert pool.miss_buckets == {1, 2, 4, 8}
+    assert _counter(stats, "rowpool.miss_buckets") == 4
+    before = programs()
+    for n in range(1, 10):
+        pool.acquire(list(range(nxt, nxt + n)), gens)
+        nxt += n
+    assert programs() == before
+    # ... and every row that was paged in reads back as storage has it.
+    id_pos, matrix, _ = pool.acquire(list(range(nxt - 9, nxt)), gens)
+    for s in (0, n_slices - 1):
+        frag = h.fragment("i", "stargazer", "standard", s)
+        for r in range(nxt - 9, nxt):
+            got = np.asarray(matrix)[s, id_pos[r]].reshape(-1)
+            assert (got == frag.row_dense(r)).all() and got.any()
+    h.close()
+
+
+def test_numpy_engine_gets_its_misses_unpadded(tmp_path, monkeypatch):
+    """(b) The numpy engine compiles nothing: its blocks are the miss
+    counts themselves."""
+    h, ex, _, _ = _tall_frame(tmp_path, monkeypatch, "numpy")
+    pool = ex._pool_for("i", "stargazer", "standard", [0, 1])
+    pool.acquire(list(range(SLOTS)), (0, 0))
+    pool.acquire([20, 21, 22], (0, 0))
+    assert pool.miss_buckets == {8, 3} and pool.stat_evictions == 3      # 16 rows: two chunks of 8
+    h.close()
+
+
+@pytest.mark.parametrize("row_major", [False, True])
+def test_block_of_the_new_fetch_equals_row_dense(tmp_path, monkeypatch, row_major):
+    """(c) A block from the pool's fetch equals ``row_dense`` plane for
+    plane - array containers, a bitmap container, a pending bulk overlay,
+    an absent row and a bucket's tail (row -1) - and leaves the fragments'
+    row caches as it found them."""
+    h, ex, _, _ = _tall_frame(tmp_path, monkeypatch, "numpy")
+    fr = h.index("i").frame("stargazer")
+    frag0 = h.fragment("i", "stargazer", "standard", 0)
+    dense = np.random.default_rng(3).choice(1 << 16, size=5000, replace=False) + (1 << 17)
+    frag0.set_bits(np.full(len(dense), 5, dtype=np.uint64), dense.astype(np.uint64))  # a bitmap container
+    overlay = np.zeros(PLANE_WORDS, dtype=np.uint32)
+    overlay[[3, 2048 + 7, PLANE_WORDS - 1]] = [0x80000001, 0xF0, 0x1]
+    frag0.bulk_or_words(np.array([7], dtype=np.uint64), np.array([3]),
+                        np.array([3, 2048 + 7, PLANE_WORDS - 1]), overlay[[3, 2048 + 7, PLANE_WORDS - 1]])
+    assert 7 in frag0._bulk_planes
+    rows = [5, 7, 40, 4000, -1, 127]
+    cached = {s: list(h.fragment("i", "stargazer", "standard", s)._row_cache) for s in (0, 1)}
+    block = ex._densify_block("i", "stargazer", "standard", [0, 1], rows, row_major=row_major)
+    assert block.shape == ((len(rows), 2, PLANE_WORDS) if row_major else (2, len(rows), PLANE_WORDS))
+    for s in (0, 1):
+        frag = h.fragment("i", "stargazer", "standard", s)
+        assert list(frag._row_cache) == cached[s]
+        for k, r in enumerate(rows):
+            plane = block[k, s] if row_major else block[s, k]
+            want = frag.row_dense(r) if r >= 0 else np.zeros(PLANE_WORDS, dtype=np.uint32)
+            assert (plane == want).all(), (s, r)
+    assert block[(1, 0) if row_major else (0, 1)].any()      # the overlay's row is not empty
+    h.close()
+
+
+@pytest.mark.parametrize("kind", ["numpy", "jax"])
+def test_a_sampled_miss_says_what_it_paged_and_gathered(tmp_path, monkeypatch, kind):
+    """(d) A sampled request that misses carries ``pool.miss`` with
+    ``bucket`` and its two stages, and the gather dispatch's ``device``
+    span says what it gathered."""
+    h, ex, cols_of, _ = _tall_frame(tmp_path, monkeypatch, kind)
+    root = Span("root")
+    body = " ".join(
+        f'Count({op}(Bitmap(rowID={a}, frame="stargazer"), Bitmap(rowID={b}, frame="stargazer")))'
+        for op, a, b in (("Intersect", 1, 2), ("Intersect", 3, 1), ("Union", 2, 50)))
+    assert ex.execute("i", body, opt=ExecOptions(span=root)) == [
+        len(cols_of[1] & cols_of[2]), len(cols_of[3] & cols_of[1]), len(cols_of[2] | cols_of[50])]
+
+    def walk(sp):
+        yield sp
+        for c in sp.children:
+            yield from walk(c)
+
+    spans = list(walk(root))
+    miss = next(s for s in spans if s.name == "pool.miss")
+    assert miss.tags["rows"] == 4 and miss.tags["bucket"] == 4 and miss.tags["evicted"] == 0
+    assert miss.tags["upload_bytes"] == (4 * 2 * PLANE_WORDS * 4 if kind == "jax" else 0)
+    assert [c.name for c in miss.children] == ["pool.miss.fetch", "pool.miss.scatter"]  # one chunk
+    assert all(c.ms is not None for c in miss.children)
+    gathers = [s for s in spans if s.name == "device" and s.tags.get("lane") == "gather"]
+    assert sorted((g.tags["pairs"], g.tags["unique_rows"]) for g in gathers) == [(1, 2), (2, 3)]
+    assert {g.tags["layout"] for g in gathers} == {"slice_major"}
+    # the engine that compiles runs a batch at its power-of-four bucket
+    assert sorted(g.tags["bucket"] for g in gathers) == ([1, 4] if kind == "jax" else [1, 2])
+    h.close()
+
+
+def test_one_bucketing_rule_each():
+    """Rows uploaded pad to powers of two, a gather's batch to powers of
+    four; the padded batch repeats its first tuple."""
+    from pilosa_tpu import rowpool
+    from pilosa_tpu.engine import JaxEngine, NumpyEngine, _padded_batch, _pow2, _pow4
+
+    assert rowpool._pow2 is _pow2
+    assert [_pow2(n) for n in (1, 2, 3, 5, 9, 33)] == [1, 2, 4, 8, 16, 64]
+    assert [_pow4(n) for n in (1, 2, 4, 5, 16, 17, 64, 65)] == [1, 4, 4, 16, 16, 64, 64, 256]
+    pairs = np.array([[3, 4], [5, 6], [7, 8]], dtype=np.int32)
+    assert _padded_batch(pairs).tolist() == [[3, 4], [5, 6], [7, 8], [3, 4]]
+    assert _padded_batch(pairs[:1]) .tolist() == [[3, 4]]
+    assert NumpyEngine().gather_bucket(3) == 3 and JaxEngine.gather_bucket(None, 3) == 4

@@ -136,6 +136,10 @@ _KERNEL_CASES = {
     # pool [64, 256, W], pair groups of 32 and 40 --
     "deploy_gather_b32": (_p("fused_gather_count2", "xor"), [_rm(64, 256), _ids(32, 2)]),
     "deploy_gather_b40": (_p("fused_gather_count2", "andnot"), [_rm(64, 256), _ids(40, 2)]),
+    # seg64's paged pair reads: 8 pairs an op a body, 1-8 bodies a pass,
+    # at the gather dispatch's power-of-four buckets (engine._pow4).
+    "seg64_gather_b16": (_p("fused_gather_count2", "or"), [_rm(64, 256), _ids(16, 2)]),
+    "seg64_gather_b64": (_p("fused_gather_count2", "and"), [_rm(64, 256), _ids(64, 2)]),
     "deploy_tree_k8": (_p("fused_gather_count_tree"), [_rm(64, 256), _ids(2, 8), _ids(2, 7)]),
     # TopN(src): the all-slice scorer.
     "deploy_topn_all_slice_scorer": (
@@ -217,20 +221,29 @@ def test_bulk_build_kernel_compiles_for_v5e(one_chip):
         assert mem.temp_size_in_bytes + mem.output_size_in_bytes < HBM_BYTES
 
 
-def test_pool_page_in_compiles_and_fits(one_chip):
-    """Paging a full miss batch into a full 2 GiB pool (rowpool
-    set_rows_at: a functional scatter, old and new matrix both alive)
-    stays inside the chip next to a second pool and a Range matrix."""
+@pytest.mark.parametrize("donate", [False, True])
+@pytest.mark.parametrize("bucket", [1, 8])
+def test_pool_page_in_compiles_and_fits(bucket, donate, one_chip):
+    """Paging a miss's chunk into a full 2 GiB pool (rowpool._page_in:
+    ``ops.bitwise.set_rows``), one program per power-of-two bucket of a
+    chunk's rows (1 .. 8): the first chunk's into a copy (old and new
+    matrix both alive: it stays inside the chip next to a second pool
+    and a Range matrix), the others' into that copy itself (donated: the
+    output is the input's buffer).  No second copy either way, and its
+    ops carry the scope's name."""
     import jax
 
-    def page_in(matrix, slots, block):
-        return matrix.at[:, slots].set(block)
+    from pilosa_tpu.ops.bitwise import set_rows
 
-    compiled = _compile(jax.jit(page_in), [_rm(64, 256), _ids(256), _rm(64, 256)],
-                        one_chip, kernel=False)
+    compiled = _compile(
+        jax.jit(set_rows, static_argnames="axis", donate_argnums=(0,) if donate else ()),
+        [_rm(64, 256), _ids(bucket), _rm(64, bucket)], one_chip, kernel=False)
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     assert total + 3 * 2**31 < HBM_BYTES
+    assert mem.temp_size_in_bytes < 2**20
+    assert mem.alias_size_in_bytes == (64 * 256 * W * 4 if donate else 0)
+    assert "pool.set_rows" in compiled.as_text()
 
 
 def _mesh_args(slice_mesh, n_slices, n_rows, ids_shape):
@@ -302,17 +315,17 @@ def test_mesh_engine_path_compiles_for_four_chips(path, topo, slice_mesh):
     else:
         # A pool miss block uploaded sharded like the pool (MeshEngine.
         # _match_block): the scatter stays local to each device.
-        lowered = jax.jit(lambda m, slots, block: m.at[:, slots].set(block)).lower(
-            rm, on((None,), (256,), "int32"), rm)
+        lowered = sharded._sharded_set_rows_kernel(slice_mesh, "slice", 4).lower(
+            rm, on((None,), (8,), "int32"), on(("slice", None, None, None), (64, 8, T, 128)))
         text = lowered.compile().as_text()
-        assert "all-gather" not in text and "all-to-all" not in text
+        assert "all-gather" not in text and "all-to-all" not in text and "all-reduce" not in text
     mem = lowered.compile().memory_analysis()
     per_device = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     assert per_device < HBM_BYTES // 2
 
 
 @pytest.mark.parametrize("program", [
-    "pair_gram", "set_plane_cells_1", "set_plane_cells_8",
+    "pair_gram", "set_plane_cells_1", "set_plane_cells_8", "set_rows_8", "set_rows_8_donated",
     "gram_update_b256", "gram_update_b1024"])
 def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, monkeypatch):
     """What the four-chip dashboard deployment (256 slices x 256 slots: a
@@ -335,6 +348,11 @@ def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, m
     rm = on(("slice", None, None, None), (256, 256, T, 128))
     if program == "pair_gram":
         lowered = sharded._sharded_pair_gram_kernel(slice_mesh, "slice", 4).lower(rm)
+    elif program.startswith("set_rows"):   # the fill's paging: a miss's chunk of 8 rows
+        k = 8
+        lowered = sharded._sharded_set_rows_kernel(
+            slice_mesh, "slice", 4, program.endswith("donated")).lower(
+            rm, on((None,), (k,), "int32"), on(("slice", None, None, None), (256, k, T, 128)))
     elif program.startswith("set_plane_cells"):
         c = int(program.rsplit("_", 1)[1])
         lowered = sharded._sharded_set_plane_cells_kernel(slice_mesh, "slice", 4).lower(
@@ -349,11 +367,13 @@ def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, m
     assert "all-gather" not in text and "all-to-all" not in text
     mem = compiled.memory_analysis()
     shard = 256 * 256 * W * 4 // 4
-    assert mem.argument_size_in_bytes < shard + 64 * 2**20
+    block = 8 * 64 * W * 4 if program.startswith("set_rows") else 0   # a device's slices of 8 rows
+    assert mem.argument_size_in_bytes < shard + block + 64 * 2**20
     assert mem.temp_size_in_bytes < 2**30
-    if program.startswith("set_plane_cells"):
+    if program.startswith("set_"):
         assert mem.output_size_in_bytes == shard and "all-reduce" not in text
-        assert "pool.set_plane_rows" in text
+        assert mem.alias_size_in_bytes == (shard if program.endswith("donated") else 0)
+        assert ("pool.set_rows" if program.startswith("set_rows") else "pool.set_plane_rows") in text
     else:
         assert "all-reduce" in text
         assert ("tpu_custom_call" in text) == program.startswith("gram_update")
