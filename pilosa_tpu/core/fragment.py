@@ -151,6 +151,67 @@ def _batch_intersection_counts(rows: np.ndarray, src: np.ndarray) -> np.ndarray:
     return bw.np_popcount(rows & src).reshape(rows.shape[0], -1).sum(axis=1)
 
 
+class RowPieces:
+    """What ``Fragment.walk_rows`` found of a block of planes (``W`` words
+    each), fragment after fragment, and the one numpy pass that turns it
+    into words.
+
+    The block holds ``row_ids`` (a negative id is no row: a zero plane)
+    over some slices; row ``k``'s plane in a fragment's part of the block
+    is that fragment's first plane plus ``k * stride`` (1 where a slice's
+    rows lie together: slice-major; the slice count where a row's slices
+    do: row-major).  Array containers' values are kept as they are
+    stored (copied under the fragment's lock, one array a fragment);
+    ``words()`` turns all of them at once into the block's words that are
+    not zero - ``(word, bits)``: an index into the flattened block, and
+    the ``uint32`` it holds, equal words OR-ed - so a block built from 64
+    fragments costs one pass, not 64.  Bitmap containers and pending bulk
+    overlays are dense pieces (``dense``: ``(first word, words)`` copies);
+    a block that has any is not its word list alone.  The two consumers:
+    ``fill`` writes words and dense pieces into a zeroed block (what
+    ``row_dense`` would give, plane for plane); a pool miss with no dense
+    piece ships ``words()`` itself."""
+
+    __slots__ = ("rows", "off", "at", "lens", "vals", "dense", "_words")
+
+    def __init__(self, row_ids: Sequence[int], stride: int = 1):
+        per_row = SLICE_WIDTH >> 16  # containers a row spans
+        # (first word of the row's plane, row id), the rows that exist
+        self.rows = [(k * stride * _WORDS, r) for k, r in enumerate(row_ids) if r >= 0]
+        # container key -> its first word, less the fragment's first plane's
+        self.off = {
+            r * per_row + j: w0 + j * 2048 for w0, r in self.rows for j in range(per_row)
+        }
+        self.at: list[int] = []    # per array container: its first word in the flattened block
+        self.lens: list[int] = []  # ... and how many values it holds
+        self.vals: list[np.ndarray] = []  # the values, one concatenated array a fragment
+        self.dense: list[tuple[int, np.ndarray]] = []
+        self._words = None
+
+    def words(self) -> tuple[np.ndarray, np.ndarray]:
+        if self._words is None:
+            if not self.vals:
+                self._words = (np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.uint32))
+                return self._words
+            v = np.concatenate(self.vals)
+            word = np.repeat(np.asarray(self.at, dtype=np.int64), self.lens) + (v >> 5)
+            bit = np.uint32(1) << (v & np.uint32(31))
+            # Values ascend inside a container and no two containers share
+            # a word, so equal words are neighbours: OR each run.
+            first = np.flatnonzero(np.concatenate(([True], word[1:] != word[:-1])))
+            self._words = (word[first], np.bitwise_or.reduceat(bit, first))
+        return self._words
+
+    def fill(self, out: np.ndarray) -> None:
+        """Into ``out``: the block, uint32, contiguous, zeroed by the caller."""
+        flat = out.reshape(-1)
+        for w0, piece in self.dense:
+            flat[w0 : w0 + len(piece)] |= piece
+        word, bits = self.words()
+        if len(word):
+            flat[word] |= bits  # beside an overlay's bits, where there is one
+
+
 @lockcheck.guarded_class
 class Fragment:
     """One slice of one view's row-major bitmap matrix."""
@@ -1165,48 +1226,39 @@ class Fragment:
                 self._row_cache.popitem(last=False)
             return words
 
-    def rows_dense_into(self, row_ids: Sequence[int], out: np.ndarray) -> None:
-        """``row_dense`` of every row of ``row_ids`` into ``out``
-        (uint32[len(row_ids), W], zeroed by the caller; a strided view of
-        a larger block will do), in one pass over the rows' containers: a
-        row's 16 container keys are probed, the array containers' set
-        bits of all rows go into ``out`` as words in one numpy scatter,
-        a bitmap container is copied, the pending bulk overlay is merged.
-        A negative row id is no row: its plane stays zero.  What a pool
-        fetch runs (a block of many rows, most of them a few bits a
-        slice): no dense plane per row is built on the way and the row
-        cache is neither read nor filled."""
-        per_row = SLICE_WIDTH >> 16  # containers a row spans
+    def walk_rows(self, pieces: RowPieces, plane0: int) -> None:
+        """Note in ``pieces`` what this slice holds of the block's rows,
+        its part of the block beginning at plane ``plane0``: the rows'
+        container keys (16 a row, one set for the whole block) are met
+        with this fragment's in one set intersection, an array
+        container's values are noted (copied: a later write may move them
+        where they lie), a bitmap container and a pending bulk overlay
+        are copied as dense pieces.  The walk of a pool fetch (a block of
+        many rows, most of them a few bits a slice): no numpy pass runs
+        here (``RowPieces.words`` runs one for the whole block), no dense
+        plane is built and the row cache is neither read nor filled."""
+        base = plane0 * _WORDS
         with self._mu:
             self._assert_open()
-            get = self.storage.containers.get
-            at, lows = [], []  # per array container: first word in out.ravel() terms, values
-            for k, row_id in enumerate(row_ids):
-                if row_id < 0:
-                    continue
-                key0 = row_id * per_row
-                for j, c in enumerate(map(get, range(key0, key0 + per_row))):
-                    if c is None:
-                        continue
-                    if c.bitmap is not None:
-                        out[k, j * 2048 : (j + 1) * 2048] = c.bitmap.view(np.uint32)[: 2 * roaring.BITMAP_N]
-                    elif len(c.array):
-                        at.append(k * _WORDS + j * 2048)
-                        lows.append(c.array)
-                ov = self._bulk_planes.get(row_id)
-                if ov is not None:
-                    out[k] |= ov
-            if not lows:
-                return
-            v = np.concatenate(lows)
-            word = np.repeat(np.asarray(at, dtype=np.int64), [len(a) for a in lows]) + (v >> 5)
-            bit = np.uint32(1) << (v & np.uint32(31))
-            # Values ascend inside a container and containers were taken
-            # in block order, so equal words are neighbours: OR each run.
-            first = np.flatnonzero(np.concatenate(([True], word[1:] != word[:-1])))
-            word, bit = word[first], np.bitwise_or.reduceat(bit, first)
-            k_idx, w_idx = np.divmod(word, _WORDS)
-            out[k_idx, w_idx] |= bit  # beside an overlay's bits, where there is one
+            cs, off = self.storage.containers, pieces.off
+            at, lens, lows = pieces.at, pieces.lens, []
+            for key in cs.keys() & off.keys():
+                c = cs[key]
+                if c.bitmap is not None:
+                    pieces.dense.append(
+                        (base + off[key], c.bitmap.view(np.uint32)[: 2 * roaring.BITMAP_N].copy())
+                    )
+                elif len(c.array):
+                    at.append(base + off[key])
+                    lens.append(len(c.array))
+                    lows.append(c.array)
+            if self._bulk_planes:
+                for w0, row_id in pieces.rows:
+                    ov = self._bulk_planes.get(row_id)
+                    if ov is not None:
+                        pieces.dense.append((base + w0, ov.copy()))
+            if lows:
+                pieces.vals.append(np.concatenate(lows))
 
     def row_device(self, row_id: int, engine):
         """Dense row as an ENGINE array, cached device-side.

@@ -88,6 +88,20 @@ def _padded_batch(idx) -> np.ndarray:
     return np.concatenate([idx, np.broadcast_to(idx[:1], (pad,) + idx.shape[1:])])
 
 
+def _padded_words(cells, values) -> tuple[np.ndarray, np.ndarray]:
+    """A sparse miss's words as one scatter's arguments: ``cells``
+    int32[N, 3] of (slice, slot, word) and ``values`` uint32[N], N the
+    ``_pow4`` bucket of their count (one program a bucket; a padded word
+    costs its 16 bytes); the tail is (-1, -1, -1) over 0, which the
+    scatter drops."""
+    n = len(values)
+    idx = np.full((_pow4(n), 3), -1, dtype=np.int32)
+    idx[:n] = cells
+    vals = np.zeros(len(idx), dtype=np.uint32)
+    vals[:n] = values
+    return idx, vals
+
+
 def _padded_cells(groups):
     """A repair's written cells as one scatter's arguments: ``cells`` (the
     (slice, slot) pairs in group order), ``idx`` int32[C, 2] and ``planes``
@@ -317,7 +331,8 @@ class NumpyEngine:
 
     def set_rows_at(self, matrix, slots, block, donate: bool = False):
         """Write rows into ARBITRARY slots (row-pool paging: a miss's
-        chunk scatters into freed slots in one call): into a copy, or
+        dense chunk scatters into freed slots in one call; a sparse one
+        goes through ``set_words_at``): into a copy, or
         with ``donate`` into ``matrix`` itself (the caller holds the only
         reference: the copy an earlier chunk of the same miss made).  The
         pool pads a chunk only for engines that compile
@@ -326,7 +341,19 @@ class NumpyEngine:
         out[:, list(slots), :] = block
         return out
 
-    def warm_set_rows(self, matrix, max_rows: int, row_major: bool = False) -> None:
+    def set_words_at(self, matrix, slots, cells, values, donate: bool = False):
+        """The sparse form of ``set_rows_at``: the rows in ``slots`` become
+        zero but for ``values[c]`` at word ``cells[c, 2]`` of the (slice
+        ``cells[c, 0]``, slot ``cells[c, 1]``) plane.  Unpadded, as this
+        engine's blocks are."""
+        out = matrix if donate else matrix.copy()
+        out[:, list(slots), :] = 0
+        out[cells[:, 0], cells[:, 1], cells[:, 2]] = values
+        return out
+
+    def warm_set_rows(
+        self, matrix, max_rows: int, row_major: bool = False, max_words: int = 0
+    ) -> None:
         """Nothing compiles here."""
 
     def grow_rows(self, matrix, n: int):
@@ -732,8 +759,10 @@ class JaxEngine:
         )
 
     def set_rows_at(self, matrix, slots, block, donate: bool = False):
-        """Scatter a miss's chunk into arbitrary pool slots: only the new
-        rows cross host->device (the upload is enqueued, not waited for);
+        """Scatter a miss's DENSE chunk (one with a bitmap container, a
+        bulk overlay or too many words; every other goes through
+        ``set_words_at``) into arbitrary pool slots: the chunk's planes
+        cross host->device whole (the upload is enqueued, not waited for);
         the scatter itself is HBM->HBM, into a copy of the pool (a reader
         may hold ``matrix``) or, with ``donate``, into ``matrix`` itself
         (the copy an earlier chunk of the same miss made: the caller
@@ -758,13 +787,51 @@ class JaxEngine:
             self._match_block(matrix, block), axis=axis,
         )
 
-    def warm_set_rows(self, matrix, max_rows: int, row_major: bool = False) -> None:
-        """Compile ``set_rows_at``'s programs for every bucket a miss's
-        chunk on this pool can pad to (1 .. ``max_rows``), copying and
+    def set_words_at(self, matrix, slots, cells, values, donate: bool = False):
+        """The sparse form of ``set_rows_at``: a miss's chunk crosses
+        host->device as its words that are not zero - ``cells`` int32[N,
+        3] of (slice, slot, word) and ``values`` uint32[N], padded here to
+        their ``_pow4`` bucket, 16 bytes a word - and one program
+        (``ops.bitwise.set_row_words``, one a word bucket and form)
+        zeroes the rows of ``slots`` (a fixed count, -1 dropped) and
+        writes the words into them, HBM->HBM: into a copy of the pool,
+        or with ``donate`` into ``matrix`` itself, as ``set_rows_at``."""
+        return self._set_words(matrix, slots, cells, values, 1, donate)
+
+    def set_words_at_rm(self, matrix, slots, cells, values, donate: bool = False):
+        """``set_words_at`` on a row-major pool ``[cap, S, ...]``."""
+        return self._set_words(matrix, slots, cells, values, 0, donate)
+
+    def _set_words(self, matrix, slots, cells, values, axis: int, donate: bool):
+        cells, values = _padded_words(cells, values)
+        self._note_upload(cells.nbytes + values.nbytes)
+        return self._scatter_words(
+            matrix, np.asarray(slots, dtype=np.int32), cells, values, axis, donate
+        )
+
+    def _scatter_words(self, matrix, slots, cells, values, axis: int, donate: bool):
+        if not hasattr(self, "_set_words_jit"):
+            import jax
+
+            from pilosa_tpu.ops.bitwise import set_row_words
+
+            self._set_words_jit = {
+                d: jax.jit(set_row_words, static_argnames="axis", donate_argnums=(0,) if d else ())
+                for d in (False, True)
+            }
+        return self._set_words_jit[donate](matrix, slots, cells, values, axis=axis)
+
+    def warm_set_rows(
+        self, matrix, max_rows: int, row_major: bool = False, max_words: int = 0
+    ) -> None:
+        """Compile a miss's scatter programs for this pool, copying and
         donating, by running each once with every slot dropped (nothing
-        is written).  A pool calls this when it first evicts: from then
-        on it pages for as long as it lives, and no later miss count
-        compiles."""
+        is written): ``set_rows_at``'s for every bucket a chunk can pad
+        to (1 .. ``max_rows``) and, where the pool pages sparse
+        (``max_words``), ``set_words_at``'s for every word bucket (1 ..
+        ``max_words``) at ``max_rows`` slots.  A pool calls this when it
+        first evicts: from then on it pages for as long as it lives, and
+        no later miss compiles."""
         axis, k = (0 if row_major else 1), 1
         shape = list(matrix.shape[: 2]) + [int(np.prod(matrix.shape[2:]))]
         while k <= min(max_rows, matrix.shape[axis]):
@@ -772,8 +839,17 @@ class JaxEngine:
             drop = np.full(k, -1, dtype=np.int32)
             block = np.zeros(shape, dtype=np.uint32)
             copy = self._set_rows(matrix, drop, block, axis, False)
+            # done before the block goes up again: one upload of it is alive
+            # beside the pool and its copy, not two (the pool's peak memory)
+            copy.block_until_ready()
             self._set_rows(copy, drop, block, axis, True).block_until_ready()
             k *= 2
+        drop, n = np.full(max_rows, -1, dtype=np.int32), 1
+        while n <= max_words:
+            cells, values = np.full((n, 3), -1, dtype=np.int32), np.zeros(n, dtype=np.uint32)
+            copy = self._set_words(matrix, drop, cells, values, axis, False)
+            self._set_words(copy, drop, cells, values, axis, True).block_until_ready()
+            n *= 4
 
     def grow_rows(self, matrix, n: int):
         """Append n zero capacity rows DEVICE-side (no host transfer)."""
@@ -1187,6 +1263,20 @@ class MeshEngine(JaxEngine):
         return sharded_set_rows(
             self.mesh, self._shard_stack(matrix), np.asarray(slots, dtype=np.int32),
             self._match_block(matrix, block), donate,
+        )
+
+    def _scatter_words(self, matrix, slots, cells, values, axis: int, donate: bool):
+        if axis != 1 or self.slice_axis_devices(matrix.shape[0]) == 1:
+            sharding = matrix.sharding  # read before a donation takes the array
+            return self._jax.device_put(
+                super()._scatter_words(matrix, slots, cells, values, axis, donate), sharding
+            )
+        from pilosa_tpu.parallel.sharded import sharded_set_row_words
+
+        # Cells and values go to every device; each keeps the words of its
+        # own slices and drops the rest.
+        return sharded_set_row_words(
+            self.mesh, self._shard_stack(matrix), slots, cells, values, donate
         )
 
     def grow_rows(self, matrix, n):

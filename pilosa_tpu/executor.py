@@ -35,7 +35,7 @@ from pilosa_tpu import pql
 from pilosa_tpu.analysis import lockcheck
 from pilosa_tpu import qcache as qcache_mod
 from pilosa_tpu.core import cache as cache_mod
-from pilosa_tpu.core.fragment import TopOptions
+from pilosa_tpu.core.fragment import RowPieces, TopOptions
 from pilosa_tpu.core import timequantum as tq
 from pilosa_tpu.core.view import VIEW_INVERSE, VIEW_STANDARD
 from pilosa_tpu.engine import new_engine
@@ -2414,6 +2414,23 @@ class Executor:
             ),
         )
 
+    def _walk_block(self, index, frame, view, chunk_slices, rows, row_major=False):
+        """What storage holds of a block of ``rows`` x ``chunk_slices``
+        (``core.fragment.RowPieces``): one walk a fragment over the rows'
+        containers (``Fragment.walk_rows``, under that fragment's lock),
+        planes numbered as the block lays them out - slice-major
+        ``[len(chunk_slices), len(rows)]``, or ``[len(rows),
+        len(chunk_slices)]`` with ``row_major``.  Its two consumers:
+        ``_densify_block`` (the dense block) and a pool miss's sparse
+        upload (``rowpool._page_in``: the word list itself)."""
+        n_s, n_r = len(chunk_slices), len(rows)
+        pieces = RowPieces(rows, stride=n_s if row_major else 1)
+        for bi, s in enumerate(chunk_slices):
+            f = self.holder.fragment(index, frame, view, s)
+            if f is not None:
+                f.walk_rows(pieces, bi if row_major else bi * n_r)
+        return pieces
+
     def _densify_block(
         self, index, frame, view, chunk_slices, rows, row_major=False
     ) -> np.ndarray:
@@ -2421,18 +2438,15 @@ class Executor:
         (slice-major — pool fetches and transient streaming matrices), or
         [len(rows), len(chunk_slices), W] with ``row_major=True`` (the
         streaming gather lane: each row's slices contiguous for one-descriptor
-        DMAs).  Filled directly in target order — no transpose copy — by
-        one pass per fragment over the rows' containers
-        (``Fragment.rows_dense_into``); a negative row id (the tail of a
-        pool miss's bucket) is a zero plane."""
+        DMAs).  Filled directly in target order — no transpose copy — from
+        one walk per fragment over the rows' containers and one numpy pass
+        over all of them (``_walk_block``, ``RowPieces.fill``); a negative
+        row id (the tail of a pool miss's bucket) is a zero plane."""
         if row_major:
             block = np.zeros((len(rows), len(chunk_slices), _WORDS), dtype=np.uint32)
         else:
             block = np.zeros((len(chunk_slices), len(rows), _WORDS), dtype=np.uint32)
-        for bi, s in enumerate(chunk_slices):
-            f = self.holder.fragment(index, frame, view, s)
-            if f is not None:
-                f.rows_dense_into(rows, block[:, bi] if row_major else block[bi])
+        self._walk_block(index, frame, view, chunk_slices, rows, row_major).fill(block)
         return block
 
     def _transient_matrix(
@@ -2645,9 +2659,17 @@ class Executor:
                         [slc[si] for si in slice_idxs], row_ids, row_major=_rm,
                     )
 
+                def fetch_pieces(row_ids, slice_idxs, _key=key, _rm=row_major):
+                    idx_n, frame_n, view_n, slc, _lane = _key
+                    return self._walk_block(
+                        idx_n, frame_n, view_n,
+                        [slc[si] for si in slice_idxs], row_ids, row_major=_rm,
+                    )
+
                 pool = DeviceRowPool(
                     self.engine, len(slices), _WORDS, fetch, row_major=row_major,
                     stats=self.meter.stats if self.meter is not None else None,
+                    fetch_pieces=fetch_pieces,
                 )
                 self._matrix_cache[key] = pool
             self._matrix_cache.move_to_end(key)

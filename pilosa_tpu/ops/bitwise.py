@@ -385,26 +385,66 @@ def set_plane_cells(row_matrix, cells, planes, first_slice=0):
         return row_matrix.at[si, cells[:, 1]].set(planes, mode="drop")
 
 
+def _write_slots(row_matrix, slots, axis: int, new_row):
+    """``row_matrix`` with ``new_row(i, old)`` written into slot
+    ``slots[i]`` along ``axis``, one slot after another into one copy of
+    the pool; a negative slot is dropped (it puts slot 0's own contents
+    back)."""
+
+    def write(i, m):
+        slot = slots[i]
+        at = jnp.maximum(slot, 0)
+        old = lax.dynamic_slice_in_dim(m, at, 1, axis)
+        row = jnp.where(slot >= 0, new_row(i, old), old)
+        return lax.dynamic_update_slice_in_dim(m, row, at, axis)
+
+    return lax.fori_loop(0, slots.shape[0], write, row_matrix)
+
+
 def set_rows(row_matrix, slots, block, axis: int = 1):
     """A pool matrix with ``block``'s rows written into ``slots``
     (int32[k]) along ``axis`` (1: slice-major ``[S, cap, ...]`` and
-    ``block`` ``[S, k, ...]``; 0: row-major): a pool miss's scatter (a new
-    array; jitted by the engines, one program a ``k``).  A negative slot -
-    the tail of a miss's bucket - is dropped.  One row after another into
-    one copy of the pool: XLA's own scatter kept a second copy of a block
-    of 64 rows or more (found by compiling for a described v5e).  Its ops
-    carry ``pool.set_rows`` in a device trace."""
+    ``block`` ``[S, k, ...]``; 0: row-major): the dense form of a pool
+    miss's scatter (a new array; jitted by the engines, one program a
+    ``k``).  A negative slot - the tail of a miss's bucket - is dropped.
+    One row after another into one copy of the pool: XLA's own scatter
+    kept a second copy of a block of 64 rows or more (found by compiling
+    for a described v5e).  Its ops carry ``pool.set_rows`` in a device
+    trace."""
     with jax.named_scope("pool.set_rows"):
+        return _write_slots(
+            row_matrix, slots, axis, lambda i, _: lax.dynamic_slice_in_dim(block, i, 1, axis)
+        )
 
-        def write(i, m):
-            slot = slots[i]
-            at = jnp.maximum(slot, 0)
-            row = lax.dynamic_slice_in_dim(block, i, 1, axis)
-            # a dropped row puts slot 0's own contents back
-            row = jnp.where(slot >= 0, row, lax.dynamic_slice_in_dim(m, at, 1, axis))
-            return lax.dynamic_update_slice_in_dim(m, row, at, axis)
 
-        return lax.fori_loop(0, slots.shape[0], write, row_matrix)
+def set_row_words(row_matrix, slots, cells, values, axis: int = 1, first_slice=0):
+    """A pool matrix with the rows of ``slots`` (int32[k], a negative one
+    dropped) made of ``values`` alone: the sparse form of a pool miss's
+    scatter.  Each slot's row is zeroed first (the row it held before
+    must go), then ``values[c]`` (uint32) is written at word
+    ``cells[c, 2]`` of the (slice ``cells[c, 0]``, slot ``cells[c, 1]``)
+    plane - no two cells name one word (the host has OR-ed equal words,
+    so the scatter may take them in any order).  ``axis`` as in
+    ``set_rows``: 1 for a slice-major pool ``[S, cap, ...]``, 0 for a
+    row-major one ``[cap, S, ...]`` (the cell stays (slice, slot, word)).
+    A cell whose slice, less ``first_slice``, is not one of the matrix's
+    is dropped: a word bucket's (-1, -1, -1) tail, and on a mesh the
+    cells of the other devices' shards.  A new array, or the caller's own
+    where it is donated; jitted by the engines, one program a word
+    bucket.  Its ops carry ``pool.set_rows`` in a device trace."""
+    with jax.named_scope("pool.set_rows"):
+        m = _write_slots(row_matrix, slots, axis, lambda _, old: jnp.zeros_like(old))
+        n = m.shape[1 - axis]
+        si = cells[:, 0] - first_slice
+        si = jnp.where((cells[:, 0] >= 0) & (si >= 0) & (si < n), si, n)  # past the end: dropped
+        lead = (si, cells[:, 1]) if axis == 1 else (cells[:, 1], si)
+        w = cells[:, 2]
+        if m.ndim == 4:  # tiled: a plane is [W / lanes, lanes]
+            lanes = m.shape[3]
+            word = (w // lanes, w % lanes)
+        else:
+            word = (w,)
+        return m.at[lead + word].set(values, mode="drop", unique_indices=True)
 
 
 def repair_planes(row_matrix, cells, planes, n: int):

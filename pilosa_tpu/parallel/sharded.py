@@ -401,6 +401,47 @@ def sharded_set_rows(mesh: SliceMesh, row_matrix, slots, block, donate: bool = F
 
 
 @functools.lru_cache(maxsize=None)
+def _sharded_set_row_words_kernel(mesh_obj, axis: str, rm_ndim: int, donate: bool = False):
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from pilosa_tpu.ops.bitwise import set_row_words
+
+    rest = [None] * (rm_ndim - 1)
+
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh_obj,
+        in_specs=(P(axis, *rest), P(None), P(None, None), P(None)),
+        out_specs=P(axis, *rest),
+        check_vma=False,
+    )
+    def set_row_words_shards(rm_shard, slots, cells, values):
+        return set_row_words(
+            rm_shard, slots, cells, values,
+            first_slice=lax.axis_index(axis) * rm_shard.shape[0],
+        )
+
+    return jax.jit(set_row_words_shards, donate_argnums=(0,) if donate else ())
+
+
+def sharded_set_row_words(
+    mesh: SliceMesh, row_matrix, slots, cells, values, donate: bool = False
+):
+    """``ops.bitwise.set_row_words`` on a slice-sharded pool matrix: the
+    sparse form of a pool miss.  ``slots``, ``cells`` (int32[N, 3] of
+    (slice, slot, word)) and ``values`` are replicated; every device
+    zeroes the slots' rows in a copy of its own shard (with ``donate`` in
+    the shard itself), writes the words whose slice it holds and drops
+    the rest, the way ``sharded_set_plane_cells`` does - no communication,
+    the result born with the matrix's sharding."""
+    _require_divisible(row_matrix.shape[0], mesh.n_devices)
+    kernel = _sharded_set_row_words_kernel(mesh.mesh, mesh.AXIS, row_matrix.ndim, donate)
+    return kernel(row_matrix, slots, cells, values)
+
+
+@functools.lru_cache(maxsize=None)
 def _sharded_repair_planes_kernel(mesh_obj, axis: str, rm_ndim: int, n: int):
     import jax
     import jax.numpy as jnp

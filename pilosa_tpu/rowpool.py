@@ -10,9 +10,12 @@ cache.go:126-275) and rows page between mmap and memory on demand
 
 This module is the TPU-native replacement: a fixed-capacity slot pool
 ``uint32[n_slices, capacity, W]`` in device memory.  Rows page in on
-demand (host roaring -> dense -> one scatter per miss batch), LRU rows
-page out when the pool is full, and the capacity itself grows by
-power-of-two doubling up to an HBM budget.  Query kernels index rows by
+demand - a miss goes to the device, chunk by chunk, as its rows' words
+that are not zero (host roaring -> a word list -> one scatter a chunk:
+the planes are built in HBM), or as a dense host block where a row has
+a bitmap container, a pending bulk overlay, or too many words
+(``_page_in``) - LRU rows page out when the pool is full, and the
+capacity itself grows by power-of-two doubling up to an HBM budget.  Query kernels index rows by
 SLOT id — the same gather kernels as before, they never cared whether
 slot assignment was dense or paged.
 
@@ -42,11 +45,22 @@ from typing import Callable, Optional, Sequence
 import numpy as np
 
 POOL_BYTES_PER_DEVICE = 2 * 1024 * 1024 * 1024
-# A miss pages its rows in chunks of this many: the host builds chunk k+1
-# while chunk k's upload (64 MiB at 64 slices) is in flight, the block a
-# chunk is padded to wastes at most 3 rows, and a pool's scatter programs
-# are the buckets up to here (1, 2, 4, 8), copying and donating.
+# A miss pages its rows in chunks of this many: the host reads chunk k+1
+# from storage while chunk k's upload and scatter are in flight; a sparse
+# chunk's program takes this many slots, whatever its rows; a dense
+# chunk's block (64 MiB at 64 slices) is padded to at most 3 rows more
+# than it holds, and its programs are the buckets up to here (1, 2, 4,
+# 8).  Copying and donating forms of each.
 MISS_CHUNK_ROWS = 8
+# The most words a chunk may ship sparse (the largest word bucket, a power
+# of four: ``engine._pow4``); a chunk with more goes dense.  Where the two
+# forms cost the device and PCIe the same on a TPU v5e (PERF.md 7): the
+# sparse scatter into a 2 GiB pool takes 0.3 ms and 0.097 us a word (6.4 ms
+# at 65,536), a dense chunk of 8 rows at 64 slices 6.4 ms to upload and
+# write (0.096 ns a byte); the host's side only widens the gap (no 64 MiB
+# of zeros to make and fill).  Below one row of a frame as dense as
+# CENSUS1881's (78,700 set words over 64 slices): such rows page dense.
+MISS_WORDS_MAX = 65536
 
 
 def pool_bytes(engine=None, n_slices: int = 0) -> tuple[int, int]:
@@ -59,8 +73,9 @@ def pool_bytes(engine=None, n_slices: int = 0) -> tuple[int, int]:
     benches and tests tune it).  Total pool memory is bounded by this times
     the executor's matrix-cache entry count; transient peaks reach 2x one
     pool plus a miss's chunks in flight during a miss (the pool is copied
-    once a miss, not donated: old + new array alive, and the blocks of
-    ``MISS_CHUNK_ROWS`` rows being uploaded)."""
+    once a miss, not donated: old + new array alive, and what the chunks
+    being uploaded hold: 16 bytes a word of a sparse chunk, a block of
+    ``MISS_CHUNK_ROWS`` rows of planes for a dense one)."""
     devices = engine.slice_axis_devices(n_slices) if engine is not None else 1
     # analysis-ok: lockstep-determinism: deployment config, launcher sets identical env on every rank
     env = os.environ.get("PILOSA_TPU_POOL_BYTES")
@@ -88,7 +103,10 @@ class DeviceRowPool:
     """One frame-view's paged row working set over a fixed slice batch.
 
     ``fetch(row_ids, slice_idxs) -> uint32[len(slice_idxs), len(row_ids), W]``
-    pulls dense rows from host storage (fragment ``row_dense``).
+    pulls dense rows from host storage (fragment ``row_dense``): the
+    repair's, the blind refresh's and a dense miss chunk's block.
+    ``fetch_pieces``, where given, is the walk under it, from which a
+    miss takes the rows' set words alone (``_page_in``).
     """
 
     def __init__(
@@ -100,12 +118,19 @@ class DeviceRowPool:
         cap_max: int = 0,
         row_major: bool = False,
         stats=None,
+        fetch_pieces: Optional[Callable] = None,
     ):
         self.engine = engine
         self.stats = stats if stats is not None else NOP_STATS
         self.n_slices = n_slices
         self.words = words
         self.fetch = fetch
+        # ``fetch_pieces(row_ids, slice_idxs)``: the walk under ``fetch``
+        # without the block (words(), dense, fill(): what
+        # ``core.fragment.RowPieces`` has), planes numbered as ``fetch``'s
+        # block lays them out.  With it a miss pages sparse where the
+        # chunk allows (``_page_in``); without it every chunk is dense.
+        self.fetch_pieces = fetch_pieces
         # Row-major pools store [cap, n_slices, W] (tiled) so the gather
         # regime's kernels get one contiguous DMA descriptor per operand
         # row; ``fetch`` must then return [len(row_ids), len(slice_idxs),
@@ -370,38 +395,81 @@ class DeviceRowPool:
         return True
 
     def _page_in(self, missing: list[int], slots: list[int], span=None):
-        """The pool's array with ``missing`` in ``slots``, and the rows
-        uploaded for it: chunk after chunk of ``MISS_CHUNK_ROWS``, each
-        built on the host (``fetch``; span ``pool.miss.fetch``) while the
-        one before uploads, then enqueued (``pool.miss.scatter``).  The
-        first chunk is scattered into a COPY of the pool (a reader may
-        hold the array), the others into that copy itself (donated: no
-        other reference exists).  An engine that compiles gets each block
-        in the power-of-two bucket of its rows (one scatter program a
-        bucket and form): row -1 is a zero plane, slot -1 is dropped."""
+        """The pool's array with ``missing`` in ``slots``, and what was
+        uploaded for it (rows, padding included; chunks that went sparse;
+        their words): chunk after chunk of ``MISS_CHUNK_ROWS``, each
+        read from storage on the host (span ``pool.miss.fetch``) while
+        the one before uploads, then enqueued (``pool.miss.scatter``).
+        The first chunk is scattered into a COPY of the pool (a reader
+        may hold the array), the others into that copy itself (donated:
+        no other reference exists).
+
+        A chunk is sparse or dense by what the walk of its rows found
+        (``fetch_pieces``; a pool built with ``fetch`` alone pages dense).
+        Sparse, when no row of it has a bitmap container or a pending
+        bulk overlay and its words that are not zero number at most
+        ``MISS_WORDS_MAX``: those words go to the device as (slice, slot,
+        word) cells and ``uint32`` values, and the engine zeroes the
+        chunk's slots and writes the words into them
+        (``engine.set_words_at``).  Dense otherwise: the same words
+        scattered into a zeroed host block, bitmap containers and
+        overlays copied in, uploaded whole (``engine.set_rows_at``).  An
+        engine that compiles gets a dense block in the power-of-two
+        bucket of its rows (row -1 a zero plane, slot -1 dropped) and a
+        sparse chunk's slots padded to ``MISS_CHUNK_ROWS``: one scatter
+        program a bucket and form."""
         static = getattr(self.engine, "wants_static_shapes", False)
-        set_rows = self.engine.set_rows_at_rm if self.row_major else self.engine.set_rows_at
+        engine = self.engine
+        set_rows = engine.set_rows_at_rm if self.row_major else engine.set_rows_at
+        if self.fetch_pieces is not None:
+            set_words = engine.set_words_at_rm if self.row_major else engine.set_words_at
         all_slices = list(range(self.n_slices))
-        matrix, uploaded = self.matrix, 0
+        matrix, uploaded, sparse, n_words = self.matrix, 0, 0, 0
         for at in range(0, len(missing), MISS_CHUNK_ROWS):
             rows = missing[at : at + MISS_CHUNK_ROWS]
+            into = slots[at : at + MISS_CHUNK_ROWS]
             tail = [-1] * (_pow2(len(rows)) - len(rows) if static else 0)
             bucket = len(rows) + len(tail)
             if bucket not in self.miss_buckets:
                 self.miss_buckets.add(bucket)
                 self.stats.count("rowpool.miss_buckets")
             sp = span.child("pool.miss.fetch") if span is not None else None
-            block = self.fetch(rows + tail, all_slices)  # layout per self.row_major
+            cells = None
+            if self.fetch_pieces is None:
+                block = self.fetch(rows + tail, all_slices)  # layout per self.row_major
+            else:
+                pieces = self.fetch_pieces(rows + tail, all_slices)
+                word, values = pieces.words()
+                if pieces.dense or len(word) > MISS_WORDS_MAX:
+                    shape = (bucket, self.n_slices) if self.row_major else (self.n_slices, bucket)
+                    block = np.zeros(shape + (self.words,), dtype=np.uint32)
+                    pieces.fill(block)
+                else:
+                    plane, w = np.divmod(word, self.words)
+                    if self.row_major:
+                        k, si = np.divmod(plane, self.n_slices)
+                    else:
+                        si, k = np.divmod(plane, bucket)
+                    cells = np.stack(
+                        [si, np.asarray(into, dtype=np.int64)[k], w], axis=1
+                    ).astype(np.int32)
             if sp is not None:
                 sp.finish()
                 sp = span.child("pool.miss.scatter")
-            matrix = set_rows(
-                matrix, slots[at : at + MISS_CHUNK_ROWS] + tail, block, donate=at > 0
-            )
+            if cells is None:
+                matrix = set_rows(matrix, into + tail, block, donate=at > 0)
+                self.stats.count("rowpool.miss_chunks_dense")
+            else:
+                pad = [-1] * (MISS_CHUNK_ROWS - len(into) if static else 0)
+                matrix = set_words(matrix, into + pad, cells, values, donate=at > 0)
+                sparse += 1
+                n_words += len(values)
+                self.stats.count("rowpool.miss_chunks_sparse")
+                self.stats.count("rowpool.miss_words", len(values))
             if sp is not None:
                 sp.finish()
             uploaded += bucket
-        return matrix, uploaded
+        return matrix, uploaded, sparse, n_words
 
     def _repair_spanned(self, stale: list[int], dirty_rows, span) -> bool:
         """``_repair_dirty`` under the request's ``pool.repair`` span."""
@@ -451,17 +519,23 @@ class DeviceRowPool:
 
         What a miss costs, under the lock: the LRU's victims leave
         (host bookkeeping), then the missing rows page in by chunks of
-        ``MISS_CHUNK_ROWS`` (``_page_in``): ``fetch`` builds a chunk's
-        block on the host (padded to the power-of-two bucket of its rows
-        for an engine that compiles; exact on numpy) - a span
-        ``pool.miss.fetch`` a chunk - and the engine enqueues its upload
-        and its scatter (``pool.miss.scatter``), into a COPY of the pool
-        for the first chunk (the pool is not donated, for a reader may
-        hold it) and into that copy for the rest.  ``pool.miss`` carries
-        ``rows``, ``bucket`` (rows uploaded, padding included),
-        ``evicted`` and ``upload_bytes``.  The first miss that evicts has
-        the engine compile every bucket's programs first
-        (``warm_set_rows``): a second or two, once a pool.
+        ``MISS_CHUNK_ROWS`` (``_page_in``): one walk a fragment over a
+        chunk's rows and one numpy pass give the chunk's words that are
+        not zero - a span ``pool.miss.fetch`` a chunk - and the engine
+        enqueues their upload (16 bytes a word) and the program that
+        zeroes the chunk's slots and writes the words into them
+        (``pool.miss.scatter``); a chunk the word list cannot hold (a
+        bitmap container, a bulk overlay, over ``MISS_WORDS_MAX`` words)
+        goes up as a dense block instead, padded to the power-of-two
+        bucket of its rows for an engine that compiles.  Either into a
+        COPY of the pool for the first chunk (the pool is not donated,
+        for a reader may hold it) and into that copy for the rest.
+        ``pool.miss`` carries ``rows``, ``bucket`` (rows paged, a dense
+        block's padding included), ``evicted``, ``sparse`` (chunks that
+        went sparse), ``words`` (what they shipped) and ``upload_bytes``
+        (bytes handed to the device: cells and values, or blocks).  The
+        first miss that evicts has the engine compile every program a
+        miss can meet first (``warm_set_rows``): once a pool.
         """
         want = list(dict.fromkeys(want))  # de-dup, keep order
         if len(want) > self.cap_max:
@@ -508,7 +582,10 @@ class DeviceRowPool:
                 if len(free) < len(missing) and not self.stat_evictions:
                     # From here on this pool pages for as long as it
                     # lives: no later miss count may compile.
-                    self.engine.warm_set_rows(self.matrix, MISS_CHUNK_ROWS, self.row_major)
+                    self.engine.warm_set_rows(
+                        self.matrix, MISS_CHUNK_ROWS, self.row_major,
+                        MISS_WORDS_MAX if self.fetch_pieces is not None else 0,
+                    )
                 up0, ev0 = self.engine.stat_upload_bytes, self.stat_evictions
                 if len(free) < len(missing):
                     want_set = set(want)
@@ -523,7 +600,7 @@ class DeviceRowPool:
                         free.append(s)
                         self.stat_evictions += 1
                 slots = free[: len(missing)]
-                self.matrix, bucket = self._page_in(missing, slots, sp)
+                self.matrix, bucket, sparse, words = self._page_in(missing, slots, sp)
                 for r, s in zip(missing, slots):
                     self.slot_of[r] = s
                     self.row_at[s] = r
@@ -533,6 +610,7 @@ class DeviceRowPool:
                 if sp is not None:
                     sp.finish().annotate(
                         rows=len(missing), evicted=evicted, bucket=bucket,
+                        sparse=sparse, words=words,
                         upload_bytes=self.engine.stat_upload_bytes - up0,
                     )
             for r in want:

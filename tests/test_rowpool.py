@@ -642,15 +642,24 @@ def test_paged_pair_bodies_equal_the_set_reference(tmp_path, monkeypatch, kind):
     h.close()
 
 
+def _word_buckets():
+    """The word buckets a sparse chunk can pad to: 1, 4, .. MISS_WORDS_MAX."""
+    from pilosa_tpu import rowpool
+
+    return [4 ** e for e in range(20) if 4 ** e <= rowpool.MISS_WORDS_MAX]
+
+
 @pytest.mark.parametrize("kind", ["jax", "mesh"])
 def test_miss_counts_compile_one_program_a_bucket(tmp_path, monkeypatch, kind):
-    """(b) Miss counts 1-9 in turn run the scatter at four block sizes (1,
-    2, 4, 8: a miss pages in chunks of 8, each padded to its power-of-two
-    bucket), copying for a miss's first chunk and donating for the rest:
-    eight programs, all compiled at the pool's first eviction, and a
-    second pass compiles none.  Counted where jax counts them: the jitted
-    scatter's own cache (which every jit of the function shares, so this
-    test has a slice count of its own)."""
+    """(b) Miss counts 1-9 in turn page sparse (a chunk of up to 8 rows as
+    its words, padded to their power-of-four bucket, the slots to 8),
+    copying for a miss's first chunk and donating for the rest.  Every
+    program a miss can meet is compiled at the pool's first eviction -
+    the sparse scatter at every word bucket and the dense one at four
+    block sizes (1, 2, 4, 8: a chunk with a bitmap container goes dense),
+    both forms of each - and a second pass compiles none.  Counted where
+    jax counts them: the jitted scatters' own caches (which every jit of
+    a function shares, so this test has a slice count of its own)."""
     n_slices = 8 if kind == "mesh" else 3
     h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, kind, n_slices)
     engine = ex.engine
@@ -661,26 +670,30 @@ def test_miss_counts_compile_one_program_a_bucket(tmp_path, monkeypatch, kind):
         if kind == "mesh":
             from pilosa_tpu.parallel import sharded
 
-            return sum(sharded._sharded_set_rows_kernel(
-                engine.mesh.mesh, engine.mesh.AXIS, 4, d)._cache_size() for d in (False, True))
-        from pilosa_tpu.ops.bitwise import set_rows
+            return sum(k(engine.mesh.mesh, engine.mesh.AXIS, 4, d)._cache_size()
+                       for k in (sharded._sharded_set_rows_kernel,
+                                 sharded._sharded_set_row_words_kernel) for d in (False, True))
+        from pilosa_tpu.ops.bitwise import set_row_words, set_rows
 
-        return sum(jax.jit(set_rows, static_argnames="axis", donate_argnums=d)._cache_size()
-                   for d in ((), (0,)))
+        return sum(jax.jit(f, static_argnames="axis", donate_argnums=d)._cache_size()
+                   for f in (set_rows, set_row_words) for d in ((), (0,)))
 
     before = programs()
     pool.acquire(list(range(SLOTS)), gens)           # the pool is full: every later miss evicts
     assert pool.cap == SLOTS and pool.stat_evictions == 0
     at_full = programs()
-    assert at_full - before == 2                     # the fill: a chunk of 8 copied, one donated
+    # the fill: a chunk of 8 rows copied, one donated, 66-192 words each: bucket 256
+    assert at_full - before == 2
     nxt = SLOTS
     for n in range(1, 10):
         pool.acquire(list(range(nxt, nxt + n)), gens)
         nxt += n
-    # The first eviction compiled the whole ladder; 8 and 8 were there already.
-    assert programs() - at_full == 6
+    # The first eviction compiled both ladders; the sparse 256 and 256 were there already.
+    assert programs() - at_full == 8 + 2 * len(_word_buckets()) - 2
     assert pool.miss_buckets == {1, 2, 4, 8}
     assert _counter(stats, "rowpool.miss_buckets") == 4
+    assert _counter(stats, "rowpool.miss_chunks_dense") == 0
+    assert _counter(stats, "rowpool.miss_chunks_sparse") == 2 + 10   # 9 misses, the last of two chunks
     before = programs()
     for n in range(1, 10):
         pool.acquire(list(range(nxt, nxt + n)), gens)
@@ -759,7 +772,10 @@ def test_a_sampled_miss_says_what_it_paged_and_gathered(tmp_path, monkeypatch, k
     spans = list(walk(root))
     miss = next(s for s in spans if s.name == "pool.miss")
     assert miss.tags["rows"] == 4 and miss.tags["bucket"] == 4 and miss.tags["evicted"] == 0
-    assert miss.tags["upload_bytes"] == (4 * 2 * PLANE_WORDS * 4 if kind == "jax" else 0)
+    # rows 1, 2, 3 and 50 hold 4 + 2 + 5 + 1 bits a slice, each in a word of its own
+    assert (miss.tags["sparse"], miss.tags["words"]) == (1, 24)
+    # bytes handed to the device: (slice, slot, word) and a value, 16 a word of the bucket of 64
+    assert miss.tags["upload_bytes"] == (64 * 16 if kind == "jax" else 0)
     assert [c.name for c in miss.children] == ["pool.miss.fetch", "pool.miss.scatter"]  # one chunk
     assert all(c.ms is not None for c in miss.children)
     gathers = [s for s in spans if s.name == "device" and s.tags.get("lane") == "gather"]
@@ -767,6 +783,129 @@ def test_a_sampled_miss_says_what_it_paged_and_gathered(tmp_path, monkeypatch, k
     assert {g.tags["layout"] for g in gathers} == {"slice_major"}
     # the engine that compiles runs a batch at its power-of-four bucket
     assert sorted(g.tags["bucket"] for g in gathers) == ([1, 4] if kind == "jax" else [1, 2])
+    h.close()
+
+
+def _paged_pool(ex, kind, n_slices, row_major):
+    return ex._pool_for("i", "stargazer", "standard", list(range(n_slices)),
+                        lane="rmgather" if row_major else "")
+
+
+def _assert_planes_equal_storage(h, pool, matrix, id_pos, rows):
+    m = np.asarray(matrix)
+    for s in range(pool.n_slices):
+        frag = h.fragment("i", "stargazer", "standard", s)
+        for r in rows:
+            plane = (m[id_pos[r], s] if pool.row_major else m[s, id_pos[r]]).reshape(-1)
+            assert (plane == frag.row_dense(r)).all(), (s, r)
+
+
+def _miss_spans(root):
+    return [c for c in root.children if c.name == "pool.miss"]
+
+
+@pytest.mark.parametrize("kind,row_major", [
+    ("numpy", False), ("jax", False), ("jax", True), ("mesh", False), ("mesh", True)],
+    ids=["numpy", "jax", "jax_row_major", "mesh", "mesh_row_major"])    # numpy: no row-major pool
+def test_rows_paged_in_sparse_equal_row_dense(tmp_path, monkeypatch, kind, row_major):
+    """(e) A miss ships its rows' set words, and what lands in the pool
+    is ``row_dense`` plane for plane: a full chunk, a chunk of three (the
+    slots' tail, the word bucket's tail), an absent row, and slots that
+    held other rows before (their bits must be gone).  The counters and
+    the span say that every chunk went sparse, how many words, and how
+    many bytes were handed to the device: 16 a word of the bucket."""
+    from pilosa_tpu.engine import _pow4
+
+    n_slices = 4 if kind == "mesh" else 2
+    h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, kind)
+    pool = _paged_pool(ex, kind, n_slices, row_major)
+    gens = (0,) * n_slices
+    root = Span("root")
+    id_pos, matrix, _ = pool.acquire(list(range(SLOTS)), gens, span=root)
+    _assert_planes_equal_storage(h, pool, matrix, id_pos, range(SLOTS))
+    held = dict(id_pos)
+    late = [20, 4000, 21]                                # 4000: no such row
+    id_pos, matrix, _ = pool.acquire(late, gens, span=root)
+    assert pool.stat_evictions == 3
+    assert {id_pos[r] for r in late} == {held[r] for r in (0, 1, 2)}     # rows 0-2 lay there
+    _assert_planes_equal_storage(h, pool, matrix, id_pos, late + list(range(3, SLOTS)))
+    empty = np.asarray(matrix)[id_pos[4000]] if row_major else np.asarray(matrix)[:, id_pos[4000]]
+    assert not empty.any()
+    words = [sum(len(cols_of[r]) for r in rows) for rows in (range(8), range(8, 16), (20, 21))]
+    assert _counter(stats, "rowpool.miss_chunks_sparse") == 3
+    assert _counter(stats, "rowpool.miss_chunks_dense") == 0
+    assert _counter(stats, "rowpool.miss_words") == sum(words)
+    fill, miss = _miss_spans(root)
+    assert (fill.tags["sparse"], fill.tags["words"]) == (2, words[0] + words[1])
+    assert (miss.tags["sparse"], miss.tags["words"], miss.tags["bucket"]) == (1, words[2], 3 if kind == "numpy" else 4)
+    compiled = kind != "numpy"                           # the numpy engine counts no upload
+    assert fill.tags["upload_bytes"] == (16 * (_pow4(words[0]) + _pow4(words[1])) if compiled else 0)
+    assert miss.tags["upload_bytes"] == (16 * _pow4(words[2]) if compiled else 0)
+    h.close()
+
+
+@pytest.mark.parametrize("why", ["bitmap_container", "bulk_overlay", "too_many_words"])
+@pytest.mark.parametrize("kind", ["numpy", "jax", "mesh"])
+def test_a_chunk_the_word_list_cannot_hold_goes_dense(tmp_path, monkeypatch, kind, why):
+    """(f) A chunk is sparse or dense by what the walk found: one row
+    with a bitmap container, or with a pending bulk overlay, or more
+    words than the largest bucket, and the chunk goes up as the dense
+    block; the chunk beside it stays sparse; both equal ``row_dense``."""
+    from pilosa_tpu import rowpool
+    from pilosa_tpu.engine import _pow4
+
+    n_slices = 4 if kind == "mesh" else 2
+    h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, kind)
+    frag0 = h.fragment("i", "stargazer", "standard", 0)
+    if why == "bitmap_container":
+        dense = np.random.default_rng(3).choice(1 << 16, size=5000, replace=False) + (1 << 17)
+        frag0.set_bits(np.full(len(dense), 5, dtype=np.uint64), dense.astype(np.uint64))
+    elif why == "bulk_overlay":
+        frag0.bulk_or_words(np.array([5], dtype=np.uint64), np.array([3]),
+                            np.array([3, 2048 + 7, PLANE_WORDS - 1]),
+                            np.array([0x80000001, 0xF0, 0x1], dtype=np.uint32))
+        assert 5 in frag0._bulk_planes
+    else:
+        first = sum(len(cols_of[r]) for r in range(8))
+        second = sum(len(cols_of[r]) for r in range(8, 16))
+        assert first < second
+        monkeypatch.setattr(rowpool, "MISS_WORDS_MAX", first)        # rows 0-7 just fit
+    pool = _paged_pool(ex, kind, n_slices, False)
+    root = Span("root")
+    id_pos, matrix, _ = pool.acquire(list(range(SLOTS)), (0,) * n_slices, span=root)
+    _assert_planes_equal_storage(h, pool, matrix, id_pos, range(SLOTS))
+    assert _counter(stats, "rowpool.miss_chunks_dense") == 1
+    assert _counter(stats, "rowpool.miss_chunks_sparse") == 1
+    sparse_rows = range(8, 16) if why != "too_many_words" else range(8)
+    (fill,) = _miss_spans(root)
+    assert (fill.tags["sparse"], fill.tags["words"]) == (1, sum(len(cols_of[r]) for r in sparse_rows))
+    assert _counter(stats, "rowpool.miss_words") == fill.tags["words"]
+    if kind != "numpy":       # the dense chunk's 8 rows of planes, and the other's words
+        assert fill.tags["upload_bytes"] == (
+            8 * n_slices * PLANE_WORDS * 4 + 16 * _pow4(fill.tags["words"]))
+    h.close()
+
+
+def test_one_walk_feeds_the_block_and_the_word_list(tmp_path, monkeypatch):
+    """(g) ``RowPieces``: the word list of a block is what ``fill`` writes
+    besides the dense pieces - the words that are not zero, once each,
+    equal words OR-ed - and it is computed once, however many consumers."""
+    h, ex, cols_of, _ = _tall_frame(tmp_path, monkeypatch, "numpy")
+    rows = [3, -1, 9, 4000]
+    pieces = ex._walk_block("i", "stargazer", "standard", [0, 1], rows)
+    word, bits = pieces.words()
+    assert pieces.words()[0] is word and not pieces.dense
+    block = ex._densify_block("i", "stargazer", "standard", [0, 1], rows)
+    flat = block.reshape(-1)
+    assert (np.flatnonzero(flat) == np.sort(word)).all() and len(set(word.tolist())) == len(word)
+    assert (flat[word] == bits).all()
+    assert int(np.bitwise_count(bits).sum()) == len(cols_of[3]) + len(cols_of[9])
+    # two bits of one word: one word, both bits
+    fr = h.index("i").frame("stargazer")
+    fr.set_bit("standard", 200, 64)
+    fr.set_bit("standard", 200, 65)
+    word, bits = ex._walk_block("i", "stargazer", "standard", [0], [200]).words()
+    assert word.tolist() == [2] and bits.tolist() == [3]
     h.close()
 
 

@@ -246,6 +246,35 @@ def test_pool_page_in_compiles_and_fits(bucket, donate, one_chip):
     assert "pool.set_rows" in compiled.as_text()
 
 
+@pytest.mark.parametrize("donate", [False, True])
+@pytest.mark.parametrize("words", [4096, "largest"])
+def test_pool_sparse_page_in_compiles_and_fits(words, donate, one_chip):
+    """The sparse form of the same (``ops.bitwise.set_row_words``: the
+    chunk's 8 slots zeroed, then its words scattered), at the bucket
+    ``seg64``'s chunks fall into and at the largest one
+    (``rowpool.MISS_WORDS_MAX``): what crosses to the device is 16 bytes a
+    word, no second copy of the 2 GiB pool is made either way, the
+    donating form writes into its input, and its ops carry the scope's
+    name."""
+    import jax
+
+    from pilosa_tpu.ops.bitwise import set_row_words
+    from pilosa_tpu.rowpool import MISS_CHUNK_ROWS, MISS_WORDS_MAX
+
+    n = MISS_WORDS_MAX if words == "largest" else words
+    compiled = _compile(
+        jax.jit(set_row_words, static_argnames="axis", donate_argnums=(0,) if donate else ()),
+        [_rm(64, 256), _ids(MISS_CHUNK_ROWS), _ids(n, 3), ((n,), "uint32")], one_chip,
+        kernel=False)
+    mem = compiled.memory_analysis()
+    pool = 64 * 256 * W * 4
+    assert mem.argument_size_in_bytes < pool + 20 * n + 2**16   # a cell lies padded to four words
+    assert mem.output_size_in_bytes == pool
+    assert mem.temp_size_in_bytes < 2**20
+    assert mem.alias_size_in_bytes == (pool if donate else 0)
+    assert "pool.set_rows" in compiled.as_text()
+
+
 def _mesh_args(slice_mesh, n_slices, n_rows, ids_shape):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -326,6 +355,7 @@ def test_mesh_engine_path_compiles_for_four_chips(path, topo, slice_mesh):
 
 @pytest.mark.parametrize("program", [
     "pair_gram", "set_plane_cells_1", "set_plane_cells_8", "set_rows_8", "set_rows_8_donated",
+    "set_row_words_4096", "set_row_words_4096_donated",
     "gram_update_b256", "gram_update_b1024"])
 def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, monkeypatch):
     """What the four-chip dashboard deployment (256 slices x 256 slots: a
@@ -348,6 +378,11 @@ def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, m
     rm = on(("slice", None, None, None), (256, 256, T, 128))
     if program == "pair_gram":
         lowered = sharded._sharded_pair_gram_kernel(slice_mesh, "slice", 4).lower(rm)
+    elif program.startswith("set_row_words"):   # a miss's chunk of 8 rows as its words
+        lowered = sharded._sharded_set_row_words_kernel(
+            slice_mesh, "slice", 4, program.endswith("donated")).lower(
+            rm, on((None,), (8,), "int32"), on((None, None), (4096, 3), "int32"),
+            on((None,), (4096,)))
     elif program.startswith("set_rows"):   # the fill's paging: a miss's chunk of 8 rows
         k = 8
         lowered = sharded._sharded_set_rows_kernel(
@@ -373,7 +408,9 @@ def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, m
     if program.startswith("set_"):
         assert mem.output_size_in_bytes == shard and "all-reduce" not in text
         assert mem.alias_size_in_bytes == (shard if program.endswith("donated") else 0)
-        assert ("pool.set_rows" if program.startswith("set_rows") else "pool.set_plane_rows") in text
+        assert ("pool.set_rows" if program.startswith("set_row") else "pool.set_plane_rows") in text
+        if program.startswith("set_row_words"):
+            assert mem.temp_size_in_bytes < 2**20 and "collective-permute" not in text
     else:
         assert "all-reduce" in text
         assert ("tpu_custom_call" in text) == program.startswith("gram_update")

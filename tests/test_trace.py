@@ -763,7 +763,9 @@ def test_a_cold_read_through_the_coalescing_queue_owns_its_pool_spans(tmp_path):
         assert root["tags"]["coalesced"] == 1
         (miss,) = _find(root, "pool.miss")
         assert miss["tags"]["rows"] == 4 and miss["tags"]["evicted"] == 0
-        assert miss["tags"]["upload_bytes"] >= 4 * 131072  # the rows, and the new pool
+        # one bit a row: the chunk went up as four (slice, slot, word) cells and their values
+        assert (miss["tags"]["sparse"], miss["tags"]["words"]) == (1, 4)
+        assert miss["tags"]["upload_bytes"] == 4 * 16
         assert _find(root, "pool.lock_wait")
     finally:
         s.close()
@@ -803,12 +805,16 @@ def test_pool_spans_and_counters_for_paging_refresh_and_eviction():
     assert names == ["pool.lock_wait", "pool.miss", "pool.lock_wait", "pool.refresh",
                      "pool.lock_wait", "pool.miss"]
     assert all(c.ms is not None for c in root.children)
-    assert root.children[1].tags == {"rows": 2, "bucket": 2, "evicted": 0, "upload_bytes": 0}
+    # a pool built with ``fetch`` alone pages dense: no chunk went sparse, no words shipped
+    assert root.children[1].tags == {"rows": 2, "bucket": 2, "evicted": 0, "sparse": 0,
+                                     "words": 0, "upload_bytes": 0}
     assert [c.name for c in root.children[1].children] == ["pool.miss.fetch", "pool.miss.scatter"]
     assert root.children[3].tags == {"rows": 2, "upload_bytes": 0}
-    assert root.children[5].tags == {"rows": 1, "bucket": 1, "evicted": 1, "upload_bytes": 0}
+    assert root.children[5].tags == {"rows": 1, "bucket": 1, "evicted": 1, "sparse": 0,
+                                     "words": 0, "upload_bytes": 0}
     # two block sizes met (the numpy engine's are the chunks' row counts themselves)
-    assert stats.n == {"rowpool.misses": 3, "rowpool.evictions": 1, "rowpool.miss_buckets": 2}
+    assert stats.n == {"rowpool.misses": 3, "rowpool.evictions": 1, "rowpool.miss_buckets": 2,
+                       "rowpool.miss_chunks_dense": 2}
     # what the pool was budgeted when it took device memory: the default per device, its own cap
     assert stats.g == {"rowpool.budget_bytes_per_device": 2 << 30, "rowpool.capacity_slots": 2}
     assert (pool.stat_misses, pool.stat_evictions, pool.stat_repairs) == (3, 1, 0)
