@@ -1,0 +1,25 @@
+"""Layer: mesh collectives.  The host's wait for the mesh a read request: the
+``mesh.fetch`` spans under a pass that gathered (a tree that holds a ``device``
+span of lane ``gather``), summed a pass - one span for each op group's psummed
+counts, the first of them the wait itself once every dispatch of the pass has
+gone out - and averaged over the read requests those passes answered (the read
+coalescer gives the pass's spans to its first request, whose root says how
+many it answered: ``coalesced``).  Source: program_span.  Moves
+``read_p50_ms``.  A program that waits for the mesh outside any span (its
+gather dispatches block one by one) gives nothing to read."""
+
+from lib import spantree
+
+
+def read(ctx):
+    waited = answered = found = 0
+    for tree in spantree.trees(ctx, writes=False):
+        if not any((n.get("tags") or {}).get("lane") == "gather"
+                   for n in spantree.named(tree, ("device",))):
+            continue
+        n, ms = spantree.ms_of(tree, "mesh.fetch")
+        weight = int(spantree.root_tag(tree, "coalesced") or 1)
+        found += n
+        waited += weight * ms
+        answered += weight
+    return waited / answered if found else None

@@ -471,7 +471,7 @@ class NumpyEngine:
         array of this engine (what the row pool's budget follows)."""
         return 1
 
-    def to_numpy(self, x) -> np.ndarray:
+    def to_numpy(self, x, span=None) -> np.ndarray:
         return np.asarray(x)
 
     def device_info(self) -> dict:
@@ -1376,6 +1376,14 @@ class MeshEngine(JaxEngine):
         return ""
 
     def gather_count(self, op, row_matrix, pairs):
+        out = self.gather_count_dev(op, row_matrix, pairs)
+        return self._fetch(out)[: len(pairs)].astype(np.int64)
+
+    def gather_count_dev(self, op, row_matrix, pairs):
+        """The pair counts of the whole mesh, enqueued and un-fetched, at
+        the engine's bucket for ``len(pairs)`` (the caller drops the
+        tail): psummed and replicated, so any device's copy is the
+        answer and ``to_numpy(counts, span)`` is the one wait."""
         # A pallas_call can't lower under GSPMD partitioning directly, but
         # shard_map restores the kernel tier: each shard runs the SAME
         # hand-tuned Pallas kernel on its local block and psum merges over
@@ -1386,16 +1394,14 @@ class MeshEngine(JaxEngine):
 
         rm = self._shard_stack(self._jnp.asarray(row_matrix))
         mode = self._pallas_mode(rm.shape[0], rm_words(rm))
-        n, pairs = len(pairs), self._jnp.asarray(_padded_batch(pairs))
+        pairs = self._jnp.asarray(_padded_batch(pairs))
         if mode:
             from pilosa_tpu.parallel.sharded import sharded_gather_count
 
-            out = sharded_gather_count(
+            return sharded_gather_count(
                 self.mesh, op, rm, pairs, interpret=(mode == "interpret"),
             )
-            return self._fetch(out)[:n].astype(np.int64)
-        out = self._gather_jit(op, rm, pairs)
-        return self._fetch(out)[:n].astype(np.int64)
+        return self._gather_jit(op, rm, pairs)
 
     def _fetch(self, arr, span=None) -> np.ndarray:
         """Fetch an engine array to host, allgathering when its shards
@@ -1421,60 +1427,62 @@ class MeshEngine(JaxEngine):
         # so allgather-aware fetching covers them all on multi-host.
         return self._fetch(x, span)
 
+    def _chunked(self, run, n: int, chunk: int):
+        """``run(i, j)`` over ``[0, n)`` in chunks of ``chunk``, the
+        results joined on the device (nothing is fetched)."""
+        outs = [run(i, min(n, i + chunk)) for i in range(0, n, chunk)]
+        return outs[0] if len(outs) == 1 else self._jnp.concatenate(outs)
+
     def gather_count_multi(self, op, row_matrix, idx):
+        return self._fetch(self.gather_count_multi_dev(op, row_matrix, idx)).astype(np.int64)
+
+    def gather_count_multi_dev(self, op, row_matrix, idx):
+        """``gather_count_multi``'s counts un-fetched (psummed and
+        replicated under the kernels, as ``gather_count_dev``)."""
         from pilosa_tpu.ops.pallas_kernels import rm_words
 
         rm = self._shard_stack(self._jnp.asarray(row_matrix))
         s, w = rm.shape[0], rm_words(rm)
-        k = idx.shape[1]
         mode = self._pallas_mode(s, w)
         if mode:
-            # Kernel tier under the mesh (no materialized gather); bound
-            # the prefetched id footprint like single-chip dispatch does.
+            # Kernel tier under the mesh (no materialized gather); it
+            # bounds the prefetched id footprint like single-chip dispatch
+            # does, a chunk a program.
             from pilosa_tpu.parallel.sharded import sharded_gather_count_multi
 
-            chunk = max(1, 2048 // max(1, k))
-            outs = [
-                self._fetch(
-                    sharded_gather_count_multi(
-                        self.mesh, op, rm, self._jnp.asarray(idx[i : i + chunk]),
-                        interpret=(mode == "interpret"),
-                    )
-                )
-                for i in range(0, idx.shape[0], chunk)
-            ]
-            return np.concatenate(outs).astype(np.int64)
+            return sharded_gather_count_multi(
+                self.mesh, op, rm, self._jnp.asarray(idx), interpret=(mode == "interpret"),
+            )
         # The jnp form materializes the [S, chunk, K, W] gather per shard;
         # chunk the batch so that transient stays bounded (the same budget
         # dispatch.py applies to its XLA fallback).
         from pilosa_tpu.pilosa import OR_MULTI_BUDGET_DEVICE, or_multi_chunk_size
 
-        chunk = or_multi_chunk_size(s, k, w, OR_MULTI_BUDGET_DEVICE)
-        outs = [
-            self._fetch(self._gather_multi_jit(op, rm, self._jnp.asarray(idx[i : i + chunk])))
-            for i in range(0, idx.shape[0], chunk)
-        ]
-        return np.concatenate(outs).astype(np.int64)
+        return self._chunked(
+            lambda i, j: self._gather_multi_jit(op, rm, self._jnp.asarray(idx[i:j])),
+            idx.shape[0], or_multi_chunk_size(s, idx.shape[1], w, OR_MULTI_BUDGET_DEVICE),
+        )
 
     def gather_count_or_multi(self, row_matrix, idx):
         return self.gather_count_multi("or", row_matrix, idx)
 
     def gather_count_tree(self, row_matrix, leaves, opc):
+        return self._fetch(self.gather_count_tree_dev(row_matrix, leaves, opc)).astype(np.int64)
+
+    def gather_count_tree_dev(self, row_matrix, leaves, opc):
+        """``gather_count_tree``'s counts un-fetched."""
         from pilosa_tpu.ops.pallas_kernels import rm_words
 
         rm = self._shard_stack(self._jnp.asarray(row_matrix))
         s, w = rm.shape[0], rm_words(rm)
-        k = leaves.shape[1]
         mode = self._pallas_mode(s, w)
         if mode:
             from pilosa_tpu.parallel.sharded import sharded_gather_count_tree
 
-            return self._fetch(
-                sharded_gather_count_tree(
-                    self.mesh, rm, self._jnp.asarray(leaves),
-                    self._jnp.asarray(opc), interpret=(mode == "interpret"),
-                )
-            ).astype(np.int64)
+            return sharded_gather_count_tree(
+                self.mesh, rm, self._jnp.asarray(leaves),
+                self._jnp.asarray(opc), interpret=(mode == "interpret"),
+            )
         # jnp form materializes the gather per shard: bound the transient
         # exactly like gather_count_multi's fallback.
         from pilosa_tpu.ops import bitwise as _bw
@@ -1482,29 +1490,12 @@ class MeshEngine(JaxEngine):
 
         if self._tree_jit is None:
             self._tree_jit = self._jax.jit(_bw.gather_count_tree)
-        chunk = or_multi_chunk_size(s, k, w, OR_MULTI_BUDGET_DEVICE)
-        outs = [
-            self._fetch(
-                self._tree_jit(
-                    rm, self._jnp.asarray(leaves[i : i + chunk]),
-                    self._jnp.asarray(opc[i : i + chunk]),
-                )
-            )
-            for i in range(0, leaves.shape[0], chunk)
-        ]
-        return np.concatenate(outs).astype(np.int64)
-
-    def gather_count_dev(self, op, row_matrix, pairs):
-        # Sharded matrices go through the GSPMD-partitioned jnp form (the
-        # Pallas dispatch the Jax parent would pick can't lower under
-        # GSPMD); the result is small, so the sync fetch costs little.
-        return self.gather_count(op, row_matrix, pairs)
-
-    def gather_count_multi_dev(self, op, row_matrix, idx):
-        return self.gather_count_multi(op, row_matrix, idx)
-
-    def gather_count_tree_dev(self, row_matrix, leaves, opc):
-        return self.gather_count_tree(row_matrix, leaves, opc)
+        return self._chunked(
+            lambda i, j: self._tree_jit(
+                rm, self._jnp.asarray(leaves[i:j]), self._jnp.asarray(opc[i:j])
+            ),
+            leaves.shape[0], or_multi_chunk_size(s, leaves.shape[1], w, OR_MULTI_BUDGET_DEVICE),
+        )
 
 
 def engine_name(name: str = "auto") -> str:

@@ -1690,7 +1690,7 @@ class Executor:
                             self._gather_pairs(op, matrix, pairs, rm_pool, span),
                         ))
                 for om, n, counts in pending:
-                    fout[om] = self.engine.to_numpy(counts)[:n].astype(np.int64)
+                    fout[om] = self._fetch_counts(counts, matrix, span)[:n]
                 out[fmask] = fout
         return out.tolist()
 
@@ -2246,13 +2246,15 @@ class Executor:
                         if not rm_pool and any(kb == 2 for _, kb in groups)
                         else None
                     )
-                    for gk, op_idxs in sorted(groups.items(), key=_group_sort_key):
-                        counts = self.engine.to_numpy(
-                            self._group_counts(
-                                gk, op_idxs, matched, id_pos, matrix, static,
-                                gram, row_major=rm_pool, span=span,
-                            )
-                        )
+                    pending = [  # every group's dispatch goes out before the first fetch blocks
+                        (op_idxs, self._group_counts(
+                            gk, op_idxs, matched, id_pos, matrix, static,
+                            gram, row_major=rm_pool, span=span,
+                        ))
+                        for gk, op_idxs in sorted(groups.items(), key=_group_sort_key)
+                    ]
+                    for op_idxs, counts in pending:
+                        counts = self._fetch_counts(counts, matrix, span)
                         for k2, i in enumerate(op_idxs):
                             out[i] = int(counts[k2])
                 else:
@@ -2294,12 +2296,23 @@ class Executor:
                                 )
                             )
                     for gk, op_idxs in sorted(groups.items(), key=_group_sort_key):
-                        total = sum(
-                            self.engine.to_numpy(a).astype(np.int64) for a in acc[gk]
-                        )
+                        total = sum(self._fetch_counts(a, matrix, span) for a in acc[gk])
                         for k2, i in enumerate(op_idxs):
                             out[i] = int(total[k2])
         return [out[i] for i in idxs]
+
+    def _fetch_counts(self, counts, matrix, span=None) -> np.ndarray:
+        """One dispatch's counts on the host, int64: the wait for them,
+        made after the pass's last dispatch has gone out.  Where
+        ``matrix`` lies over several devices the counts were psummed
+        across the mesh, and the wait is a ``mesh.fetch`` span of the
+        traced request and one ``gather.mesh_fetches``; a Gram's counts
+        are the host's already."""
+        if isinstance(counts, np.ndarray):
+            return counts.astype(np.int64, copy=False)
+        if self.meter is not None and self.engine.slice_axis_devices(matrix.shape[0]) > 1:
+            self.meter.stats.count("gather.mesh_fetches")
+        return self.engine.to_numpy(counts, span).astype(np.int64)
 
     def _group_counts(
         self, gk, op_idxs, matched, id_pos, matrix, static, gram, row_major=False,
@@ -2327,8 +2340,9 @@ class Executor:
         bucket for ``len(pairs)`` (``engine.gather_bucket``: the caller
         drops the tail).  Metered as the "gather" lane; the traced
         request's ``device`` span says what was gathered (``pairs``,
-        ``unique_rows``, ``layout``, ``bucket``), counters
-        ``gather.dispatches`` / ``gather.pairs``."""
+        ``unique_rows``, ``layout``, ``bucket``, ``devices``: how many
+        share the matrix's slice axis), counters ``gather.dispatches`` /
+        ``gather.pairs``."""
         def dispatch():
             if row_major:
                 return self.engine.gather_count_rowmajor_dev(op, matrix, pairs)
@@ -2343,6 +2357,7 @@ class Executor:
                 pairs=len(pairs), unique_rows=len(np.unique(pairs)),
                 layout="row_major" if row_major else "slice_major",
                 bucket=self.engine.gather_bucket(len(pairs)),
+                devices=self.engine.slice_axis_devices(matrix.shape[1 if row_major else 0]),
             )
             return dispatch()
 

@@ -228,7 +228,9 @@ def sharded_gather_count(
     driver dryrun).
 
     Requires the slice axis divisible by the mesh; callers fall back to
-    the GSPMD-partitioned jnp form otherwise.
+    the GSPMD-partitioned jnp form otherwise.  Returns the psummed counts
+    as a replicated device array: nothing is fetched here (the mesh
+    engine's ``_dev`` forms hand it on as it is).
     """
     import jax.numpy as jnp
 

@@ -532,8 +532,9 @@ class DeviceRowPool:
         for a reader may hold it) and into that copy for the rest.
         ``pool.miss`` carries ``rows``, ``bucket`` (rows paged, a dense
         block's padding included), ``evicted``, ``sparse`` (chunks that
-        went sparse), ``words`` (what they shipped) and ``upload_bytes``
-        (bytes handed to the device: cells and values, or blocks).  The
+        went sparse), ``words`` (what they shipped), ``upload_bytes``
+        (bytes handed to the device: cells and values, or blocks) and
+        ``devices`` (how many share the pool's slice axis).  The
         first miss that evicts has the engine compile every program a
         miss can meet first (``warm_set_rows``): once a pool.
         """
@@ -612,6 +613,7 @@ class DeviceRowPool:
                         rows=len(missing), evicted=evicted, bucket=bucket,
                         sparse=sparse, words=words,
                         upload_bytes=self.engine.stat_upload_bytes - up0,
+                        devices=self.engine.slice_axis_devices(self.n_slices),
                     )
             for r in want:
                 self.lru[r] = None

@@ -275,6 +275,14 @@ def test_pool_sparse_page_in_compiles_and_fits(words, donate, one_chip):
     assert "pool.set_rows" in compiled.as_text()
 
 
+def _all_reduced(text: str) -> set:
+    """The shapes a compiled program's all-reduce ops return:
+    "%name = s32[C,256]{..} all-reduce("."""
+    import re
+
+    return set(re.findall(r"= \(?(\w+\[[\d,]*\])[^=]*? all-reduce(?:-start)?\(", text))
+
+
 def _mesh_args(slice_mesh, n_slices, n_rows, ids_shape):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -356,6 +364,7 @@ def test_mesh_engine_path_compiles_for_four_chips(path, topo, slice_mesh):
 @pytest.mark.parametrize("program", [
     "pair_gram", "set_plane_cells_1", "set_plane_cells_8", "set_rows_8", "set_rows_8_donated",
     "set_row_words_4096", "set_row_words_4096_donated",
+    "set_row_words_16384", "set_row_words_16384_donated",   # seg256: a chunk's 2,048-10,240 words
     "gram_update_b256", "gram_update_b1024"])
 def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, monkeypatch):
     """What the four-chip dashboard deployment (256 slices x 256 slots: a
@@ -379,10 +388,11 @@ def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, m
     if program == "pair_gram":
         lowered = sharded._sharded_pair_gram_kernel(slice_mesh, "slice", 4).lower(rm)
     elif program.startswith("set_row_words"):   # a miss's chunk of 8 rows as its words
+        n_words = int(program.split("_")[3])
         lowered = sharded._sharded_set_row_words_kernel(
             slice_mesh, "slice", 4, program.endswith("donated")).lower(
-            rm, on((None,), (8,), "int32"), on((None, None), (4096, 3), "int32"),
-            on((None,), (4096,)))
+            rm, on((None,), (8,), "int32"), on((None, None), (n_words, 3), "int32"),
+            on((None,), (n_words,)))
     elif program.startswith("set_rows"):   # the fill's paging: a miss's chunk of 8 rows
         k = 8
         lowered = sharded._sharded_set_rows_kernel(
@@ -446,7 +456,30 @@ def test_mesh256_repair_step_updates_every_shard_in_place(cells, slice_mesh):
     assert mem.temp_size_in_bytes < 8 * 2**20
     text = compiled.as_text()
     assert "all-gather" not in text and "all-to-all" not in text
-    # The shape an all-reduce op returns: "%name = s32[C,256]{..} all-reduce(".
-    reduced = re.findall(r"= \(?(\w+\[[\d,]*\])[^=]*? all-reduce(?:-start)?\(", text)
-    assert reduced and set(reduced) == {f"s32[{cells},256]"}
+    assert _all_reduced(text) == {f"s32[{cells},256]"}
     assert not re.search(r"= u32\[64,256,256,128\][^=]*? copy(?:-start)?\(", text)
+
+
+@pytest.mark.parametrize("op", ["and", "xor"])
+@pytest.mark.parametrize("pairs", [16, 64])
+def test_seg256_gather_dispatch_stays_on_its_shards(pairs, op, slice_mesh):
+    """A read of the tall frame on four chips (``MeshEngine.gather_count_dev``:
+    ``seg256.tall_pairs``' op groups of 16 and 64 pairs over the 256 slots x
+    256 slices of the sharded pool): the pair kernel on every device's own
+    2 GiB shard under ``shard_map``, nothing gathered, one all-reduce of the
+    counts, int32[pairs], and the kernel under the ``gather.count`` name the
+    roofline share is read by."""
+    from pilosa_tpu.ops.pallas_kernels import resident_strategy
+    from pilosa_tpu.parallel import sharded
+
+    rm, ids = _mesh_args(slice_mesh, 256, 256, (pairs, 2))
+    kernel = sharded._sharded_pair_kernel(
+        slice_mesh, "slice", op, resident_strategy(256, W, pairs), False, 4)
+    compiled = kernel.lower(rm, ids).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gather.count" in text
+    assert "all-gather" not in text and "all-to-all" not in text
+    assert _all_reduced(text) == {f"s32[{pairs}]"}
+    mem = compiled.memory_analysis()
+    shard = 256 * 256 * W * 4 // 4
+    assert mem.argument_size_in_bytes < shard + 2**20 and mem.temp_size_in_bytes < 2**26

@@ -807,11 +807,11 @@ def test_pool_spans_and_counters_for_paging_refresh_and_eviction():
     assert all(c.ms is not None for c in root.children)
     # a pool built with ``fetch`` alone pages dense: no chunk went sparse, no words shipped
     assert root.children[1].tags == {"rows": 2, "bucket": 2, "evicted": 0, "sparse": 0,
-                                     "words": 0, "upload_bytes": 0}
+                                     "words": 0, "upload_bytes": 0, "devices": 1}
     assert [c.name for c in root.children[1].children] == ["pool.miss.fetch", "pool.miss.scatter"]
     assert root.children[3].tags == {"rows": 2, "upload_bytes": 0}
     assert root.children[5].tags == {"rows": 1, "bucket": 1, "evicted": 1, "sparse": 0,
-                                     "words": 0, "upload_bytes": 0}
+                                     "words": 0, "upload_bytes": 0, "devices": 1}
     # two block sizes met (the numpy engine's are the chunks' row counts themselves)
     assert stats.n == {"rowpool.misses": 3, "rowpool.evictions": 1, "rowpool.miss_buckets": 2,
                        "rowpool.miss_chunks_dense": 2}
