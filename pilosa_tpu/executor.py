@@ -495,10 +495,7 @@ class Executor:
                     # Gram / gather kernels behind one entry point).
                     span.tags["lane"] = "flat"
                 if qtoken is not None:
-                    csp = span.child("qcache.commit") if span is not None else None
-                    self.qcache.commit(self.holder, qtoken, fast)
-                    if csp is not None:
-                        csp.finish()
+                    self._qcache_commit(qtoken, fast, span)
                 return fast
             psp = span.child("parse") if span is not None else None
             query = pql.parse_cached(query)
@@ -582,11 +579,21 @@ class Executor:
             if csp is not None:
                 csp.finish()
         if qtoken is not None:
-            csp = span.child("qcache.commit") if span is not None else None
-            self.qcache.commit(self.holder, qtoken, results)
-            if csp is not None:
-                csp.finish()
+            self._qcache_commit(qtoken, results, span)
         return results
+
+    def _qcache_commit(self, qtoken, results, span) -> None:
+        """Offer one executed read to the query cache.  A sampled
+        request's ``qcache.commit`` span says where the entry's key came
+        from: ``memo`` (the lookup knew the string), ``match`` (the
+        native pair matcher's reading) or ``parse``; a never-seen string
+        under the admission floor is not keyed at all."""
+        csp = span.child("qcache.commit") if span is not None else None
+        self.qcache.commit(self.holder, qtoken, results)
+        if csp is not None:
+            csp.finish()
+            if qtoken.keyed is not None:
+                csp.tags["keyed"] = qtoken.keyed
 
     # -- query-batch fusion ------------------------------------------------
 
@@ -950,7 +957,8 @@ class Executor:
         ANYTHING outside the exact shape — other calls, inverse views,
         unusual args, parse errors — so the normal parse path keeps every
         behavior and error message.  ``qtoken`` is the request's query
-        cache token, told how long the request queued for a repair.
+        cache token, told how long the request queued for a repair and,
+        where the pair matcher takes the body, what it read.
         """
         # analysis-ok: lockstep-determinism: deployment config, launcher sets identical env on every rank
         if os.environ.get("PILOSA_TPU_NO_FASTLANE", "").lower() in ("1", "true", "yes"):
@@ -1082,6 +1090,12 @@ class Executor:
             fr = self.holder.frame(index, fname)
             if fr is None or key_names[k_id] != fr.row_label:
                 return None
+        if qtoken is not None and qtoken.deferred:
+            # The matcher has read every call of this body: should the
+            # result be worth storing, the query cache names the entry
+            # by these arrays and parses nothing (a reference only -
+            # most tokens die under the admission floor).
+            qtoken.match = m
         # Index resolution AFTER shape matching keeps error precedence
         # identical to the normal path (shape mismatches never raise here).
         idx_obj = self.holder.index(index)

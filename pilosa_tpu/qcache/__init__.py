@@ -43,6 +43,21 @@ Design:
   parsed by the cache, however often it is sent.  Given up: the FIRST
   send of a new spelling of an already-cached call tree misses (the
   memo cannot know a spelling without parsing it); its second hits.
+- **A body a native matcher took is not parsed at all**: the flat
+  lane's ``native.pql_match_pairs`` has read every call of an
+  all-``Count(op(Bitmap, Bitmap))`` body before the body is evaluated,
+  and the executor leaves what it read on the deferred token
+  (``_Deferred.match``, a reference: nothing is built under the
+  floor).  A commit then keys the entry by the matched calls - the op,
+  frame, row-label and row arrays' bytes with the two name tables, a
+  tuple, which no ``str(Query)`` fingerprint can equal - and takes the
+  frames from the matcher's frame table (``_matched_info``).  Two
+  strings the matcher reads as the same calls share an entry, as two
+  with one ``str(Query)`` do.  The two key spaces never meet: a string
+  keyed by the matcher and, after the memo dropped it, by the parse
+  (it was answered by another lane) is a miss at worst.  Tokens that
+  carry no match - the AST path, the armed ``pn_serve_pairs`` lane,
+  the tree and Range lanes, a single call - canonicalise by the parse.
 - **Store**: byte-accounted LRU with cost-aware admission — only
   results whose measured execution cost clears ``min_cost_ms`` are
   admitted (cheap requests would pay more in cache bookkeeping than
@@ -167,6 +182,43 @@ def generation_vector(holder, index: str, frames: tuple) -> Optional[tuple]:
     return tuple(vec)
 
 
+def _parsed_info(query_str: str) -> Optional[tuple]:
+    """(fingerprint, frames) by the Python parse: the ``str(Query)``
+    rendering and the frames its tree names, None for write-bearing /
+    non-cacheable / unparseable strings."""
+    from pilosa_tpu import pql
+
+    try:
+        q = pql.parse_cached(query_str)
+    # analysis-ok: exception-hygiene: fingerprint probe; the normal execution path raises the real parse error
+    except Exception:  # noqa: BLE001 — normal path raises the real error
+        return None
+    if q.calls and all(c.name in CACHEABLE_CALLS for c in q.calls):
+        return str(q), referenced_frames(q)
+    return None
+
+
+def _matched_info(match) -> tuple:
+    """(fingerprint, frames) of a body ``native.pql_match_pairs`` took,
+    from its output alone: every call is a ``Count`` of one op over two
+    ``Bitmap`` leaves of one frame, so the five arrays and the two name
+    tables ARE the call tree (injective: each array's length is the
+    call count), and the frame table names every frame it can touch."""
+    from pilosa_tpu.executor import DEFAULT_FRAME
+
+    op_ids, frame_ids, key_ids, r1, r2, frames_b, keys_b = match
+    frames_b = tuple(frames_b)
+    fingerprint = (
+        op_ids.tobytes(), frame_ids.tobytes(), key_ids.tobytes(),
+        r1.tobytes(), r2.tobytes(), frames_b, tuple(keys_b),
+    )
+    frames = {
+        frames_b[f].decode("utf-8") if f >= 0 else DEFAULT_FRAME
+        for f in sorted(set(frame_ids.tolist()))
+    }
+    return fingerprint, tuple(sorted(frames))
+
+
 def result_nbytes(results) -> int:
     """Byte-accounting estimate for one result list (duck-typed so this
     module never imports the executor)."""
@@ -192,6 +244,7 @@ class _Pending:
 
     __slots__ = ("key", "index", "frames", "vec0", "t0", "queued")
     deferred = False
+    keyed = "memo"
 
     def __init__(self, key, index, frames, vec0, t0):
         self.key = key
@@ -205,9 +258,15 @@ class _Pending:
 class _Deferred:
     """A miss in flight on a request string nobody has parsed: the raw
     string and the write epoch stand in for the key and the vector
-    until :meth:`QueryCache.commit` finds the result worth storing."""
+    until :meth:`QueryCache.commit` finds the result worth storing.
+    ``match`` is the executor's to set: the output of the native pair
+    matcher that took the body, which then names the entry instead of
+    a parse.  ``keyed`` says which did, once a commit has asked."""
 
-    __slots__ = ("query_str", "index", "slices_key", "remote", "epoch0", "t0", "queued")
+    __slots__ = (
+        "query_str", "index", "slices_key", "remote", "epoch0", "t0", "queued",
+        "match", "keyed",
+    )
     deferred = True
 
     def __init__(self, query_str, index, slices_key, remote, epoch0, t0):
@@ -218,6 +277,8 @@ class _Deferred:
         self.epoch0 = epoch0
         self.t0 = t0
         self.queued = 0.0
+        self.match = None
+        self.keyed = None
 
 
 class _Entry:
@@ -239,16 +300,19 @@ class QueryCache:
     """The byte-accounted, generation-validated query result LRU.
 
     Thread-safe.  Counters (``hits / misses / bypasses / ineligible /
-    evictions / stores / deferred / deferred_parsed`` and the ``bytes``
-    gauge) are exposed both as attributes (tests, bench) and through
-    the optional stats client (``qcache.hit`` etc. at /debug/vars).
+    evictions / stores / deferred / deferred_parsed / deferred_matched``
+    and the ``bytes`` gauge) are exposed both as attributes (tests,
+    bench) and through the optional stats client (``qcache.hit`` etc.
+    at /debug/vars).
     ``bypasses`` counts ONLY client-requested skips (X-Pilosa-No-Cache)
     so the A/B hit-rate denominator stays clean; writes, non-cacheable
     trees and cluster-scope requests count as ``ineligible``.
     ``deferred`` counts the lookups (misses all) that took a deferred
-    token, ``deferred_parsed`` the commits that canonicalised one - a
-    non-cacheable tree sent as a never-seen string is judged, and
-    counted ``ineligible``, there.
+    token, ``deferred_parsed`` the commits that canonicalised one by a
+    parse - a non-cacheable tree sent as a never-seen string is judged,
+    and counted ``ineligible``, there - and ``deferred_matched`` the
+    commits that keyed one by what a native matcher had read of it: of
+    the bodies that reached a key, the share the cache never parsed.
     """
 
     # Lockset race detector declarations: the store/canon LRUs and the
@@ -268,6 +332,7 @@ class QueryCache:
         "stores": "qcache._mu",
         "deferred": "qcache._mu",
         "deferred_parsed": "qcache._mu",
+        "deferred_matched": "qcache._mu",
     }
 
     def __init__(
@@ -312,6 +377,7 @@ class QueryCache:
         self.stores = 0
         self.deferred = 0
         self.deferred_parsed = 0
+        self.deferred_matched = 0
 
     # -- fingerprinting ---------------------------------------------------
 
@@ -319,11 +385,12 @@ class QueryCache:
     # ineligible query on the lock-free probe below.
     _CANON_MISS = object()
 
-    def _canonical(self, query_str: str) -> Optional[tuple]:
+    def _canonical(self, query_str: str, match=None) -> Optional[tuple]:
         """(fingerprint, frames) for an eligible query string, None for
         write-bearing / non-cacheable / unparseable ones.  Memoized: the
         steady-state repeated request pays one dict lookup, not a parse
-        + render.
+        + render.  ``match``, where a native matcher took the string,
+        stands in for the parse (``_matched_info``).
 
         The hit probe is LOCK-FREE: memo values are immutable once
         stored (a tuple or None), so a concurrent insert/evict at worst
@@ -338,19 +405,7 @@ class QueryCache:
             return val
         info = None
         if len(query_str) <= _FINGERPRINT_MAX_LEN:
-            from pilosa_tpu import pql
-
-            try:
-                q = pql.parse_cached(query_str)
-            # analysis-ok: exception-hygiene: fingerprint probe; the normal execution path raises the real parse error
-            except Exception:  # noqa: BLE001 — normal path raises the real error
-                q = None
-            if (
-                q is not None
-                and q.calls
-                and all(c.name in CACHEABLE_CALLS for c in q.calls)
-            ):
-                info = (str(q), referenced_frames(q))
+            info = _matched_info(match) if match is not None else _parsed_info(query_str)
         with self._mu:
             self._canon[query_str] = info
             self._canon.move_to_end(query_str)
@@ -443,11 +498,20 @@ class QueryCache:
         (counted ``ineligible`` here) or the write epoch moved since
         the lookup.  The memo is filled before the epoch is looked at,
         so under a steady stream of writes the body's next send still
-        takes the memoized path, whose validity is per frame."""
-        with self._mu:
-            self.deferred_parsed += 1
-        self.stats.count("qcache.deferred_parsed")
-        info = self._canonical(d.query_str)
+        takes the memoized path, whose validity is per frame.  A token
+        that carries a native matcher's output is keyed by it: nothing
+        is parsed."""
+        if d.match is not None:
+            d.keyed = "match"
+            with self._mu:
+                self.deferred_matched += 1
+            self.stats.count("qcache.deferred_matched")
+        else:
+            d.keyed = "parse"
+            with self._mu:
+                self.deferred_parsed += 1
+            self.stats.count("qcache.deferred_parsed")
+        info = self._canonical(d.query_str, d.match)
         if info is None:
             self.note_ineligible()
             return None

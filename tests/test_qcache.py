@@ -371,13 +371,17 @@ def test_stats_counters_at_debug_vars(tmp_path):
     ex.execute("i", Q_PAIR)
     ex.execute("i", Q_PAIR, opt=ExecOptions(no_cache=True))
     ex.execute("i", 'SetBit(rowID=2, frame="f", columnID=3)')
+    pair2 = Q_PAIR + " " + Q_PAIR  # two calls: the pair matcher's to read
+    assert ex.execute("i", pair2) == ex.execute("i", pair2)
     snap = stats.snapshot()
-    assert snap["qcache.hit"] == 1
-    assert snap["qcache.miss"] == 1
+    assert snap["qcache.hit"] == 2
+    assert snap["qcache.miss"] == 2
     # The one miss was a never-seen string, canonicalised at its commit.
-    assert snap["qcache.deferred"] == 1
+    assert snap["qcache.deferred"] == 2
     assert snap["qcache.deferred_parsed"] == 1
-    assert snap["qcache.store"] == 1
+    # ... and one was a body the pair matcher had read: keyed by that.
+    assert snap["qcache.deferred_matched"] == 1
+    assert snap["qcache.store"] == 2
     assert snap["qcache.bypass"] == 1
     assert snap["qcache.ineligible"] == 1  # the write, not a bypass
     assert snap["qcache.bytes"] > 0
@@ -587,26 +591,49 @@ def _dear(ex, clock, seconds=0.005):
     ex._singleton_write_fast = clock.during(ex._singleton_write_fast, seconds)
 
 
-def test_dear_repeated_body_hits_from_its_second_send(dash):
+# Two bodies dearer than the floor.  "armed_lane": rows the armed serve
+# state knows, which pn_serve_pairs answers where a state is armed (jax,
+# mesh) - that lane hands the cache no match, so the commit parses.
+# "pair_matcher": a row no state knows, which the armed lane declines,
+# so on every engine native.pql_match_pairs reads the body and the
+# commit keys the entry by what it read.  numpy arms nothing: both
+# bodies go through the matcher there.
+_DEAR_BODIES = {"armed_lane": range(5), "pair_matcher": [0, 2, 4, 8]}
+
+
+@pytest.mark.parametrize("which", sorted(_DEAR_BODIES))
+def test_dear_repeated_body_hits_from_its_second_send(dash, which, monkeypatch):
     """The polled dashboard (ROADMAP D13's condition): a body dearer
-    than the floor is parsed once, at its first commit, hits on its
-    second send, and goes on hitting after another frame's write."""
+    than the floor is keyed once, at its first commit - by the pair
+    matcher's reading where it took the body, with no Python parse, by
+    a parse elsewhere - hits on its second send, and goes on hitting
+    after another frame's write."""
+    from pilosa_tpu.pql import parser
+
     h, ex, qc, clock, fresh = dash
     _dear(ex, clock)
-    body = _pairs(range(5))
+    body = _pairs(_DEAR_BODIES[which])
     want = fresh.execute("i", body)
+    matched = which == "pair_matcher" or ("i", "f") not in ex._serve_states
+    keyed = (0, 1) if matched else (1, 0)
+    parses = []
+    monkeypatch.setattr(parser, "parse", lambda src, _p=parser.parse: parses.append(src) or _p(src))
     assert ex.execute("i", body) == want
-    assert (qc.hits, qc.misses, qc.deferred, qc.deferred_parsed, qc.stores) == (0, 1, 1, 1, 1)
+    assert (qc.hits, qc.misses, qc.deferred, qc.stores) == (0, 1, 1, 1)
+    assert (qc.deferred_parsed, qc.deferred_matched) == keyed
     assert ex.execute("i", body) == want
     assert (qc.hits, qc.misses, qc.deferred) == (1, 1, 1)
     h.index("i").frame("g").set_bit("standard", 1, 9)  # moves the epoch, not f's vector
     assert ex.execute("i", body) == want
-    assert (qc.hits, qc.misses, qc.deferred, qc.deferred_parsed, qc.stores) == (2, 1, 1, 1, 1)
+    assert (qc.hits, qc.misses, qc.deferred, qc.stores) == (2, 1, 1, 1)
+    assert (qc.deferred_parsed, qc.deferred_matched) == keyed
     # Its own frame's write: a miss on the memoized path, the fresh answer stored.
     h.index("i").frame("f").set_bit("standard", 0, 3 * SLICE_WIDTH + 999)
     want = fresh.execute("i", body)
     assert ex.execute("i", body) == want and ex.execute("i", body) == want
     assert (qc.hits, qc.misses, qc.deferred, qc.stores) == (3, 2, 1, 2)
+    if matched:
+        assert parses == []  # elsewhere pql's own parse cache may know the body
 
 
 def test_cheap_body_is_never_parsed_by_the_cache(dash, monkeypatch):
@@ -681,25 +708,244 @@ _BETWEEN = {
 }
 
 
+@pytest.mark.parametrize("keyed_by", ["parse", "match"])
 @pytest.mark.parametrize("between", sorted(_BETWEEN))
-def test_write_between_deferred_lookup_and_commit_declines_the_store(env, between):
-    """A deferred token cannot name its frames before the parse, so it
+def test_write_between_deferred_lookup_and_commit_declines_the_store(env, between, keyed_by):
+    """A deferred token cannot name its frames before it is keyed, so it
     holds the process's write epoch: anything that could move any
     validity vector between the lookup and the commit - a bit, on any
     frame; a fragment's creation; a schema field of the vector's header
-    - declines the store.  The memo is filled all the same, so the next
-    send is judged by its own frames' vector."""
+    - declines the store, whether the key then comes from a parse or
+    from the pair matcher's reading.  The memo is filled all the same,
+    so the next send is judged by its own frames' vector."""
+    from pilosa_tpu import native
+
     h, fr, ex, qc = env
     h.index("i").create_frame("g", FrameOptions()).set_bit("standard", 0, 1)
     edit, stored = _BETWEEN[between]
-    cached, tok = qc.lookup(h, "i", Q_PAIR, None)
+    body = Q_PAIR if keyed_by == "parse" else Q_PAIR + " " + Q_PAIR
+    results = [5] if keyed_by == "parse" else [5, 5]
+    cached, tok = qc.lookup(h, "i", body, None)
     assert cached is None and tok.deferred
+    if keyed_by == "match":
+        tok.match = native.pql_match_pairs(body.encode())
+        assert tok.match is not None
     edit(h)
-    assert qc.commit(h, tok, [5]) is stored
-    assert (qc.stores, len(qc), qc.deferred_parsed) == (int(stored), int(stored), 1)
-    cached, tok = qc.lookup(h, "i", Q_PAIR, None)
+    assert qc.commit(h, tok, results) is stored
+    assert (qc.stores, len(qc)) == (int(stored), int(stored))
+    assert (qc.deferred_parsed, qc.deferred_matched) == (
+        (1, 0) if keyed_by == "parse" else (0, 1))
+    assert tok.keyed == keyed_by
+    cached, tok = qc.lookup(h, "i", body, None)
     assert qc.deferred == 1  # the memo knows the string now
-    assert (cached == [5]) if stored else (cached is None and not tok.deferred)
+    assert (cached == results) if stored else (cached is None and not tok.deferred)
+
+
+# -- a body the pair matcher took is keyed by what it read -------------------
+
+
+def _call(op="Intersect", a=0, b=1, frame="f", key="rowID"):
+    f = f', frame="{frame}"' if frame else ""
+    return f"Count({op}(Bitmap({key}={a}{f}), Bitmap({key}={b}{f})))"
+
+
+_BASE = [_call(), _call("Union", 2, 3)]
+# One edit each of the base body: what the fingerprint has to tell apart.
+_VARIANTS = {
+    "one_op": [_call("Xor"), _BASE[1]],
+    "one_row": [_call(b=2), _BASE[1]],
+    "one_frame": [_call(frame="g"), _BASE[1]],
+    "one_row_label_key": [_call(key="id"), _BASE[1]],
+    "order_of_two_calls": _BASE[::-1],
+    "default_against_named_frame": [_call(frame=None), _BASE[1]],
+    "rows_swapped_in_a_call": [_call(a=1, b=0), _BASE[1]],
+    "a_call_more": _BASE + [_call()],
+}
+
+
+@pytest.mark.parametrize("variant", sorted(_VARIANTS))
+def test_matched_fingerprint_tells_bodies_apart(env, variant):
+    """Injective where it must be: a body that differs from another in
+    one op, row, frame, row-label key, the order of two calls, or the
+    default against a named frame gets an entry of its own and hits only
+    itself.  Through the cache's own door, so that a row-label key no
+    frame has can be keyed too."""
+    from pilosa_tpu import native
+
+    h, fr, ex, qc = env
+    bodies = {"base": " ".join(_BASE), variant: " ".join(_VARIANTS[variant])}
+    for n, (name, body) in enumerate(bodies.items()):
+        cached, tok = qc.lookup(h, "i", body, None)
+        assert cached is None and tok.deferred
+        tok.match = native.pql_match_pairs(body.encode())
+        assert tok.match is not None
+        assert qc.commit(h, tok, [name] * len(tok.match[0]))
+        assert len(qc) == n + 1
+    for name, body in bodies.items():
+        cached, tok = qc.lookup(h, "i", body, None)
+        assert tok is None and set(cached) == {name}
+    assert (qc.deferred_matched, qc.deferred_parsed, qc.stores, qc.hits) == (2, 0, 2, 2)
+
+
+@pytest.fixture()
+def wide(env):
+    """``env`` with rows 0-3 of frames f, g and the default frame."""
+    h, fr, ex, qc = env
+    for name in ("g", "general"):
+        h.index("i").create_frame(name, FrameOptions())
+    rng = np.random.default_rng(5)
+    for name in ("f", "g", "general"):
+        for r in range(4):
+            for c in rng.choice(64, size=24, replace=False):
+                h.index("i").frame(name).set_bit("standard", r, int(c))
+    return h, ex, qc, Executor(h, engine="numpy", qcache=None)
+
+
+def test_matched_bodies_hit_only_themselves_through_the_executor(wide, monkeypatch):
+    from pilosa_tpu.pql import parser
+
+    h, ex, qc, fresh = wide
+    bodies = [" ".join(_BASE)] + [
+        " ".join(calls) for name, calls in sorted(_VARIANTS.items())
+        if name != "one_row_label_key"  # no frame of the index has that label
+    ]
+    want = [fresh.execute("i", b) for b in bodies]
+    assert len({tuple(w) for w in want}) > 4  # the data tells most of them apart too
+    monkeypatch.setattr(parser, "parse", lambda src: pytest.fail(f"parsed {src!r}"))
+    assert [ex.execute("i", b) for b in bodies] == want
+    assert (qc.stores, len(qc), qc.deferred_matched, qc.hits) == (len(bodies),) * 3 + (0,)
+    assert [ex.execute("i", b) for b in bodies] == want
+    assert (qc.hits, qc.misses, qc.deferred_parsed) == (len(bodies), len(bodies), 0)
+
+
+def test_two_spellings_of_a_matched_body_share_an_entry(wide, monkeypatch):
+    """What the matcher reads alike is one entry: whitespace, the
+    quotes round a frame's name, the order of a leaf's two arguments.
+    A spelling's first send is a deferred miss, as ever (it stores under
+    the same key); its second hits."""
+    from pilosa_tpu.pql import parser
+
+    h, ex, qc, fresh = wide
+    monkeypatch.setattr(parser, "parse", lambda src: pytest.fail(f"parsed {src!r}"))
+    base = " ".join(_BASE)
+    spellings = [
+        base,
+        "  " + base.replace(", ", " ,\n\t").replace("(", "( ") + "\n",
+        base.replace('"f"', "'f'"),
+        base.replace('"f"', "f"),
+        base.replace('Bitmap(rowID=0, frame="f")', 'Bitmap(frame="f", rowID=0)'),
+    ]
+    assert len(set(spellings)) == len(spellings)
+    want = fresh.execute("i", base)
+    for n, body in enumerate(spellings, 1):
+        assert ex.execute("i", body) == want
+        assert (len(qc), qc.deferred_matched, qc.hits) == (1, n, 0)
+    for n, body in enumerate(spellings, 1):
+        assert ex.execute("i", body) == want and qc.hits == n
+    assert (qc.misses, qc.deferred_parsed) == (len(spellings), 0)
+
+
+_UNMATCHED = {
+    "three_operands": " ".join(
+        f'Count(Intersect(Bitmap(rowID={a}, frame="f"), Bitmap(rowID=1, frame="f"), '
+        'Bitmap(rowID=2, frame="g")))' for a in (0, 3)),
+    "nested_tree": 'Count(Union(Intersect(Bitmap(rowID=0, frame="f"), '
+        f'Bitmap(rowID=1, frame="g")), Bitmap(rowID=2, frame="f"))) {_call()}',
+    "single_call": _call(),
+    "bitmap_result": f'Intersect(Bitmap(rowID=0, frame="f"), Bitmap(rowID=1, frame="f")) {_call()}',
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_UNMATCHED))
+def test_body_no_matcher_takes_is_keyed_by_the_parse(wide, shape):
+    h, ex, qc, fresh = wide
+    body = _UNMATCHED[shape]
+    want = [getattr(r, "bits", lambda: r)() for r in fresh.execute("i", body)]
+    got = lambda: [getattr(r, "bits", lambda: r)() for r in ex.execute("i", body)]
+    assert got() == want
+    assert (qc.deferred_parsed, qc.deferred_matched, qc.stores, qc.hits) == (1, 0, 1, 0)
+    assert isinstance(next(iter(qc._store))[1], str)
+    assert got() == want
+    assert (qc.deferred_parsed, qc.deferred_matched, qc.stores, qc.hits) == (1, 0, 1, 1)
+
+
+def test_topn_beside_pair_counts_is_judged_by_the_parse(wide):
+    h, ex, qc, fresh = wide
+    body = f'{_call()} TopN(frame="f", n=2)'
+    for sends in (1, 2):
+        assert ex.execute("i", body) == fresh.execute("i", body)
+        assert (qc.deferred_parsed, qc.deferred_matched, qc.ineligible, len(qc)) == (1, 0, sends, 0)
+
+
+@pytest.mark.parametrize("again", ["by_the_matcher", "by_the_parse"])
+def test_matched_body_dropped_from_the_memo_and_keyed_again(wide, again, monkeypatch):
+    """The memo is a 512-entry LRU: a body it dropped is a deferred miss
+    on its next send and is keyed anew.  By the matcher again, it finds
+    its old entry's key and replaces it; by the parse (another lane
+    answered this time), it stores beside it - the key spaces never
+    meet - which costs a miss and never a wrong count."""
+    h, ex, qc, fresh = wide
+    body, other = " ".join(_BASE), " ".join(_VARIANTS["one_op"])
+    want = fresh.execute("i", body)
+    assert ex.execute("i", body) == want and ex.execute("i", body) == want
+    assert (qc.hits, qc.deferred_matched, len(qc)) == (1, 1, 1)
+    qc._canon_max = 1
+    assert ex.execute("i", other) == fresh.execute("i", other)
+    assert list(qc._canon) == [other]
+    if again == "by_the_parse":
+        monkeypatch.setenv("PILOSA_TPU_NO_FASTLANE", "1")
+    assert ex.execute("i", body) == want
+    assert (qc.hits, qc.misses, qc.deferred) == (1, 3, 3)
+    if again == "by_the_matcher":
+        assert (qc.deferred_matched, qc.deferred_parsed, len(qc)) == (3, 0, 2)
+    else:
+        assert (qc.deferred_matched, qc.deferred_parsed, len(qc)) == (2, 1, 3)
+        assert sorted(type(k[1]).__name__ for k in qc._store) == ["str", "tuple", "tuple"]
+    h.index("i").frame("f").set_bit("standard", 0, 60)
+    h.index("i").frame("f").set_bit("standard", 1, 60)
+    assert ex.execute("i", body) == fresh.execute("i", body) != want
+
+
+@pytest.mark.parametrize("route", ["match", "parse"])
+def test_body_over_the_fingerprint_bound_is_keyed_by_neither_route(wide, route, monkeypatch):
+    import pilosa_tpu.qcache as qcache_mod
+    from pilosa_tpu.pql import parser
+
+    h, ex, qc, fresh = wide
+    body = " ".join(_BASE)
+    monkeypatch.setattr(qcache_mod, "_FINGERPRINT_MAX_LEN", len(body) - 1)
+    monkeypatch.setattr(qcache_mod, "_matched_info", lambda m: pytest.fail("keyed by the match"))
+    monkeypatch.setattr(qcache_mod, "_parsed_info", lambda s: pytest.fail("keyed by the parse"))
+    if route == "parse":
+        monkeypatch.setenv("PILOSA_TPU_NO_FASTLANE", "1")
+    for sends in (1, 2):
+        assert ex.execute("i", body) == fresh.execute("i", body)
+        assert (qc.stores, len(qc), qc.ineligible, qc.hits) == (0, 0, sends, 0)
+    assert qc._canon[body] is None
+    assert (qc.deferred_matched, qc.deferred_parsed) == ((1, 0) if route == "match" else (0, 1))
+
+
+def test_commit_span_says_what_keyed_the_entry(wide):
+    from pilosa_tpu.trace import Span
+
+    h, ex, qc, fresh = wide
+    tags = []
+    for body in (" ".join(_BASE), _UNMATCHED["three_operands"]):
+        for _ in range(2):
+            root = Span("root")
+            ex.execute("i", body, opt=ExecOptions(span=root))
+            commit = [c for c in root.children if c.name == "qcache.commit"]
+            tags.append(commit[0].tags if commit else root.tags["qcache"])
+    assert tags == [{"keyed": "match"}, "hit", {"keyed": "parse"}, "hit"]
+    h.index("i").frame("f").set_bit("standard", 0, 61)
+    root = Span("root")
+    ex.execute("i", " ".join(_BASE), opt=ExecOptions(span=root))
+    assert root.children[-1].tags == {"keyed": "memo"}
+    qc.min_cost_ms = 1e9
+    root = Span("root")
+    ex.execute("i", " ".join(_VARIANTS["one_op"]), opt=ExecOptions(span=root))
+    assert root.children[-1].name == "qcache.commit"
+    assert root.children[-1].tags == {}  # under the floor: keyed by nothing
 
 
 def test_non_cacheable_body_counts_ineligible_once_at_commit(env):
