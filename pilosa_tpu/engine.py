@@ -1042,9 +1042,16 @@ class JaxEngine:
         return 1
 
     def to_numpy(self, x, span=None) -> np.ndarray:
-        """``x`` on the host; ``span`` is for the mesh engine, whose wait
-        is a ``mesh.fetch`` child of it."""
-        return np.asarray(x)
+        """``x`` on the host.  Under a sampled request's ``span`` the
+        conversion is its ``device.fetch`` child: the host blocks here
+        until the device has computed ``x`` and copied it over
+        (``mesh.fetch`` on the mesh engine)."""
+        if span is None:
+            return np.asarray(x)
+        sp = span.child("device.fetch")
+        out = np.asarray(x)
+        sp.finish()
+        return out
 
     def _devices(self) -> list:
         import jax

@@ -1127,7 +1127,8 @@ class Executor:
                     (tuple(frames_b), tuple(keys_b)),
                     tuple(std_slices),
                     opt.span,
-                )
+                ),
+                span=opt.span,
             )
         if self._is_distributed(opt):
             # Cluster hop: build the matched dict + forwarded Query (from
@@ -2320,12 +2321,16 @@ class Executor:
         made after the pass's last dispatch has gone out.  Where
         ``matrix`` lies over several devices the counts were psummed
         across the mesh, and the wait is a ``mesh.fetch`` span of the
-        traced request and one ``gather.mesh_fetches``; a Gram's counts
-        are the host's already."""
+        traced request and one ``gather.mesh_fetches``; on one device it
+        is a ``device.fetch`` span and one ``gather.fetches``; a Gram's
+        counts are the host's already."""
         if isinstance(counts, np.ndarray):
             return counts.astype(np.int64, copy=False)
-        if self.meter is not None and self.engine.slice_axis_devices(matrix.shape[0]) > 1:
-            self.meter.stats.count("gather.mesh_fetches")
+        if self.meter is not None:
+            if self.engine.slice_axis_devices(matrix.shape[0]) > 1:
+                self.meter.stats.count("gather.mesh_fetches")
+            else:
+                self.meter.stats.count("gather.fetches")
         return self.engine.to_numpy(counts, span).astype(np.int64)
 
     def _group_counts(

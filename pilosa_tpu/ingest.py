@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import struct
 import threading
+import time
 import zlib
 
 from pilosa_tpu.analysis import lockcheck
@@ -351,6 +352,15 @@ class StreamIngestor:
         return {"staged": st["off"], "done": done, "ops": st["ops"]}
 
 
+def _stamp(stamps: list, i: int) -> None:
+    """Now, in field ``i`` of the stamps of every item that a sampled
+    request brought (none: no clock is read)."""
+    if stamps:
+        t = time.perf_counter()
+        for st in stamps:
+            st[i] = t
+
+
 @lockcheck.guarded_class
 class WriteQueue:
     """Rotating-leader group commit (no dedicated thread, no idle timer)."""
@@ -375,11 +385,22 @@ class WriteQueue:
         self.stat_batches = 0
         self.stat_items = 0
 
-    def submit(self, item):
+    def submit(self, item, span=None):
         """Enqueue one item; blocks until its batch commits.  Returns the
         per-item result from apply_batch (raising it if it is an
-        exception), or raises the whole batch's error."""
-        slot = [False, None, None]  # done, result, exception
+        exception), or raises the whole batch's error.
+
+        Under a sampled request's ``span`` the item's slot also carries
+        three stamps - its append, the start of the batch that took it
+        and that batch's completion - and the request gets two finished
+        children from them: ``serve.queue`` (append to batch start: the
+        wait for the batches ahead) and ``serve.pass`` (batch start to
+        done; tags ``leader``: this thread ran it, ``batch``: items in
+        it).  An item without a span takes no stamp."""
+        slot = [False, None, None, None]  # done, result, exception, a sampled request's stamps
+        if span is not None:
+            # appended, batch start, batch done, leader's thread, batch size
+            slot[3] = [time.perf_counter(), 0.0, 0.0, 0, 0]
         with self._cv:
             self._items.append((item, slot))
             while not slot[0]:
@@ -393,12 +414,18 @@ class WriteQueue:
                     self.stat_batches += 1
                     self.stat_items += len(batch)
                     self._mu.release()
+                    stamped = [s[3] for _, s in batch if s[3] is not None]
+                    for st in stamped:
+                        st[3], st[4] = threading.get_ident(), len(batch)
+                    _stamp(stamped, 1)
                     try:
                         results = self._apply([it for it, _ in batch])
+                        _stamp(stamped, 2)
                         for (_, s), r in zip(batch, results):
                             s[1] = r
                             s[0] = True
                     except BaseException as e:  # noqa: BLE001 — poison batch
+                        _stamp(stamped, 2)
                         for _, s in batch:
                             s[2] = e
                             s[0] = True
@@ -408,6 +435,11 @@ class WriteQueue:
                         self._cv.notify_all()
                     continue
                 self._cv.wait()
+        if span is not None:
+            appended, began, done, leader, n = slot[3]
+            span.record("serve.queue", appended, began)
+            span.record("serve.pass", began, done).annotate(
+                leader=leader == threading.get_ident(), batch=n)
         if slot[2] is not None:
             raise slot[2]
         if isinstance(slot[1], BaseException):

@@ -252,7 +252,8 @@ class Handler:
         request's root span starts there, its ``door.read`` child covers
         take-up to here (header parse, body read), and its ``cpu_ms`` tag
         is the thread's CPU time over the root's interval, so ``ms -
-        cpu_ms`` is the time the request held no core.
+        cpu_ms`` is the time the request held no core; its ``t0_s`` tag is
+        the take-up stamp itself, on ``perf_counter``'s clock.
 
         The TRACE door wraps the QoS door: the head-sampling decision is
         made once here (``X-Pilosa-Trace`` forces it — the client
@@ -274,6 +275,9 @@ class Handler:
         t0 = t_here if taken is None else taken[0]
         trace = tracer.begin(headers, name=f"{method} {path}", t0=t0)
         if trace is not None:
+            # The root's start on the machine's monotonic clock: a client
+            # on this machine places the root between its own stamps.
+            trace.root.tags["t0_s"] = round(t0, 6)
             cpu0 = time.thread_time() if taken is None else taken[1]
             if taken is not None:
                 trace.root.record("door.read", t0, t_here).tags["bytes"] = len(body)
@@ -669,6 +673,8 @@ class Handler:
         stats = {}
         if self.stats is not None and hasattr(self.stats, "snapshot"):
             self._publish_shard_gauge()
+            if self.tracer is not None:
+                self.tracer.publish_gc()
             # One consistent snapshot under one short lock hold (the
             # striped client drains every write shard in the same hold).
             stats = self.stats.snapshot()
@@ -753,6 +759,8 @@ class Handler:
             # scrape time — they are pull-model state, not event counters.
             lockcheck.publish_global_stats(self.stats)
             self._publish_shard_gauge()
+            if self.tracer is not None:
+                self.tracer.publish_gc()
         # render() reads one snapshot_typed() — the striped client
         # drains and renders under a single lock hold, so a scrape is
         # consistent against concurrent mutation by construction.
@@ -1337,6 +1345,24 @@ class _HTTPRequestHandler(BaseHTTPRequestHandler):
                                     taken=self._taken)
         status, ctype, payload = out[:3]
         extra = out[3] if len(out) > 3 else {}
+        if trace_mod.TRACE_SPANS_HEADER not in extra:
+            self._reply(status, ctype, payload, extra)
+            return
+        # A traced response: the root span has ended (its tree is in
+        # ``extra``), so the reply is an annotation of its own in a
+        # profile's host plane, and a timing.
+        t = time.perf_counter()
+        ann = trace_mod.open_annotation("door.reply")
+        try:
+            self._reply(status, ctype, payload, extra)
+        finally:
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            if self.handler.stats is not None:
+                # a timing takes seconds: /debug/vars shows http.reply_ms.avg_ms
+                self.handler.stats.timing("http.reply_ms", time.perf_counter() - t)
+
+    def _reply(self, status: int, ctype: str, payload: bytes, extra: dict) -> None:
         self.send_response(status)
         self.send_header("Content-Type", ctype)
         self.send_header("Content-Length", str(len(payload)))

@@ -61,14 +61,26 @@ Design:
   imported at the first sampled span, so this module imports without it.
 - **Span names of the served path** — root (``POST /index/<i>/query``,
   from the request line's arrival; tag ``cpu_ms`` = the serving thread's
-  CPU time over the same interval), ``door.read``, ``qos.admit``,
+  CPU time over the same interval, tag ``t0_s`` = that arrival on
+  ``time.perf_counter()``, the machine's monotonic clock, so a client on
+  the same machine can place the root between its own send and receive
+  stamps), ``door.read``, ``qos.admit``,
   ``qcache.lookup`` / ``qcache.commit``, ``serve.validate``, ``serve.repair`` >
   ``pool.lock_wait``, ``pool.repair`` > ``pool.fetch`` / ``pool.scatter``
   / ``pool.gram`` (> ``mesh.fetch``: the mesh engine's wait for a reduced
-  result), ``pool.refresh``, ``pool.miss`` > ``pool.miss.fetch`` /
-  ``pool.miss.scatter``, ``device`` (tag ``lane``; a gather dispatch
-  says what it gathered), ``write.apply``, ``parse``, ``fused``, ``call.<Name>``,
-  ``slices`` / ``slice_chunk``, ``remote``, ``encode``.
+  result), ``serve.queue`` / ``serve.pass`` (the read coalescer: a
+  request's wait for the batch that took it, and that batch from start to
+  done, tags ``leader`` and ``batch``; recorded after the fact, the work
+  inside has its own names on the leader's thread), ``pool.refresh``,
+  ``pool.miss`` > ``pool.miss.fetch`` / ``pool.miss.scatter``, ``device``
+  (tag ``lane``; a gather dispatch says what it gathered), ``device.fetch``
+  (one chip: the host's wait for a dispatch's counts; ``mesh.fetch`` on a
+  mesh), ``write.apply``, ``parse``, ``fused``, ``call.<Name>``,
+  ``slices`` / ``slice_chunk``, ``remote``, ``encode``, ``interp.gc`` (a
+  full collection of the interpreter that overlapped the request, on
+  whichever thread it ran: it stops them all; tags ``collected``,
+  ``t0_s``).  Outside the tree, as annotations only: ``door.reply`` (the
+  status line, headers and payload of a traced response going out).
 
 Finished traces land in a bounded in-memory ring served at
 ``/debug/traces`` (JSON, newest-first, ``?min-ms=`` filter).  Config:
@@ -78,6 +90,7 @@ env, wired through Config into the server, lockstep CLI, and handler.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import logging
@@ -131,6 +144,8 @@ _annotation_cls: Any = None
 
 
 def _open_annotation(name: str):
+    """An entered ``TraceAnnotation`` of ``name`` (leave it with
+    ``__exit__(None, None, None)``), or None where jax is not there."""
     global _annotation_cls
     cls = _annotation_cls
     if cls is None:
@@ -144,6 +159,75 @@ def _open_annotation(name: str):
     ann = cls(name)
     ann.__enter__()
     return ann
+
+
+def open_annotation(name: str):
+    """For a site outside any span (the door's reply, after the root has
+    ended): an entered annotation to leave with ``__exit__``, or None."""
+    return _open_annotation(name)
+
+
+class GcWatch:
+    """The interpreter's full collections, as the one ``gc.callbacks``
+    entry of the process.  A collection of generation 2 stops every thread
+    for as long as it walks the heap: it is stamped, covered by an
+    ``interp.gc`` annotation, counted (``collections``, ``pause_ms``: the
+    gauges ``gc.full_collections`` and ``gc.full_pause_ms`` at scrape
+    time) and kept among the last ``KEPT`` pauses ``(t0, t1, collected)``,
+    from which a sampled request takes those that overlapped it.  Younger
+    generations return at the first comparison.
+
+    A collection starts wherever a thread allocates, under any lock, so
+    the callback takes none and imports nothing: plain attributes, and an
+    annotation only once a sampled span has resolved the class."""
+
+    KEPT = 64
+
+    def __init__(self):
+        self.collections = 0
+        self.pause_ms = 0.0
+        self.pauses: "deque[tuple]" = deque(maxlen=self.KEPT)
+        self._t0 = 0.0
+        self._ann = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            if _annotation_cls:
+                self._ann = _open_annotation("interp.gc")
+            return
+        t1 = time.perf_counter()
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        self.pauses.append((self._t0, t1, info["collected"]))
+        self.collections += 1
+        self.pause_ms += (t1 - self._t0) * 1e3
+
+    def overlapping(self, t0: float, t1: float) -> list:
+        """The kept pauses that overlap ``[t0, t1]``, oldest first."""
+        out = []
+        for p in reversed(list(self.pauses)):   # newest first, to the first that ended before t0
+            if p[1] <= t0:
+                break
+            if p[0] < t1:
+                out.append(p)
+        return out[::-1]
+
+
+_gc_watch: Optional[GcWatch] = None
+
+
+def gc_watch() -> GcWatch:
+    """The process's one ``GcWatch``, installed at the first call; a later
+    call adds no entry."""
+    global _gc_watch
+    if _gc_watch is None:
+        _gc_watch = GcWatch()
+        gc.callbacks.append(_gc_watch)
+    return _gc_watch
 
 
 class Span:
@@ -304,6 +388,7 @@ class Tracer:
         stats=None,
         rng: Optional[random.Random] = None,
         costs=None,
+        gc_watch: Optional[GcWatch] = None,
     ):
         from pilosa_tpu.stats import NOP_STATS
 
@@ -314,6 +399,9 @@ class Tracer:
         # trace folds into EWMA cost/bandwidth estimates keyed by
         # (index, frame, fingerprint, lane).  None = ledger disabled.
         self.costs = costs
+        # The process's GcWatch (the server's tracer has it): a sampled
+        # request shows the full collections that overlapped it.
+        self.gc_watch = gc_watch
         self._rng = rng if rng is not None else random.Random()
         self._mu = lockcheck.named_lock("trace._mu")
         self._ring: "deque[dict]" = deque(maxlen=max(1, int(ring)))
@@ -371,9 +459,10 @@ class Tracer:
         caller asked for propagation.  The unsampled fast path is one
         comparison."""
         slow = self.slow_ms > 0.0 and dt_ms >= self.slow_ms
-        if trace is None and not slow:
+        sampled = trace is not None
+        if not sampled and not slow:
             return None
-        if trace is None:
+        if not sampled:
             # Unsampled but slow: synthesize a root-only trace so the
             # ring and the log still carry the event (head sampling
             # cannot reconstruct stages after the fact).
@@ -382,6 +471,9 @@ class Tracer:
             trace.root.tags["unsampled"] = True
         root = trace.root
         root.finish()
+        if sampled and self.gc_watch is not None:
+            for t0, t1, collected in self.gc_watch.overlapping(root.t0, root.t0 + root.ms / 1e3):
+                root.record("interp.gc", t0, t1).annotate(collected=collected, t0_s=round(t0, 6))
         if status:
             root.tags["status"] = status
         if tags:
@@ -400,8 +492,16 @@ class Tracer:
                 slim.pop("children", None)
                 slim["truncated"] = True
                 payload = json.dumps([slim], separators=(",", ":"))
+                self.stats.count("trace.spans_truncated")
             return {TRACE_SPANS_HEADER: payload}
         return None
+
+    def publish_gc(self) -> None:
+        """Pull-model gauges, at scrape time: the process's full
+        collections so far and the milliseconds they stopped it for."""
+        if self.gc_watch is not None:
+            self.stats.gauge("gc.full_collections", float(self.gc_watch.collections))
+            self.stats.gauge("gc.full_pause_ms", self.gc_watch.pause_ms)
 
     def record(self, trace: Trace) -> None:
         with self._mu:
@@ -453,6 +553,7 @@ def from_config(cfg, stats=None, costs=None) -> Tracer:
         ring=getattr(cfg, "trace_ring", DEFAULT_RING),
         stats=stats,
         costs=costs,
+        gc_watch=gc_watch(),
     )
 
 

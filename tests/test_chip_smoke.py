@@ -13,9 +13,11 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _chip_smoke(*args, timeout=600):
+def _chip_smoke(*args, timeout=600, cache_dir=None):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
+    if cache_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
     # conftest's 8 virtual devices are for the in-process tests: the
     # script describes its own deployment.
     env.pop("XLA_FLAGS", None)
@@ -27,8 +29,11 @@ def _chip_smoke(*args, timeout=600):
     return out, lines
 
 
-def test_rehearsal_passes_and_names_the_cpu():
-    out, lines = _chip_smoke("--rehearse")
+def test_rehearsal_passes_and_names_the_cpu(tmp_path):
+    # A compile cache of its own: the script counts the directory's new
+    # entries over its second start, and the checkout's directory is
+    # written by every other test worker that compiles meanwhile.
+    out, lines = _chip_smoke("--rehearse", cache_dir=tmp_path / "jax_cache")
     assert out.returncode == 0, out.stdout[-1500:] + out.stderr[-1500:]
     assert lines[-1] == {"ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
     phases = {ln["phase"]: ln for ln in lines[:-1]}

@@ -152,7 +152,15 @@ def test_slow_sampled_trace_logs_stage_breakdown(caplog):
 
 
 def test_propagate_returns_header_and_truncates_oversize():
-    t = Tracer(sample_rate=0.0)
+    class Counts:
+        def __init__(self):
+            self.n = {}
+
+        def count(self, name, value=1):
+            self.n[name] = self.n.get(name, 0) + value
+
+    stats = Counts()
+    t = Tracer(sample_rate=0.0, stats=stats)
     tr = t.begin({TRACE_HEADER.lower(): "deadbeef"}, name="POST /q")
     extra = t.finish_request(tr, name="POST /q", dt_ms=1.0)
     payload = json.loads(extra[TRACE_SPANS_HEADER])
@@ -166,6 +174,8 @@ def test_propagate_returns_header_and_truncates_oversize():
     assert len(raw) < 32000
     slim = json.loads(raw)[0]
     assert slim.get("truncated") and "children" not in slim
+    # Every reader of the header has lost that request: it is counted.
+    assert stats.n == {"trace.sampled": 2, "trace.spans_truncated": 1}
 
 
 def test_fingerprint_stable_and_bounded():
@@ -477,7 +487,10 @@ def _pool(served):
 
 
 def test_served_flat_read_has_a_span_for_each_layer(served):
+    sent = time.perf_counter()
     res, root = _post(served.host, _PAIRS)
+    # The root says when it began, on the clock this process reads too.
+    assert sent <= root["tags"]["t0_s"] <= time.perf_counter() - root["ms"] / 1e3
     assert len(res) == 6 and root["tags"]["lane"] == "flat"
     names = [c["name"] for c in root["children"]]
     for want in ("door.read", "qos.admit", "serve.validate", "device", "encode"):
@@ -611,7 +624,7 @@ def test_a_write_has_write_apply(served, body, lane, changed):
 def test_an_unsampled_request_builds_no_span_and_no_annotation(served, monkeypatch):
     from pilosa_tpu import trace as trace_mod
 
-    made = {"span": 0, "annotation": 0}
+    made = {"span": 0, "annotation": 0, "reply": 0}
     real_init = Span.__init__
 
     def counting_init(self, *a, **kw):
@@ -619,7 +632,8 @@ def test_an_unsampled_request_builds_no_span_and_no_annotation(served, monkeypat
         real_init(self, *a, **kw)
 
     def counting_annotation(name):
-        made["annotation"] += 1
+        if name != "interp.gc":   # a collection may fall anywhere: not a request's doing
+            made["reply" if name == "door.reply" else "annotation"] += 1
         return None
 
     monkeypatch.setattr(Span, "__init__", counting_init)
@@ -629,13 +643,15 @@ def test_an_unsampled_request_builds_no_span_and_no_annotation(served, monkeypat
     res, tree = _post(host, _PAIRS, trace=False)  # repairs, then serves
     _post(host, _PAIRS, trace=False)
     assert len(res) == 6 and tree is None
-    assert made == {"span": 0, "annotation": 0}
+    assert made == {"span": 0, "annotation": 0, "reply": 0}
     # The same three sampled: every span opens one annotation, but for
-    # door.read, which is made after the fact.
+    # door.read, which is made after the fact; and each traced response
+    # goes out under door.reply, an annotation with no span.
     _post(host, 'SetBit(rowID=0, frame="f", columnID=4001)')
     _res, tree = _post(host, _PAIRS)
     assert made["span"] > 10
     assert made["span"] - made["annotation"] == 2  # two requests' door.read
+    assert made["reply"] == 2
 
 
 def test_spans_are_annotations_while_open(monkeypatch):
@@ -648,10 +664,12 @@ def test_spans_are_annotations_while_open(monkeypatch):
             self.name = name
 
         def __enter__(self):
-            log.append(("enter", self.name))
+            if self.name != "interp.gc":   # a collection may fall anywhere
+                log.append(("enter", self.name))
 
         def __exit__(self, *exc):
-            log.append(("exit", self.name))
+            if self.name != "interp.gc":
+                log.append(("exit", self.name))
 
     monkeypatch.setattr(trace_mod, "_annotation_cls", Ann)
     root = Span("root")
@@ -743,11 +761,27 @@ def test_pool_and_engine_counters_reach_debug_vars(served):
     assert snap["engine.upload_bytes"] - snap0["engine.upload_bytes"] >= 131072
     metrics = urllib.request.urlopen(f"http://{host}/metrics", timeout=30).read().decode()
     assert "rowpool_repairs" in metrics and "engine_upload_bytes" in metrics
+    # A traced response's reply is timed; an untraced one's is not.
+    def replies():
+        typed = served.handler.stats.snapshot_typed()["timings"]
+        return typed.get("http.reply_ms", {"count": 0})["count"]
+
+    n0 = replies()
+    _post(host, _PAIRS)
+    _post(host, _PAIRS, trace=False)
+    assert replies() == n0 + 1
+    snap = json.loads(urllib.request.urlopen(f"http://{host}/debug/vars", timeout=30).read())
+    assert 0 < snap["http.reply_ms.avg_ms"] < 1000
+    # The process's full collections, as gauges at scrape time.
+    assert snap["gc.full_collections"] == served.handler.tracer.gc_watch.collections
+    assert "gc_full_pause_ms" in metrics
 
 
 def test_a_cold_read_through_the_coalescing_queue_owns_its_pool_spans(tmp_path):
     """The unarmed flat lane hands its arrays to the serve queue; the
     shared pass's pool spans go to the group's first sampled request."""
+    import urllib.request
+
     from pilosa_tpu.server.client import Client
     from pilosa_tpu.server.server import Server
 
@@ -767,6 +801,17 @@ def test_a_cold_read_through_the_coalescing_queue_owns_its_pool_spans(tmp_path):
         assert (miss["tags"]["sparse"], miss["tags"]["words"]) == (1, 4)
         assert miss["tags"]["upload_bytes"] == 4 * 16
         assert _find(root, "pool.lock_wait")
+        # The coalescer's wait and pass: alone in its batch, this thread ran it.
+        (queued,), (ran,) = _find(root, "serve.queue"), _find(root, "serve.pass")
+        assert ran["tags"] == {"leader": True, "batch": 1}
+        assert queued["start_ms"] + queued["ms"] == pytest.approx(ran["start_ms"], abs=0.002)
+        assert _fits(miss, ran["start_ms"], ran["start_ms"] + ran["ms"])
+        # One chip: the host's wait for each dispatch's counts is a span inside the pass.
+        fetches = _find(root, "device.fetch")
+        assert fetches and all(_fits(f, ran["start_ms"], ran["start_ms"] + ran["ms"])
+                               for f in fetches)
+        snap = json.loads(urllib.request.urlopen(f"http://{s.host}/debug/vars", timeout=30).read())
+        assert snap["gather.fetches"] == len(fetches) and "gather.mesh_fetches" not in snap
     finally:
         s.close()
 
@@ -820,3 +865,155 @@ def test_pool_spans_and_counters_for_paging_refresh_and_eviction():
     assert (pool.stat_misses, pool.stat_evictions, pool.stat_repairs) == (3, 1, 0)
     pool._reset()
     assert stats.n["rowpool.resets"] == pool.stat_resets == 1
+
+
+# -- the read coalescer's wait and pass, the one-chip wait, the collector -------
+
+
+def test_a_follower_of_a_coalesced_pass_holds_the_queues_wait_and_the_pass():
+    """Three sampled requests: the first runs alone and blocks; the two that
+    arrive meanwhile wait for it, then share one batch that one of them runs."""
+    import threading
+
+    from pilosa_tpu.ingest import WriteQueue
+
+    hold, entered = threading.Event(), threading.Event()
+
+    def apply(items):
+        if items == ["first"]:
+            entered.set()
+            assert hold.wait(30)
+        return [it.upper() for it in items]
+
+    q = WriteQueue(apply)
+    roots = {name: Span(name) for name in ("first", "b", "c")}
+    out = {}
+
+    def go(name):
+        out[name] = q.submit(name, span=roots[name])
+
+    threads = [threading.Thread(target=go, args=(n,)) for n in ("first", "b", "c")]
+    threads[0].start()
+    assert entered.wait(30)
+    threads[1].start()
+    threads[2].start()
+    deadline = time.monotonic() + 30
+    while len(q._items) < 2 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    time.sleep(0.02)   # what the two wait for: the batch ahead of theirs
+    hold.set()
+    for t in threads:
+        t.join(30)
+        assert not t.is_alive()
+    assert out == {"first": "FIRST", "b": "B", "c": "C"} and q.stat_batches == 2
+    for root in roots.values():
+        assert [c.name for c in root.children] == ["serve.queue", "serve.pass"]
+        assert all(c.ms is not None and c._ann is None for c in root.children)  # after the fact
+        queued, ran = root.children
+        assert queued.t0 + queued.ms / 1e3 == pytest.approx(ran.t0)
+    assert roots["first"].children[1].tags == {"leader": True, "batch": 1}
+    assert roots["first"].children[0].ms < 15.0
+    # One pass for the two: the same stamps, one leader, and a wait behind the first's batch.
+    (qb, pb), (qc, pc) = roots["b"].children, roots["c"].children
+    assert (pb.t0, pb.ms) == (pc.t0, pc.ms) and pb.tags["batch"] == pc.tags["batch"] == 2
+    assert sorted([pb.tags["leader"], pc.tags["leader"]]) == [False, True]
+    assert qb.ms >= 15.0 and qc.ms >= 15.0
+    assert pb.t0 >= roots["first"].children[1].t0 + roots["first"].children[1].ms / 1e3
+
+
+def test_submit_without_a_span_reads_no_clock(monkeypatch):
+    from pilosa_tpu import ingest
+
+    class Clock:
+        reads = 0
+
+        def perf_counter(self):
+            self.reads += 1
+            return time.perf_counter()
+
+    clock = Clock()
+    monkeypatch.setattr(ingest, "time", clock)
+    q = ingest.WriteQueue(lambda items: [i + 1 for i in items])
+    assert [q.submit(i) for i in range(3)] == [1, 2, 3] and clock.reads == 0
+    root = Span("root")
+    assert q.submit(7, span=root) == 8
+    assert clock.reads == 3  # the append, the batch's start, its end
+    with pytest.raises(ValueError):   # a batch that fails still closes its spans
+        ingest.WriteQueue(lambda items: [ValueError("no")]).submit(0, span=root)
+    assert [c.name for c in root.children] == ["serve.queue", "serve.pass"] * 2
+
+
+def test_the_jax_engines_fetch_is_a_span_only_under_one(monkeypatch):
+    import numpy as np
+
+    from pilosa_tpu.engine import JaxEngine
+
+    eng = JaxEngine()
+    x = eng.asarray(np.arange(6, dtype=np.uint32))
+    made = []
+    real_init = Span.__init__
+    monkeypatch.setattr(Span, "__init__",
+                        lambda self, *a, **kw: (made.append(a[0]), real_init(self, *a, **kw))[1])
+    assert eng.to_numpy(x).tolist() == [0, 1, 2, 3, 4, 5] and made == []
+    root = Span("root")
+    assert eng.to_numpy(x, root).tolist() == [0, 1, 2, 3, 4, 5]
+    assert made == ["root", "device.fetch"]
+    (sp,) = root.children
+    assert sp.name == "device.fetch" and sp.ms is not None and sp._ann is None
+
+
+def test_a_full_collection_inside_a_traced_request_is_its_interp_gc_child():
+    import gc
+
+    from pilosa_tpu import trace as trace_mod
+
+    watch = trace_mod.gc_watch()
+    assert trace_mod.gc_watch() is watch and gc.callbacks.count(watch) == 1
+    tr = trace_mod.from_config(Config())       # the server's: a second tracer adds no entry
+    assert tr.gc_watch is watch and gc.callbacks.count(watch) == 1
+    assert Tracer().gc_watch is None
+    was = gc.isenabled()
+    gc.disable()     # no collection but the ones made here
+    try:
+        n0, ms0 = watch.collections, watch.pause_ms
+        before = tr.begin({TRACE_HEADER.lower(): "1"}, name="POST /a")
+        tr.finish_request(before, name="POST /a", dt_ms=0.1)
+        trace = tr.begin({TRACE_HEADER.lower(): "1"}, name="POST /b")
+        t_in = time.perf_counter()
+        gc.collect(0)
+        gc.collect(1)
+        assert (watch.collections, watch.pause_ms) == (n0, ms0)   # young generations: nothing
+        gc.collect()
+        t_out = time.perf_counter()
+        other = tr.begin({TRACE_HEADER.lower(): "1"}, name="POST /c")  # began after the pause
+        extra = tr.finish_request(trace, name="POST /b", dt_ms=1.0)
+        tr.finish_request(other, name="POST /c", dt_ms=0.1)
+    finally:
+        if was:
+            gc.enable()
+    assert watch.collections == n0 + 1 and watch.pause_ms > ms0
+    (pause,) = [c for c in trace.root.children if c.name == "interp.gc"]
+    assert t_in <= pause.t0 and pause.t0 + pause.ms / 1e3 <= t_out and pause._ann is None
+    assert pause.tags == {"collected": pause.tags["collected"], "t0_s": round(pause.t0, 6)}
+    assert pause.tags["collected"] >= 0
+    assert not before.root.children and not other.root.children
+    (child,) = json.loads(extra[TRACE_SPANS_HEADER])[0]["children"]
+    assert child["name"] == "interp.gc" and child["tags"]["t0_s"] == pause.tags["t0_s"]
+    assert watch.overlapping(t_out + 1.0, t_out + 2.0) == []
+    assert len(watch.pauses) <= watch.KEPT
+
+
+def test_an_unsampled_root_has_no_t0_s_and_no_span(served, monkeypatch):
+    made = []
+    real_init = Span.__init__
+    monkeypatch.setattr(Span, "__init__",
+                        lambda self, *a, **kw: (made.append(a[0]), real_init(self, *a, **kw))[1])
+    out = served.handler.dispatch("POST", "/index/i/query", {}, _PAIRS.encode(), {},
+                                  taken=(time.perf_counter(), time.thread_time()))
+    assert out[0] == 200 and made == []
+    assert TRACE_SPANS_HEADER not in (out[3] if len(out) > 3 else {})
+    out = served.handler.dispatch("POST", "/index/i/query", {}, _PAIRS.encode(),
+                                  {TRACE_HEADER.lower(): "1"},
+                                  taken=(time.perf_counter(), time.thread_time()))
+    root = json.loads(out[3][TRACE_SPANS_HEADER])[0]
+    assert made[0] == "POST /index/i/query" and isinstance(root["tags"]["t0_s"], float)
