@@ -151,17 +151,42 @@ def _batch_intersection_counts(rows: np.ndarray, src: np.ndarray) -> np.ndarray:
     return bw.np_popcount(rows & src).reshape(rows.shape[0], -1).sum(axis=1)
 
 
+class FragmentColumns:
+    """One fragment's array containers at generation ``gen``, copied
+    under its lock (``Fragment.array_columns``): ``keys`` ascending,
+    container ``i``'s values ``vals[start[i] : start[i] + lens[i]]``; and
+    what these columns cannot serve: the keys of its bitmap containers,
+    and whether a bulk overlay was pending (``overlay``).  A part of a
+    view's ``core.columns.ViewColumns``."""
+
+    __slots__ = ("gen", "keys", "start", "lens", "vals", "bitmap_keys", "overlay")
+
+    def __init__(self, gen, keys, lens, vals, bitmap_keys, overlay):
+        keys = np.asarray(keys, dtype=np.int64)
+        lens = np.asarray(lens, dtype=np.int32)
+        start = np.cumsum(lens, dtype=np.int64) - lens
+        if len(keys) > 1 and not (keys[1:] > keys[:-1]).all():
+            order = np.argsort(keys)
+            keys, start, lens = keys[order], start[order], lens[order]
+        self.gen, self.overlay = gen, overlay
+        self.keys, self.start, self.lens, self.vals = keys, start, lens, vals
+        self.bitmap_keys = np.asarray(bitmap_keys, dtype=np.int64)
+
+
 class RowPieces:
-    """What ``Fragment.walk_rows`` found of a block of planes (``W`` words
-    each), fragment after fragment, and the one numpy pass that turns it
-    into words.
+    """What a walk found of a block of planes (``W`` words each) -
+    ``Fragment.walk_rows`` fragment after fragment, a view's columns
+    (``core.columns.ViewColumns``) for all the fragments they serve at
+    once - and the one numpy pass that turns it into words.
 
     The block holds ``row_ids`` (a negative id is no row: a zero plane)
     over some slices; row ``k``'s plane in a fragment's part of the block
     is that fragment's first plane plus ``k * stride`` (1 where a slice's
     rows lie together: slice-major; the slice count where a row's slices
     do: row-major).  Array containers' values are kept as they are
-    stored (copied under the fragment's lock, one array a fragment);
+    stored (copied under the fragment's lock, one array a fragment; from
+    the columns one array for all their fragments, ``cols``: each
+    container's first word, its length, the values);
     ``words()`` turns all of them at once into the block's words that are
     not zero - ``(word, bits)``: an index into the flattened block, and
     the ``uint32`` it holds, equal words OR-ed - so a block built from 64
@@ -172,29 +197,44 @@ class RowPieces:
     ``row_dense`` would give, plane for plane); a pool miss with no dense
     piece ships ``words()`` itself."""
 
-    __slots__ = ("rows", "off", "at", "lens", "vals", "dense", "_words")
+    __slots__ = ("rows", "_off", "at", "lens", "vals", "cols", "served", "dense", "_words")
 
     def __init__(self, row_ids: Sequence[int], stride: int = 1):
-        per_row = SLICE_WIDTH >> 16  # containers a row spans
         # (first word of the row's plane, row id), the rows that exist
         self.rows = [(k * stride * _WORDS, r) for k, r in enumerate(row_ids) if r >= 0]
-        # container key -> its first word, less the fragment's first plane's
-        self.off = {
-            r * per_row + j: w0 + j * 2048 for w0, r in self.rows for j in range(per_row)
-        }
+        self._off = None
         self.at: list[int] = []    # per array container: its first word in the flattened block
         self.lens: list[int] = []  # ... and how many values it holds
         self.vals: list[np.ndarray] = []  # the values, one concatenated array a fragment
+        # The same three of the fragments a view's columns served, as arrays.
+        self.cols: Optional[tuple[np.ndarray, np.ndarray, np.ndarray]] = None
+        self.served = 0  # ... and how many fragments that was
         self.dense: list[tuple[int, np.ndarray]] = []
         self._words = None
 
+    @property
+    def off(self) -> dict[int, int]:
+        """Container key -> its first word, less the fragment's first
+        plane's (what ``walk_rows`` meets a fragment's keys with)."""
+        if self._off is None:
+            per_row = SLICE_WIDTH >> 16  # containers a row spans
+            self._off = {
+                r * per_row + j: w0 + j * 2048 for w0, r in self.rows for j in range(per_row)
+            }
+        return self._off
+
     def words(self) -> tuple[np.ndarray, np.ndarray]:
         if self._words is None:
-            if not self.vals:
+            if self.cols is None and not self.vals:
                 self._words = (np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.uint32))
                 return self._words
-            v = np.concatenate(self.vals)
-            word = np.repeat(np.asarray(self.at, dtype=np.int64), self.lens) + (v >> 5)
+            at, lens, v = np.asarray(self.at, dtype=np.int64), self.lens, self.vals
+            if self.cols is not None:  # the columns' fragments first, then the walked ones
+                at = np.concatenate((self.cols[0], at))
+                lens = np.concatenate((self.cols[1], np.asarray(lens, dtype=np.int32)))
+                v = [self.cols[2]] + v
+            v = np.concatenate(v)
+            word = np.repeat(at, lens) + (v >> 5)
             bit = np.uint32(1) << (v & np.uint32(31))
             # Values ascend inside a container and no two containers share
             # a word, so equal words are neighbours: OR each run.
@@ -1259,6 +1299,22 @@ class Fragment:
                         pieces.dense.append((base + w0, ov.copy()))
             if lows:
                 pieces.vals.append(np.concatenate(lows))
+
+    def array_columns(self) -> FragmentColumns:
+        """This slice's array containers as columns, at the generation it
+        has now: one pass over the container dict and one copy of the
+        values, under the lock."""
+        with self._mu:
+            self._assert_open()
+            cs = self.storage.containers
+            keys, arrays = list(cs), [c.array for c in cs.values()]
+            bitmap_keys = [k for k, a in zip(keys, arrays) if a is None]
+            if bitmap_keys:
+                keys = [k for k, a in zip(keys, arrays) if a is not None]
+                arrays = [a for a in arrays if a is not None]
+            vals = np.concatenate(arrays) if arrays else np.zeros(0, dtype=np.uint32)
+            gen, overlay = self.generation, bool(self._bulk_planes)
+        return FragmentColumns(gen, keys, [len(a) for a in arrays], vals, bitmap_keys, overlay)
 
     def row_device(self, row_id: int, engine):
         """Dense row as an ENGINE array, cached device-side.

@@ -16,6 +16,7 @@ from pilosa_tpu.analysis import lockcheck
 from typing import Callable, Optional
 
 from pilosa_tpu.core import cache as cache_mod
+from pilosa_tpu.core.columns import ViewColumns
 from pilosa_tpu.core.fragment import DEFAULT_CACHE_SIZE, Fragment
 from pilosa_tpu.pilosa import SLICE_WIDTH
 
@@ -62,6 +63,9 @@ class View:
         # Guards fragment create against concurrent writers (view.go mu analog).
         self._mu = lockcheck.named_rlock("core.view._mu")
         self.fragments: dict[int, Fragment] = {}
+        # The fragments' array containers as columns, for a block's walk
+        # (executor._walk_block); built by what the walks observe.
+        self.columns = ViewColumns(self.fragments)
 
     # -- lifecycle ------------------------------------------------------
 
@@ -77,6 +81,7 @@ class View:
         for f in list(self.fragments.values()):
             f.close()
         self.fragments.clear()
+        self.columns.drop()
 
     def flush_caches(self) -> None:
         # list() snapshots: writers may insert fragments concurrently

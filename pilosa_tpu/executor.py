@@ -40,6 +40,7 @@ from pilosa_tpu.core import timequantum as tq
 from pilosa_tpu.core.view import VIEW_INVERSE, VIEW_STANDARD
 from pilosa_tpu.engine import new_engine
 from pilosa_tpu.rowpool import DeviceRowPool, chunk_queries, pool_capacity
+from pilosa_tpu.stats import NOP_STATS
 from pilosa_tpu.pilosa import (
     ErrFrameInverseDisabled,
     ErrFrameNotFound,
@@ -2450,19 +2451,34 @@ class Executor:
 
     def _walk_block(self, index, frame, view, chunk_slices, rows, row_major=False):
         """What storage holds of a block of ``rows`` x ``chunk_slices``
-        (``core.fragment.RowPieces``): one walk a fragment over the rows'
-        containers (``Fragment.walk_rows``, under that fragment's lock),
-        planes numbered as the block lays them out - slice-major
-        ``[len(chunk_slices), len(rows)]``, or ``[len(rows),
+        (``core.fragment.RowPieces``): one lookup in the view's columns
+        (``core.columns.ViewColumns``: a copy of the view's array
+        containers, two probes a row whatever the slice count) for every
+        fragment whose part of them is at the fragment's generation, and
+        one walk a fragment over the rows' containers
+        (``Fragment.walk_rows``, under that fragment's lock) for the
+        others - no part yet or a write since, a bitmap container among
+        the block's keys, a pending bulk overlay; fragment by fragment
+        what ``walk_rows`` would have returned at some instant of this
+        call.  Planes are numbered as the block lays them out -
+        slice-major ``[len(chunk_slices), len(rows)]``, or ``[len(rows),
         len(chunk_slices)]`` with ``row_major``.  Its two consumers:
         ``_densify_block`` (the dense block) and a pool miss's sparse
         upload (``rowpool._page_in``: the word list itself)."""
         n_s, n_r = len(chunk_slices), len(rows)
         pieces = RowPieces(rows, stride=n_s if row_major else 1)
-        for bi, s in enumerate(chunk_slices):
-            f = self.holder.fragment(index, frame, view, s)
-            if f is not None:
-                f.walk_rows(pieces, bi if row_major else bi * n_r)
+        v = self.holder.view(index, frame, view)
+        if v is not None:
+            stats = self.meter.stats if self.meter is not None else NOP_STATS
+            held = v.columns.nbytes
+            v.columns.walk(pieces, chunk_slices, np.arange(n_s) * (1 if row_major else n_r), stats)
+            if v.columns.nbytes != held:  # parts were built: what all views' columns hold now
+                stats.gauge("walk.snapshot_bytes", sum(
+                    vw.columns.nbytes
+                    for idx in list(self.holder.indexes.values())
+                    for fr in list(idx.frames.values())
+                    for vw in list(fr.views.values())
+                ))
         return pieces
 
     def _densify_block(
@@ -2473,8 +2489,9 @@ class Executor:
         [len(rows), len(chunk_slices), W] with ``row_major=True`` (the
         streaming gather lane: each row's slices contiguous for one-descriptor
         DMAs).  Filled directly in target order — no transpose copy — from
-        one walk per fragment over the rows' containers and one numpy pass
-        over all of them (``_walk_block``, ``RowPieces.fill``); a negative
+        one walk of the block (the view's columns, a fragment's dict where
+        they cannot serve it) and one numpy pass over what it found
+        (``_walk_block``, ``RowPieces.fill``); a negative
         row id (the tail of a pool miss's bucket) is a zero plane."""
         if row_major:
             block = np.zeros((len(rows), len(chunk_slices), _WORDS), dtype=np.uint32)

@@ -260,6 +260,22 @@ class DeviceRowPool:
         self._reset()
         self.box = self._new_box()
 
+    def _fetch_block(self, rows: list[int], slice_idxs: list[int]):
+        """(the dense block of ``rows`` over ``slice_idxs``, laid out per
+        ``self.row_major``; how many of its fragments the view's columns
+        served: ``RowPieces.served``, 0 for a pool with ``fetch`` alone)."""
+        if self.fetch_pieces is None:
+            return self.fetch(rows, slice_idxs), 0
+        pieces = self.fetch_pieces(rows, slice_idxs)
+        return self._dense(pieces, len(rows), len(slice_idxs)), pieces.served
+
+    def _dense(self, pieces, n_rows: int, n_slices: int) -> np.ndarray:
+        """The dense block of a walk's pieces, laid out per ``self.row_major``."""
+        shape = (n_rows, n_slices) if self.row_major else (n_slices, n_rows)
+        block = np.zeros(shape + (self.words,), dtype=np.uint32)
+        pieces.fill(block)
+        return block
+
     def _refresh_stale(self, stale: list[int]) -> None:
         """Re-pull resident rows' planes for written slices, or reset.
 
@@ -315,7 +331,9 @@ class DeviceRowPool:
         ``span`` (the request's ``pool.repair``) gets the tag ``form``
         (``step`` or ``composed``: which form the engine ran) and a child
         per stage:
-        ``pool.fetch`` (host densify), ``pool.scatter`` (index building,
+        ``pool.fetch`` (host densify; tag ``snapshot``: fragments that
+        the view's columns served - none where only the slice just
+        written is fetched), ``pool.scatter`` (index building,
         upload and the dispatch) and ``pool.gram``, where the host blocks
         on the device for the counts and folds them into its Gram (on the
         mesh engine with a ``mesh.fetch`` child for the wait itself)."""
@@ -342,12 +360,14 @@ class DeviceRowPool:
         for si in patched:
             by_rows.setdefault(tuple(per_slice[si]), []).append(si)
         sp = span.child("pool.fetch") if span is not None else None
-        groups = [
-            (group, [self.slot_of[r] for r in rows_t], self.fetch(list(rows_t), group))
-            for rows_t, group in by_rows.items()  # blocks laid out per self.row_major
-        ]
+        served = 0
+        groups = []
+        for rows_t, group in by_rows.items():
+            block, n = self._fetch_block(list(rows_t), group)
+            groups.append((group, [self.slot_of[r] for r in rows_t], block))
+            served += n
         if sp is not None:
-            sp.finish()
+            sp.finish().annotate(snapshot=served)
             sp = span.child("pool.scatter")
         planes = sum(len(group) * len(slots) for group, slots, _ in groups)
         self.stat_patch_planes += planes
@@ -406,6 +426,13 @@ class DeviceRowPool:
 
         A chunk is sparse or dense by what the walk of its rows found
         (``fetch_pieces``; a pool built with ``fetch`` alone pages dense).
+        The walk (``executor._walk_block``) reads the view's columns - a
+        copy of its array containers in flat arrays, two probes a row and
+        a fixed handful of numpy calls whatever the slice count
+        (``core.columns.ViewColumns``) - for every fragment whose part of
+        them is at the fragment's generation, and walks the dict
+        (``Fragment.walk_rows``) of the others; ``pool.miss.fetch``'s tag
+        ``snapshot`` says how many fragments the columns served.
         Sparse, when no row of it has a bitmap container or a pending
         bulk overlay and its words that are not zero number at most
         ``MISS_WORDS_MAX``: those words go to the device as (slice, slot,
@@ -441,9 +468,7 @@ class DeviceRowPool:
                 pieces = self.fetch_pieces(rows + tail, all_slices)
                 word, values = pieces.words()
                 if pieces.dense or len(word) > MISS_WORDS_MAX:
-                    shape = (bucket, self.n_slices) if self.row_major else (self.n_slices, bucket)
-                    block = np.zeros(shape + (self.words,), dtype=np.uint32)
-                    pieces.fill(block)
+                    block = self._dense(pieces, bucket, self.n_slices)
                 else:
                     plane, w = np.divmod(word, self.words)
                     if self.row_major:
@@ -455,6 +480,8 @@ class DeviceRowPool:
                     ).astype(np.int32)
             if sp is not None:
                 sp.finish()
+                if self.fetch_pieces is not None:
+                    sp.annotate(snapshot=pieces.served)
                 sp = span.child("pool.miss.scatter")
             if cells is None:
                 matrix = set_rows(matrix, into + tail, block, donate=at > 0)
@@ -519,9 +546,12 @@ class DeviceRowPool:
 
         What a miss costs, under the lock: the LRU's victims leave
         (host bookkeeping), then the missing rows page in by chunks of
-        ``MISS_CHUNK_ROWS`` (``_page_in``): one walk a fragment over a
-        chunk's rows and one numpy pass give the chunk's words that are
-        not zero - a span ``pool.miss.fetch`` a chunk - and the engine
+        ``MISS_CHUNK_ROWS`` (``_page_in``): one lookup in the view's
+        columns for all the fragments they serve (a fragment that was
+        written is walked dict by dict until it has been quiet for two
+        walks: ``core.columns``) and one numpy pass give the chunk's words
+        that are not zero - a span ``pool.miss.fetch`` a chunk, tag
+        ``snapshot``: the fragments the columns served - and the engine
         enqueues their upload (16 bytes a word) and the program that
         zeroes the chunk's slots and writes the words into them
         (``pool.miss.scatter``); a chunk the word list cannot hold (a

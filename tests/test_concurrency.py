@@ -57,7 +57,8 @@ def test_concurrent_writers_readers_snapshots(tmp_path):
     def snapshotter():
         try:
             while not stop.is_set():
-                for frag in list(fr.view("standard").fragments.values()):
+                view = fr.view("standard")      # None until the first write has made it
+                for frag in list(view.fragments.values()) if view is not None else ():
                     frag.snapshot()
         except BaseException as exc:  # pragma: no cover
             errors.append(exc)
@@ -326,4 +327,80 @@ def test_gram_at_scale_reads_stable_under_write_churn(tmp_path, write_queue):
         assert not t.is_alive(), "thread hung (deadlock?)"
     assert not failures, failures[:2]
     assert writes_done[0] > 0, "writer made no progress: churn never happened"
+    h.close()
+
+
+def test_blocks_walked_while_a_slice_is_written_are_exact(tmp_path):
+    """A writer sets bits in one slice while a reader walks blocks over
+    all slices (``Executor._walk_block``: the view's columns where a
+    fragment's part is at its generation, its dict where not): the
+    written slice's part of every block is the fragment's content at one
+    of the generations it had between the walk's start and its end, the
+    other slices' are what they always held, and the walks took both
+    ways and rebuilt the written part (the counters).  Under the lock
+    checker (conftest's gate): the order ``core.columns._mu`` ->
+    ``core.fragment._mu`` and the lockset of ``ViewColumns._state``."""
+    from pilosa_tpu.analysis import lockcheck
+    from pilosa_tpu.stats import ExpvarStatsClient
+
+    assert lockcheck.enabled()
+    n_slices, rows, written = 4, list(range(12)), 1
+    h = Holder(str(tmp_path / "data"))
+    h.open()
+    h.create_index("i").create_frame("f", FrameOptions())
+    fr = h.index("i").frame("f")
+    rng = np.random.default_rng(37)
+    for r in rows:
+        for s in range(n_slices):
+            for c in rng.choice(4096, size=1 + r % 3, replace=False):
+                fr.set_bit("standard", r, int(s * SLICE_WIDTH + c * 13))
+    stats = ExpvarStatsClient()
+    ex = Executor(h, engine="numpy", stats=stats)
+    frag = h.fragment("i", "f", "standard", written)
+    words = SLICE_WIDTH // 32
+
+    def content():
+        return np.stack([frag.row_dense(r) for r in rows])
+
+    history = [content()]      # the written fragment's rows, one entry a generation
+    errors: list[BaseException] = []
+    done = threading.Event()
+
+    def writer():
+        try:
+            wrng = np.random.default_rng(41)
+            for i in range(120):
+                with frag._mu:     # the write and its entry are one step for a reader
+                    if frag.set_bit(int(wrng.integers(0, len(rows))), int(written * SLICE_WIDTH + wrng.integers(0, 1 << 16))):
+                        history.append(content())
+                if i % 10 == 9:
+                    done.wait(0.03)      # quiet for a few walks: the part is rebuilt
+        except BaseException as exc:  # pragma: no cover
+            errors.append(exc)
+        finally:
+            done.set()
+
+    still = {s: np.stack([h.fragment("i", "f", "standard", s).row_dense(r) for r in rows])
+             for s in range(n_slices) if s != written}
+    t = threading.Thread(target=writer)
+    walks = 0
+    t.start()
+    try:
+        while not done.is_set() or walks < 8:
+            i0 = len(history)
+            block = ex._densify_block("i", "f", "standard", list(range(n_slices)), rows)
+            i1 = len(history)
+            walks += 1
+            for s, planes in still.items():
+                assert (block[s] == planes).all()
+            assert any((block[written] == history[i]).all() for i in range(i0 - 1, i1)), (i0, i1)
+    finally:
+        done.set()
+        t.join()
+    assert not errors and block.shape == (n_slices, len(rows), words)
+    final = ex._densify_block("i", "f", "standard", list(range(n_slices)), rows)
+    assert (final[written] == content()).all()
+    got = stats.snapshot()
+    assert got["walk.fragments_snapshot"] > 0 and got["walk.fragments_dict"] > 0
+    assert got["walk.snapshot_builds"] > n_slices     # every part once, the written one again
     h.close()

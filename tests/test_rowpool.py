@@ -909,6 +909,144 @@ def test_one_walk_feeds_the_block_and_the_word_list(tmp_path, monkeypatch):
     h.close()
 
 
+# -- a block's walk reads the view's columns (core/columns.py) ---------------
+
+WALK_ROWS = [5, 7, -1, 4000, 40, 127]     # -1: a bucket's tail; 4000: no such row
+
+
+def _dict_walk(h, slices, rows, row_major):
+    """The block's pieces by ``Fragment.walk_rows`` alone: the parent's walk."""
+    from pilosa_tpu.core.fragment import RowPieces
+
+    pieces = RowPieces(rows, stride=len(slices) if row_major else 1)
+    for bi, s in enumerate(slices):
+        h.fragment("i", "stargazer", "standard", s).walk_rows(
+            pieces, bi if row_major else bi * len(rows))
+    return pieces
+
+
+def _assert_same_block(h, got, slices, rows, row_major):
+    """``got`` equals the dict walk's pieces - the word list as a set of
+    (word, bits), the dense pieces, the filled block - and the block
+    equals ``row_dense`` plane for plane."""
+    want = _dict_walk(h, slices, rows, row_major)
+    (gw, gb), (ww, wb) = got.words(), want.words()
+    assert sorted(zip(gw.tolist(), gb.tolist())) == sorted(zip(ww.tolist(), wb.tolist()))
+    assert sorted(w0 for w0, _ in got.dense) == sorted(w0 for w0, _ in want.dense)
+    shape = (len(rows), len(slices)) if row_major else (len(slices), len(rows))
+    blocks = [np.zeros(shape + (PLANE_WORDS,), dtype=np.uint32) for _ in range(2)]
+    got.fill(blocks[0])
+    want.fill(blocks[1])
+    assert (blocks[0] == blocks[1]).all()
+    for bi, s in enumerate(slices):
+        frag = h.fragment("i", "stargazer", "standard", s)
+        for k, r in enumerate(rows):
+            plane = blocks[0][k, bi] if row_major else blocks[0][bi, k]
+            dense = frag.row_dense(r) if r >= 0 else np.zeros(PLANE_WORDS, dtype=np.uint32)
+            assert (plane == dense).all(), (s, r)
+    return blocks[0]
+
+
+def _walk_counters(stats):
+    return tuple(_counter(stats, "walk." + n)
+                 for n in ("fragments_snapshot", "fragments_dict", "snapshot_builds"))
+
+
+@pytest.mark.parametrize("case", ["arrays", "bitmap_container", "bulk_overlay", "set_bit"])
+@pytest.mark.parametrize("row_major", [False, True], ids=["slice_major", "row_major"])
+@pytest.mark.parametrize("kind", ["numpy", "jax", "mesh"])
+def test_a_block_from_the_columns_equals_the_dict_walk(tmp_path, monkeypatch, kind, row_major, case):
+    """(h) ``_walk_block`` reads a fragment from the view's columns from
+    its second walk at one generation on, and what it returns equals the
+    walk of the dicts (``words()``, ``fill()``, ``row_dense``): array
+    containers only; a bitmap container among the block's keys (that
+    fragment falls back, the dense piece is there); a pending bulk
+    overlay; a ``SetBit`` (the written fragment's part is not used on
+    the next walk, the others' are; the walk after rebuilds it, with the
+    bit).  The three counters say which way each fragment went."""
+    h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, kind)
+    n = 4 if kind == "mesh" else 2
+    slices = list(range(n))
+    frag0 = h.fragment("i", "stargazer", "standard", 0)
+    if case == "bitmap_container":
+        dense = np.random.default_rng(3).choice(1 << 16, size=5000, replace=False) + (1 << 17)
+        frag0.set_bits(np.full(len(dense), 5, dtype=np.uint64), dense.astype(np.uint64))
+    elif case == "bulk_overlay":
+        frag0.bulk_or_words(np.array([7], dtype=np.uint64), np.array([3]),
+                            np.array([3, 2048 + 7, PLANE_WORDS - 1]),
+                            np.array([0x80000001, 0xF0, 0x1], dtype=np.uint32))
+        assert 7 in frag0._bulk_planes
+
+    def walk(rows=WALK_ROWS):
+        pieces = ex._walk_block("i", "stargazer", "standard", slices, rows, row_major)
+        _assert_same_block(h, pieces, slices, rows, row_major)
+        return pieces
+
+    first = walk()
+    assert first.served == 0 and first.cols is None
+    assert _walk_counters(stats) == (0, n, 0)            # the first walk: every dict, no part built
+    second = walk()                                      # the second at these generations: every part
+    fell_back = 0 if case in ("arrays", "set_bit") else 1
+    assert second.served == n - fell_back and second.cols is not None
+    assert bool(second.dense) == bool(fell_back)
+    assert _walk_counters(stats) == (n - fell_back, n + fell_back, n)
+    third = walk()                                       # the write epoch has not moved: no fragment is looked at
+    assert third.served == n - fell_back
+    assert _walk_counters(stats) == (2 * (n - fell_back), n + 2 * fell_back, n)
+    before = _walk_counters(stats)
+    if case == "bitmap_container":
+        # rows without the bitmap container's: the fragment is the columns' again
+        assert walk([7, -1, 40, 9]).served == n and not walk([7, -1, 40, 9]).dense
+        assert _walk_counters(stats) == (before[0] + 2 * n, before[1], n)
+    elif case == "bulk_overlay":
+        # the overlay is paid (a write: the generation moves), two walks later the part serves
+        assert frag0.materialize_bulk() == 1
+        assert [walk().served for _ in range(3)] == [n - 1, n, n]
+        assert _walk_counters(stats) == (before[0] + 3 * n - 1, before[1] + 1, n + 1)
+    elif case == "set_bit":
+        fr = h.index("i").frame("stargazer")
+        col = (n - 1) * SLICE_WIDTH + 40 * 1021 + 3      # a new bit of row 40, in the last slice
+        assert fr.set_bit("standard", 40, col)
+        written = walk()
+        assert written.served == n - 1                   # the written fragment: its dict, once
+        assert _walk_counters(stats) == (before[0] + n - 1, before[1] + 1, n)
+        rebuilt = walk()                                 # quiet for two walks: rebuilt, with the bit
+        assert rebuilt.served == n and _walk_counters(stats) == (before[0] + 2 * n - 1, before[1] + 1, n + 1)
+        block = np.zeros(((len(WALK_ROWS), n) if row_major else (n, len(WALK_ROWS))) + (PLANE_WORDS,), np.uint32)
+        rebuilt.fill(block)
+        plane = block[4, n - 1] if row_major else block[n - 1, 4]
+        local = col % SLICE_WIDTH
+        assert plane[local >> 5] >> (local & 31) & 1
+    assert _counter(stats, "walk.snapshot_bytes") > 0    # the gauge: host bytes the columns hold
+    h.close()
+
+
+def test_a_walk_of_some_fragments_builds_no_part(tmp_path, monkeypatch):
+    """(i) A part is built by a walk that crosses every fragment of the
+    view, and by no other: a repair's fetch of the slice just written,
+    made twice at one generation (two readers that took different
+    generations), walks the dict both times; the parts that are there
+    serve a walk of some fragments too."""
+    h, ex, _, stats = _tall_frame(tmp_path, monkeypatch, "numpy")
+    fr = h.index("i").frame("stargazer")
+
+    def walk(slices):
+        pieces = ex._walk_block("i", "stargazer", "standard", slices, WALK_ROWS)
+        _assert_same_block(h, pieces, slices, WALK_ROWS, False)
+        return pieces.served
+
+    assert [walk([1]) for _ in range(3)] == [0, 0, 0]
+    assert _walk_counters(stats) == (0, 3, 0)
+    assert [walk([0, 1]) for _ in range(2)] == [1, 2]    # slice 1 was walked before at its generation
+    assert _walk_counters(stats) == (3, 4, 2)
+    assert walk([1]) == 1 and _walk_counters(stats) == (4, 4, 2)
+    assert fr.set_bit("standard", 40, SLICE_WIDTH + 40 * 1021 + 3)
+    assert [walk([1]) for _ in range(3)] == [0, 0, 0]    # the written slice, fetched again and again
+    assert _walk_counters(stats) == (4, 7, 2)
+    assert walk([0, 1]) == 2 and _walk_counters(stats) == (6, 7, 3)
+    h.close()
+
+
 def test_one_bucketing_rule_each():
     """Rows uploaded pad to powers of two, a gather's batch to powers of
     four; the padded batch repeats its first tuple."""
