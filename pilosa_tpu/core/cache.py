@@ -18,6 +18,8 @@ from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Iterable
 
+import numpy as np
+
 THRESHOLD_FACTOR = 1.1
 
 # RankCache invalidation debounce (cache.go:219-226's hard-coded 10 s,
@@ -60,7 +62,36 @@ def pairs_sorted(pairs: Iterable[Pair]) -> list[Pair]:
     return sorted(pairs, key=lambda p: (-p.count, p.id))
 
 
-class LRUCache:
+def pair_arrays(pairs: list[Pair]) -> tuple[np.ndarray, np.ndarray]:
+    """(ids, counts) int64 of ``pairs``, in their order, read-only."""
+    ids = np.fromiter((p.id for p in pairs), dtype=np.int64, count=len(pairs))
+    counts = np.fromiter((p.count for p in pairs), dtype=np.int64, count=len(pairs))
+    ids.setflags(write=False)
+    counts.setflags(write=False)
+    return ids, counts
+
+
+class _Versioned:
+    """``version`` moves whenever ``top()`` may answer differently, so a
+    consumer keyed by it (TopN's device-resident candidates) knows when to
+    rebuild; ``top_arrays()`` is ``top()`` as arrays, kept per version."""
+
+    version = 0
+    _arrays = None
+
+    def _changed(self) -> None:
+        # analysis-ok: check-then-act: a fragment's cache is written under that fragment's _mu (its one owner)
+        self.version += 1
+        self._arrays = None
+
+    def top_arrays(self) -> tuple[np.ndarray, np.ndarray]:
+        got = self._arrays
+        if got is None or got[0] != self.version:
+            got = self._arrays = (self.version, pair_arrays(self.top()))
+        return got[1]
+
+
+class LRUCache(_Versioned):
     """LRU row-count cache (cache.go:55-123)."""
 
     def __init__(self, max_entries: int):
@@ -68,6 +99,8 @@ class LRUCache:
         self._od: OrderedDict[int, int] = OrderedDict()
 
     def add(self, id: int, n: int) -> None:
+        if self._od.get(id) != n:
+            self._changed()
         self._od[id] = n
         self._od.move_to_end(id)
         while len(self._od) > self.max_entries:
@@ -97,7 +130,7 @@ class LRUCache:
         return pairs_sorted(Pair(id=k, count=v) for k, v in self._od.items() if v > 0)
 
 
-class RankCache:
+class RankCache(_Versioned):
     """Ranked row cache with entry threshold (cache.go:126-275).
 
     Keeps up to ``max_entries`` top rows by count plus a slop buffer;
@@ -106,7 +139,10 @@ class RankCache:
     ``debounce_s`` (default 10 s, cache.go:219-226; config
     ``[cache] ranking-debounce-s`` / PILOSA_TPU_RANKING_DEBOUNCE_S,
     resolved in Config and threaded through holder construction);
-    ``recalculate`` forces it.
+    ``recalculate`` forces it.  ``rankings`` is replaced only when a
+    recalculation ranks differently (``version`` then moves); one with no
+    add since the last, which trimmed nothing, would rank as it did and
+    is skipped (``_stale``).
     """
 
     def __init__(self, max_entries: int, _now=time.monotonic, debounce_s=None):
@@ -120,18 +156,22 @@ class RankCache:
         self.debounce_s = float(debounce_s)
         self._now = _now
         self._update_time = _now() - 1e9
+        self._stale = True  # entries moved since the last recalculation
 
     def add(self, id: int, n: int) -> None:
         if n < self.threshold_value:
             return
-        self.entries[id] = n
+        self.bulk_add(id, n)
         self.invalidate()
 
     def bulk_add(self, id: int, n: int) -> None:
         """Unsorted add; caller should invalidate()/recalculate() after."""
         if n < self.threshold_value:
             return
-        self.entries[id] = n
+        # analysis-ok: check-then-act: a fragment's cache is written under that fragment's _mu (its one owner)
+        if self.entries.get(id) != n:
+            self.entries[id] = n
+            self._stale = True
 
     def get(self, id: int) -> int:
         return self.entries.get(id, 0)
@@ -148,30 +188,38 @@ class RankCache:
         self.recalculate()
 
     def recalculate(self) -> None:
+        self._update_time = self._now()
+        if not self._stale:
+            return
+        self._stale = False
         rankings = pairs_sorted(Pair(id=k, count=v) for k, v in self.entries.items())
         if len(rankings) > self.max_entries:
             self.threshold_value = rankings[self.max_entries].count
             rankings = rankings[: self.max_entries]
         else:
             self.threshold_value = 1
-        self.rankings = rankings
-        self._update_time = self._now()
+        if rankings != self.rankings:
+            self.rankings = rankings
+            self._changed()
         if len(self.entries) > self.threshold_buffer:
             self.entries = {
                 k: v for k, v in self.entries.items() if v > self.threshold_value
             }
+            self._stale = True
 
     def top(self) -> list[Pair]:
         return self.rankings
 
 
-class SimpleCache:
+class SimpleCache(_Versioned):
     """Unbounded id->count map (cache.go:438-462 BitmapCache/SimpleCache)."""
 
     def __init__(self):
         self.entries: dict[int, int] = {}
 
     def add(self, id: int, n: int) -> None:
+        if self.entries.get(id) != n:
+            self._changed()
         self.entries[id] = n
 
     bulk_add = add

@@ -12,17 +12,16 @@ TPU-first departures from the reference:
   (``row_dense``), the exact layout the device kernels consume; the roaring
   form is only touched at the storage boundary.
 - TopN's per-candidate ``Src.IntersectionCount(f.Row(id))`` scalar loop
-  (fragment.go:553-560) becomes chunked *batched* popcount counts over a
-  stacked candidate matrix (`_batch_intersection_counts`) — same results,
-  same threshold-pruning semantics, but the hot loop is one vectorized
-  call per chunk instead of K scalar loops, so the executor can push it
-  through the fused TPU kernel.
+  (fragment.go:553-560) becomes counts of all candidates at once (the
+  executor's device scorer), or chunked *batched* popcounts over a stacked
+  candidate matrix with the reference's pruning between chunks, and the
+  heap becomes one selection over arrays (``select_top``): the same counts
+  win; among rows tied at the n-th count the lowest ids do.
 """
 
 from __future__ import annotations
 
 import hashlib
-import math
 import os
 import tempfile
 import threading
@@ -30,7 +29,7 @@ import threading
 from pilosa_tpu.analysis import lockcheck
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Optional, Sequence
+from typing import Any, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -119,6 +118,22 @@ _DIRTY_LOG_MAX = int(os.environ.get("PILOSA_TPU_DIRTY_LOG_MAX", "512"))
 _CACHE_MAGIC = b"PTPC\x01"
 
 
+class TopCandidates(NamedTuple):
+    """What ``Fragment.top`` hands its whole-set scorer: the candidate ids
+    to score, in rank order (count descending, id ascending), and what they
+    were read at - the fragment's ``generation`` and its cache's
+    ``version``; ``ranked`` is all of the rank cache's candidates where
+    they came from it (``ids`` is then a subsequence of it), None for ids
+    the caller gave; ``bits``: all of those candidates' bits together (0
+    for given ids), what a form of their words can hold at most."""
+
+    ids: np.ndarray
+    gen: int
+    version: int
+    ranked: Optional[np.ndarray]
+    bits: int
+
+
 @dataclass
 class TopOptions:
     """Options for Fragment.top (fragment.go:662-677)."""
@@ -129,21 +144,59 @@ class TopOptions:
     # path passes this directly so the device-evaluated child bitmap never
     # round-trips through a roaring conversion.
     src_dense: Optional[np.ndarray] = None
-    # Optional batched scorer: callable(list[row_id]) -> int array of
-    # |row & src| per id, or None to decline a chunk (the fragment then
-    # scores it with its own host path).  The executor passes an
-    # engine-backed one so the candidate hot loop (fragment.go:553-560)
-    # runs on device against the cached HBM row matrix.
-    scorer: Optional[object] = None
+    # Optional scorers of |row & src| (the candidate loop of
+    # fragment.go:553-560): ``scorer(TopCandidates)`` -> counts of every
+    # candidate at once, or None to decline; ``chunk_scorer(ids)`` -> counts
+    # of one chunk of TOPN_SCORE_CHUNK ids, or None to decline that chunk,
+    # with the reference's pruning between chunks.  What both decline, the
+    # fragment scores with its own host path.
+    scorer: Optional[Any] = None
+    chunk_scorer: Optional[Any] = None
     row_ids: Sequence[int] = field(default_factory=list)
     min_threshold: int = 0
     filter_field: str = ""
     filter_values: Sequence = field(default_factory=list)
     tanimoto_threshold: int = 0
+    span: Any = None  # trace.Span of the TopN phase: topn.candidates / topn.select under it
+    # Out, set by Fragment.top: the candidates left after the filters on
+    # their own counts and attributes, and how many of them were scored.
+    candidates: int = 0
+    scored: int = 0
 
     @property
     def has_src(self) -> bool:
         return self.src is not None or self.src_dense is not None
+
+
+def select_top(ids, own, counts, n: int, min_threshold: int = 0,
+               tanimoto: int = 0, src_count: int = 0) -> tuple[np.ndarray, np.ndarray]:
+    """The result of a fragment's TopN over candidates scored in rank order
+    (``ids``, their own bit counts ``own``, their intersections ``counts``):
+    (ids, counts) of the top ``n`` (all where ``n`` is 0) by count
+    descending, then id ascending - ties at the n-th count go to the lowest
+    ids.  A candidate is taken with a count above 0 and at least
+    ``min_threshold``, or with ``tanimoto`` a similarity above it; as in the
+    reference's heap (fragment.go:553-606), once ``n`` candidates have
+    passed, a later one enters on its count alone (no similarity test).
+    The counts that win are the heap's: a later candidate's count is at
+    most its own, so the pruning between chunks changes nothing."""
+    ok = counts > 0
+    if tanimoto:
+        with np.errstate(divide="ignore", invalid="ignore"):
+            t = np.ceil(counts * 100.0 / (own + src_count - counts))
+        passing = ok & (t > tanimoto)
+        if n:
+            first = np.flatnonzero(passing)
+            if len(first) >= n:
+                full = first[n - 1] + 1
+                passing[full:] = ok[full:]
+    else:
+        passing = ok & (counts >= min_threshold)
+    at = np.flatnonzero(passing)
+    order = np.lexsort((ids[at], -counts[at]))
+    if n:
+        order = order[:n]
+    return ids[at[order]], counts[at[order]]
 
 
 def _batch_intersection_counts(rows: np.ndarray, src: np.ndarray) -> np.ndarray:
@@ -241,6 +294,25 @@ class RowPieces:
             first = np.flatnonzero(np.concatenate(([True], word[1:] != word[:-1])))
             self._words = (word[first], np.bitwise_or.reduceat(bit, first))
         return self._words
+
+    def nonzero(self) -> tuple[np.ndarray, np.ndarray]:
+        """``words()`` with the dense pieces' words that are not zero
+        merged in (equal words OR-ed): the whole block as (word, bits),
+        word ascending - what ``fill`` writes, without the zeros."""
+        word, bits = self.words()
+        words, values = [word], [bits]
+        for w0, piece in self.dense:
+            at = np.flatnonzero(piece)
+            words.append(at + w0)
+            values.append(piece[at])
+        # words() lists the containers in the order the walk met them
+        word, bits = np.concatenate(words), np.concatenate(values)
+        order = np.argsort(word, kind="stable")
+        word, bits = word[order], bits[order]
+        if not len(word):
+            return word, bits
+        first = np.flatnonzero(np.concatenate(([True], word[1:] != word[:-1])))
+        return word[first], np.bitwise_or.reduceat(bits, first)
 
     def fill(self, out: np.ndarray) -> None:
         """Into ``out``: the block, uint32, contiguous, zeroed by the caller."""
@@ -1411,25 +1483,35 @@ class Fragment:
 
     # -- TopN (fragment.go:493-659) -------------------------------------
 
-    def top_pairs(self, row_ids: Sequence[int]) -> list[cache_mod.Pair]:
-        """Candidate (id, count) pairs, count-descending (topBitmapPairs)."""
+    def top_candidates(self, row_ids: Sequence[int]):
+        """(ids, own counts, generation, cache version, ranked): the
+        candidates in rank order (topBitmapPairs) - the rank cache's, as
+        arrays it keeps, where ``row_ids`` is empty (ranked True), else the
+        given ids that hold bits - read under the lock at one generation."""
         with self._mu:
             self._flush_row_bookkeeping()
-        if not row_ids:
-            self.cache.invalidate()
-            return list(self.cache.top())
-        pairs = []
-        for row_id in row_ids:
-            n = self.cache.get(row_id) or self.row_count(row_id)
-            if n > 0:
-                pairs.append(cache_mod.Pair(id=row_id, count=n))
-        return cache_mod.pairs_sorted(pairs)
+            gen = self.generation
+            if not row_ids:
+                self.cache.invalidate()
+                ids, counts = self.cache.top_arrays()
+                return ids, counts, gen, self.cache.version, True
+            pairs = []
+            for row_id in row_ids:
+                n = self.cache.get(row_id) or self._row_count_locked(row_id)
+                if n > 0:
+                    pairs.append(cache_mod.Pair(id=row_id, count=n))
+            ids, counts = cache_mod.pair_arrays(cache_mod.pairs_sorted(pairs))
+            return ids, counts, gen, self.cache.version, False
+
+    def _host_counts(self, ids: np.ndarray, src_dense: np.ndarray) -> np.ndarray:
+        rows = np.stack([self.row_dense(int(i)) for i in ids])
+        return _batch_intersection_counts(rows, src_dense)
 
     def top(self, opt: TopOptions) -> list[cache_mod.Pair]:
-        pairs = self.top_pairs(list(opt.row_ids))
+        span = opt.span
+        sp = span.child("topn.candidates") if span is not None else None
+        ids, own, gen, version, ranked = self.top_candidates(list(opt.row_ids))
         n = 0 if opt.row_ids else opt.n  # explicit ids -> no truncation
-
-        filters = set(opt.filter_values) if (opt.filter_field and opt.filter_values) else None
 
         tanimoto = opt.tanimoto_threshold if (opt.tanimoto_threshold > 0 and opt.has_src) else 0
         src_count = 0
@@ -1439,77 +1521,67 @@ class Fragment:
                 if opt.src is not None
                 else int(bw.np_popcount(opt.src_dense).sum())
             )
-        min_tan = (src_count * tanimoto) / 100.0 if tanimoto else 0.0
-        max_tan = (src_count * 100.0) / tanimoto if tanimoto else 0.0
-
-        # Pre-filter candidates on cached counts (cheap, host-side).
-        cands: list[cache_mod.Pair] = []
-        for p in pairs:
-            if p.count <= 0:
-                continue
-            if tanimoto:
-                if p.count <= min_tan or p.count >= max_tan:
-                    continue
-            elif p.count < opt.min_threshold:
-                continue
-            if filters is not None:
-                attrs = self.row_attr_store.attrs(p.id) if self.row_attr_store else None
+        # Pre-filter candidates on their own counts (cheap, host-side).
+        keep = own > 0
+        if tanimoto:
+            keep &= (own * 100 > src_count * tanimoto) & (own * tanimoto < src_count * 100)
+        else:
+            keep &= own >= opt.min_threshold
+        if opt.filter_field and opt.filter_values:
+            filters = set(opt.filter_values)
+            attrs_of = self.row_attr_store.attrs if self.row_attr_store else (lambda _id: None)
+            for i in np.flatnonzero(keep).tolist():
+                attrs = attrs_of(int(ids[i]))
                 if not attrs or attrs.get(opt.filter_field) not in filters:
-                    continue
-            cands.append(p)
+                    keep[i] = False
+        cands, cown = (ids, own) if keep.all() else (ids[keep], own[keep])
+        opt.candidates = len(cands)
+        if sp is not None:
+            sp.annotate(candidates=len(cands), ranked=ranked).finish()
 
         if not opt.has_src:
             # Counts are final; take the first n.
-            results = cands[:n] if n else cands
-            return cache_mod.pairs_sorted(results)
+            if n:
+                cands, cown = cands[:n], cown[:n]
+            return [cache_mod.Pair(id=i, count=c) for i, c in zip(cands.tolist(), cown.tolist())]
 
-        # Intersection-count phase: process candidates count-descending in
-        # chunks; batched popcount per chunk; heap-threshold pruning between
-        # candidates exactly as the reference does between iterations.
         src_dense = (
             opt.src_dense
             if opt.src_dense is not None
             else opt.src.to_dense_words(self.slice * SLICE_WIDTH, SLICE_WIDTH)
         )
-        results: list[cache_mod.Pair] = []
-        chunk = TOPN_SCORE_CHUNK
-        i = 0
-        while i < len(cands):
-            batch = cands[i : i + chunk]
-            i += chunk
-            counts = None
-            if opt.scorer is not None:
-                counts = opt.scorer([p.id for p in batch])
-            if counts is None:  # no scorer, or scorer declined this chunk
-                rows = np.stack([self.row_dense(p.id) for p in batch])
-                counts = _batch_intersection_counts(rows, src_dense)
-            else:
-                counts = np.asarray(counts)
-            stop = False
-            for p, count in zip(batch, counts.tolist()):
-                if n and len(results) >= n:
-                    results.sort(key=lambda q: q.count)
-                    threshold = results[0].count
-                    if threshold < opt.min_threshold or p.count < threshold:
-                        stop = True
+        counts = None
+        if opt.scorer is not None and len(cands):
+            counts = opt.scorer(TopCandidates(cands, gen, version, ids if ranked else None,
+                                              int(own.sum()) if ranked else 0))
+        if counts is None:
+            # Chunk by chunk in rank order, stopping (as the reference does
+            # between candidates) once the next candidate's own count is
+            # below the n-th best intersection so far: no later one can
+            # enter.
+            counts = np.zeros(len(cands), dtype=np.int64)
+            done = 0
+            while done < len(cands):
+                chunk = cands[done : done + TOPN_SCORE_CHUNK]
+                got = opt.chunk_scorer(chunk.tolist()) if opt.chunk_scorer is not None else None
+                if got is None:
+                    got = self._host_counts(chunk, src_dense)
+                counts[done : done + len(chunk)] = np.asarray(got)[: len(chunk)]
+                done += len(chunk)
+                if n and done < len(cands):
+                    _, best = select_top(cands[:done], cown[:done], counts[:done], n,
+                                         opt.min_threshold, tanimoto, src_count)
+                    if len(best) >= n and cown[done] < best[-1]:
                         break
-                    if count < threshold:
-                        continue
-                    results.pop(0)
-                    results.append(cache_mod.Pair(id=p.id, count=count))
-                    continue
-                if count == 0:
-                    continue
-                if tanimoto:
-                    t = math.ceil(count * 100.0 / (p.count + src_count - count))
-                    if t <= tanimoto:
-                        continue
-                elif count < opt.min_threshold:
-                    continue
-                results.append(cache_mod.Pair(id=p.id, count=count))
-            if stop:
-                break
-        return cache_mod.pairs_sorted(results)
+            cands, cown, counts = cands[:done], cown[:done], counts[:done]
+        opt.scored = len(cands)
+        sp = span.child("topn.select") if span is not None else None
+        got_ids, got_counts = select_top(cands, cown, np.asarray(counts, dtype=np.int64), n,
+                                         opt.min_threshold, tanimoto, src_count)
+        out = [cache_mod.Pair(id=i, count=c) for i, c in zip(got_ids.tolist(), got_counts.tolist())]
+        if sp is not None:
+            sp.annotate(n=len(out)).finish()
+        return out
 
     # -- bulk import (fragment.go:924-989) ------------------------------
 

@@ -649,16 +649,43 @@ class JaxEngine:
             op, self._jnp.asarray(row_matrix), self._jnp.asarray(idx)
         )
 
-    # -- TopN all-slice candidate scorer (one dispatch per chunk set) ----
+    # -- TopN: a fragment's rank-cache candidates, sparse on the device ----
 
-    @property
-    def row_scorer_all_slices(self) -> bool:
-        """Single-chip jax engines route through the memoizing scorer
-        factory too (round 5): phase-1 candidate chunks dispatch their
-        one slice eagerly, and a candidate set re-asked by a SECOND
-        slice (phase 2's merged-id refetch) upgrades to one all-slice
-        launch memoized for the rest."""
-        return True
+    # Each ranked fragment's candidates live on the device as their words
+    # that are not zero, scored against a src row in one dispatch
+    # (``topn_sparse_counts``); the mesh scores through its sharded pool.
+    supports_sparse_topn = True
+
+    def topn_sparse_form(self, widx, values, seg, rows: int):
+        """A fragment's TopN candidates on the device: their words that are
+        not zero as word index ``widx`` and ``values``, candidate ``seg``
+        ascending, padded to the ``_pow4`` bucket of the words with value 0;
+        and where each candidate's words end, padded to the bucket of
+        ``rows`` with empty candidates.  Returns (the device arrays, word
+        bucket)."""
+        n, k = _pow4(len(values)), _pow4(rows)
+        w, v = np.zeros(n, dtype=np.int32), np.zeros(n, dtype=np.uint32)
+        w[: len(widx)], v[: len(values)] = widx, values
+        ends = np.full(k, len(values), dtype=np.int32)
+        ends[:rows] = np.cumsum(np.bincount(seg, minlength=rows)[:rows])
+        self._note_upload(w.nbytes + v.nbytes + ends.nbytes)
+        return tuple(self._jnp.asarray(a) for a in (w, v, ends)), n
+
+    def topn_sparse_counts(self, src_dense, form, span=None) -> np.ndarray:
+        """int64 |candidate & src| of a ``topn_sparse_form``'s candidate
+        bucket: the src row's upload, one dispatch
+        (``ops.bitwise.topn_sparse_counts``) and the fetch of the counts
+        (``device.fetch`` under ``span``)."""
+        if not hasattr(self, "_topn_jit"):
+            import jax
+
+            from pilosa_tpu.ops.bitwise import topn_sparse_counts
+
+            self._topn_jit = jax.jit(topn_sparse_counts)
+        src = np.ascontiguousarray(src_dense, dtype=np.uint32)
+        self._note_upload(src.nbytes)
+        out = self._topn_jit(self._jnp.asarray(src), *form)
+        return self.to_numpy(out, span).astype(np.int64)
 
     @property
     def supports_single_slice_score(self) -> bool:
@@ -1106,14 +1133,9 @@ class MeshEngine(JaxEngine):
         process-addressable."""
         return True
 
-    @property
-    def row_scorer_all_slices(self) -> bool:
-        """Meshes always route through the hybrid scorer factory; the
-        single-vs-all-slice dispatch decision lives there, gated by
-        supports_single_slice_score (multi-process meshes must stay
-        SPMD — eager matrix[si] indexing would touch non-addressable
-        shards)."""
-        return True
+    # Every candidate of a mesh pages dense through its sharded pool (the
+    # hybrid scorer: one slice, or all of them in one SPMD dispatch).
+    supports_sparse_topn = False
 
     @property
     def supports_single_slice_score(self) -> bool:

@@ -84,6 +84,59 @@ _TREE_PASS = 4
 _TREE_DEPTH_MAX = 4
 
 
+# TopN on the one-chip jax engine scores a ranked fragment's candidates from
+# a sparse form on the device (``_TopnForm``): one where their bits together
+# are at most this many (so its word bucket is too: 8 B a word, 128 MiB);
+# a taller candidate set pages dense through the pool's "topn" lane, chunk by
+# chunk.  The forms of all fragments hold at most _TOPN_FORMS_WORDS words.
+_TOPN_FORM_WORDS_MAX = 1 << 24
+_TOPN_FORMS_WORDS = 1 << 26
+
+
+class _TopnForm:
+    """A fragment's rank-cache candidates on the device, as their words
+    that are not zero: ``dev`` = (word index int32, value uint32) padded to
+    the word ``bucket``, candidate after candidate, and where each
+    candidate's words end (what ``engine.topn_sparse_counts`` scores
+    against a src row).  Valid while the fragment's generation is ``gen``
+    and its cache's version ``version``: then its candidates are ``ids``,
+    in rank order."""
+
+    __slots__ = ("gen", "version", "ids", "_sorted", "_order", "dev", "words", "bucket")
+
+    def __init__(self, gen, version, ids, dev, words, bucket):
+        self.gen, self.version, self.ids = gen, version, ids
+        self._order = np.argsort(ids, kind="stable")
+        self._sorted = ids[self._order]
+        self.dev, self.words, self.bucket = dev, words, bucket
+
+    def valid(self, gen: int, version: int) -> bool:
+        return self.gen == gen and self.version == version
+
+    def positions(self, ids: np.ndarray) -> Optional[np.ndarray]:
+        """Where each of ``ids`` is among the candidates; None if one is not."""
+        at = np.minimum(np.searchsorted(self._sorted, ids), len(self._sorted) - 1)
+        if len(ids) and (not len(self._sorted) or (self._sorted[at] != ids).any()):
+            return None
+        return self._order[at]
+
+
+class _TopnRun:
+    """One TopN call across its two phases: the src phase 1 evaluated, per
+    slice batch, and per fragment the form it scored and the counts it got
+    (phase 2 reads them instead of evaluating and scoring again), and the
+    phase in progress - its span, the candidates its fragments found and
+    how many they scored."""
+
+    __slots__ = ("src", "counts", "span", "candidates", "scored")
+
+    def __init__(self):
+        self.src: dict = {}
+        self.counts: dict = {}
+        self.span = None
+        self.candidates = self.scored = 0
+
+
 class _TreeUnfusable(Exception):
     """Tree shape outside the fused lane (not an error — sequential path)."""
 
@@ -235,6 +288,7 @@ class Executor:
         "_multi_matrix_cache": "executor._matrix_mu",
         "_serve_states": "executor._matrix_mu",
         "_dirty_rows": "executor._dirty_mu",
+        "_topn_forms": "executor._topn_mu",
         # Monotonic invalidation counter for the per-thread armed lane
         # tables (each thread's tables are private; only the epoch is
         # shared, written on frame/index drops).
@@ -276,6 +330,11 @@ class Executor:
         # (index, frame, views, slices); validated the same way.
         self._multi_matrix_cache: "OrderedDict[tuple, tuple]" = OrderedDict()
         self._matrix_mu = lockcheck.named_lock("executor._matrix_mu")
+        # TopN: each ranked fragment's candidates held sparse on the device
+        # (``_TopnForm``), keyed (index, frame, slice), least recently used
+        # first, their word buckets together at most _TOPN_FORMS_WORDS.
+        self._topn_forms: "OrderedDict[tuple, _TopnForm]" = OrderedDict()
+        self._topn_mu = lockcheck.named_lock("executor._topn_mu")
         # Tuning-knob precedence, uniform across every routed knob below:
         # constructor arg (the server passes Config fields, which already
         # fold CLI > env > config file) > raw env var (deprecated spelling
@@ -1464,7 +1523,8 @@ class Executor:
     def drop_frame_state(self, index: str, frame: str) -> None:
         """Drop every cached serving artifact for one (index, frame):
         serve states, device row pools (and their Grams), multi-view
-        Range matrices, the fast-write pin, and the dirty ledger.  Called
+        Range matrices, TopN's candidate forms, the fast-write pin, and
+        the dirty ledger.  Called
         on frame deletion so a recreated namesake can never be served
         from (or pin the memory of) the old frame's device state; the
         generation/identity validity checks already guarantee
@@ -1483,6 +1543,9 @@ class Executor:
             self._lane_epoch += 1
         with self._dirty_mu:
             self._dirty_rows.pop((index, frame), None)
+        with self._topn_mu:
+            for k in [k for k in self._topn_forms if k[0] == index and k[1] == frame]:
+                del self._topn_forms[k]
         if self.qcache is not None:
             # A recreated namesake frame gets fresh generations (the
             # counter never repeats), so validity already prevents stale
@@ -1502,6 +1565,9 @@ class Executor:
         with self._dirty_mu:
             for k in [k for k in self._dirty_rows if k[0] == index]:
                 del self._dirty_rows[k]
+        with self._topn_mu:
+            for k in [k for k in self._topn_forms if k[0] == index]:
+                del self._topn_forms[k]
         if self.qcache is not None:
             self.qcache.purge_index(index)
 
@@ -2971,26 +3037,41 @@ class Executor:
     def _execute_topn(self, index: str, c: pql.Call, slices, opt: ExecOptions) -> list[cache_mod.Pair]:
         row_ids, _ = c.uint_slice_arg("ids")
         n, _ = c.uint_arg("n")
-        pairs = self._execute_topn_slices(index, c, slices, opt)
+        self._stats().count("topn.calls")
+        run = _TopnRun()
+        pairs = self._topn_phase(1, index, c, slices, opt, run, n)
         if not pairs or row_ids or opt.remote:
             return pairs
         # Phase 2: coordinator refetches exact counts for the merged id set
         # across all slices, then truncates (executor.go:299-317).
         other = c.clone()
         other.args["ids"] = sorted(p.id for p in pairs)
-        trimmed = self._execute_topn_slices(index, other, slices, opt)
+        trimmed = self._topn_phase(2, index, other, slices, opt, run, n)
         if n:
             trimmed = trimmed[:n]
         return trimmed
 
-    def _execute_topn_slices(self, index: str, c: pql.Call, slices, opt: ExecOptions) -> list[cache_mod.Pair]:
+    def _topn_phase(self, phase: int, index: str, c: pql.Call, slices, opt: ExecOptions,
+                    run: _TopnRun, n: int) -> list[cache_mod.Pair]:
+        """One phase over every slice, under a ``topn.phase`` span."""
+        sp = opt.span.child("topn.phase") if opt.span is not None else None
+        run.span, run.candidates, run.scored = sp, 0, 0
+
         def local_map(local_slices: list[int]) -> list[cache_mod.Pair]:
-            return self._topn_local(index, c, local_slices)
+            return self._topn_local(index, c, local_slices, run)
 
-        pairs = self._map_reduce(index, c, slices, opt, local_map, cache_mod.pairs_add, [])
-        return cache_mod.pairs_sorted(pairs)
+        try:
+            pairs = self._map_reduce(index, c, slices, opt, local_map, cache_mod.pairs_add, [])
+            return cache_mod.pairs_sorted(pairs)
+        finally:
+            if sp is not None:
+                sp.annotate(phase=phase, candidates=run.candidates, scored=run.scored,
+                            n=int(n)).finish()
 
-    def _topn_local(self, index: str, c: pql.Call, slices: list[int]) -> list[cache_mod.Pair]:
+    def _stats(self):
+        return self.meter.stats if self.meter is not None else NOP_STATS
+
+    def _topn_local(self, index: str, c: pql.Call, slices: list[int], run: _TopnRun) -> list[cache_mod.Pair]:
         frame_name = c.string_arg("frame") or DEFAULT_FRAME
         n, _ = c.uint_arg("n")
         field = c.string_arg("field")
@@ -3003,9 +3084,20 @@ class Executor:
         if c.children:
             if len(c.children) > 1:
                 raise PilosaError("TopN() can only have one input bitmap")
-            src_batch = self.engine.to_numpy(self._eval_stack(index, c.children[0], slices))
+            # Phase 2 scores against the src phase 1 evaluated.
+            src_batch = run.src.get(tuple(slices))
+            if src_batch is None:
+                src_batch = run.src[tuple(slices)] = self.engine.to_numpy(
+                    self._eval_stack(index, c.children[0], slices))
 
-        scorer_for = self._topn_scorer_factory(index, frame_name, slices, src_batch)
+        sparse = src_batch is not None and getattr(self.engine, "supports_sparse_topn", False)
+        # Where the candidates are the fragments' own, what their sparse
+        # forms cannot hold pages dense through the "topn" lane; given ids
+        # (phase 2: a few a slice) that phase 1's counts do not cover are
+        # the fragment's host path's.
+        dense_for = self._topn_dense_scorers(
+            index, frame_name, slices, None if sparse and row_ids else src_batch)
+        stats = self._stats()
         merged: list[cache_mod.Pair] = []
         for i, s in enumerate(slices):
             frag = self.holder.fragment(index, frame_name, VIEW_STANDARD, s)
@@ -3015,115 +3107,142 @@ class Executor:
             topt = TopOptions(
                 n=int(n),
                 src_dense=src_dense,
-                scorer=scorer_for(i, src_dense),
+                scorer=(
+                    lambda cands, _f=frag, _src=src_dense: self._topn_sparse_score(
+                        index, frame_name, _f, _src, run, cands)
+                ) if sparse else None,
+                chunk_scorer=dense_for(i, src_dense),
                 row_ids=row_ids,
                 min_threshold=int(min_threshold),
                 filter_field=field,
                 filter_values=filters,
                 tanimoto_threshold=int(tanimoto),
+                span=run.span,
             )
             merged = cache_mod.pairs_add(merged, frag.top(topt))
+            run.candidates += topt.candidates
+            run.scored += topt.scored
+            if topt.scored:
+                stats.count("topn.rows_scored", topt.scored)
         return merged
 
-    def _topn_scorer_factory(self, index, frame_name, slices, src_batch):
-        """Per-slice engine-backed |row & src| scorers for TopN candidates.
+    def _topn_sparse_score(self, index: str, frame_name: str, frag, src_dense, run: _TopnRun, cands):
+        """|row & src| of every candidate ``Fragment.top`` hands over
+        (``TopCandidates``), from the fragment's candidates held sparse on
+        the device (``_topn_form``), in one dispatch; for given ids (phase
+        2) the counts phase 1 took from the same form, where it holds them
+        all.  None where neither serves: the fragment's chunk scorer takes
+        them - the dense "topn" lane its own candidates, its host path
+        given ids."""
+        key = (index, frame_name, frag.slice)
+        span = run.span
+        if cands.ranked is None:
+            form, counts = run.counts.get(key, (None, None))
+            pos = form.positions(cands.ids) if form is not None and form.valid(cands.gen, cands.version) else None
+            return None if pos is None else counts[pos]
+        sp = span.child("topn.candidates") if span is not None else None
+        form, built = self._topn_form(index, frame_name, frag, cands)
+        if sp is not None:
+            sp.annotate(built=built, rows=len(cands.ranked)).finish()
+        if form is None:
+            return None
+        sp = span.child("topn.score") if span is not None else None
+        counts = self.engine.topn_sparse_counts(src_dense, form.dev, span=sp)[: len(form.ids)]
+        if sp is not None:
+            sp.annotate(rows=len(form.ids), words=form.words, bucket=form.bucket).finish()
+        run.counts[key] = (form, counts)
+        if len(cands.ids) == len(form.ids):
+            return counts
+        return counts[form.positions(cands.ids)]
 
-        The reference scores candidates with a per-row scalar loop
-        (fragment.go:553-560); here each candidate chunk is one fused
-        device dispatch against a paged device row pool.  The pool lives
-        on its OWN lane key ("topn") so streaming tens of thousands of
-        candidates through HBM pages against the scorer's slots without
-        evicting the fused Count lane's hot rows or its Gram.  Chunks are
-        padded to the fragment scoring chunk so jitted shapes never vary.
-        Unbounded candidate sets just page (rank-cache scale included);
-        the only host fallback left is an engine that can't score rows
-        (numpy: the fragment's host path is the same math without an
-        engine round trip) or a pool too small for even one chunk.
-        """
+    def _topn_form(self, index: str, frame_name: str, frag, cands) -> tuple[Optional[_TopnForm], bool]:
+        """(the fragment's candidates held sparse on the device at the
+        generation and cache version ``cands`` was read at, built now?).
+        Built from one walk of the candidates' rows (``_walk_block``: the
+        view's columns, the fragment's dict for its bitmap containers) and
+        ``RowPieces.nonzero``.  None where the candidates' bits are more
+        than a form holds."""
+        key = (index, frame_name, frag.slice)
+        with self._topn_mu:
+            form = self._topn_forms.get(key)
+            if form is not None and form.valid(cands.gen, cands.version):
+                self._topn_forms.move_to_end(key)
+                return form, False
+        if cands.bits > _TOPN_FORM_WORDS_MAX:
+            return None, False
+        ranked = cands.ranked
+        pieces = self._walk_block(index, frame_name, VIEW_STANDARD, [frag.slice], ranked.tolist())
+        word, values = pieces.nonzero()
+        dev, bucket = self.engine.topn_sparse_form(
+            (word % _WORDS).astype(np.int32), values, word // _WORDS, len(ranked))
+        form = _TopnForm(cands.gen, cands.version, ranked, dev, len(word), bucket)
+        self._stats().count("topn.sparse_builds")
+        with self._topn_mu:
+            self._topn_forms[key] = form
+            self._topn_forms.move_to_end(key)
+            held = sum(f.bucket for f in self._topn_forms.values())
+            while held > _TOPN_FORMS_WORDS and len(self._topn_forms) > 1:
+                _, old = self._topn_forms.popitem(last=False)
+                held -= old.bucket
+        return form, True
+
+    def _topn_dense_scorers(self, index, frame_name, slices, src_batch):
+        """``dense_for(si, src_dense)``: a chunk scorer for slice position
+        ``si`` (``TopOptions.chunk_scorer``) through the pool's "topn"
+        lane (``_topn_scorer_factory``), made at its first chunk, or None
+        where no engine scores rows (numpy, or no src)."""
+        if src_batch is None or self.engine.name == "numpy":
+            return lambda si, src_dense: None
+        box: dict = {}
+        stats = self._stats()
+
+        def dense_for(si: int, src_dense):
+            def score(ids):
+                made = box.get("f")
+                if made is None:
+                    made = box["f"] = self._topn_scorer_factory(index, frame_name, slices, src_batch)
+                sc = made(si, src_dense)
+                if sc is None:
+                    return None
+                stats.count("topn.dense_fallbacks")
+                return sc(ids)
+
+            return score
+
+        return dense_for
+
+    def _topn_scorer_factory(self, index, frame_name, slices, src_batch):
+        """Per-slice dense |row & src| chunk scorers for TopN candidates,
+        each chunk one device dispatch against a paged device row pool -
+        what a mesh scores every candidate with, and one chip what its
+        sparse forms do not take.  The pool lives on its OWN lane key
+        ("topn") so streaming candidates through HBM pages against the
+        scorer's slots without evicting the fused Count lane's hot rows or
+        its Gram; chunks are padded to the fragment scoring chunk so jitted
+        shapes never vary.  A candidate set's first sight (phase 1: each
+        fragment scores its own rank-cache candidates) dispatches just its
+        slice; a set re-asked by a SECOND slice (phase 2's merged-id
+        refetch) upgrades to ONE all-slice launch
+        (``engine.topn_scorer_counts``) memoized for the rest.
+        Multi-process meshes always take the SPMD all-slice dispatch (eager
+        ``matrix[si]`` indexing would touch shards owned by other
+        processes).  The fragment's host path is left for an engine that
+        can't score rows, a pool too small for one chunk, or a slice count
+        a mesh can't shard evenly."""
+        from pilosa_tpu.core.fragment import TOPN_SCORE_CHUNK
+
+        all_slices = list(slices)
+        n_dev = getattr(getattr(self.engine, "mesh", None), "n_devices", 1)
         if (
             src_batch is None
             or self.engine.name == "numpy"
             or not getattr(self.engine, "supports_row_scorer", True)
+            or len(all_slices) % n_dev
         ):
             return lambda si, src_dense: None
-        from pilosa_tpu.core.fragment import TOPN_SCORE_CHUNK
-
-        state = {"src_dev": {}}
-        all_slices = list(slices)
         pool = self._pool_for(index, frame_name, VIEW_STANDARD, all_slices, lane="topn")
         if pool.cap_max < TOPN_SCORE_CHUNK:
             return lambda si, src_dense: None  # can't hold one chunk
-
-        if getattr(self.engine, "row_scorer_all_slices", False):
-            return self._topn_scorer_factory_all_slices(
-                index, frame_name, all_slices, src_batch, pool
-            )
-
-        def scorer_for(si: int, src_dense):
-            if src_dense is None:
-                return None
-
-            def score(ids):
-                matrix, pos = self._topn_acquire_pos(
-                    index, frame_name, all_slices, pool, ids
-                )
-                src_dev = state["src_dev"].get(si)
-                if src_dev is None:
-                    # Tiled to match rows sliced from the 4D pool matrix.
-                    tile = getattr(self.engine, "tile_src", self.engine.asarray)
-                    src_dev = state["src_dev"][si] = tile(src_dense)
-                rows = matrix[si][pos]
-                counts = self.engine.batch_intersection_count(
-                    rows, src_dev, tiled=getattr(matrix, "ndim", 3) == 4
-                )
-                return counts[: len(ids)]
-
-            return score
-
-        return scorer_for
-
-    def _topn_acquire_pos(self, index, frame_name, all_slices, pool, ids):
-        """Shared scorer helper: page the candidate rows into the pool
-        and map ids to matrix slots, padded to TOPN_SCORE_CHUNK so the
-        jitted scorer shapes never vary (pad scores are discarded)."""
-        from pilosa_tpu.core.fragment import TOPN_SCORE_CHUNK
-
-        frags = [
-            self.holder.fragment(index, frame_name, VIEW_STANDARD, s)
-            for s in all_slices
-        ]
-        gens = tuple(-1 if f is None else f.generation for f in frags)
-        id_pos, matrix, _ = pool.acquire(sorted(set(ids)), gens)
-        n = len(ids)
-        padded = (
-            list(ids) + [ids[0]] * (TOPN_SCORE_CHUNK - n)
-            if n < TOPN_SCORE_CHUNK
-            else list(ids)
-        )
-        pos = np.fromiter(
-            (id_pos[i] for i in padded), dtype=np.int32, count=len(padded)
-        )
-        return matrix, pos
-
-    def _topn_scorer_factory_all_slices(
-        self, index, frame_name, all_slices, src_batch, pool
-    ):
-        """Hybrid memoizing scorer (round 5): phase-1 candidate chunks
-        (each fragment's own rank-cache candidates, one consuming slice)
-        dispatch just their slice; a candidate set re-asked by a SECOND
-        slice (phase 2's merged-id refetch across every slice) upgrades
-        to ONE all-slice launch (engine.topn_scorer_counts) memoized for
-        the rest.  Multi-process meshes always use the SPMD all-slice
-        dispatch (eager ``matrix[si]`` indexing would touch shards owned
-        by other processes).  Falls back to the host loop for slice
-        counts a mesh can't shard evenly."""
-        n_dev = getattr(getattr(self.engine, "mesh", None), "n_devices", 1)
-        if len(all_slices) % n_dev:
-            return lambda si, src_dense: None
-        # Single-slice dispatches are legal whenever every shard is
-        # process-addressable (single-chip jax engines, single-process
-        # meshes); multi-process meshes must always go through the SPMD
-        # all-slice dispatch.
         single_ok = bool(getattr(self.engine, "supports_single_slice_score", True))
         state: dict = {"src_dev": None, "src_si": {}}
 
@@ -3139,7 +3258,13 @@ class Executor:
         seen: dict = {}  # ids -> first slice position that scored them
 
         def acquire_pos(ids):
-            return self._topn_acquire_pos(index, frame_name, all_slices, pool, ids)
+            """Page the candidate rows into the pool and map ids to matrix
+            slots, padded to TOPN_SCORE_CHUNK (pad scores are dropped)."""
+            frags = [self.holder.fragment(index, frame_name, VIEW_STANDARD, s) for s in all_slices]
+            gens = tuple(-1 if f is None else f.generation for f in frags)
+            id_pos, matrix, _ = pool.acquire(sorted(set(ids)), gens)
+            padded = list(ids) + [ids[0]] * max(0, TOPN_SCORE_CHUNK - len(ids))
+            return matrix, np.fromiter((id_pos[i] for i in padded), dtype=np.int32, count=len(padded))
 
         def scorer_for(si: int, src_dense):
             if src_dense is None:
@@ -3151,10 +3276,6 @@ class Executor:
                 if counts is not None:
                     return counts[si, : len(ids)]
                 if single_ok and seen.setdefault(key, si) == si:
-                    # First sight of this candidate set (phase 1: each
-                    # fragment scores its OWN rank-cache candidates):
-                    # dispatch just this slice — the all-slice launch
-                    # would do S x the compute for one consumed row.
                     matrix, pos = acquire_pos(ids)
                     tile = getattr(self.engine, "tile_src", self.engine.asarray)
                     src_dev = state["src_si"].get(si)
@@ -3165,9 +3286,6 @@ class Executor:
                         rows, src_dev, tiled=getattr(matrix, "ndim", 3) == 4
                     )
                     return c[: len(ids)]
-                # A SECOND slice asking for the same ids (phase 2's
-                # merged-id refetch re-queries every slice): one
-                # all-slice dispatch, memoized for the rest.
                 matrix, pos = acquire_pos(ids)
                 counts = memo[key] = self.engine.topn_scorer_counts(
                     matrix, pos, all_src_dev()

@@ -447,6 +447,59 @@ def set_row_words(row_matrix, slots, cells, values, axis: int = 1, first_slice=0
         return m.at[lead + word].set(values, mode="drop", unique_indices=True)
 
 
+# A src row of at most this many words that are not zero meets the
+# candidates' words by comparison (a pass over them a src word); a larger
+# one is gathered at every candidate word.
+TOPN_SRC_COMPARED = 16
+
+
+def _cumsum_long(x):
+    """``jnp.cumsum`` of a long 1-D array as row sums of a [n/1024, 1024]
+    view plus their carries: the plain form of a million elements took the
+    TPU compiler half a minute."""
+    n = x.shape[0]
+    if n < 4096:
+        return jnp.cumsum(x)
+    rows = jnp.cumsum(x.reshape(n // 1024, 1024), axis=1)
+    carry = jnp.cumsum(rows[:, -1]) - rows[:, -1]
+    return (rows + carry[:, None]).reshape(n)
+
+
+def topn_sparse_counts(src, widx, vals, ends):
+    """|row_k & src| for every candidate ``k`` of a fragment's TopN, from
+    the candidates' words that are not zero, candidate after candidate
+    (``widx`` int32[N], the word of the slice; ``vals`` uint32[N], the
+    candidate's bits there; candidate ``k``'s words end at ``ends[k]``,
+    int32[K], ascending) and the slice's src words ``src`` uint32[W]:
+    int32[K].  Each candidate word is met with the src's word at the same
+    place - by comparison with each of the src's words that are not zero
+    where there are at most TOPN_SRC_COMPARED of them (a user of a few
+    stars), else by a gather of ``src`` - then a popcount of the AND and a
+    sum a candidate (differences of one running sum at ``ends``).  A padded
+    word (value 0) counts nothing.  Jitted by the jax engine, one program a
+    (word, candidate) bucket; its ops carry ``topn.count`` in a device
+    trace."""
+    with jax.named_scope("topn.count"):
+
+        def compared(_):
+            at = jnp.nonzero(src, size=TOPN_SRC_COMPARED, fill_value=-1)[0].astype(jnp.int32)
+            words = jnp.where(at >= 0, src[jnp.maximum(at, 0)], jnp.uint32(0))
+            hit = jnp.zeros_like(vals)
+            for j in range(TOPN_SRC_COMPARED):
+                hit = hit | jnp.where(widx == at[j], words[j], jnp.uint32(0))
+            return hit
+
+        def gathered(_):
+            return src.at[widx].get(mode="promise_in_bounds")
+
+        few = jnp.count_nonzero(src) <= TOPN_SRC_COMPARED
+        hit = lax.cond(few, compared, gathered, None)
+        ones = lax.population_count(hit & vals).astype(jnp.int32)
+        total = jnp.concatenate([jnp.zeros(1, jnp.int32), _cumsum_long(ones)])
+        starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]])
+        return total[ends] - total[starts]
+
+
 def repair_planes(row_matrix, cells, planes, n: int):
     """Write new planes into a pool matrix and return what each write
     does to the AND-count Gram over the first ``n`` slots.

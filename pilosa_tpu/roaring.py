@@ -760,9 +760,7 @@ class Bitmap:
         n_words = n_bits // 32
         out = np.zeros(n_words, dtype=np.uint32)
         k0, k1 = highbits(start), highbits(start + n_bits - 1)
-        for key in self.containers.keys():
-            if not (k0 <= key <= k1):
-                continue
+        for key in self._keys_in_range(k0, k1):
             c = self.containers[key]
             word_off = ((key - k0) << 16) // 32
             if c.bitmap is not None:

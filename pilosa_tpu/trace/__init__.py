@@ -75,7 +75,13 @@ Design:
   ``pool.miss`` > ``pool.miss.fetch`` / ``pool.miss.scatter``, ``device``
   (tag ``lane``; a gather dispatch says what it gathered), ``device.fetch``
   (one chip: the host's wait for a dispatch's counts; ``mesh.fetch`` on a
-  mesh), ``write.apply``, ``parse``, ``fused``, ``call.<Name>``,
+  mesh), ``write.apply``, ``parse``, ``fused``, ``call.<Name>`` (a TopN
+  call: ``topn.phase`` a phase, tags ``phase``, ``candidates``, ``scored``,
+  ``n``; under it a fragment's ``topn.candidates`` - the rank cache's
+  candidates as arrays, and the sparse form of their words on the device,
+  tag ``built`` where it was built anew - ``topn.score``, the src row's
+  upload, the one dispatch and the fetch of the counts, tags ``rows``,
+  ``words``, ``bucket``, and ``topn.select``, the selection over the counts),
   ``slices`` / ``slice_chunk``, ``remote``, ``encode``, ``interp.gc`` (a
   full collection of the interpreter that overlapped the request, on
   whichever thread it ran: it stops them all; tags ``collected``,

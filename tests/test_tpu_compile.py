@@ -275,6 +275,29 @@ def test_pool_sparse_page_in_compiles_and_fits(words, donate, one_chip):
     assert "pool.set_rows" in compiled.as_text()
 
 
+@pytest.mark.parametrize("words,rows", [(4096, 256), (1 << 20, 1 << 16), (1 << 24, 1 << 16)])
+def test_topn_sparse_counts_compiles_and_fits(words, rows, one_chip):
+    """The TopN scoring of a fragment's candidates held sparse
+    (``ops.bitwise.topn_sparse_counts``: the src's words met by comparison
+    or a gather, a popcount, a running sum a candidate) at a small bucket,
+    at the one ``ranked1``'s 50,000 candidates and about 975,000 words fall
+    into, and at the largest form (``executor._TOPN_FORM_WORDS_MAX``): its
+    arguments are the form's 8 bytes a word, its transient a few times
+    that, and its ops carry ``topn.count``."""
+    import jax
+
+    from pilosa_tpu.ops.bitwise import topn_sparse_counts
+
+    compiled = _compile(
+        jax.jit(topn_sparse_counts),
+        [((W,), "uint32"), _ids(words), ((words,), "uint32"), _ids(rows)], one_chip, kernel=False)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 4 * W + 8 * words + 4 * rows
+    assert mem.output_size_in_bytes == 4 * rows
+    assert mem.temp_size_in_bytes <= 16 * words + 2**21
+    assert "topn.count" in compiled.as_text()
+
+
 def _all_reduced(text: str) -> set:
     """The shapes a compiled program's all-reduce ops return:
     "%name = s32[C,256]{..} all-reduce("."""
