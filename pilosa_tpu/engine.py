@@ -791,9 +791,10 @@ class JaxEngine:
         ``set_words_at``) into arbitrary pool slots: the chunk's planes
         cross host->device whole (the upload is enqueued, not waited for);
         the scatter itself is HBM->HBM, into a copy of the pool (a reader
-        may hold ``matrix``) or, with ``donate``, into ``matrix`` itself
-        (the copy an earlier chunk of the same miss made: the caller
-        holds the only reference, and the array is gone afterwards).  One
+        holds ``matrix``) or, with ``donate``, into ``matrix`` itself
+        (the pool's array that no reader holds, or the copy an earlier
+        chunk of the same miss made: the caller holds the only reference,
+        and the array is gone afterwards).  One
         program a block size and form (``ops.bitwise.set_rows``): the pool
         pads a chunk to its power-of-two bucket, slot -1 over a zero
         plane, which the scatter drops."""

@@ -24,6 +24,7 @@ import os
 import re
 import threading
 from collections import OrderedDict
+from contextlib import contextmanager
 from dataclasses import dataclass, field, replace as dc_replace
 from datetime import datetime
 from typing import Any, Optional, Sequence
@@ -1714,66 +1715,66 @@ class Executor:
                     n_parts=len(qparts), n_pairs=len(qpart), max_k=2,
                     has_tree=False, pool_cap=pool.cap,
                 )
-                id_pos, matrix, box = self._frame_matrix(
+                with self._frame_matrix(
                     index, fname, slices, set(rows.tolist()),
                     lane="rmgather" if rm_pool else "", span=span,
-                )
-                gram = None if rm_pool else self._frame_gram(matrix, box)
-                if gram is not None:  # implies a live box (_frame_gram contract)
-                    # Native lane: the gram_lut (sorted id table + positions)
-                    # lives and dies with the cache box, like the Gram itself.
-                    glut = box.get("gram_lut")
-                    if glut is None:
-                        rs = np.array(sorted(id_pos), dtype=np.int64)
-                        ps = np.fromiter(
-                            (id_pos[int(v)] for v in rs), dtype=np.int32, count=len(rs)
+                ) as (id_pos, matrix, box):
+                    gram = None if rm_pool else self._frame_gram(matrix, box)
+                    if gram is not None:  # implies a live box (_frame_gram contract)
+                        # Native lane: the gram_lut (sorted id table + positions)
+                        # lives and dies with the cache box, like the Gram itself.
+                        glut = box.get("gram_lut")
+                        if glut is None:
+                            rs = np.array(sorted(id_pos), dtype=np.int64)
+                            ps = np.fromiter(
+                                (id_pos[int(v)] for v in rs), dtype=np.int32, count=len(rs)
+                            )
+                            glut = box["gram_lut"] = (rs, np.ascontiguousarray(gram), ps)
+                        # Mask indexing yields fresh C-contiguous arrays, so the
+                        # raw pointers hand off to C directly.
+                        counts = native.gram_counts(
+                            op_ids[fmask], fr1, fr2, glut[0], glut[2], glut[1]
                         )
-                        glut = box["gram_lut"] = (rs, np.ascontiguousarray(gram), ps)
-                    # Mask indexing yields fresh C-contiguous arrays, so the
-                    # raw pointers hand off to C directly.
-                    counts = native.gram_counts(
-                        op_ids[fmask], fr1, fr2, glut[0], glut[2], glut[1]
+                        if counts is not None:
+                            out[fmask] = counts
+                            # Arm the single-call serve lane: this exact
+                            # state (frame + glut) just served natively, so
+                            # subsequent requests can skip straight to
+                            # pn_serve_pairs — or, when a batch spans several
+                            # frames, to pn_serve_multi (each frame group
+                            # arms its own state here).  Unpaged working sets
+                            # only; re-capture only when the glut changed.
+                            st = self._serve_states.get((index, fname))
+                            if (
+                                len(qparts) == 1
+                                and (st is None or st["glut_id"] is not glut)
+                            ):
+                                self._capture_serve_state(index, fname, slices, glut, box)
+                            continue
+                    lut = np.fromiter(
+                        (id_pos[int(rv)] for rv in rows), dtype=np.int32, count=len(rows)
                     )
-                    if counts is not None:
-                        out[fmask] = counts
-                        # Arm the single-call serve lane: this exact
-                        # state (frame + glut) just served natively, so
-                        # subsequent requests can skip straight to
-                        # pn_serve_pairs — or, when a batch spans several
-                        # frames, to pn_serve_multi (each frame group
-                        # arms its own state here).  Unpaged working sets
-                        # only; re-capture only when the glut changed.
-                        st = self._serve_states.get((index, fname))
-                        if (
-                            len(qparts) == 1
-                            and (st is None or st["glut_id"] is not glut)
-                        ):
-                            self._capture_serve_state(index, fname, slices, glut, box)
-                        continue
-                lut = np.fromiter(
-                    (id_pos[int(rv)] for rv in rows), dtype=np.int32, count=len(rows)
-                )
-                p1 = lut[np.searchsorted(rows, fr1)]
-                p2 = lut[np.searchsorted(rows, fr2)]
-                fops = op_ids[fmask]
-                fout = np.zeros(len(fr1), dtype=np.int64)
-                pending = []  # every op's dispatch goes out before the first fetch blocks
-                for op_id in np.unique(fops):
-                    om = fops == op_id
-                    pairs = np.stack([p1[om], p2[om]], axis=1).astype(np.int32)
-                    op = PQL_PAIR_OPS[int(op_id)]
-                    if gram is not None:
-                        from pilosa_tpu.ops.bitwise import gram_pair_counts
+                    p1 = lut[np.searchsorted(rows, fr1)]
+                    p2 = lut[np.searchsorted(rows, fr2)]
+                    fops = op_ids[fmask]
+                    fout = np.zeros(len(fr1), dtype=np.int64)
+                    pending = []  # every op's dispatch goes out before the first fetch blocks
+                    for op_id in np.unique(fops):
+                        om = fops == op_id
+                        pairs = np.stack([p1[om], p2[om]], axis=1).astype(np.int32)
+                        op = PQL_PAIR_OPS[int(op_id)]
+                        if gram is not None:
+                            from pilosa_tpu.ops.bitwise import gram_pair_counts
 
-                        fout[om] = gram_pair_counts(op, gram, pairs)
-                    else:
-                        pending.append((
-                            om, len(pairs),
-                            self._gather_pairs(op, matrix, pairs, rm_pool, span),
-                        ))
-                for om, n, counts in pending:
-                    fout[om] = self._fetch_counts(counts, matrix, span)[:n]
-                out[fmask] = fout
+                            fout[om] = gram_pair_counts(op, gram, pairs)
+                        else:
+                            pending.append((
+                                om, len(pairs),
+                                self._gather_pairs(op, matrix, pairs, rm_pool, span),
+                            ))
+                    for om, n, counts in pending:
+                        fout[om] = self._fetch_counts(counts, matrix, span)[:n]
+                    out[fmask] = fout
         return out.tolist()
 
     def _tree_build(self, index: str, c: pql.Call, fv_box: dict):
@@ -2316,29 +2317,29 @@ class Executor:
                         max_k=max(kb for _, kb in groups),
                         has_tree=has_tree, pool_cap=pool.cap,
                     )
-                    id_pos, matrix, box = self._frame_matrix(
+                    with self._frame_matrix(
                         index, frame, slices, set(want), view,
                         lane="rmgather" if rm_pool else "", span=span,
-                    )
-                    # The Gram only answers 2-operand counts — don't
-                    # trigger its (expensive, cached) build for requests
-                    # without a pair group.
-                    gram = (
-                        self._frame_gram(matrix, box)
-                        if not rm_pool and any(kb == 2 for _, kb in groups)
-                        else None
-                    )
-                    pending = [  # every group's dispatch goes out before the first fetch blocks
-                        (op_idxs, self._group_counts(
-                            gk, op_idxs, matched, id_pos, matrix, static,
-                            gram, row_major=rm_pool, span=span,
-                        ))
-                        for gk, op_idxs in sorted(groups.items(), key=_group_sort_key)
-                    ]
-                    for op_idxs, counts in pending:
-                        counts = self._fetch_counts(counts, matrix, span)
-                        for k2, i in enumerate(op_idxs):
-                            out[i] = int(counts[k2])
+                    ) as (id_pos, matrix, box):
+                        # The Gram only answers 2-operand counts — don't
+                        # trigger its (expensive, cached) build for requests
+                        # without a pair group.
+                        gram = (
+                            self._frame_gram(matrix, box)
+                            if not rm_pool and any(kb == 2 for _, kb in groups)
+                            else None
+                        )
+                        pending = [  # every group's dispatch goes out before the first fetch blocks
+                            (op_idxs, self._group_counts(
+                                gk, op_idxs, matched, id_pos, matrix, static,
+                                gram, row_major=rm_pool, span=span,
+                            ))
+                            for gk, op_idxs in sorted(groups.items(), key=_group_sort_key)
+                        ]
+                        for op_idxs, counts in pending:
+                            counts = self._fetch_counts(counts, matrix, span)
+                            for k2, i in enumerate(op_idxs):
+                                out[i] = int(counts[k2])
                 else:
                     # Streaming regime (SURVEY §7 hard part (d) at scale):
                     # the working set exceeds the HBM pool budget, so the
@@ -2794,18 +2795,22 @@ class Executor:
                 self._matrix_cache.popitem(last=False)
         return pool
 
+    @contextmanager
     def _frame_matrix(
         self, index: str, frame: str, slices, want: set[int],
         view: str = VIEW_STANDARD, lane: str = "", span=None,
-    ) -> tuple[dict[int, int], object, Optional[dict]]:
-        """Device row matrix holding (at least) ``want`` for a frame view.
+    ):
+        """Device row matrix holding (at least) ``want`` for a frame view,
+        as ``(id_pos, matrix, box)`` for the ``with`` block.
 
         Pool-backed: rows page into HBM slots on demand and stay resident
-        across requests; the returned id_pos maps every RESIDENT row to
-        its slot in the returned (immutable) matrix snapshot.  Generations
-        are read BEFORE acquire: a concurrent mutation mid-fetch can only
-        make the recorded generations stale, forcing a refresh next
-        request — never a stale hit.
+        across requests; the id_pos maps every RESIDENT row to its slot in
+        the matrix snapshot, which stays as it is for the block: the pool's
+        hold on it is given back when the block ends, however it ends, and
+        the block fetches every count it dispatched on the matrix before
+        then.  Generations are read BEFORE acquire: a concurrent mutation
+        mid-fetch can only make the recorded generations stale, forcing a
+        refresh next request — never a stale hit.
         """
         frags = [self.holder.fragment(index, frame, view, s) for s in slices]
         gens = tuple(-1 if f is None else f.generation for f in frags)
@@ -2822,9 +2827,13 @@ class Executor:
         if pool_gens is not None and pool_gens != gens:
             dirty = self._journal_dirty_rows(frags, pool_gens, gens)
         out = pool.acquire(sorted(want), gens, dirty_rows=dirty, span=span)
-        if self.meter is not None:
-            self._note_resident()
-        return out
+        try:
+            if self.meter is not None:
+                self._note_resident()
+            yield out
+        finally:
+            if want:
+                pool.release(out[1])
 
     def _note_resident(self) -> None:
         """Gauge the HBM-resident working set (engine.hbm_bytes): the
@@ -3228,7 +3237,10 @@ class Executor:
         ``matrix[si]`` indexing would touch shards owned by other
         processes).  The fragment's host path is left for an engine that
         can't score rows, a pool too small for one chunk, or a slice count
-        a mesh can't shard evenly."""
+        a mesh can't shard evenly.  A chunk holds the pool's array from
+        its acquire until its counts are on the host (the memo keeps
+        counts, never the array): a miss in between copies the pool, one
+        after writes into it where it lies."""
         from pilosa_tpu.core.fragment import TOPN_SCORE_CHUNK
 
         all_slices = list(slices)
@@ -3275,22 +3287,24 @@ class Executor:
                 counts = memo.get(key)
                 if counts is not None:
                     return counts[si, : len(ids)]
-                if single_ok and seen.setdefault(key, si) == si:
-                    matrix, pos = acquire_pos(ids)
-                    tile = getattr(self.engine, "tile_src", self.engine.asarray)
-                    src_dev = state["src_si"].get(si)
-                    if src_dev is None:
-                        src_dev = state["src_si"][si] = tile(src_dense)
-                    rows = matrix[si][pos]
-                    c = self.engine.batch_intersection_count(
-                        rows, src_dev, tiled=getattr(matrix, "ndim", 3) == 4
-                    )
-                    return c[: len(ids)]
                 matrix, pos = acquire_pos(ids)
-                counts = memo[key] = self.engine.topn_scorer_counts(
-                    matrix, pos, all_src_dev()
-                )
-                return counts[si, : len(ids)]
+                try:  # both forms fetch their counts before they return
+                    if single_ok and seen.setdefault(key, si) == si:
+                        tile = getattr(self.engine, "tile_src", self.engine.asarray)
+                        src_dev = state["src_si"].get(si)
+                        if src_dev is None:
+                            src_dev = state["src_si"][si] = tile(src_dense)
+                        rows = matrix[si][pos]
+                        c = self.engine.batch_intersection_count(
+                            rows, src_dev, tiled=getattr(matrix, "ndim", 3) == 4
+                        )
+                        return c[: len(ids)]
+                    counts = memo[key] = self.engine.topn_scorer_counts(
+                        matrix, pos, all_src_dev()
+                    )
+                    return counts[si, : len(ids)]
+                finally:
+                    pool.release(matrix)
 
             return score
 

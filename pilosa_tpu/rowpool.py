@@ -20,12 +20,13 @@ SLOT id — the same gather kernels as before, they never cared whether
 slot assignment was dense or paged.
 
 Consistency model: a reader that acquired ``(positions, matrix)`` for
-rows it wanted holds an immutable snapshot — a concurrent eviction or
-write repair can only affect later acquires, never a result in flight.
-Paging, growth and the blind refresh produce a NEW engine array
-(functional ``.at[].set``); the write repair (``_repair_dirty``) may
-update the pool's array in place, and does so only while that array has
-never been handed to a reader.  Write invalidation is generation-based
+rows it wanted holds a hold on that array until it ``release``s it, and
+the array stays what it was while a hold is out - a concurrent miss or
+write repair then writes into a copy, and can only affect later acquires,
+never a result in flight.  While no hold on the pool's array is out, a
+miss (``_page_in``) and a write repair (``_repair_dirty``) donate it and
+write into it where it lies; growth and the blind refresh produce a NEW
+engine array (functional ``.at[].set``).  Write invalidation is generation-based
 exactly like the old cache: stale slices get their planes re-fetched
 (bounded), or the pool resets when a refresh would cost more than
 repopulating on demand.
@@ -71,11 +72,12 @@ def pool_bytes(engine=None, n_slices: int = 0) -> tuple[int, int]:
     the 2 GiB share it would hold alone.  ``PILOSA_TPU_POOL_BYTES``, where
     set, is one pool's whole budget whatever the engine (read per call:
     benches and tests tune it).  Total pool memory is bounded by this times
-    the executor's matrix-cache entry count; transient peaks reach 2x one
-    pool plus a miss's chunks in flight during a miss (the pool is copied
-    once a miss, not donated: old + new array alive, and what the chunks
-    being uploaded hold: 16 bytes a word of a sparse chunk, a block of
-    ``MISS_CHUNK_ROWS`` rows of planes for a dense one)."""
+    the executor's matrix-cache entry count; a miss adds what its chunks in
+    flight hold (16 bytes a word of a sparse chunk, a block of
+    ``MISS_CHUNK_ROWS`` rows of planes for a dense one), and the pool once
+    more where a reader holds its array during the miss (the pool is then
+    copied once: old + new array alive; with no hold out it is donated and
+    written where it lies)."""
     devices = engine.slice_axis_devices(n_slices) if engine is not None else 1
     # analysis-ok: lockstep-determinism: deployment config, launcher sets identical env on every rank
     env = os.environ.get("PILOSA_TPU_POOL_BYTES")
@@ -144,7 +146,7 @@ class DeviceRowPool:
         self._cap_override = cap_max
         self.mu = lockcheck.named_rlock("rowpool.mu")
         self.gens: Optional[tuple] = None
-        self.matrix = None  # engine array [n_slices, cap, W]; clears _handed_out
+        self.matrix = None  # engine array [n_slices, cap, W]; clears _holds
         self.cap = 0
         self.slot_of: dict[int, int] = {}
         self.row_at: list[Optional[int]] = []
@@ -153,7 +155,8 @@ class DeviceRowPool:
         # Telemetry for benches/tests: paging behavior must be observable.
         # Each also goes out through ``stats`` where it is incremented
         # (rowpool.misses, .evictions, .resets, .repairs, .repairs_in_place,
-        # .repairs_composed, .patch_planes).
+        # .repairs_composed, .patch_planes; a miss also counts
+        # rowpool.miss_in_place or rowpool.miss_copied: ``_page_in``).
         self.stat_misses = 0
         self.stat_evictions = 0
         self.stat_resets = 0
@@ -188,11 +191,21 @@ class DeviceRowPool:
 
     @matrix.setter
     def matrix(self, m) -> None:
-        # A new array object has not left the pool yet: ``acquire`` marks
-        # it when it hands it to a caller that asked for rows, and a write
-        # repair may donate it (update it in place) until then.
+        # A new array object has no reader yet: ``acquire`` gives a hold on
+        # it to each caller that asks for rows and ``release`` takes it
+        # back; a miss or a write repair donates it (writes into it where
+        # it lies) while no hold is out.
         self._matrix = m
-        self._handed_out = False
+        self._holds = 0
+
+    def release(self, matrix) -> None:
+        """Give back the hold ``acquire`` gave on ``matrix``: the caller
+        has fetched the counts of its last dispatch on it and dispatches
+        on it no more.  A hold on an array the pool has since replaced is
+        moot: that array was copied, never donated."""
+        with self.mu:
+            if matrix is self._matrix and self._holds > 0:
+                self._holds -= 1
 
     @property
     def cap_max(self) -> int:
@@ -316,8 +329,8 @@ class DeviceRowPool:
         breach that the conservative full refresh handles.
 
         Planes and Gram go through ONE engine call a repair
-        (``engine.repair_planes``).  While the pool's array has never
-        been handed to a reader, the jax and mesh engines update it IN
+        (``engine.repair_planes``).  While no reader holds the pool's
+        array (``acquire``, ``release``), the jax and mesh engines update it IN
         PLACE (the array is donated to one compiled step - on the mesh
         the same step on every device's own shard, under ``shard_map``,
         the deltas psummed: no copy of the pool or of a shard, and the
@@ -383,7 +396,7 @@ class DeviceRowPool:
         taken = self.matrix
         try:
             self.matrix, finish, in_place, form = self.engine.repair_planes(
-                taken, gram, groups, donate=not self._handed_out
+                taken, gram, groups, donate=not self._holds
             )
             if sp is not None:
                 sp.finish()
@@ -415,14 +428,18 @@ class DeviceRowPool:
         return True
 
     def _page_in(self, missing: list[int], slots: list[int], span=None):
-        """The pool's array with ``missing`` in ``slots``, and what was
+        """The pool's array with ``missing`` in ``slots``, what was
         uploaded for it (rows, padding included; chunks that went sparse;
-        their words): chunk after chunk of ``MISS_CHUNK_ROWS``, each
+        their words), and whether the pool's own array was written where
+        it lies: chunk after chunk of ``MISS_CHUNK_ROWS``, each
         read from storage on the host (span ``pool.miss.fetch``) while
         the one before uploads, then enqueued (``pool.miss.scatter``).
-        The first chunk is scattered into a COPY of the pool (a reader
-        may hold the array), the others into that copy itself (donated:
-        no other reference exists).
+        Where no reader holds the pool's array, the first chunk is
+        scattered into it where it lies (donated: counter
+        ``rowpool.miss_in_place``); where one does, into a COPY of it
+        (``rowpool.miss_copied``), and the reader's array stays as it
+        was.  The others go into what the first wrote (donated: no other
+        reference exists).
 
         A chunk is sparse or dense by what the walk of its rows found
         (``fetch_pieces``; a pool built with ``fetch`` alone pages dense).
@@ -452,6 +469,11 @@ class DeviceRowPool:
             set_words = engine.set_words_at_rm if self.row_major else engine.set_words_at
         all_slices = list(range(self.n_slices))
         matrix, uploaded, sparse, n_words = self.matrix, 0, 0, 0
+        in_place = not self._holds
+        if in_place:
+            self.stats.count("rowpool.miss_in_place")
+        else:
+            self.stats.count("rowpool.miss_copied")
         for at in range(0, len(missing), MISS_CHUNK_ROWS):
             rows = missing[at : at + MISS_CHUNK_ROWS]
             into = slots[at : at + MISS_CHUNK_ROWS]
@@ -484,11 +506,13 @@ class DeviceRowPool:
                     sp.annotate(snapshot=pieces.served)
                 sp = span.child("pool.miss.scatter")
             if cells is None:
-                matrix = set_rows(matrix, into + tail, block, donate=at > 0)
+                matrix = set_rows(matrix, into + tail, block, donate=at > 0 or in_place)
                 self.stats.count("rowpool.miss_chunks_dense")
             else:
                 pad = [-1] * (MISS_CHUNK_ROWS - len(into) if static else 0)
-                matrix = set_words(matrix, into + pad, cells, values, donate=at > 0)
+                matrix = set_words(
+                    matrix, into + pad, cells, values, donate=at > 0 or in_place
+                )
                 sparse += 1
                 n_words += len(values)
                 self.stats.count("rowpool.miss_chunks_sparse")
@@ -496,7 +520,7 @@ class DeviceRowPool:
             if sp is not None:
                 sp.finish()
             uploaded += bucket
-        return matrix, uploaded, sparse, n_words
+        return matrix, uploaded, sparse, n_words, in_place
 
     def _repair_spanned(self, stale: list[int], dirty_rows, span) -> bool:
         """``_repair_dirty`` under the request's ``pool.repair`` span."""
@@ -522,11 +546,14 @@ class DeviceRowPool:
 
         ``id_pos`` maps every RESIDENT row id to its slot (a stable
         snapshot — safe to index concurrently); ``matrix`` is the engine
-        array snapshot those slots refer to: the caller's to keep and to
-        dispatch on outside the lock when it asked for rows (the array
-        is then marked as handed out, and no later repair touches it); a
-        caller with no ``want`` takes the box alone, for the array may be
-        updated in place by the next write repair.  Raises ValueError when
+        array snapshot those slots refer to: the caller's to dispatch on
+        outside the lock when it asked for rows, and it comes with a hold
+        on the array that the caller gives back with ``release(matrix)``
+        once it has fetched the counts of its last dispatch on it (in a
+        ``finally``).  While the hold is out no miss or repair writes into
+        the array; once none is out, the next one may donate it.  A caller
+        with no ``want`` takes the box alone and no hold, for the array
+        may be updated in place by the next miss or repair.  Raises ValueError when
         ``want`` alone exceeds the pool capacity — callers chunk their
         query batch by unique-row count first (``chunk_queries``).
 
@@ -557,13 +584,16 @@ class DeviceRowPool:
         (``pool.miss.scatter``); a chunk the word list cannot hold (a
         bitmap container, a bulk overlay, over ``MISS_WORDS_MAX`` words)
         goes up as a dense block instead, padded to the power-of-two
-        bucket of its rows for an engine that compiles.  Either into a
-        COPY of the pool for the first chunk (the pool is not donated,
-        for a reader may hold it) and into that copy for the rest.
+        bucket of its rows for an engine that compiles.  The first chunk
+        goes into the pool's array where it lies (donated) when no hold on
+        it is out, else into a COPY of it; the rest into what the first
+        wrote.  A scatter that fails after it took the array leaves the
+        pool empty (``_drop``).
         ``pool.miss`` carries ``rows``, ``bucket`` (rows paged, a dense
         block's padding included), ``evicted``, ``sparse`` (chunks that
         went sparse), ``words`` (what they shipped), ``upload_bytes``
-        (bytes handed to the device: cells and values, or blocks) and
+        (bytes handed to the device: cells and values, or blocks),
+        ``in_place`` (the first chunk donated the pool's array) and
         ``devices`` (how many share the pool's slice axis).  The
         first miss that evicts has the engine compile every program a
         miss can meet first (``warm_set_rows``): once a pool.
@@ -631,7 +661,15 @@ class DeviceRowPool:
                         free.append(s)
                         self.stat_evictions += 1
                 slots = free[: len(missing)]
-                self.matrix, bucket, sparse, words = self._page_in(missing, slots, sp)
+                taken = self.matrix
+                try:
+                    self.matrix, bucket, sparse, words, in_place = self._page_in(
+                        missing, slots, sp
+                    )
+                except BaseException:
+                    if getattr(taken, "is_deleted", lambda: False)():
+                        self._drop()  # the donated array is gone
+                    raise
                 for r, s in zip(missing, slots):
                     self.slot_of[r] = s
                     self.row_at[s] = r
@@ -643,6 +681,7 @@ class DeviceRowPool:
                         rows=len(missing), evicted=evicted, bucket=bucket,
                         sparse=sparse, words=words,
                         upload_bytes=self.engine.stat_upload_bytes - up0,
+                        in_place=in_place,
                         devices=self.engine.slice_axis_devices(self.n_slices),
                     )
             for r in want:
@@ -659,7 +698,7 @@ class DeviceRowPool:
             self.box["gens"] = gens
             self.box["hits"] += 1
             if want:
-                self._handed_out = True
+                self._holds += 1
             return self.box["id_pos"], self.matrix, self.box
 
 

@@ -155,10 +155,14 @@ def mesh_of_four():
 def served(mesh_of_four, tmp_path_factory):
     out = {e: Served(e, str(tmp_path_factory.mktemp(e))) for e in ENGINES}
     for s in out.values():
-        # The first repair after paging: the pager handed the pool's array
-        # to the reads that wanted rows, so this one may not donate it.
+        # The first repair after paging, while a reader still holds the
+        # pool's array, as one whose counts are in flight does: this one
+        # may not donate it.
+        pool = s.pool()
+        _, held, _ = pool.acquire([0], pool.gens)
         s.set_bit(0, 4 * SLICE_WIDTH + 7)
         s.first_repair, _ = s.repair_of([("Intersect", 0, 1)])
+        pool.release(held)
     yield out
     for s in out.values():
         s.server.close()
@@ -281,8 +285,9 @@ def test_a_wide_mesh_repair_is_counted_as_composed(served):
 
 @pytest.mark.parametrize("engine", ENGINES)
 def test_the_first_repair_after_paging_patches_a_copy(served, engine):
-    """The reads that paged the rows in took the pool's array; the repair
-    that found it handed out ran the same step on a copy."""
+    """A reader held the pool's array when the first write after paging
+    came; the repair that found the hold out ran the same step on a
+    copy."""
     tags = served[engine].first_repair["tags"]
     assert (tags["form"], tags["in_place"], tags["planes"]) == ("step", False, 1)
     assert tags["devices"] == (DEVICES if engine == "mesh" else 1)
@@ -310,7 +315,7 @@ def test_repeated_body_stays_on_the_armed_lane(served, engine):
         assert [d["tags"]["lane"] for d in _find(root, "device")] == ["native"], send
         for repair in _find(root, "pool.repair"):
             assert (repair["tags"]["form"], repair["tags"]["in_place"]) == ("step", True), send
-        assert not s.pool()._handed_out, send
+        assert not s.pool()._holds, send
     after = _get(s.host, "/debug/vars")
     assert writes == 6
     assert after["rowpool.repairs_in_place"] == before.get("rowpool.repairs_in_place", 0) + writes

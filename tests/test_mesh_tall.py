@@ -219,6 +219,30 @@ def test_a_sampled_read_on_the_mesh_says_where_it_waited(served):
     assert max(g["start_ms"] for g in gathers) <= min(f["start_ms"] for f in fetches)
 
 
+def test_a_sharded_miss_no_reader_holds_writes_the_pool_in_place(served):
+    """Reads through the door give their hold on the sharded pool back once
+    their counts are on the host, so a miss finds none out: its first chunk
+    is scattered into every device's shard where it lies (the array it
+    replaced is gone, and no miss of the server copied the pool), and the
+    answers stay exact."""
+    host, oracle = served["host"], served["oracle"]
+    fill = [(OPS[i % 4], 2 * i, 2 * i + 1) for i in range(SLOTS // 2)]
+    assert _post(host, _body(fill))[0] == _want(oracle, fill)
+    (pool,) = served["server"].executor._matrix_cache.values()
+    assert pool.cap == SLOTS                                      # full: the miss below evicts
+    before, stats0 = pool.matrix, _get(host, "/debug/vars")
+    calls = [(OPS[i % 4], 101 + i, 131 + i) for i in range(8)]    # a body no test sent: no cached answer
+    results, root = _post(host, _body(calls), trace=True)
+    assert results == _want(oracle, calls)
+    (miss,) = _find(root, "pool.miss")
+    assert miss["tags"]["in_place"] is True and miss["tags"]["devices"] == DEVICES
+    assert before.is_deleted() and not pool.matrix.is_deleted()
+    assert len(pool.matrix.sharding.device_set) == DEVICES
+    stats = _get(host, "/debug/vars")
+    assert stats["rowpool.miss_in_place"] == stats0["rowpool.miss_in_place"] + 1
+    assert stats.get("rowpool.miss_copied", 0) == 0
+
+
 def _get(host, path):
     conn = http.client.HTTPConnection(host, timeout=60)
     try:
@@ -358,4 +382,59 @@ def test_every_gather_dispatch_goes_out_before_the_first_mesh_fetch(tmp_path, mo
     assert all(g.t0 + g.ms / 1e3 <= fetches[0].t0 for g in gathers)     # and each had returned
     assert all(f in root.children and f.ms is not None for f in fetches)
     assert stats.snapshot()["gather.mesh_fetches"] == 4 == stats.snapshot()["gather.dispatches"]
+    h.close()
+
+
+# -- TopN's "topn" lane on the mesh -------------------------------------------------
+
+
+def test_topn_through_the_topn_lane_with_a_miss_between_its_phases(tmp_path, monkeypatch):
+    """A TopN over four slices on the mesh scores its candidates through the
+    pool's "topn" lane, chunk by chunk; each chunk gives its hold back once
+    its counts are on the host.  Between phase 1 and phase 2 other rows page
+    in and evict every candidate, so phase 2 misses too; every miss writes
+    into the pool where it lies, and each answer equals the host path's
+    (the numpy engine: the fragment's own scoring)."""
+    from pilosa_tpu.core.fragment import TOPN_SCORE_CHUNK
+    from pilosa_tpu.core.frame import FrameOptions
+    from pilosa_tpu.core.holder import Holder
+    from pilosa_tpu.engine import MeshEngine
+    from pilosa_tpu.executor import Executor
+    from pilosa_tpu.stats import ExpvarStatsClient
+
+    n_slices, n_rows = 4, 600                    # three chunks of candidates a slice
+    monkeypatch.delenv("PILOSA_TPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setenv("PILOSA_TPU_POOL_BYTES", str(n_slices * TOPN_SCORE_CHUNK * PLANE_WORDS * 4))
+    h = Holder(str(tmp_path / "data"))
+    h.open()
+    h.create_index("i").create_frame("f", FrameOptions())
+    fr = h.index("i").frame("f")
+    rng = np.random.default_rng(40)
+    for s in range(n_slices):
+        window = rng.choice(SLICE_WIDTH, size=64, replace=False)
+        for r in range(n_rows):                  # four bits a (row, slice): no early stop
+            for c in rng.choice(window, size=4, replace=False):
+                fr.set_bit("standard", r, int(s * SLICE_WIDTH + c))
+    stats = ExpvarStatsClient()
+    ex = Executor(h, engine=MeshEngine(devices=jax.devices()[:DEVICES]), stats=stats)
+    host = Executor(h, engine="numpy")
+    phase = ex._topn_phase
+
+    def evicting(n, *args, **kw):
+        out = phase(n, *args, **kw)
+        if n == 1:                               # page other rows over every candidate
+            pool = ex._pool_for("i", "f", "standard", list(range(n_slices)), lane="topn")
+            _, m, _ = pool.acquire(list(range(n_rows, n_rows + TOPN_SCORE_CHUNK)), pool.gens)
+            pool.release(m)
+        return out
+
+    monkeypatch.setattr(ex, "_topn_phase", evicting)
+    for src in (0, 17):
+        q = f'TopN(Bitmap(rowID={src}, frame="f"), frame="f", n=5)'
+        got, want = ex.execute("i", q), host.execute("i", q)
+        assert [(p.id, p.count) for p in got[0]] == [(p.id, p.count) for p in want[0]]
+        assert len(got[0]) == 5 and got[0][0].id == src
+    snap = stats.snapshot()
+    assert snap["rowpool.miss_in_place"] >= 2 * (3 + 1 + 1) and snap.get("rowpool.miss_copied", 0) == 0
+    assert snap["topn.dense_fallbacks"] > 0
     h.close()

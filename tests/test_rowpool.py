@@ -659,7 +659,10 @@ def test_miss_counts_compile_one_program_a_bucket(tmp_path, monkeypatch, kind):
     block sizes (1, 2, 4, 8: a chunk with a bitmap container goes dense),
     both forms of each - and a second pass compiles none.  Counted where
     jax counts them: the jitted scatters' own caches (which every jit of
-    a function shares, so this test has a slice count of its own)."""
+    a function shares, so this test has a slice count of its own).  The
+    fill writes into the new pool where it lies (no reader holds it);
+    every later miss, while this test holds what the one before handed
+    it, copies."""
     n_slices = 8 if kind == "mesh" else 3
     h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, kind, n_slices)
     engine = ex.engine
@@ -682,14 +685,16 @@ def test_miss_counts_compile_one_program_a_bucket(tmp_path, monkeypatch, kind):
     pool.acquire(list(range(SLOTS)), gens)           # the pool is full: every later miss evicts
     assert pool.cap == SLOTS and pool.stat_evictions == 0
     at_full = programs()
-    # the fill: a chunk of 8 rows copied, one donated, 66-192 words each: bucket 256
-    assert at_full - before == 2
+    # the fill: two chunks of 8 rows donated, 66-192 words each: bucket 256
+    assert at_full - before == 1
+    assert (_counter(stats, "rowpool.miss_in_place"), _counter(stats, "rowpool.miss_copied")) == (1, 0)
     nxt = SLOTS
     for n in range(1, 10):
         pool.acquire(list(range(nxt, nxt + n)), gens)
         nxt += n
-    # The first eviction compiled both ladders; the sparse 256 and 256 were there already.
-    assert programs() - at_full == 8 + 2 * len(_word_buckets()) - 2
+    # The first eviction compiled both ladders; the donating sparse 256 was there already.
+    assert programs() - at_full == 8 + 2 * len(_word_buckets()) - 1
+    assert _counter(stats, "rowpool.miss_copied") == 9
     assert pool.miss_buckets == {1, 2, 4, 8}
     assert _counter(stats, "rowpool.miss_buckets") == 4
     assert _counter(stats, "rowpool.miss_chunks_dense") == 0
@@ -1060,3 +1065,181 @@ def test_one_bucketing_rule_each():
     assert _padded_batch(pairs).tolist() == [[3, 4], [5, 6], [7, 8], [3, 4]]
     assert _padded_batch(pairs[:1]) .tolist() == [[3, 4]]
     assert NumpyEngine().gather_bucket(3) == 3 and JaxEngine.gather_bucket(None, 3) == 4
+
+
+# -- a miss or a repair writes into the pool where it lies once no reader holds it --
+
+
+def _set_counts(cols_of, op, pairs):
+    return [len(SET_OPS[op](cols_of[a], cols_of[b])) for a, b in pairs]
+
+
+@pytest.mark.parametrize("held", [False, True], ids=["released", "held"])
+def test_a_miss_writes_into_the_pool_unless_a_reader_holds_its_array(tmp_path, monkeypatch, held):
+    """(a) A miss whose array no reader holds - the reader that took it
+    gave its hold back with ``release`` - donates it: its first chunk is
+    scattered into the pool where it lies, the old array is gone, and
+    counts over the new one equal the set reference.  (b) While a reader
+    still holds the array the miss copies it, and the reader's dispatch
+    on its own array answers the rows as they were before the miss."""
+    h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, "jax")
+    pool = _paged_pool(ex, "jax", 2, False)
+    gens = (0, 0)
+    id_pos, first, _ = pool.acquire(list(range(SLOTS)), gens)       # the pool is full
+    assert (_counter(stats, "rowpool.miss_in_place"), _counter(stats, "rowpool.miss_copied")) == (1, 0)
+    old = [(a, b) for a in range(4) for b in range(4)]              # rows 0-2 are the LRU's victims
+    old_slots = np.array([[id_pos[a], id_pos[b]] for a, b in old], dtype=np.int32)
+    if not held:
+        pool.release(first)
+    root = Span("root")
+    late = [20, 21, 22]
+    id_pos, after, _ = pool.acquire(late, gens, span=root)
+    assert pool.stat_evictions == 3
+    assert (_counter(stats, "rowpool.miss_in_place"), _counter(stats, "rowpool.miss_copied")) == (
+        (1, 1) if held else (2, 0))
+    (miss,) = _miss_spans(root)
+    assert miss.tags["in_place"] is not held
+    assert first.is_deleted() is not held and not after.is_deleted()
+    new = [(a, b) for a in late + [3, 9] for b in late + [3, 9]]
+    new_slots = np.array([[id_pos[a], id_pos[b]] for a, b in new], dtype=np.int32)
+    for op in ("and", "xor"):
+        want = _set_counts(cols_of, {"and": "Intersect", "xor": "Xor"}[op], new)
+        assert ex.engine.gather_count(op, after, new_slots).tolist() == want
+        if held:      # the held array still has rows 0-2 where they were
+            want = _set_counts(cols_of, {"and": "Intersect", "xor": "Xor"}[op], old)
+            assert ex.engine.gather_count(op, first, old_slots).tolist() == want
+    h.close()
+
+
+@pytest.mark.parametrize("stage", ["dispatch", "fetch"])
+def test_a_pass_that_fails_gives_its_hold_back(tmp_path, monkeypatch, stage):
+    """(c) A pass that raises between its acquire and its last fetch - in
+    a gather dispatch or in the wait for its counts - still gives its
+    hold on the pool's array back: the error surfaces, no hold is out,
+    and the next pass's miss writes into the pool where it lies and
+    answers exactly."""
+    h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, "jax")
+    body = lambda pairs: " ".join(  # noqa: E731
+        f'Count(Intersect(Bitmap(rowID={a}, frame="stargazer"), Bitmap(rowID={b}, frame="stargazer")))'
+        for a, b in pairs)
+    first = [(a, a + 1) for a in range(0, SLOTS, 2)]                 # fills the pool
+    assert ex.execute("i", body(first)) == _set_counts(cols_of, "Intersect", first)
+    (pool,) = ex._matrix_cache.values()
+    assert pool._holds == 0
+
+    def boom(*args, **kw):
+        raise RuntimeError("device lost")
+
+    with monkeypatch.context() as mp:
+        if stage == "dispatch":
+            mp.setattr(ex.engine, "gather_count_dev", boom)
+        else:
+            mp.setattr(ex, "_fetch_counts", boom)
+        with pytest.raises(RuntimeError, match="device lost"):
+            ex.execute("i", body([(1, 30), (3, 31)]))                # a miss, then the gathers
+    assert pool._holds == 0
+    held = pool.matrix
+    in_place = _counter(stats, "rowpool.miss_in_place")
+    late = [(40, 41), (42, 3)]
+    assert ex.execute("i", body(late)) == _set_counts(cols_of, "Intersect", late)
+    assert _counter(stats, "rowpool.miss_in_place") == in_place + 1
+    assert _counter(stats, "rowpool.miss_copied") == 0 and held.is_deleted()
+    h.close()
+
+
+@pytest.mark.parametrize("engine", STEPPING)
+def test_a_repair_after_the_reader_released_its_hold_is_in_place(engine):
+    """(d) The write repair reads the same holds: once the reader that
+    took the pool's array has given its hold back, the next repair
+    updates that array in place (it is gone afterwards), and planes and
+    Gram equal storage and the full recompute."""
+    rng = np.random.default_rng(40)
+    pool, live, gens, matrix, _ = _warm_pool(_engine(engine), rng)
+    pool.release(matrix)
+    assert pool._holds == 0
+    _write(rng, pool, live, gens, {2: {1}, 6: {4}})
+    assert (pool.stat_repairs, pool.stat_repairs_in_place) == (1, 1)
+    assert matrix.is_deleted() and pool.matrix is not matrix
+    _assert_pool_is(pool, live)
+
+
+def test_a_failed_miss_that_took_the_array_leaves_a_pool_the_next_acquire_rebuilds(tmp_path, monkeypatch):
+    """A miss's scatter that raises after it took the pool's array (donated:
+    no reader held it) leaves nothing to trust: the pool drops to its empty
+    state, the error surfaces, and the next acquire pages in from storage."""
+    h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, "jax")
+    pool = _paged_pool(ex, "jax", 2, False)
+    for want in (list(range(SLOTS)), [SLOTS]):                      # full, then a first eviction
+        _, taken, _ = pool.acquire(want, (0, 0))
+        pool.release(taken)
+    scatter = ex.engine._scatter_words
+
+    def boom(matrix, slots, cells, values, axis, donate):
+        assert donate
+        matrix.delete()  # what a donated argument is after the call
+        raise RuntimeError("device lost")
+
+    with monkeypatch.context() as mp:
+        mp.setattr(ex.engine, "_scatter_words", boom)
+        with pytest.raises(RuntimeError, match="device lost"):
+            pool.acquire([20, 21], (0, 0))
+    assert ex.engine._scatter_words == scatter
+    assert pool.matrix is None and pool.cap == 0 and not pool.slot_of and pool.gens is None
+    id_pos, matrix, _ = pool.acquire([20, 21, 3], (0, 0))
+    _assert_planes_equal_storage(h, pool, matrix, id_pos, [20, 21, 3])
+    h.close()
+
+
+def test_readers_and_misses_on_many_threads_keep_every_held_array_whole(tmp_path, monkeypatch):
+    """More threads than cores, a short switch interval: each acquires rows
+    (hot ones and a cold one, so misses evict and run beside other readers'
+    holds), reads its rows back from the array it holds, then releases it.
+    No held array is ever donated under its reader - every read equals
+    storage - and once all are done no hold is out."""
+    import os
+    import sys
+    import threading
+    import time
+
+    h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, "jax")
+    pool = _paged_pool(ex, "jax", 2, False)
+    _, m, _ = pool.acquire(list(range(SLOTS)), (0, 0))
+    pool.release(m)
+    dense = {(s, r): h.fragment("i", "stargazer", "standard", s).row_dense(r)
+             for s in range(2) for r in range(TALL_ROWS)}
+    errors: list = []
+
+    def reader(k):
+        rng = np.random.default_rng(k)
+        try:
+            for _ in range(12):
+                rows = [int(r) for r in rng.choice(HOT, size=3, replace=False)] + [
+                    int(rng.integers(HOT, TALL_ROWS))]
+                id_pos, matrix, _ = pool.acquire(rows, (0, 0))
+                try:
+                    time.sleep(0.002)  # the reader's counts in flight: other threads miss meanwhile
+                    got = np.asarray(matrix).reshape(2, SLOTS, -1)
+                    for s in range(2):
+                        for r in rows:
+                            if not (got[s, id_pos[r]] == dense[(s, r)]).all():
+                                errors.append((k, s, r))
+                finally:
+                    pool.release(matrix)
+        except Exception as e:  # noqa: BLE001 - the test reports it below
+            errors.append(repr(e))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=reader, args=(k,)) for k in range(2 * (os.cpu_count() or 4))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert errors == []
+    assert pool._holds == 0
+    assert _counter(stats, "rowpool.miss_copied") > 0          # misses did run beside holds
+    h.close()

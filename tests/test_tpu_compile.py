@@ -94,6 +94,14 @@ def _ids(*shape):
     return (shape, "int32")
 
 
+def _pool_copies(compiled, shape="u32[64,256,256,128]"):
+    """How many ``copy`` ops of a whole 64-slice, 256-slot pool (or one
+    device's shard of a 256-slice one) the compiled program holds."""
+    import re
+
+    return len(re.findall(re.escape(shape) + r"\S* copy\(", compiled.as_text()))
+
+
 def _p(fn_name, *static, **kw):
     """A kernel of ops/pallas_kernels.py by name with its static arguments
     bound (looked up when the test runs: importing this file imports no
@@ -228,9 +236,10 @@ def test_pool_page_in_compiles_and_fits(bucket, donate, one_chip):
     ``ops.bitwise.set_rows``), one program per power-of-two bucket of a
     chunk's rows (1 .. 8): the first chunk's into a copy (old and new
     matrix both alive: it stays inside the chip next to a second pool
-    and a Range matrix), the others' into that copy itself (donated: the
-    output is the input's buffer).  No second copy either way, and its
-    ops carry the scope's name."""
+    and a Range matrix) where a reader holds the pool, else into the
+    pool itself, and the others' into what the first wrote (donated: the
+    output is the input's buffer, and no ``copy`` of the pool is made).
+    No second copy either way, and its ops carry the scope's name."""
     import jax
 
     from pilosa_tpu.ops.bitwise import set_rows
@@ -244,6 +253,7 @@ def test_pool_page_in_compiles_and_fits(bucket, donate, one_chip):
     assert mem.temp_size_in_bytes < 2**20
     assert mem.alias_size_in_bytes == (64 * 256 * W * 4 if donate else 0)
     assert "pool.set_rows" in compiled.as_text()
+    assert _pool_copies(compiled) == (0 if donate else 1)
 
 
 @pytest.mark.parametrize("donate", [False, True])
@@ -254,8 +264,9 @@ def test_pool_sparse_page_in_compiles_and_fits(words, donate, one_chip):
     ``seg64``'s chunks fall into and at the largest one
     (``rowpool.MISS_WORDS_MAX``): what crosses to the device is 16 bytes a
     word, no second copy of the 2 GiB pool is made either way, the
-    donating form writes into its input, and its ops carry the scope's
-    name."""
+    donating form writes into its input with no copy of the pool at all
+    (the copying form's one is the ``copy`` a traced miss showed), and
+    its ops carry the scope's name."""
     import jax
 
     from pilosa_tpu.ops.bitwise import set_row_words
@@ -273,6 +284,7 @@ def test_pool_sparse_page_in_compiles_and_fits(words, donate, one_chip):
     assert mem.temp_size_in_bytes < 2**20
     assert mem.alias_size_in_bytes == (pool if donate else 0)
     assert "pool.set_rows" in compiled.as_text()
+    assert _pool_copies(compiled) == (0 if donate else 1)
 
 
 @pytest.mark.parametrize("words,rows", [(4096, 256), (1 << 20, 1 << 16), (1 << 24, 1 << 16)])
@@ -444,6 +456,8 @@ def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, m
         assert ("pool.set_rows" if program.startswith("set_row") else "pool.set_plane_rows") in text
         if program.startswith("set_row_words"):
             assert mem.temp_size_in_bytes < 2**20 and "collective-permute" not in text
+        if program.startswith("set_row"):   # a miss no reader's hold stops writes in place
+            assert _pool_copies(compiled) == (0 if program.endswith("donated") else 1)
     else:
         assert "all-reduce" in text
         assert ("tpu_custom_call" in text) == program.startswith("gram_update")

@@ -532,10 +532,10 @@ def test_read_after_a_setbit_shows_the_repair_under_the_pools_lock(served):
 
 def test_a_repair_of_an_array_no_reader_holds_is_in_place(tmp_path):
     """Four slices: a write to one is a repair the jax engine's compiled
-    step takes.  The first patches a copy (the warm-up's readers hold the
-    pool's array); the new array never left the pool, so the second
-    updates it in place; tag, counter and /debug/vars say so, and the
-    stages keep their names and order."""
+    step takes.  The warm-up's readers gave their holds on the pool's
+    array back once they had their counts, so the first repair already
+    updates it in place, and so does every later one; tag, counter and
+    /debug/vars say so, and the stages keep their names and order."""
     import urllib.request
 
     from pilosa_tpu.server.client import Client
@@ -554,7 +554,7 @@ def test_a_repair_of_an_array_no_reader_holds_is_in_place(tmp_path):
         for _ in range(4):
             res0, _ = _post(s.host, _PAIRS, trace=False)
         pool = _pool(s)
-        for nth, in_place in ((1, False), (2, True), (3, True)):
+        for nth, in_place in ((1, True), (2, True), (3, True)):
             _post(s.host, f'SetBit(rowID=1, frame="f", columnID={(2 << 20) + 1000 + nth})',
                   trace=False)
             res, root = _post(s.host, _PAIRS)
@@ -564,9 +564,9 @@ def test_a_repair_of_an_array_no_reader_holds_is_in_place(tmp_path):
             assert prep["tags"]["in_place"] is in_place and prep["tags"]["planes"] == 1
             assert prep["tags"]["upload_bytes"] == 131072
             assert res == res0  # those columns are in no other row
-        assert (pool.stat_repairs, pool.stat_repairs_in_place) == (3, 2)
+        assert (pool.stat_repairs, pool.stat_repairs_in_place) == (3, 3)
         snap = json.loads(urllib.request.urlopen(f"http://{s.host}/debug/vars", timeout=30).read())
-        assert snap["rowpool.repairs"] == 3 and snap["rowpool.repairs_in_place"] == 2
+        assert snap["rowpool.repairs"] == 3 and snap["rowpool.repairs_in_place"] == 3
     finally:
         s.close()
 
@@ -850,16 +850,19 @@ def test_pool_spans_and_counters_for_paging_refresh_and_eviction():
     assert names == ["pool.lock_wait", "pool.miss", "pool.lock_wait", "pool.refresh",
                      "pool.lock_wait", "pool.miss"]
     assert all(c.ms is not None for c in root.children)
-    # a pool built with ``fetch`` alone pages dense: no chunk went sparse, no words shipped
+    # a pool built with ``fetch`` alone pages dense: no chunk went sparse, no words shipped;
+    # the new pool's array had no reader, so the miss wrote into it where it lay
     assert root.children[1].tags == {"rows": 2, "bucket": 2, "evicted": 0, "sparse": 0,
-                                     "words": 0, "upload_bytes": 0, "devices": 1}
+                                     "words": 0, "upload_bytes": 0, "in_place": True, "devices": 1}
     assert [c.name for c in root.children[1].children] == ["pool.miss.fetch", "pool.miss.scatter"]
     assert root.children[3].tags == {"rows": 2, "upload_bytes": 0}
+    # the second acquire's hold on the refreshed array is still out: this miss copies
     assert root.children[5].tags == {"rows": 1, "bucket": 1, "evicted": 1, "sparse": 0,
-                                     "words": 0, "upload_bytes": 0, "devices": 1}
+                                     "words": 0, "upload_bytes": 0, "in_place": False, "devices": 1}
     # two block sizes met (the numpy engine's are the chunks' row counts themselves)
     assert stats.n == {"rowpool.misses": 3, "rowpool.evictions": 1, "rowpool.miss_buckets": 2,
-                       "rowpool.miss_chunks_dense": 2}
+                       "rowpool.miss_chunks_dense": 2, "rowpool.miss_in_place": 1,
+                       "rowpool.miss_copied": 1}
     # what the pool was budgeted when it took device memory: the default per device, its own cap
     assert stats.g == {"rowpool.budget_bytes_per_device": 2 << 30, "rowpool.capacity_slots": 2}
     assert (pool.stat_misses, pool.stat_evictions, pool.stat_repairs) == (3, 1, 0)
