@@ -214,17 +214,35 @@ class NumpyEngine:
         chunk = or_multi_chunk_size(s, k, w, OR_MULTI_BUDGET_HOST)
         out = np.empty(idx.shape[0], dtype=np.int64)
         for i in range(0, idx.shape[0], chunk):
-            g = row_matrix[:, idx[i : i + chunk], :]
-            if op == "or":
-                acc = np.bitwise_or.reduce(g, axis=2)
-            elif op == "and":
-                acc = np.bitwise_and.reduce(g, axis=2)
-            elif op == "andnot":
-                acc = g[:, :, 0] & ~np.bitwise_or.reduce(g[:, :, 1:], axis=2)
-            else:
-                raise ValueError(f"unsupported multi-op {op!r}")
-            out[i : i + chunk] = self.count(acc).sum(axis=0)
+            out[i : i + chunk] = self._fold_count(op, row_matrix[:, idx[i : i + chunk], :])
         return out
+
+    def gather_count_xframe(self, op: str, rm_a, idx_a, rm_b, idx_b) -> np.ndarray:
+        """``gather_count_multi`` over operands of two row matrices (two
+        frames' pools over the same slices): per query the fold of
+        ``rm_a``'s rows ``idx_a[q]`` then ``rm_b``'s ``idx_b[q]``.
+        Chunked over the batch as ``gather_count_multi`` is."""
+        from pilosa_tpu.pilosa import OR_MULTI_BUDGET_HOST, or_multi_chunk_size
+
+        s, _, w = rm_a.shape
+        chunk = or_multi_chunk_size(s, idx_a.shape[1] + idx_b.shape[1], w, OR_MULTI_BUDGET_HOST)
+        out = np.empty(idx_a.shape[0], dtype=np.int64)
+        for i in range(0, idx_a.shape[0], chunk):
+            out[i : i + chunk] = self._fold_count(op, np.concatenate(
+                [rm_a[:, idx_a[i : i + chunk], :], rm_b[:, idx_b[i : i + chunk], :]], axis=2))
+        return out
+
+    def _fold_count(self, op: str, g) -> np.ndarray:
+        """Per-query popcount of the fold over g's operand axis: [S, B, K, W]."""
+        if op == "or":
+            acc = np.bitwise_or.reduce(g, axis=2)
+        elif op == "and":
+            acc = np.bitwise_and.reduce(g, axis=2)
+        elif op == "andnot":
+            acc = g[:, :, 0] & ~np.bitwise_or.reduce(g[:, :, 1:], axis=2)
+        else:
+            raise ValueError(f"unsupported multi-op {op!r}")
+        return self.count(acc).sum(axis=0)
 
     def gather_count_or_multi(self, row_matrix, idx) -> np.ndarray:
         return self.gather_count_multi("or", row_matrix, idx)
@@ -274,6 +292,9 @@ class NumpyEngine:
 
     def gather_count_multi_dev(self, op: str, row_matrix, idx):
         return self.gather_count_multi(op, row_matrix, idx)
+
+    def gather_count_xframe_dev(self, op: str, rm_a, idx_a, rm_b, idx_b):
+        return self.gather_count_xframe(op, rm_a, idx_a, rm_b, idx_b)
 
     def gather_count_tree_dev(self, row_matrix, leaves, opc):
         return self.gather_count_tree(row_matrix, leaves, opc)
@@ -647,6 +668,21 @@ class JaxEngine:
     def gather_count_multi_dev(self, op: str, row_matrix, idx):
         return self._dispatch.gather_count_multi(
             op, self._jnp.asarray(row_matrix), self._jnp.asarray(idx)
+        )
+
+    def gather_count_xframe(self, op: str, rm_a, idx_a, rm_b, idx_b) -> np.ndarray:
+        return self.to_numpy(
+            self.gather_count_xframe_dev(op, rm_a, idx_a, rm_b, idx_b)
+        ).astype(np.int64)
+
+    def gather_count_xframe_dev(self, op: str, rm_a, idx_a, rm_b, idx_b):
+        """The cross-frame fold's counts un-fetched: operands ``idx_a`` of
+        ``rm_a`` then ``idx_b`` of ``rm_b`` (two frames' pools), one
+        dispatch (``dispatch.gather_count_xframe``: the kernel under
+        ``gather.xcount`` where Pallas runs)."""
+        jnp = self._jnp
+        return self._dispatch.gather_count_xframe(
+            op, jnp.asarray(rm_a), jnp.asarray(idx_a), jnp.asarray(rm_b), jnp.asarray(idx_b)
         )
 
     # -- TopN: a fragment's rank-cache candidates, sparse on the device ----
@@ -1176,6 +1212,7 @@ class MeshEngine(JaxEngine):
         self._gather_jit = jax.jit(_bw.gather_count, static_argnums=0)
         self._gather_multi_jit = jax.jit(_bw.gather_count_multi, static_argnums=0)
         self._tree_jit = None  # built on first tree batch
+        self._xframe_jit = None  # ... and cross-frame batch
         self._count_jit = jax.jit(_bw.count)
 
         jnp = self._jnp
@@ -1495,6 +1532,39 @@ class MeshEngine(JaxEngine):
 
     def gather_count_or_multi(self, row_matrix, idx):
         return self.gather_count_multi("or", row_matrix, idx)
+
+    def gather_count_xframe_dev(self, op, rm_a, idx_a, rm_b, idx_b):
+        """The cross-frame fold over two frames' sharded pools, un-fetched:
+        under the kernels ``sharded_gather_count_xframe`` (both matrices
+        split along the slice axis alike, the fold on each shard, one psum);
+        otherwise the jnp form, which XLA partitions itself."""
+        from pilosa_tpu.ops.pallas_kernels import rm_words
+
+        jnp = self._jnp
+        rm_a = self._shard_stack(jnp.asarray(rm_a))
+        rm_b = self._shard_stack(jnp.asarray(rm_b))
+        s, w = rm_a.shape[0], rm_words(rm_a)
+        mode = self._pallas_mode(s, w)
+        if mode:
+            from pilosa_tpu.parallel.sharded import sharded_gather_count_xframe
+
+            return sharded_gather_count_xframe(
+                self.mesh, op, rm_a, jnp.asarray(idx_a), rm_b, jnp.asarray(idx_b),
+                interpret=(mode == "interpret"),
+            )
+        from pilosa_tpu.pilosa import OR_MULTI_BUDGET_DEVICE, or_multi_chunk_size
+
+        if self._xframe_jit is None:
+            from pilosa_tpu.ops import bitwise as _bw
+
+            self._xframe_jit = self._jax.jit(_bw.gather_count_xframe, static_argnums=0)
+        return self._chunked(
+            lambda i, j: self._xframe_jit(
+                op, rm_a, jnp.asarray(idx_a[i:j]), rm_b, jnp.asarray(idx_b[i:j])
+            ),
+            idx_a.shape[0],
+            or_multi_chunk_size(s, idx_a.shape[1] + idx_b.shape[1], w, OR_MULTI_BUDGET_DEVICE),
+        )
 
     def gather_count_tree(self, row_matrix, leaves, opc):
         return self._fetch(self.gather_count_tree_dev(row_matrix, leaves, opc)).astype(np.int64)

@@ -24,7 +24,7 @@ import os
 import re
 import threading
 from collections import OrderedDict
-from contextlib import contextmanager
+from contextlib import ExitStack, contextmanager
 from dataclasses import dataclass, field, replace as dc_replace
 from datetime import datetime
 from typing import Any, Optional, Sequence
@@ -40,7 +40,7 @@ from pilosa_tpu.core.fragment import RowPieces, TopOptions
 from pilosa_tpu.core import timequantum as tq
 from pilosa_tpu.core.view import VIEW_INVERSE, VIEW_STANDARD
 from pilosa_tpu.engine import new_engine
-from pilosa_tpu.rowpool import DeviceRowPool, chunk_queries, pool_capacity
+from pilosa_tpu.rowpool import DeviceRowPool, chunk_queries, chunk_queries_by, pool_capacity
 from pilosa_tpu.stats import NOP_STATS
 from pilosa_tpu.pilosa import (
     ErrFrameInverseDisabled,
@@ -65,6 +65,13 @@ _WORDS = SLICE_WIDTH // 32
 # per-chunk partials in int64 HOST-side (same bound as the Gram's
 # _GRAM_SLICES_MAX; BASELINE.md round-3 addendum 3 measured the overflow).
 _INT32_SAFE_SLICES = 2047
+
+# A cross-frame group dispatches in batches of at most this many calls, each
+# padded to the engine's gather bucket (powers of four where it compiles):
+# a group's shape meets the same few programs whatever the number of requests
+# the fused queue joins into a pass - a pass size met first inside a measured
+# window would otherwise compile there.
+_XFRAME_BATCH = 64
 
 
 # --- fused tree compilation helpers (executor.go:261-276, fused) -----------
@@ -139,7 +146,9 @@ class _TopnRun:
 
 
 class _TreeUnfusable(Exception):
-    """Tree shape outside the fused lane (not an error — sequential path)."""
+    """Tree shape outside the fused lane (not an error — sequential path);
+    ``args[0]`` says why (``frames``, ``view``, ``shape``: what
+    ``count.unfused`` counts it under)."""
 
 
 # First frame reference in a request (double-quoted, single-quoted, or
@@ -355,6 +364,7 @@ class Executor:
         # fighting over the interpreter per request.
         self._write_queue = None
         self._serve_queue = None
+        self._fused_queue = None  # fused count passes with a cross-frame call
         # Per-THREAD armed tables for the write lanes (the table-per-
         # thread registry extending PR-10's armed-table validity rule):
         # each serving thread owns a private {(index, frame) -> arm}
@@ -441,6 +451,7 @@ class Executor:
 
             self._write_queue = WriteQueue(self._apply_queued_writes)
             self._serve_queue = WriteQueue(self._apply_queued_reads, max_batch=64)
+            self._fused_queue = WriteQueue(self._apply_queued_fused, max_batch=64)
 
     def _lane_tables(self):
         """This thread's private armed write-lane tables:
@@ -601,9 +612,12 @@ class Executor:
             return batched_writes
 
         fsp = span.child("fused") if span is not None else None
-        fused = self._fuse_count_pair_batch(index, query.calls, std_slices, inv_slices, opt)
+        why: dict = {}
+        fused = self._fuse_count_pair_batch(index, query.calls, std_slices, inv_slices, opt, why)
         if fused is None:
             fused = self._fuse_count_range_batch(index, query.calls, std_slices, opt)
+        if fused is None or span is not None:
+            self._note_unfused(query.calls, fused, why, span)
         if fsp is not None:
             fsp.finish()
             if fused is None:
@@ -642,6 +656,29 @@ class Executor:
         if qtoken is not None:
             self._qcache_commit(qtoken, results, span)
         return results
+
+    def _note_unfused(self, calls, fused, why: dict, span) -> None:
+        """Count the request's Count calls that no fused lane answered -
+        they run on the sequential path, one call and one slice at a time:
+        counter ``count.unfused``, and ``count.unfused.<why>`` each under
+        the reason a lane declined it for (``frames``, ``view``,
+        ``depth``, ``shape``), else the request's first such reason, else
+        ``shape`` (a lone Count, a write beside it).  A traced request's
+        root gets the number as tag ``unfused`` (0 where the fused lanes
+        answered every Count)."""
+        n = 0
+        if fused is None:
+            first = next(iter(why.values()), "shape")
+            for i, c in enumerate(calls):
+                if c.name == "Count":
+                    n += 1
+                    if self.meter is not None:
+                        reason = why.get(i, first)
+                        self.meter.stats.count(f"count.unfused.{reason}")
+            if n and self.meter is not None:
+                self.meter.stats.count("count.unfused", n)
+        if span is not None:
+            span.tags["unfused"] = n
 
     def _qcache_commit(self, qtoken, results, span) -> None:
         """Offer one executed read to the query cache.  A sampled
@@ -1782,19 +1819,20 @@ class Executor:
 
         Returns int (a Bitmap leaf's row id) or (op_id, left, right).
         Raises _TreeUnfusable for shapes outside the lane (Range leaves,
-        <2-child nodes, mixed frame/view) and PilosaError for invalid
-        leaves (callers abort the whole fuse so the sequential path
-        surfaces the identical error)."""
+        <2-child nodes, mixed frame/view: the tree lane is one frame's, so
+        a nested tree over two frames stays sequential) and PilosaError
+        for invalid leaves (callers abort the whole fuse so the sequential
+        path surfaces the identical error)."""
         if c.name == "Bitmap":
             frame, view, row = self._resolve_bitmap_leaf(index, c)
             if fv_box["fv"] is None:
                 fv_box["fv"] = (frame, view)
             elif fv_box["fv"] != (frame, view):
-                raise _TreeUnfusable()
+                raise _TreeUnfusable("view" if fv_box["fv"][1] != view else "frames")
             return int(row)
         op = self._FUSABLE_OPS.get(c.name)
         if op is None or len(c.children) < 2:
-            raise _TreeUnfusable()
+            raise _TreeUnfusable("shape")
         subs = [self._tree_build(index, ch, fv_box) for ch in c.children]
         if op == "andnot":
             # a &~ b &~ c ... == a & ~(b | c | ...) — the rest joins
@@ -1807,21 +1845,29 @@ class Executor:
             return (_TREE_OP_IDS["andnot"], subs[0], rest)
         return _tree_balanced(_TREE_OP_IDS[op], subs)
 
-    def _compile_count_tree(self, index: str, ch: pql.Call):
+    def _compile_count_tree(self, index: str, ch: pql.Call, why: Optional[list] = None):
         """Compile one Count child tree for the fused tree lane.
 
         Returns (frame, view, ("tree", 2^D), leaves, opc) or None when the
-        shape stays sequential; propagates PilosaError for invalid leaves.
+        shape stays sequential, having appended why to ``why``
+        (``frames``, ``view``, ``depth``, ``shape``); propagates
+        PilosaError for invalid leaves.
         """
         box = {"fv": None}
         try:
             node = self._tree_build(index, ch, box)
-        except _TreeUnfusable:
+        except _TreeUnfusable as e:
+            if why is not None:
+                why.append(e.args[0])
             return None
         if isinstance(node, int):
+            if why is not None:
+                why.append("shape")
             return None
         d = _tree_depth(node)
         if d > _TREE_DEPTH_MAX:
+            if why is not None:
+                why.append("depth")
             return None
         # Pad slots gather the leftmost REAL leaf so the unique-row
         # working set (pool capacity, Gram eligibility) never grows.
@@ -1833,7 +1879,8 @@ class Executor:
         return frame, view, ("tree", 1 << d), tuple(leaves), tuple(opc)
 
     def _fuse_count_pair_batch(
-        self, index: str, calls, slices, inv_slices, opt: ExecOptions
+        self, index: str, calls, slices, inv_slices, opt: ExecOptions,
+        why: Optional[dict] = None,
     ) -> Optional[dict[int, int]]:
         """Run all Count(<op>(Bitmap, Bitmap, ...)) calls in a request as
         fused device dispatches (one per distinct op/arity group).
@@ -1853,6 +1900,21 @@ class Executor:
         (executor.go:261-276's uniform any-depth evaluation, fused).
         Distributed requests forward ONE batch per remote node and fuse
         locally per node.
+
+        A flat Count whose Bitmap leaves name TWO frames of one view (the
+        Star Trace index's ``Intersect(Bitmap(frame="stargazer", ...),
+        Bitmap(frame="language", ...))``) takes the CROSS-FRAME lane: its
+        entry is (the operands' frames, view, op, rows), each frame's rows
+        come from that frame's own pool and one kernel folds operands of
+        both (``_xframe_local_counts``); Intersect, Union and Difference.
+        That branch is reached only where a call's leaves differ in frame
+        or view.  A nested tree over two frames, three frames, or Xor
+        across frames stays sequential.  A request holding a cross-frame
+        call is served through the fused queue (``_apply_queued_fused``:
+        concurrent requests join one pass, and passes run one at a time).
+        ``why``, where given, is filled with the reason each declined
+        Count call was declined for (``frames``, ``view``, ``depth``,
+        ``shape``).
         """
         if not slices:
             return None
@@ -1862,6 +1924,7 @@ class Executor:
         # trees / multi-operand Xor (the fused tree lane).
         matched: dict[int, tuple] = {}
         batch_view: Optional[str] = None
+        caps: dict = {}  # frame -> its pool's row capacity, for the cross-frame lane
         for i, c in enumerate(calls):
             if c.name != "Count" or len(c.children) != 1:
                 continue
@@ -1877,11 +1940,15 @@ class Executor:
                 if batch_view is None:
                     batch_view = view
                 elif view != batch_view:
+                    if why is not None:
+                        why[i] = "view"
                     return None
                 matched[i] = (frame, view, "and", (row_id, row_id))
                 continue
             op = self._FUSABLE_OPS.get(ch.name)
             if op is None or len(ch.children) < 2:
+                if why is not None:
+                    why[i] = "shape"
                 continue
             entry = None
             if op != "xor" or len(ch.children) == 2:
@@ -1897,24 +1964,32 @@ class Executor:
                     except PilosaError:
                         return None  # surface the error through the normal path
                     leaves.append((frame, view, row_id))
-                if len(leaves) == len(ch.children) and all(
-                    l[:2] == leaves[0][:2] for l in leaves[1:]
-                ):
-                    entry = (
-                        leaves[0][0],
-                        leaves[0][1],
-                        op,
-                        tuple(l[2] for l in leaves),
-                    )
+                if len(leaves) == len(ch.children):
+                    if all(l[:2] == leaves[0][:2] for l in leaves[1:]):
+                        entry = (
+                            leaves[0][0],
+                            leaves[0][1],
+                            op,
+                            tuple(l[2] for l in leaves),
+                        )
+                    else:
+                        entry = self._xframe_entry(index, op, leaves, slices, caps)
+                        if isinstance(entry, str):
+                            if why is not None:
+                                why[i] = entry
+                            continue
             if entry is None:
                 # Nested / multi-Xor shapes: the tree lane (one dispatch
                 # per depth bucket — executor.go:261-276's any-depth
                 # uniformity, fused).
+                tree_why: list = []
                 try:
-                    entry = self._compile_count_tree(index, ch)
+                    entry = self._compile_count_tree(index, ch, tree_why)
                 except PilosaError:
                     return None  # surface the error through the normal path
                 if entry is None:
+                    if why is not None:
+                        why[i] = tree_why[0]
                     continue
             # Uniform view across the batch: the slice domain (standard vs
             # inverse axis) is per-mapReduce, so mixed-view requests take
@@ -1922,6 +1997,8 @@ class Executor:
             if batch_view is None:
                 batch_view = entry[1]
             elif entry[1] != batch_view:
+                if why is not None:
+                    why[i] = "view"
                 return None
             matched[i] = entry
         # Fuse only when the WHOLE request is fusable reads: a write call
@@ -1937,14 +2014,80 @@ class Executor:
             return None
 
         idxs = sorted(matched)
-        totals = self._fused_dispatch(
-            index, idxs, slices, opt,
-            lambda: pql.Query(calls=[calls[i] for i in idxs]),
-            lambda node_slices: self._fused_local_counts(
-                index, matched, idxs, node_slices, span=opt.span
-            ),
-        )
+        if (
+            self._fused_queue is not None
+            and not self._is_distributed(opt)
+            and any(isinstance(matched[i][0], tuple) for i in idxs)
+        ):
+            totals = self._fused_queue.submit(
+                (index, matched, idxs, tuple(slices), opt.span), span=opt.span
+            )
+        else:
+            totals = self._fused_dispatch(
+                index, idxs, slices, opt,
+                lambda: pql.Query(calls=[calls[i] for i in idxs]),
+                lambda node_slices: self._fused_local_counts(
+                    index, matched, idxs, node_slices, span=opt.span
+                ),
+            )
         return dict(zip(idxs, totals))
+
+    def _xframe_entry(self, index: str, op: str, leaves: list, slices, caps: dict):
+        """The cross-frame lane's entry for one flat Count whose Bitmap
+        leaves ``(frame, view, row)`` differ in frame or view: (the
+        operands' frames, view, op, rows); or, where the lane declines
+        it, the reason: ``view`` (two views), ``frames`` (more than two
+        frames), ``shape`` (Xor, whose fold has no pad; more slices than
+        one dispatch counts in int32; more of one frame's rows than its
+        pool holds).  ``caps`` memoizes each frame's pool capacity for
+        the request."""
+        view = leaves[0][1]
+        if any(l[1] != view for l in leaves):
+            return "view"
+        frames = tuple(l[0] for l in leaves)
+        if len(set(frames)) > 2:
+            return "frames"
+        if op == "xor" or len(slices) > _INT32_SAFE_SLICES:
+            return "shape"
+        for f in sorted(set(frames)):
+            cap = caps.get(f)
+            if cap is None:
+                cap = caps[f] = self._peek_pool_cap(index, f, view, slices)
+            if len({l[2] for l in leaves if l[0] == f}) > cap:
+                return "shape"
+        return frames, view, op, tuple(l[2] for l in leaves)
+
+    def _apply_queued_fused(self, items) -> list:
+        """Evaluate one drained batch of fused count requests that hold a
+        cross-frame call: requests over the same (index, slices) join ONE
+        ``_fused_local_counts`` pass - its cross-frame groups one dispatch
+        each for all of them - then split back per request.  Passes run
+        one at a time, so no reader's hold on a pool is out when the next
+        pass misses (the miss writes the pool where it lies).  The pass's
+        spans go to the group's first sampled request (tag ``coalesced``:
+        requests answered by the pass), as ``_apply_queued_reads`` does."""
+        results: list = [None] * len(items)
+        groups: dict[tuple, list[int]] = {}
+        for k, (index, _matched, _idxs, slices, _span) in enumerate(items):
+            groups.setdefault((index, slices), []).append(k)
+        for (index, slices), ks in groups.items():
+            merged: dict[int, tuple] = {}
+            for k in ks:
+                matched, idxs = items[k][1], items[k][2]
+                for i in idxs:
+                    merged[len(merged)] = matched[i]
+            span = next((items[k][4] for k in ks if items[k][4] is not None), None)
+            if span is not None:
+                span.tags["coalesced"] = len(ks)
+            counts = self._fused_local_counts(
+                index, merged, list(range(len(merged))), list(slices), span=span
+            )
+            off = 0
+            for k in ks:
+                n = len(items[k][2])
+                results[k] = counts[off : off + n]
+                off += n
+        return results
 
     def _fuse_count_range_batch(
         self, index: str, calls, slices, opt: ExecOptions
@@ -2261,6 +2404,8 @@ class Executor:
         and/or, the second for andnot) so jitted shapes stay stable.
         Batches whose unique row set exceeds the pool capacity are chunked
         (rows page through HBM per chunk) instead of falling back to host.
+        Cross-frame entries (a tuple of frames first) go to
+        ``_xframe_local_counts`` first.
         """
         slices = list(slices or [])
         out: dict[int, int] = {}
@@ -2269,8 +2414,14 @@ class Executor:
         static = getattr(self.engine, "wants_static_shapes", False)
         # One row pool per (frame, view): unique row ids -> device slots.
         by_fv: dict[tuple[str, str], list[int]] = {}
+        xframe: list[int] = []
         for i in idxs:
-            by_fv.setdefault(tuple(matched[i][:2]), []).append(i)
+            if isinstance(matched[i][0], tuple):
+                xframe.append(i)
+            else:
+                by_fv.setdefault(tuple(matched[i][:2]), []).append(i)
+        if xframe:
+            self._xframe_local_counts(index, matched, xframe, slices, static, out, span)
         for (frame, view), f_idxs in by_fv.items():
             pool = self._pool_for(index, frame, view, slices)
             # Row-chunk bound: the pool's budgeted capacity, but never so
@@ -2383,6 +2534,122 @@ class Executor:
                         for k2, i in enumerate(op_idxs):
                             out[i] = int(total[k2])
         return [out[i] for i in idxs]
+
+    def _xframe_local_counts(
+        self, index: str, matched: dict, idxs: list[int], slices: list, static: bool,
+        out: dict, span=None,
+    ) -> None:
+        """Cross-frame counts for a slice batch, into ``out``: each call's
+        operands are rows of two frames of one view.  Each frame's rows
+        come from that frame's own pool (``_pool_for``; no row is copied
+        into another frame's pool), both held at once for a part of the
+        batch whose unique rows of every frame fit that frame's pool
+        (``chunk_queries_by``).  Calls group by (op, first frame, second
+        frame, operand buckets of each): one ``gather_count_xframe_dev``
+        dispatch a batch of at most ``_XFRAME_BATCH`` calls of a group,
+        every dispatch out before the first fetch."""
+        view = matched[idxs[0]][1]
+        frames = sorted({f for i in idxs for f in matched[i][0]})
+        pools = {f: self._pool_for(index, f, view, slices) for f in frames}
+        parts = chunk_queries_by(
+            idxs, lambda i: zip(matched[i][0], matched[i][3]),
+            {f: p.cap_max for f, p in pools.items()},
+        )
+        for part in parts:
+            want: dict[str, set] = {f: set() for f in frames}
+            groups: dict[tuple, list[int]] = {}
+            for i in part:
+                fs, _view, op, rows = matched[i]
+                for f, r in zip(fs, rows):
+                    want[f].add(r)
+                # andnot keeps the call's first operand first; and / or
+                # fold in any order, so their groups share a frame order.
+                fa = fs[0] if op == "andnot" else min(fs)
+                fb = next(f for f in fs if f != fa)
+                ka = fs.count(fa)
+                kb = len(fs) - ka
+                if static:
+                    ka, kb = 1 << (ka - 1).bit_length(), 1 << (kb - 1).bit_length()
+                groups.setdefault((op, fa, fb, ka, kb), []).append(i)
+            want = {f: rows for f, rows in want.items() if rows}
+            with self._xframe_matrices(index, view, slices, pools, want, span) as held:
+                pending = []
+                for gk, op_idxs in sorted(groups.items()):
+                    for at in range(0, len(op_idxs), _XFRAME_BATCH):
+                        batch = op_idxs[at : at + _XFRAME_BATCH]
+                        pending.append((batch, held[gk[1]][1], self._xframe_dispatch(
+                            gk, batch, matched, held, span)))
+                for op_idxs, matrix, counts in pending:
+                    counts = self._fetch_counts(counts, matrix, span)
+                    for k, i in enumerate(op_idxs):
+                        out[i] = int(counts[k])
+
+    @contextmanager
+    def _xframe_matrices(self, index: str, view: str, slices, pools: dict, want: dict, span=None):
+        """Every frame's pool holding its ``want`` rows, all at once, as
+        ``{frame: (id_pos, matrix, box)}`` for the ``with`` block: taken in
+        frame-name order (``_frame_matrix`` each), every hold given back
+        when the block ends, however it ends.  Under a sampled request the
+        holds are one ``xframe.acquire`` span (the pools' own spans inside),
+        tagged ``frames``, ``rows`` and ``misses`` (rows paged in) by frame."""
+        sp = span.child("xframe.acquire") if span is not None else None
+        misses = {f: pools[f].stat_misses for f in want}
+        with ExitStack() as stack:
+            held = {
+                f: stack.enter_context(
+                    self._frame_matrix(index, f, slices, want[f], view, span=sp)
+                )
+                for f in sorted(want)
+            }
+            if sp is not None:
+                sp.finish().annotate(
+                    frames=len(want), rows={f: len(want[f]) for f in sorted(want)},
+                    misses={f: pools[f].stat_misses - misses[f] for f in sorted(want)},
+                )
+            yield held
+
+    def _xframe_dispatch(self, gk, op_idxs, matched, held, span=None):
+        """One cross-frame batch's dispatch, un-fetched: ids of the first
+        frame's operands into its pool and of the second's into its own,
+        padded as the multi fold pads (and / or: the first of each; andnot:
+        a non-first operand of the first frame, the first of the second),
+        the batch to the engine's ``gather_bucket``.  Metered as
+        lane ``xgather``; the traced request's ``device`` span carries
+        ``frames`` (2), ``calls``, ``unique_rows`` (distinct (frame, row)
+        operands), ``bucket`` and ``devices``; counters
+        ``gather.xframe_dispatches`` / ``gather.xframe_calls``."""
+        op, fa, fb, ka, kb = gk
+        n = len(op_idxs)
+        bb = self.engine.gather_bucket(n)
+        pos_a, mat_a = held[fa][0], held[fa][1]
+        pos_b, mat_b = held[fb][0], held[fb][1]
+        idx_a = np.zeros((bb, ka), dtype=np.int32)
+        idx_b = np.zeros((bb, kb), dtype=np.int32)
+        for r, i in enumerate(op_idxs):
+            fs, _view, _op, rows = matched[i]
+            a = [pos_a[x] for f, x in zip(fs, rows) if f == fa]
+            b = [pos_b[x] for f, x in zip(fs, rows) if f == fb]
+            idx_a[r, : len(a)] = a
+            idx_a[r, len(a):] = a[1] if op == "andnot" and len(a) > 1 else a[0]
+            idx_b[r, : len(b)] = b
+            idx_b[r, len(b):] = b[0]
+        idx_a[n:] = idx_a[0]
+        idx_b[n:] = idx_b[0]
+
+        def dispatch():
+            return self.engine.gather_count_xframe_dev(op, mat_a, idx_a, mat_b, idx_b)
+
+        if self.meter is None:
+            return dispatch()
+        with self.meter.measure("xgather", span) as d:
+            self.meter.stats.count("gather.xframe_dispatches")
+            self.meter.stats.count("gather.xframe_calls", n)
+            d.tag(
+                frames=2, calls=n, bucket=bb,
+                unique_rows=len({fr for i in op_idxs for fr in zip(matched[i][0], matched[i][3])}),
+                devices=self.engine.slice_axis_devices(mat_a.shape[0]),
+            )
+            return dispatch()
 
     def _fetch_counts(self, counts, matrix, span=None) -> np.ndarray:
         """One dispatch's counts on the host, int64: the wait for them,

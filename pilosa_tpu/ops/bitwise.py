@@ -157,7 +157,22 @@ def gather_count_multi(op: str, row_matrix, idx):
     """
     if row_matrix.ndim == 4:  # tiled engine form: flatten the word axis
         row_matrix = row_matrix.reshape(*row_matrix.shape[:2], -1)
-    g = jnp.take(row_matrix, idx, axis=1)  # [n_slices, B, K, W]
+    return _fold_count(op, jnp.take(row_matrix, idx, axis=1))
+
+
+def gather_count_xframe(op: str, rm_a, idx_a, rm_b, idx_b):
+    """:func:`gather_count_multi` over operands of two row matrices (two
+    frames' pools: the same slices and words, any rows each): per query the
+    left fold of ``rm_a``'s rows ``idx_a[q]`` then ``rm_b``'s ``idx_b[q]``.
+    XLA form; the Pallas version is ``fused_gather_count_xframe``."""
+    flat = [m.reshape(*m.shape[:2], -1) if m.ndim == 4 else m for m in (rm_a, rm_b)]
+    return _fold_count(op, jnp.concatenate(
+        [jnp.take(flat[0], idx_a, axis=1), jnp.take(flat[1], idx_b, axis=1)], axis=2))
+
+
+def _fold_count(op: str, g):
+    """Per-query popcount of the fold over g's operand axis: [S, B, K, W]
+    -> int32[B]."""
     if op == "or":
         acc = lax.reduce(g, np.uint32(0), lax.bitwise_or, (2,))
     elif op == "and":
@@ -260,6 +275,17 @@ def np_gather_count_multi(op: str, row_matrix: np.ndarray, idx: np.ndarray) -> n
     else:
         raise ValueError(f"unsupported multi-op {op!r}")
     return np_popcount(acc).reshape(acc.shape[0], acc.shape[1], -1).sum(axis=(0, 2))
+
+
+def np_gather_count_xframe(
+    op: str, rm_a: np.ndarray, idx_a: np.ndarray, rm_b: np.ndarray, idx_b: np.ndarray
+) -> np.ndarray:
+    """numpy ground truth for gather_count_xframe: the multi fold over the
+    two matrices stacked along the row axis, ``idx_b`` shifted past
+    ``rm_a``'s rows."""
+    stacked = np.concatenate([rm_a, rm_b], axis=1)
+    return np_gather_count_multi(
+        op, stacked, np.concatenate([idx_a, idx_b + rm_a.shape[1]], axis=1))
 
 
 def np_gather_count_or_multi(row_matrix: np.ndarray, idx: np.ndarray) -> np.ndarray:

@@ -4,8 +4,9 @@ The reference's single most important test idiom is asm-vs-Go
 equivalence over randomized inputs (roaring/assembly_test.go:45-141).
 This module is that idiom generalized to the full lane surface of this
 build: for each strategy lane (fused count, resident, slice-major
-gather, row-major gather, multi-fold both layouts, TopN scorer, Gram
-one-shot/scan/word-chunked, dispatch 3D/4D parity) it generates N
+gather, row-major gather, multi-fold both layouts, the cross-frame fold
+over two matrices, TopN scorer, Gram one-shot/scan/word-chunked, dispatch
+3D/4D parity) it generates N
 random (shape, op, density, layout) cases and requires EXACT agreement
 with a pure-numpy ground truth.
 
@@ -31,6 +32,7 @@ SHAPES = [  # (n_slices, n_rows, words)
 ]
 B = 16  # queries per case
 KS = (2, 4)  # multi-fold operand buckets
+XKS = ((1, 1), (2, 1), (2, 2))  # cross-frame fold: operands of the first matrix, of the second
 PAIR_OPS = ("and", "or", "xor", "andnot")
 MULTI_OPS = ("and", "or", "andnot")
 
@@ -98,6 +100,12 @@ def np_multi_counts(op: str, rm: np.ndarray, idx: np.ndarray) -> list[int]:
     return [int(v) for v in np_gather_count_multi(op, rm, idx)]
 
 
+def np_xframe_counts(op: str, rm_a, idx_a, rm_b, idx_b) -> list[int]:
+    from pilosa_tpu.ops.bitwise import np_gather_count_xframe
+
+    return [int(v) for v in np_gather_count_xframe(op, rm_a, idx_a, rm_b, idx_b)]
+
+
 def np_topn_counts(rm: np.ndarray, src: np.ndarray) -> list[int]:
     return [
         int(_np_pop(rm[:, ri, :] & src).sum()) for ri in range(rm.shape[1])
@@ -129,6 +137,9 @@ def run_lanes(seed: int, cases_per_lane: int, interpret: bool) -> list[str]:
 
     failures: list[str] = []
     rng = np.random.default_rng(seed)
+    # The cross-frame cases draw from a stream of their own: the other
+    # lanes' cases stay what they were.
+    xrng = np.random.default_rng([seed, 0x7F])
 
     def check(lane: str, case_i: int, got, want) -> None:
         got = np.asarray(got).astype(np.int64).reshape(-1).tolist()
@@ -194,6 +205,22 @@ def run_lanes(seed: int, cases_per_lane: int, interpret: bool) -> list[str]:
         check(f"rmmulti:{mop}:k{k}", ci,
               pk.fused_gather_count_multi_rowmajor(mop, rmt4, ji, interpret=interpret),
               want_multi)
+        # cross-frame fold: a second matrix of other rows over the same
+        # slices, operands of both, padded batch (repeats of the first query)
+        rb = 8 + 8 * int(xrng.integers(0, 3))
+        rm_b = _random_words(xrng, (s, rb, w), int(xrng.choice(_DENSITIES)))
+        ka, kb = XKS[int(xrng.integers(len(XKS)))]
+        ia = xrng.integers(0, r, size=(B, ka), dtype=np.int32)
+        ib = xrng.integers(0, rb, size=(B, kb), dtype=np.int32)
+        ia[B // 2:], ib[B // 2:] = ia[0], ib[0]
+        want_x = np_xframe_counts(mop, rm, ia, rm_b, ib)
+        rm_b4 = jnp.asarray(rm_b.reshape(s, rb, w // 128, 128))
+        check(f"xframe:{mop}:k{ka}{kb}", ci,
+              pk.fused_gather_count_xframe(mop, rm4, jnp.asarray(ia), rm_b4, jnp.asarray(ib),
+                                           interpret=interpret), want_x)
+        check(f"dispatch_xframe:{mop}", ci,
+              dispatch.gather_count_xframe(mop, rm4, jnp.asarray(ia), rm_b4, jnp.asarray(ib)),
+              want_x)
         # TopN candidate scorer
         check("topn", ci,
               pk.fused_topn_counts(rm4, jnp.asarray(src), interpret=interpret),
@@ -243,4 +270,6 @@ def lane_names() -> set[str]:
         for k in KS:
             lanes |= {f"multi:{mop}:k{k}", f"rmmulti:{mop}:k{k}"}
         lanes.add(f"dispatch_multi:{mop}")
+        lanes |= {f"xframe:{mop}:k{ka}{kb}" for ka, kb in XKS}
+        lanes.add(f"dispatch_xframe:{mop}")
     return lanes

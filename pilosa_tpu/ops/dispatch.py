@@ -24,6 +24,7 @@ from pilosa_tpu.ops.pallas_kernels import (
     fused_count2,
     fused_gather_count2,
     fused_gather_count_multi,
+    fused_gather_count_xframe,
     fused_resident_count2,
     resident_strategy,
     rm_words,
@@ -264,6 +265,32 @@ def gather_count_multi(op, row_matrix, idx):
             ]
         )
     return bitwise.gather_count_multi(op, rm, idx)
+
+
+def gather_count_xframe(op, rm_a, idx_a, rm_b, idx_b):
+    """Batched Count over a left-fold of operands gathered from two row
+    matrices (two frames' pools, the same slices): ``idx_a`` int32[B, Ka]
+    into ``rm_a``, then ``idx_b`` int32[B, Kb] into ``rm_b``, padded as
+    :func:`gather_count_multi` pads.  Batches are cut as that one cuts them:
+    the prefetched ids of both tables share its SMEM budget."""
+    b = idx_a.shape[0]
+    k = idx_a.shape[1] + idx_b.shape[1]
+    if use_pallas() and _tileable(rm_words(rm_a)):
+        chunk = max(1, (2 * _GATHER_BATCH_MAX) // k)
+        if b > chunk:
+            return jnp.concatenate([
+                fused_gather_count_xframe(op, rm_a, idx_a[i : i + chunk], rm_b, idx_b[i : i + chunk])
+                for i in range(0, b, chunk)
+            ])
+        return fused_gather_count_xframe(op, rm_a, idx_a, rm_b, idx_b)
+    from pilosa_tpu.pilosa import OR_MULTI_BUDGET_DEVICE, or_multi_chunk_size
+
+    s, _, w = _rm_dims(rm_a)
+    chunk = or_multi_chunk_size(s, k, w, OR_MULTI_BUDGET_DEVICE)
+    return jnp.concatenate([
+        bitwise.gather_count_xframe(op, rm_a, idx_a[i : i + chunk], rm_b, idx_b[i : i + chunk])
+        for i in range(0, b, chunk)
+    ]) if b > chunk else bitwise.gather_count_xframe(op, rm_a, idx_a, rm_b, idx_b)
 
 
 def gather_count_or_multi(row_matrix, idx):

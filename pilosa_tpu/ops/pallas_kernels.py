@@ -532,17 +532,31 @@ _FOLD_OPS = {
 }
 
 
-def _gather_multi_kernel(op, n_ops, idx_ref, row_ref, out_ref, acc_ref):
+def _gather_multi_kernel(op, n_ops, n_first, *refs):
+    """One grid step (query q, slice s, operand j) of the left fold: operands
+    ``j < n_first`` come from the first row matrix, the rest from the
+    second (two matrices: ``refs`` = both id tables, both row blocks, the
+    output tile, the accumulator; one: the id table, the row block, ...)."""
     s, j = pl.program_id(1), pl.program_id(2)
     fold = _FOLD_OPS[op]
+    if n_first == n_ops:
+        _, a_ref, out_ref, acc_ref = refs
+        b_ref = None
+    else:
+        _, _, a_ref, b_ref, out_ref, acc_ref = refs
 
     @pl.when(j == 0)
     def _():
-        acc_ref[...] = row_ref[0, 0]
+        acc_ref[...] = a_ref[0, 0]
 
-    @pl.when(j != 0)
+    @pl.when((j != 0) if b_ref is None else (j != 0) & (j < n_first))
     def _():
-        acc_ref[...] = fold(acc_ref[...], row_ref[0, 0])
+        acc_ref[...] = fold(acc_ref[...], a_ref[0, 0])
+
+    if b_ref is not None:
+        @pl.when(j >= n_first)
+        def _():
+            acc_ref[...] = fold(acc_ref[...], b_ref[0, 0])
 
     @pl.when((j == n_ops - 1) & (s == 0))
     def _():
@@ -551,6 +565,45 @@ def _gather_multi_kernel(op, n_ops, idx_ref, row_ref, out_ref, acc_ref):
     @pl.when((j == n_ops - 1) & (s != 0))
     def _():
         out_ref[0] = out_ref[0] + _partial_tile(acc_ref[...][None])
+
+
+def _gather_fold(op: str, mats, idxs, interpret: bool):
+    """The multi-gather fold over one row matrix or two (same slices and
+    words, any rows each): ``idxs[m]`` int32[B, K_m] ids into ``mats[m]``,
+    folded left to right, the first matrix's operands first.  Grid
+    (query, slice, operand): each step fetches the one plane it folds - the
+    other matrix's block index holds still (clamped to its first or last
+    operand), so it is not fetched again - and one plane per (query,
+    slice, operand) is all the kernel reads."""
+    rm4s = [_rm4(m) for m in mats]
+    n_slices, _, sub = rm4s[0].shape[:3]
+    b = idxs[0].shape[0]
+    n_first = idxs[0].shape[1]
+    n_ops = sum(i.shape[1] for i in idxs)
+    block = (1, 1, sub, _LANES)
+    if len(mats) == 1:
+        in_specs = [pl.BlockSpec(block, lambda q, s, j, pr: (s, pr[q, j], 0, 0))]
+        out_map = lambda q, s, j, pr: (q, 0, 0)  # noqa: E731
+    else:
+        in_specs = [
+            pl.BlockSpec(block, lambda q, s, j, ia, ib: (s, ia[q, jnp.minimum(j, n_first - 1)], 0, 0)),
+            pl.BlockSpec(block, lambda q, s, j, ia, ib: (s, ib[q, jnp.maximum(j - n_first, 0)], 0, 0)),
+        ]
+        out_map = lambda q, s, j, ia, ib: (q, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(mats),
+        grid=(b, n_slices, n_ops),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 8, _LANES), out_map),
+        scratch_shapes=[pltpu.VMEM((sub, _LANES), jnp.uint32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_gather_multi_kernel, op, n_ops, n_first),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 8, _LANES), jnp.int32),
+        interpret=interpret,
+    )(*idxs, *rm4s)
+    return out.sum(axis=(1, 2))
 
 
 @functools.partial(jax.jit, static_argnames=("op", "interpret"))
@@ -571,25 +624,26 @@ def fused_gather_count_multi(op: str, row_matrix, idx, interpret: bool = False):
     across the slice axis.  The XLA fallback materializes the whole
     [S, B, K, W] gather in HBM first.
     """
-    rm4 = _rm4(row_matrix)
-    n_slices, n_rows, sub = rm4.shape[:3]
-    b, n_ops = idx.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, n_slices, n_ops),
-        in_specs=[
-            pl.BlockSpec((1, 1, sub, _LANES), lambda q, s, j, pr: (s, pr[q, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 8, _LANES), lambda q, s, j, pr: (q, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((sub, _LANES), jnp.uint32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(_gather_multi_kernel, op, n_ops),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 8, _LANES), jnp.int32),
-        interpret=interpret,
-    )(idx, rm4)
-    return out.sum(axis=(1, 2))
+    return _gather_fold(op, (row_matrix,), (idx,), interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("op", "interpret"))
+@jax.named_scope("gather.xcount")  # the cross-frame fold's name in a device trace
+def fused_gather_count_xframe(op: str, rm_a, idx_a, rm_b, idx_b, interpret: bool = False):
+    """``fused_gather_count_multi`` over operands of TWO row matrices -
+    Count over an N-operand Intersect/Union/Difference whose Bitmaps name
+    two frames, each frame's rows in its own pool: per query
+    ``sum_s popcount(fold(rm_a[s, idx_a[q]], rm_b[s, idx_b[q]]))``, the
+    first matrix's operands folded first (andnot: its first operand is the
+    call's first).
+
+    rm_a, rm_b: uint32[n_slices, R_a / R_b, W] (or tiled 4D), the same
+    slices and words; idx_a: int32[B, Ka], idx_b: int32[B, Kb], padded as
+    the multi fold pads (and/or: any operand of the same matrix; andnot:
+    any non-first operand).  Returns int32[B].  The same kernel as the
+    multi fold, with a second input: each grid step fetches only the plane
+    it folds."""
+    return _gather_fold(op, (rm_a, rm_b), (idx_a, idx_b), interpret)
 
 
 def fused_gather_count_or(row_matrix, idx, interpret: bool = False):

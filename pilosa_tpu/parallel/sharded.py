@@ -282,6 +282,57 @@ def sharded_gather_count_multi(
 
 
 @functools.lru_cache(maxsize=None)
+def _sharded_xframe_kernel(mesh_obj, axis: str, op: str, interpret: bool, rm_ndim: int = 3):
+    import jax
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from pilosa_tpu.ops.pallas_kernels import fused_gather_count_xframe
+
+    rm_spec = P(axis, *([None] * (rm_ndim - 1)))
+
+    @functools.partial(
+        jax.shard_map,
+        mesh=mesh_obj,
+        in_specs=(rm_spec, P(None, None), rm_spec, P(None, None)),
+        out_specs=P(),
+        check_vma=False,
+    )
+    def kernel(a_shard, ids_a, b_shard, ids_b):
+        local = fused_gather_count_xframe(op, a_shard, ids_a, b_shard, ids_b, interpret=interpret)
+        return lax.psum(local, axis)
+
+    return jax.jit(kernel)
+
+
+def sharded_gather_count_xframe(
+    mesh: SliceMesh, op: str, rm_a, idx_a, rm_b, idx_b, interpret: bool = False
+):
+    """Cross-frame fold counts: two frames' row matrices, both split along
+    the slice axis alike (every frame of a slice lives on the device that
+    owns the slice), the Pallas cross-frame fold on each shard, one psum.
+    Chunks the batch so both id tables together stay inside the SMEM
+    budget, as ``sharded_gather_count_multi`` does."""
+    import jax.numpy as jnp
+
+    n_slices = rm_a.shape[0]
+    _require_divisible(n_slices, mesh.n_devices)
+    b = idx_a.shape[0]
+    chunk = max(1, (2 * _SHARDED_BATCH_MAX) // (idx_a.shape[1] + idx_b.shape[1]))
+    if b > chunk:
+        return jnp.concatenate(
+            [
+                sharded_gather_count_xframe(
+                    mesh, op, rm_a, idx_a[i : i + chunk], rm_b, idx_b[i : i + chunk], interpret
+                )
+                for i in range(0, b, chunk)
+            ]
+        )
+    kernel = _sharded_xframe_kernel(mesh.mesh, mesh.AXIS, op, interpret, rm_a.ndim)
+    return kernel(rm_a, idx_a, rm_b, idx_b)
+
+
+@functools.lru_cache(maxsize=None)
 def _sharded_tree_kernel(mesh_obj, axis: str, interpret: bool, rm_ndim: int = 3):
     import jax
     from jax import lax

@@ -705,33 +705,53 @@ class DeviceRowPool:
 def chunk_queries(
     queries: Sequence, rows_of: Callable, cap: int, oversize_ok: bool = False
 ) -> list[list]:
-    """Partition a query batch so each chunk's UNIQUE row set fits ``cap``.
+    """Partition a query batch so each chunk's UNIQUE row set fits ``cap``:
+    ``chunk_queries_by`` with one pool."""
+    return chunk_queries_by(
+        queries, lambda q: [(None, r) for r in rows_of(q)], {None: cap}, oversize_ok
+    )
+
+
+def chunk_queries_by(
+    queries: Sequence, keys_of: Callable, caps: dict, oversize_ok: bool = False
+) -> list[list]:
+    """Partition a query batch whose rows come from one pool or several so
+    each chunk's unique rows of EVERY pool fit that pool's cap:
+    ``keys_of(q)`` gives the query's (pool key, row) operands, ``caps[key]``
+    the pool's row capacity.
 
     Greedy in arrival order (preserves per-chunk dispatch order).  A
-    single query whose own rows exceed cap has no valid chunking: with
+    single query whose own rows exceed a cap has no valid chunking: with
     ``oversize_ok`` it becomes its own chunk (the caller's slice-streaming
     branch handles any row count); otherwise it raises.
     """
     chunks: list[list] = []
     cur: list = []
-    cur_rows: set = set()
+    cur_rows: dict = {}
     for q in queries:
-        rows = set(rows_of(q))
-        if len(rows) > cap:
+        rows: dict = {}
+        for key, row in keys_of(q):
+            rows.setdefault(key, set()).add(row)
+        over = next((k for k, rs in rows.items() if len(rs) > caps[k]), _NO_KEY)
+        if over is not _NO_KEY:
             if not oversize_ok:
                 raise ValueError(
-                    f"single query references {len(rows)} rows > capacity {cap}"
+                    f"single query references {len(rows[over])} rows > capacity {caps[over]}"
                 )
             if cur:
                 chunks.append(cur)
-                cur, cur_rows = [], set()
+                cur, cur_rows = [], {}
             chunks.append([q])
             continue
-        if cur and len(cur_rows | rows) > cap:
+        if cur and any(len(cur_rows.get(k, set()) | rs) > caps[k] for k, rs in rows.items()):
             chunks.append(cur)
-            cur, cur_rows = [], set()
+            cur, cur_rows = [], {}
         cur.append(q)
-        cur_rows |= rows
+        for k, rs in rows.items():
+            cur_rows.setdefault(k, set()).update(rs)
     if cur:
         chunks.append(cur)
     return chunks
+
+
+_NO_KEY = object()  # no pool's cap was exceeded

@@ -32,5 +32,8 @@ def test_lane_coverage_is_complete():
         for k in (2, 4):
             assert f"multi:{mop}:k{k}" in lanes
             assert f"rmmulti:{mop}:k{k}" in lanes
+        for ka, kb in ((1, 1), (2, 1), (2, 2)):
+            assert f"xframe:{mop}:k{ka}{kb}" in lanes
+        assert f"dispatch_xframe:{mop}" in lanes
     assert {"count1", "topn", "gram_oneshot", "gram_scan", "gram_chunked"} <= lanes
     # 2 seeds x 12 cases = 24 generated cases per lane family >= 20.

@@ -520,3 +520,27 @@ def test_seg256_gather_dispatch_stays_on_its_shards(pairs, op, slice_mesh):
     mem = compiled.memory_analysis()
     shard = 256 * 256 * W * 4 // 4
     assert mem.argument_size_in_bytes < shard + 2**20 and mem.temp_size_in_bytes < 2**26
+
+
+@pytest.mark.parametrize("ka", [1, 2])
+@pytest.mark.parametrize("calls", [16, 128])
+def test_startrace256_xframe_dispatch_stays_on_its_shards(calls, ka, slice_mesh):
+    """A cross-frame count on four chips (``MeshEngine.gather_count_xframe_dev``:
+    the Star Trace index's ``stargazer`` pool, 256 slots, and its ``language``
+    pool, 64, each 256 slices sharded alike): the fold of ``ka`` stargazer
+    rows and one language row on every device's own shards under
+    ``shard_map``, nothing gathered, one all-reduce of int32[calls], and the
+    kernel under the ``gather.xcount`` name its roofline share is read by."""
+    from pilosa_tpu.parallel import sharded
+
+    rm_a, ids_a = _mesh_args(slice_mesh, 256, 256, (calls, ka))
+    rm_b, ids_b = _mesh_args(slice_mesh, 256, 64, (calls, 1))
+    kernel = sharded._sharded_xframe_kernel(slice_mesh, "slice", "and", False, 4)
+    compiled = kernel.lower(rm_a, ids_a, rm_b, ids_b).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "gather.xcount" in text
+    assert "all-gather" not in text and "all-to-all" not in text
+    assert _all_reduced(text) == {f"s32[{calls}]"}
+    mem = compiled.memory_analysis()
+    shards = (256 + 64) * 256 * W * 4 // 4
+    assert mem.argument_size_in_bytes < shards + 2**20 and mem.temp_size_in_bytes < 2**26
