@@ -102,6 +102,36 @@ def _padded_words(cells, values) -> tuple[np.ndarray, np.ndarray]:
     return idx, vals
 
 
+def _word_shares(slots, cells, values, n_devices: int, per_device: int):
+    """A sparse chunk split by the device that holds each word's slice
+    (device ``d`` holds slices ``[d * per_device, (d + 1) * per_device)``)
+    and packed for one transfer a device: int32 ``[n_devices, 4 * B + k]``,
+    row ``d`` device ``d``'s words in the order they came as four columns
+    of ``B`` - slice less ``d * per_device``, slot, word, value's bits -
+    then the ``k`` slots.  ``B`` is the ``_pow4`` bucket of the largest
+    share; a column's tail is -1 (0 for the values), which the scatter
+    drops.  A word of slice -1 (a warm-up's) goes to the first device,
+    which drops it.  Returns ``(packed, largest share)``."""
+    k, n = len(slots), len(values)
+    dev = np.clip(cells[:, 0] // per_device, 0, n_devices - 1)
+    counts = np.bincount(dev, minlength=n_devices)
+    largest = int(counts.max()) if n else 0
+    bucket = _pow4(largest)
+    order = np.argsort(dev.astype(np.min_scalar_type(n_devices - 1)), kind="stable")
+    columns = [cells[order, 0], cells[order, 1], cells[order, 2],
+               np.asarray(values, dtype=np.uint32)[order].view(np.int32)]
+    packed = np.full((n_devices, 4 * bucket + k), -1, dtype=np.int32)
+    packed[:, 4 * bucket :] = slots
+    packed[:, 3 * bucket : 4 * bucket] = 0
+    at = 0
+    for d, c in enumerate(counts.tolist()):
+        for f, col in enumerate(columns):
+            packed[d, f * bucket : f * bucket + c] = col[at : at + c]
+        packed[d, : c] -= d * per_device
+        at += c
+    return packed, largest
+
+
 def _padded_cells(groups):
     """A repair's written cells as one scatter's arguments: ``cells`` (the
     (slice, slot) pairs in group order), ``idx`` int32[C, 2] and ``planes``
@@ -366,11 +396,12 @@ class NumpyEngine:
         """The sparse form of ``set_rows_at``: the rows in ``slots`` become
         zero but for ``values[c]`` at word ``cells[c, 2]`` of the (slice
         ``cells[c, 0]``, slot ``cells[c, 1]``) plane.  Unpadded, as this
-        engine's blocks are."""
+        engine's blocks are.  Returns ``(matrix, None)``: no device's share
+        of the words (``MeshEngine``'s) here."""
         out = matrix if donate else matrix.copy()
         out[:, list(slots), :] = 0
         out[cells[:, 0], cells[:, 1], cells[:, 2]] = values
-        return out
+        return out, None
 
     def warm_set_rows(
         self, matrix, max_rows: int, row_major: bool = False, max_words: int = 0
@@ -854,26 +885,28 @@ class JaxEngine:
     def set_words_at(self, matrix, slots, cells, values, donate: bool = False):
         """The sparse form of ``set_rows_at``: a miss's chunk crosses
         host->device as its words that are not zero - ``cells`` int32[N,
-        3] of (slice, slot, word) and ``values`` uint32[N], padded here to
-        their ``_pow4`` bucket, 16 bytes a word - and one program
-        (``ops.bitwise.set_row_words``, one a word bucket and form)
-        zeroes the rows of ``slots`` (a fixed count, -1 dropped) and
-        writes the words into them, HBM->HBM: into a copy of the pool,
-        or with ``donate`` into ``matrix`` itself, as ``set_rows_at``."""
-        return self._set_words(matrix, slots, cells, values, 1, donate)
+        3] of (slice, slot, word) and ``values`` uint32[N], padded to
+        their ``_pow4`` bucket, 16 bytes a word, to the one device that
+        holds the pool - and one program (``ops.bitwise.set_row_words``,
+        one a word bucket and form) zeroes the rows of ``slots`` (a fixed
+        count, -1 dropped) and writes the words into them, HBM->HBM: into
+        a copy of the pool, or with ``donate`` into ``matrix`` itself, as
+        ``set_rows_at``.  Returns ``(matrix, device_words)``: the largest
+        share of the words one device received where the engine split
+        them by device (``MeshEngine``), else None."""
+        return self._scatter_words(
+            matrix, np.asarray(slots, dtype=np.int32), cells, values, 1, donate
+        )
 
     def set_words_at_rm(self, matrix, slots, cells, values, donate: bool = False):
         """``set_words_at`` on a row-major pool ``[cap, S, ...]``."""
-        return self._set_words(matrix, slots, cells, values, 0, donate)
-
-    def _set_words(self, matrix, slots, cells, values, axis: int, donate: bool):
-        cells, values = _padded_words(cells, values)
-        self._note_upload(cells.nbytes + values.nbytes)
         return self._scatter_words(
-            matrix, np.asarray(slots, dtype=np.int32), cells, values, axis, donate
+            matrix, np.asarray(slots, dtype=np.int32), cells, values, 0, donate
         )
 
     def _scatter_words(self, matrix, slots, cells, values, axis: int, donate: bool):
+        cells, values = _padded_words(cells, values)
+        self._note_upload(cells.nbytes + values.nbytes)
         if not hasattr(self, "_set_words_jit"):
             import jax
 
@@ -883,7 +916,7 @@ class JaxEngine:
                 d: jax.jit(set_row_words, static_argnames="axis", donate_argnums=(0,) if d else ())
                 for d in (False, True)
             }
-        return self._set_words_jit[donate](matrix, slots, cells, values, axis=axis)
+        return self._set_words_jit[donate](matrix, slots, cells, values, axis=axis), None
 
     def warm_set_rows(
         self, matrix, max_rows: int, row_major: bool = False, max_words: int = 0
@@ -911,8 +944,8 @@ class JaxEngine:
         drop, n = np.full(max_rows, -1, dtype=np.int32), 1
         while n <= max_words:
             cells, values = np.full((n, 3), -1, dtype=np.int32), np.zeros(n, dtype=np.uint32)
-            copy = self._set_words(matrix, drop, cells, values, axis, False)
-            self._set_words(copy, drop, cells, values, axis, True).block_until_ready()
+            copy, _ = self._scatter_words(matrix, drop, cells, values, axis, False)
+            self._scatter_words(copy, drop, cells, values, axis, True)[0].block_until_ready()
             n *= 4
 
     def grow_rows(self, matrix, n: int):
@@ -1333,18 +1366,33 @@ class MeshEngine(JaxEngine):
         )
 
     def _scatter_words(self, matrix, slots, cells, values, axis: int, donate: bool):
+        """A sparse chunk on a pool whose slice axis the mesh splits: each
+        device is sent its own share of the words and no other - one
+        int32 array ``[devices, 4 * B + k]`` sharded on the slice axis,
+        a row a device holding its words as (local slice, slot, word,
+        value) columns padded to ``B``, the ``_pow4`` bucket of the
+        largest share, then the chunk's ``k`` slots (``_word_shares``) -
+        so a chunk crosses in one transfer a device, and each device
+        zeroes the slots in its shard and scatters its own words alone
+        (``parallel.sharded.sharded_set_row_words``).  ``upload_bytes``
+        counts that array once: every word, padding and slots.  A
+        row-major pool, or one the mesh does not split, takes the
+        parent's program whole and is pinned back to its sharding."""
         if axis != 1 or self.slice_axis_devices(matrix.shape[0]) == 1:
             sharding = matrix.sharding  # read before a donation takes the array
-            return self._jax.device_put(
-                super()._scatter_words(matrix, slots, cells, values, axis, donate), sharding
-            )
+            out, _ = super()._scatter_words(matrix, slots, cells, values, axis, donate)
+            return self._jax.device_put(out, sharding), None
         from pilosa_tpu.parallel.sharded import sharded_set_row_words
 
-        # Cells and values go to every device; each keeps the words of its
-        # own slices and drops the rest.
-        return sharded_set_row_words(
-            self.mesh, self._shard_stack(matrix), slots, cells, values, donate
+        packed, device_words = _word_shares(
+            slots, cells, values, self.mesh.n_devices, matrix.shape[0] // self.mesh.n_devices
         )
+        self._note_upload(packed.nbytes)
+        out = sharded_set_row_words(
+            self.mesh, self._shard_stack(matrix), self.mesh.shard_stack(packed), len(slots),
+            donate,
+        )
+        return out, device_words
 
     def grow_rows(self, matrix, n):
         # The zero rows are born with the matrix's sharding: made on the

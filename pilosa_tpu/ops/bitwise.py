@@ -443,7 +443,7 @@ def set_rows(row_matrix, slots, block, axis: int = 1):
         )
 
 
-def set_row_words(row_matrix, slots, cells, values, axis: int = 1, first_slice=0):
+def set_row_words(row_matrix, slots, cells, values, axis: int = 1):
     """A pool matrix with the rows of ``slots`` (int32[k], a negative one
     dropped) made of ``values`` alone: the sparse form of a pool miss's
     scatter.  Each slot's row is zeroed first (the row it held before
@@ -453,18 +453,26 @@ def set_row_words(row_matrix, slots, cells, values, axis: int = 1, first_slice=0
     so the scatter may take them in any order).  ``axis`` as in
     ``set_rows``: 1 for a slice-major pool ``[S, cap, ...]``, 0 for a
     row-major one ``[cap, S, ...]`` (the cell stays (slice, slot, word)).
-    A cell whose slice, less ``first_slice``, is not one of the matrix's
-    is dropped: a word bucket's (-1, -1, -1) tail, and on a mesh the
-    cells of the other devices' shards.  A new array, or the caller's own
-    where it is donated; jitted by the engines, one program a word
-    bucket.  Its ops carry ``pool.set_rows`` in a device trace."""
+    A cell whose slice is not one of the matrix's is dropped: a word
+    bucket's (-1, -1, -1) tail.  The one-chip engine sends it the whole
+    chunk; on a mesh each device runs it on its own shard with the words
+    of its own slices alone, their slices already local
+    (``parallel.sharded.sharded_set_row_words``).  A new array, or the
+    caller's own where it is donated; jitted by the engines, one program
+    a word bucket.  Its ops carry ``pool.set_rows`` in a device trace."""
+    return set_row_word_columns(
+        row_matrix, slots, cells[:, 0], cells[:, 1], cells[:, 2], values, axis
+    )
+
+
+def set_row_word_columns(row_matrix, slots, si, slot, w, values, axis: int = 1):
+    """``set_row_words`` with the cells as three int32[N] columns (slice,
+    slot, word): the form a mesh's shard is handed its words in."""
     with jax.named_scope("pool.set_rows"):
         m = _write_slots(row_matrix, slots, axis, lambda _, old: jnp.zeros_like(old))
         n = m.shape[1 - axis]
-        si = cells[:, 0] - first_slice
-        si = jnp.where((cells[:, 0] >= 0) & (si >= 0) & (si < n), si, n)  # past the end: dropped
-        lead = (si, cells[:, 1]) if axis == 1 else (cells[:, 1], si)
-        w = cells[:, 2]
+        si = jnp.where((si >= 0) & (si < n), si, n)  # past the end: dropped
+        lead = (si, slot) if axis == 1 else (slot, si)
         if m.ndim == 4:  # tiled: a plane is [W / lanes, lanes]
             lanes = m.shape[3]
             word = (w // lanes, w % lanes)

@@ -454,44 +454,57 @@ def sharded_set_rows(mesh: SliceMesh, row_matrix, slots, block, donate: bool = F
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_set_row_words_kernel(mesh_obj, axis: str, rm_ndim: int, donate: bool = False):
+def _sharded_set_row_words_kernel(
+    mesh_obj, axis: str, rm_ndim: int, n_slots: int, donate: bool = False
+):
     import jax
+    import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from pilosa_tpu.ops.bitwise import set_row_words
+    from pilosa_tpu.ops.bitwise import set_row_word_columns
 
     rest = [None] * (rm_ndim - 1)
 
     @functools.partial(
         jax.shard_map,
         mesh=mesh_obj,
-        in_specs=(P(axis, *rest), P(None), P(None, None), P(None)),
+        in_specs=(P(axis, *rest), P(axis, None)),
         out_specs=P(axis, *rest),
         check_vma=False,
     )
-    def set_row_words_shards(rm_shard, slots, cells, values):
-        return set_row_words(
-            rm_shard, slots, cells, values,
-            first_slice=lax.axis_index(axis) * rm_shard.shape[0],
+    def set_row_words_shards(rm_shard, share):
+        # This device's row of the packed chunk: its own words' local
+        # slices, slots, words and values' bits, B each, then the slots.
+        share = share[0]
+        b = (share.shape[0] - n_slots) // 4
+        si, slot, w, bits = (share[i * b : (i + 1) * b] for i in range(4))
+        return set_row_word_columns(
+            rm_shard, share[4 * b :], si, slot, w, lax.bitcast_convert_type(bits, jnp.uint32)
         )
 
     return jax.jit(set_row_words_shards, donate_argnums=(0,) if donate else ())
 
 
 def sharded_set_row_words(
-    mesh: SliceMesh, row_matrix, slots, cells, values, donate: bool = False
+    mesh: SliceMesh, row_matrix, shares, n_slots: int, donate: bool = False
 ):
     """``ops.bitwise.set_row_words`` on a slice-sharded pool matrix: the
-    sparse form of a pool miss.  ``slots``, ``cells`` (int32[N, 3] of
-    (slice, slot, word)) and ``values`` are replicated; every device
+    sparse form of a pool miss.  ``shares``: int32[devices, 4 * B +
+    n_slots] sharded on the slice axis (``engine._word_shares``), so each
+    device receives its own row alone - the words of its own slices as
+    four columns of ``B`` (slice less the shard's first, slot, word,
+    value's bits; padded with -1, -1, -1, 0), then the chunk's
+    ``n_slots`` slots.  Every device
     zeroes the slots' rows in a copy of its own shard (with ``donate`` in
-    the shard itself), writes the words whose slice it holds and drops
-    the rest, the way ``sharded_set_plane_cells`` does - no communication,
-    the result born with the matrix's sharding."""
+    the shard itself) and writes its own words, B of them and not the
+    chunk's - no communication, the result born with the matrix's
+    sharding."""
     _require_divisible(row_matrix.shape[0], mesh.n_devices)
-    kernel = _sharded_set_row_words_kernel(mesh.mesh, mesh.AXIS, row_matrix.ndim, donate)
-    return kernel(row_matrix, slots, cells, values)
+    kernel = _sharded_set_row_words_kernel(
+        mesh.mesh, mesh.AXIS, row_matrix.ndim, n_slots, donate
+    )
+    return kernel(row_matrix, shares)
 
 
 @functools.lru_cache(maxsize=None)

@@ -54,13 +54,17 @@ POOL_BYTES_PER_DEVICE = 2 * 1024 * 1024 * 1024
 # 8).  Copying and donating forms of each.
 MISS_CHUNK_ROWS = 8
 # The most words a chunk may ship sparse (the largest word bucket, a power
-# of four: ``engine._pow4``); a chunk with more goes dense.  Where the two
-# forms cost the device and PCIe the same on a TPU v5e (PERF.md 7): the
-# sparse scatter into a 2 GiB pool takes 0.3 ms and 0.097 us a word (6.4 ms
-# at 65,536), a dense chunk of 8 rows at 64 slices 6.4 ms to upload and
-# write (0.096 ns a byte); the host's side only widens the gap (no 64 MiB
-# of zeros to make and fill).  Below one row of a frame as dense as
-# CENSUS1881's (78,700 set words over 64 slices): such rows page dense.
+# of four: ``engine._pow4``); a chunk with more goes dense.  One device
+# receives the whole chunk; on a mesh that splits the slice axis each
+# device receives its own slices' words alone, padded to the bucket of the
+# largest share - which may still be the whole chunk, so the buckets a
+# pool compiles stay those up to here.  Where the two forms cost the device
+# and PCIe the same on a TPU v5e (PERF.md 7): the sparse scatter into a 2 GiB
+# pool takes 0.3 ms and 0.097 us a word (6.4 ms at 65,536), a dense chunk
+# of 8 rows at 64 slices 6.4 ms to upload and write (0.096 ns a byte); the
+# host's side only widens the gap (no 64 MiB of zeros to make and fill).
+# Below one row of a frame as dense as CENSUS1881's (78,700 set words over
+# 64 slices): such rows page dense.
 MISS_WORDS_MAX = 65536
 
 
@@ -455,10 +459,13 @@ class DeviceRowPool:
         ``MISS_WORDS_MAX``: those words go to the device as (slice, slot,
         word) cells and ``uint32`` values, and the engine zeroes the
         chunk's slots and writes the words into them
-        (``engine.set_words_at``).  Dense otherwise: the same words
-        scattered into a zeroed host block, bitmap containers and
-        overlays copied in, uploaded whole (``engine.set_rows_at``).  An
-        engine that compiles gets a dense block in the power-of-two
+        (``engine.set_words_at``; where a mesh splits the pool's slice
+        axis, each device is sent its own slices' words alone: counter
+        ``rowpool.miss_chunks_split``, and ``pool.miss.scatter``'s tag
+        ``device_words`` the largest share, which sets the bucket).
+        Dense otherwise: the same words scattered into a zeroed host
+        block, bitmap containers and overlays copied in, uploaded whole
+        (``engine.set_rows_at``).  An engine that compiles gets a dense block in the power-of-two
         bucket of its rows (row -1 a zero plane, slot -1 dropped) and a
         sparse chunk's slots padded to ``MISS_CHUNK_ROWS``: one scatter
         program a bucket and form."""
@@ -510,13 +517,17 @@ class DeviceRowPool:
                 self.stats.count("rowpool.miss_chunks_dense")
             else:
                 pad = [-1] * (MISS_CHUNK_ROWS - len(into) if static else 0)
-                matrix = set_words(
+                matrix, device_words = set_words(
                     matrix, into + pad, cells, values, donate=at > 0 or in_place
                 )
                 sparse += 1
                 n_words += len(values)
                 self.stats.count("rowpool.miss_chunks_sparse")
                 self.stats.count("rowpool.miss_words", len(values))
+                if device_words is not None:
+                    self.stats.count("rowpool.miss_chunks_split")
+                    if sp is not None:
+                        sp.annotate(device_words=device_words)
             if sp is not None:
                 sp.finish()
             uploaded += bucket
@@ -579,8 +590,9 @@ class DeviceRowPool:
         walks: ``core.columns``) and one numpy pass give the chunk's words
         that are not zero - a span ``pool.miss.fetch`` a chunk, tag
         ``snapshot``: the fragments the columns served - and the engine
-        enqueues their upload (16 bytes a word) and the program that
-        zeroes the chunk's slots and writes the words into them
+        enqueues their upload (16 bytes a word; on a mesh each device's
+        own share, tag ``device_words``) and the program that zeroes the
+        chunk's slots and writes the words into them
         (``pool.miss.scatter``); a chunk the word list cannot hold (a
         bitmap container, a bulk overlay, over ``MISS_WORDS_MAX`` words)
         goes up as a dense block instead, padded to the power-of-two

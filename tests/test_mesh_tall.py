@@ -122,9 +122,10 @@ def mesh_of_four():
 @pytest.fixture(scope="module")
 def served(mesh_of_four, tmp_path_factory):
     """The server on the mesh over the seeded frame, loaded through
-    ``ingest``; the reference; and the word buckets the pool's sparse
-    scatters were padded to."""
-    from pilosa_tpu.engine import MeshEngine
+    ``ingest``; the reference; and, for each of the pool's sparse
+    scatters, the word bucket of its chunk and that of its largest
+    device share, which the program ran at."""
+    from pilosa_tpu.engine import MeshEngine, _pow4
     from pilosa_tpu.server.client import Client
     from pilosa_tpu.server.server import Server
 
@@ -132,8 +133,10 @@ def served(mesh_of_four, tmp_path_factory):
     scatter = MeshEngine._scatter_words
 
     def noted(self, matrix, slots, cells, values, axis, donate):
-        buckets.append(len(values))
-        return scatter(self, matrix, slots, cells, values, axis, donate)
+        out, device_words = scatter(self, matrix, slots, cells, values, axis, donate)
+        if (slots >= 0).any():                   # a miss's, not the warm-up's
+            buckets.append((_pow4(len(values)), _pow4(device_words)))
+        return out, device_words
 
     mesh_of_four.setattr(MeshEngine, "_scatter_words", noted)
     rows, cols = _frame_bits()
@@ -195,11 +198,24 @@ def test_plain_row_counts_and_all_four_ops_on_cold_rows(served):
 def test_chunks_went_sparse_at_two_word_buckets_and_dense(served):
     """The reads above paged chunks of 1-5 bits a (row, slice) (bucket 64 or
     256), chunks with a fat row (4,096) and the chunk with the bitmap
-    container (dense)."""
-    stats = _get(served["host"], "/debug/vars")
+    container (dense).  Every sparse chunk went to the four devices as
+    their own shares: a fat row's 200 words a slice make a share of two
+    slices at most 1,024, so its chunk of 4,096 ran at 1,024.  (The reads
+    above paged the fat rows in one chunk with the bitmap row, so a pair
+    body here pages them again without it; the warm-up's scatters, every
+    slot dropped, are not counted.)"""
+    host, oracle = served["host"], served["oracle"]
+    fill = [(OPS[i % 4], 2 * i, 2 * i + 1) for i in range(SLOTS // 2)]   # evicts the fat rows
+    assert _post(host, _body(fill))[0] == _want(oracle, fill)
+    fat = [(OPS[i % 4], FAT_ROWS[i % 2], 64 + i) for i in range(8)]   # a body not sent before
+    assert _post(host, _body(fat))[0] == _want(oracle, fat)
+    stats = _get(host, "/debug/vars")
     assert stats["rowpool.miss_chunks_sparse"] > 0 and stats["rowpool.miss_chunks_dense"] > 0
-    met = {b for b in served["buckets"]}
+    assert stats["rowpool.miss_chunks_split"] == stats["rowpool.miss_chunks_sparse"]
+    met = {chunk for chunk, _ in served["buckets"]}
     assert 4096 in met and met & {16, 64, 256}, sorted(met)
+    assert all(share <= chunk for chunk, share in served["buckets"])
+    assert {share for chunk, share in served["buckets"] if chunk == 4096} == {1024}
 
 
 def test_a_sampled_read_on_the_mesh_says_where_it_waited(served):
@@ -241,6 +257,83 @@ def test_a_sharded_miss_no_reader_holds_writes_the_pool_in_place(served):
     stats = _get(host, "/debug/vars")
     assert stats["rowpool.miss_in_place"] == stats0["rowpool.miss_in_place"] + 1
     assert stats.get("rowpool.miss_copied", 0) == 0
+
+
+# -- a sparse miss's words, device by device ------------------------------------
+
+SHARES = {   # words a device's slices hold in the chunk: the largest share sets the bucket
+    "all_on_one_device": (0, 0, 37, 0),
+    "a_device_with_none": (9, 40, 0, 3),
+    "a_bucket_tail": (5, 16, 17, 1),
+}
+
+
+@pytest.mark.parametrize("donate", [False, True], ids=["copied", "in_place"])
+@pytest.mark.parametrize("case", list(SHARES))
+def test_a_sparse_miss_sends_each_device_its_own_slices_words(case, donate):
+    """``set_words_at`` on a pool of 8 slices over four devices: the words
+    cross as one array sharded on the slice axis, each device's row its
+    own slices' words alone (their slices local), padded to the ``_pow4``
+    bucket of the largest share, then the chunk's slots; the pool that
+    comes back is, bit for bit, what the numpy engine writes, the slots'
+    old rows gone."""
+    from pilosa_tpu.engine import MeshEngine, NumpyEngine, _pow4
+    from pilosa_tpu.parallel import sharded
+
+    engine = MeshEngine(devices=jax.devices()[:DEVICES])
+    host = _host_matrix(seed=5, s=N_SLICES, r=16, w=1024)
+    rng = np.random.default_rng(sorted(SHARES).index(case))
+    per = N_SLICES // DEVICES
+    cells = []
+    for d, n in enumerate(SHARES[case]):
+        si = d * per + rng.integers(0, per, size=n)
+        slot = rng.choice([2, 5, 11], size=n)
+        cells.append(np.stack([si, slot, np.zeros(n, dtype=np.int64)], axis=1))
+    cells = np.concatenate(cells)
+    for key in {(a, b) for a, b, _ in cells}:        # distinct words in each (slice, slot)
+        at = np.flatnonzero((cells[:, 0] == key[0]) & (cells[:, 1] == key[1]))
+        cells[at, 2] = rng.choice(1024, size=len(at), replace=False)
+    order = rng.permutation(len(cells))              # not in slice order
+    cells = cells[order].astype(np.int32)
+    values = rng.integers(1, 1 << 32, size=len(cells), dtype=np.uint64).astype(np.uint32)
+    slots = np.array([2, 5, 11, -1, -1, -1, -1, -1], dtype=np.int32)
+
+    seen = []
+    real = sharded.sharded_set_row_words
+
+    def noted(mesh, row_matrix, shares, n_slots, donate=False):
+        seen.append((shares, n_slots))
+        return real(mesh, row_matrix, shares, n_slots, donate)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sharded, "sharded_set_row_words", noted)
+        matrix = engine.matrix(host)
+        up0 = engine.stat_upload_bytes
+        out, device_words = engine.set_words_at(matrix, slots, cells, values, donate=donate)
+    want, _ = NumpyEngine().set_words_at(host.copy(), slots[slots >= 0], cells, values)
+    got = np.asarray(out).reshape(want.shape)
+    assert np.array_equal(got, want)
+    assert matrix.is_deleted() == donate
+    assert len(out.sharding.device_set) == DEVICES
+
+    bucket = _pow4(max(SHARES[case]))
+    assert device_words == max(SHARES[case])
+    ((shares, n_slots),) = seen
+    assert n_slots == len(slots) and shares.shape == (DEVICES, 4 * bucket + len(slots))
+    assert engine.stat_upload_bytes - up0 == shares.size * 4   # each word once, not once a device
+    by_device = {s.device: np.asarray(s.data) for s in shares.addressable_shards}
+    for d, dev in enumerate(engine.mesh.mesh.devices.flat):
+        row = by_device[dev]
+        assert row.shape == (1, 4 * bucket + len(slots))     # this device's row alone
+        assert row[0, 4 * bucket :].tolist() == slots.tolist()
+        si, slot, word, bits = row[0, : 4 * bucket].reshape(4, bucket)
+        n = SHARES[case][d]
+        mine = (cells[:, 0] // per) == d
+        assert (si[:n] == cells[mine, 0] - d * per).all()
+        assert (slot[:n] == cells[mine, 1]).all() and (word[:n] == cells[mine, 2]).all()
+        assert (bits[:n].view(np.uint32) == values[mine]).all()
+        tail = np.stack([si[n:], slot[n:], word[n:]])       # the bucket's tail
+        assert (tail == -1).all() and (bits[n:] == 0).all()
 
 
 def _get(host, path):

@@ -671,11 +671,14 @@ def test_miss_counts_compile_one_program_a_bucket(tmp_path, monkeypatch, kind):
 
     def programs():
         if kind == "mesh":
+            from pilosa_tpu import rowpool
             from pilosa_tpu.parallel import sharded
 
-            return sum(k(engine.mesh.mesh, engine.mesh.AXIS, 4, d)._cache_size()
-                       for k in (sharded._sharded_set_rows_kernel,
-                                 sharded._sharded_set_row_words_kernel) for d in (False, True))
+            slots = (rowpool.MISS_CHUNK_ROWS,)
+            return sum(k(engine.mesh.mesh, engine.mesh.AXIS, 4, *a, d)._cache_size()
+                       for k, a in ((sharded._sharded_set_rows_kernel, ()),
+                                    (sharded._sharded_set_row_words_kernel, slots))
+                       for d in (False, True))
         from pilosa_tpu.ops.bitwise import set_row_words, set_rows
 
         return sum(jax.jit(f, static_argnames="axis", donate_argnums=d)._cache_size()
@@ -809,6 +812,18 @@ def _miss_spans(root):
     return [c for c in root.children if c.name == "pool.miss"]
 
 
+def _sparse_upload(kind, row_major, words):
+    """Bytes a sparse chunk of ``words`` words (the same count in every
+    slice) hands the devices: 16 a word of its bucket on one device; on a
+    mesh of four that splits the slice axis, each device's row of 8 slots
+    and its own quarter of the words, 16 a word of that share's bucket."""
+    from pilosa_tpu.engine import _pow4
+
+    if kind == "mesh" and not row_major:
+        return 4 * (4 * 8 + 16 * _pow4(words // 4))
+    return 16 * _pow4(words)
+
+
 @pytest.mark.parametrize("kind,row_major", [
     ("numpy", False), ("jax", False), ("jax", True), ("mesh", False), ("mesh", True)],
     ids=["numpy", "jax", "jax_row_major", "mesh", "mesh_row_major"])    # numpy: no row-major pool
@@ -818,9 +833,9 @@ def test_rows_paged_in_sparse_equal_row_dense(tmp_path, monkeypatch, kind, row_m
     slots' tail, the word bucket's tail), an absent row, and slots that
     held other rows before (their bits must be gone).  The counters and
     the span say that every chunk went sparse, how many words, and how
-    many bytes were handed to the device: 16 a word of the bucket."""
-    from pilosa_tpu.engine import _pow4
-
+    many bytes were handed to the devices: 16 a word of the bucket, on a
+    mesh that splits the slice axis each device's share alone (and the
+    scatter span says the largest share)."""
     n_slices = 4 if kind == "mesh" else 2
     h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, kind)
     pool = _paged_pool(ex, kind, n_slices, row_major)
@@ -844,8 +859,14 @@ def test_rows_paged_in_sparse_equal_row_dense(tmp_path, monkeypatch, kind, row_m
     assert (fill.tags["sparse"], fill.tags["words"]) == (2, words[0] + words[1])
     assert (miss.tags["sparse"], miss.tags["words"], miss.tags["bucket"]) == (1, words[2], 3 if kind == "numpy" else 4)
     compiled = kind != "numpy"                           # the numpy engine counts no upload
-    assert fill.tags["upload_bytes"] == (16 * (_pow4(words[0]) + _pow4(words[1])) if compiled else 0)
-    assert miss.tags["upload_bytes"] == (16 * _pow4(words[2]) if compiled else 0)
+    sent = [_sparse_upload(kind, row_major, w) if compiled else 0 for w in words]
+    assert fill.tags["upload_bytes"] == sent[0] + sent[1]
+    assert miss.tags["upload_bytes"] == sent[2]
+    split = kind == "mesh" and not row_major             # each device its own slice's words
+    assert _counter(stats, "rowpool.miss_chunks_split") == (3 if split else 0)
+    scatters = [c for m in (fill, miss) for c in m.children if c.name == "pool.miss.scatter"]
+    assert [c.tags.get("device_words") for c in scatters] == (
+        [w // n_slices for w in words] if split else [None] * 3)
     h.close()
 
 
@@ -857,7 +878,6 @@ def test_a_chunk_the_word_list_cannot_hold_goes_dense(tmp_path, monkeypatch, kin
     words than the largest bucket, and the chunk goes up as the dense
     block; the chunk beside it stays sparse; both equal ``row_dense``."""
     from pilosa_tpu import rowpool
-    from pilosa_tpu.engine import _pow4
 
     n_slices = 4 if kind == "mesh" else 2
     h, ex, cols_of, stats = _tall_frame(tmp_path, monkeypatch, kind)
@@ -887,7 +907,7 @@ def test_a_chunk_the_word_list_cannot_hold_goes_dense(tmp_path, monkeypatch, kin
     assert _counter(stats, "rowpool.miss_words") == fill.tags["words"]
     if kind != "numpy":       # the dense chunk's 8 rows of planes, and the other's words
         assert fill.tags["upload_bytes"] == (
-            8 * n_slices * PLANE_WORDS * 4 + 16 * _pow4(fill.tags["words"]))
+            8 * n_slices * PLANE_WORDS * 4 + _sparse_upload(kind, False, fill.tags["words"]))
     h.close()
 
 

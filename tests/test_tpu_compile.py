@@ -423,11 +423,12 @@ def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, m
     if program == "pair_gram":
         lowered = sharded._sharded_pair_gram_kernel(slice_mesh, "slice", 4).lower(rm)
     elif program.startswith("set_row_words"):   # a miss's chunk of 8 rows as its words
+        # each device is sent its 8 slots and its own quarter of the words
+        # (engine._word_shares): (slice, slot, word, value) a word
         n_words = int(program.split("_")[3])
         lowered = sharded._sharded_set_row_words_kernel(
-            slice_mesh, "slice", 4, program.endswith("donated")).lower(
-            rm, on((None,), (8,), "int32"), on((None, None), (n_words, 3), "int32"),
-            on((None,), (n_words,)))
+            slice_mesh, "slice", 4, 8, program.endswith("donated")).lower(
+            rm, on(("slice", None), (4, 4 * (n_words // 4) + 8), "int32"))
     elif program.startswith("set_rows"):   # the fill's paging: a miss's chunk of 8 rows
         k = 8
         lowered = sharded._sharded_set_rows_kernel(
@@ -456,6 +457,9 @@ def test_mesh256_pool_programs_stay_on_their_shards(program, topo, slice_mesh, m
         assert ("pool.set_rows" if program.startswith("set_row") else "pool.set_plane_rows") in text
         if program.startswith("set_row_words"):
             assert mem.temp_size_in_bytes < 2**20 and "collective-permute" not in text
+            # a quarter of the chunk's 16 bytes a word reaches a device, not all
+            # of them: its row of slots and words, padded to 128 lanes
+            assert mem.argument_size_in_bytes - shard <= 16 * n_words // 4 + 4 * 128
         if program.startswith("set_row"):   # a miss no reader's hold stops writes in place
             assert _pool_copies(compiled) == (0 if program.endswith("donated") else 1)
     else:
